@@ -1,0 +1,515 @@
+"""Prove that the two hot paths start on the chip, through the entry points
+a user calls: ``LLMEngine`` serves a handful of requests and
+``init_state`` + ``make_train_step`` take a few optimizer steps, both at
+LLaMA-2-7B widths (hidden 4096, 32 heads of 128, MLP 11008, vocab 32000)
+cut in depth only, with random weights made from ``--seed``.
+
+    python chip_smoke.py              # one TPU chip; anything else fails
+    python chip_smoke.py --chips 4    # ONLY the cross-chip legs (run by hand)
+    python chip_smoke.py --tiny       # CPU rehearsal of the control flow
+
+One process, no children, no network, no git. Every phase fails the run on
+any error. Earlier lines are one JSON object each; the last line of a
+passing run on the chip is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+``--tiny`` never prints that line. Timings here are smoke timings, not a
+benchmark.
+
+Depths. One v5e chip has 16 GB. Serving at depth 8 holds 3.76 GB of bf16
+weights and a 4.29 GB K/V pool (2048 blocks of 16 positions, 8 slots of
+4096 positions): 8.06 GB of arguments and 0.55 GB of temporaries by
+``memory_analysis()`` of the decode tick compiled for a described v5e.
+Training at depth 2 holds 9.34 GB of state (bf16 parameters, fp32 master
+weights and both Adam moments, 16 bytes a parameter) and 0.90 GB of
+temporaries at batch 2 x 2048 under full remat. The run prints what
+``memory_stats()`` says the chip really held.
+"""
+import argparse
+import gc
+import json
+import os
+import sys
+import time
+
+SERVE_DEPTH, TRAIN_DEPTH = 8, 2
+TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 2, 2048, 4
+
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_compile = {"seconds": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+
+def say(**kw):
+    print(json.dumps(kw), flush=True)
+
+
+def check(ok, *why):
+    """A failed check fails the run (``assert`` would vanish under -O)."""
+    if not ok:
+        raise RuntimeError("chip_smoke check failed: "
+                           + " ".join(str(w) for w in why))
+
+
+def _listen():
+    """Sum what JAX itself reports it spent tracing, lowering and compiling
+    (or fetching from the persistent cache), and count cache hits."""
+    import jax.monitoring as mon
+
+    def on_duration(event, seconds, **_):
+        if event in _COMPILE_EVENTS:
+            _compile["seconds"] += seconds
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            _compile["cache_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            _compile["cache_misses"] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)
+    mon.register_event_listener(on_event)
+
+
+class Phase:
+    """Wall, compile and run seconds of one phase, printed when it ends."""
+
+    def __init__(self, name):
+        self.name, self.extra = name, {}
+
+    def __enter__(self):
+        self.t0, self.c0 = time.perf_counter(), dict(_compile)
+        return self.extra
+
+    def __exit__(self, exc_type, *_):
+        if exc_type is None:
+            wall = time.perf_counter() - self.t0
+            comp = _compile["seconds"] - self.c0["seconds"]
+            say(phase=self.name, wall_s=round(wall, 3),
+                compile_s=round(comp, 3), run_s=round(wall - comp, 3),
+                cache_hits=_compile["cache_hits"] - self.c0["cache_hits"],
+                cache_misses=(_compile["cache_misses"]
+                              - self.c0["cache_misses"]),
+                note="smoke timing, not a benchmark", **self.extra)
+
+
+def mem(dev):
+    st = dev.memory_stats() or {}
+    return {k: st.get(k) for k in
+            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+
+
+def cache_entries(path):
+    return len(os.listdir(path)) if os.path.isdir(path) else 0
+
+
+def free_device():
+    import jax
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+
+
+# ------------------------------------------------------------------ phases
+def check_block_until_ready(tiny):
+    """Does ``block_until_ready`` wait for the device? A chain of matmuls
+    is timed three ways: dispatch only, until ``block_until_ready``
+    returns, and a host fetch after that. If it waits, the fetch finds
+    the work done and costs nothing next to the chain."""
+    import jax
+    import jax.numpy as jnp
+    n, reps = (256, 8) if tiny else (4096, 400)
+    w = jnp.full((n, n), 1.0 / n, jnp.bfloat16)
+
+    @jax.jit
+    def chain(x):
+        for _ in range(reps // 8):
+            x = x @ w
+        return x
+
+    x = jnp.ones((n, n), jnp.bfloat16)
+    float(chain(x)[0, 0])                      # compile + warm
+    t0 = time.perf_counter()
+    y = x
+    for _ in range(8):
+        y = chain(y)
+    t_dispatch = time.perf_counter() - t0
+    y.block_until_ready()
+    t_block = time.perf_counter() - t0
+    t1 = time.perf_counter()
+    val = float(y[0, 0])
+    t_fetch = time.perf_counter() - t1
+    flops = 2.0 * n ** 3 * reps
+    waits = t_fetch < 0.25 * t_block
+    say(check="block_until_ready", matmuls=reps, n=n,
+        dispatch_s=round(t_dispatch, 5), until_block_s=round(t_block, 5),
+        fetch_after_block_s=round(t_fetch, 5),
+        chain_tflops_smoke=round(flops / t_block / 1e12, 2),
+        waits=waits, value=val)
+    if not (tiny or waits):
+        raise RuntimeError(
+            "block_until_ready returned before the device finished: "
+            f"the fetch after it took {t_fetch:.4f}s of {t_block:.4f}s")
+
+
+def llama_cfg(tiny, depth, **kw):
+    from paddle_tpu.models.llama import LlamaConfig
+    if tiny:
+        return LlamaConfig.tiny(num_hidden_layers=2,
+                                max_position_embeddings=4096, **kw)
+    return LlamaConfig.llama2_7b(num_hidden_layers=depth, **kw)
+
+
+def serve(tiny, seed, dev):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.models.llama import LlamaForCausalLM
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.serving import LLMEngine
+
+    cfg = llama_cfg(tiny, SERVE_DEPTH)
+    with Phase("serve") as out:
+        pt.seed(seed)
+        model = LlamaForCausalLM(cfg).eval()
+        engine = LLMEngine(model, num_slots=8, block_size=16,
+                           max_prompt_len=128, max_seq_len=4096, seed=seed)
+        check(engine.prefix_caching and engine.async_depth == 0)
+        rs = np.random.RandomState(seed)
+        tok = lambda n: rs.randint(1, cfg.vocab_size, n).astype(np.int32)
+        shared = tok(50)
+        # (prompt, max_new_tokens): a prompt of 300 > max_prompt_len runs
+        # chunked prefill; the second wave shares a 50-token prefix with a
+        # request of the first and is served from the radix cache: three
+        # full blocks shared, two tokens of a fourth copied on write
+        wave1 = [(tok(300), 12), (tok(57), 24),
+                 (np.concatenate([shared, tok(20)]), 16), (tok(5), 32),
+                 (tok(128), 8)]
+        wave2 = [(np.concatenate([shared, tok(13)]), 10),
+                 (np.concatenate([shared, tok(150)]), 6)]
+        pa._trace_events.clear()
+        asked = {}
+        for wave in (wave1, wave2):
+            for prompt, n_new in wave:
+                asked[engine.generate(prompt, max_new_tokens=n_new)] = (
+                    prompt, n_new)
+            engine.run()
+        reqs = engine.requests
+        for rid, (prompt, n_new) in asked.items():
+            r = reqs[rid]
+            check(r.done and len(r.tokens) == n_new,
+                  f"request {rid}: done={r.done} reason={r.finish_reason} "
+                  f"tokens={len(r.tokens)} asked={n_new}")
+        engine.assert_quiescent()
+        events = set(pa._trace_events)
+        hits = engine.mgr.cache_stats.get("token_hits", 0)
+        check(hits >= 2 * 48, f"radix cache served {hits} prompt tokens")
+
+        # reference: ONE plain forward of the same model over every request
+        # (prompt + generated tokens, teacher forcing keeps each position
+        # comparable after a near-tie), rows padded on the right, which a
+        # causal model never sees. That covers what each request went
+        # through: chunked prefill, radix reuse with copy-on-write, plain
+        # prefill and decode. A width that is no multiple of 128 keeps the
+        # reference on XLA attention, independent of the flash kernel.
+        rows = [np.concatenate([p, np.asarray(reqs[r].tokens[:-1], np.int32)])
+                for r, (p, _) in asked.items()]
+        width = -(-max(len(r) for r in rows) // 8) * 8
+        width += 8 * (width % 128 == 0)
+        ids = np.zeros((len(rows), width), np.int32)
+        for i, r in enumerate(rows):
+            ids[i, :len(r)] = r
+        logits = jax.jit(lambda m, i: m(i))(model, jnp.asarray(ids))
+        check(logits.shape == ids.shape + (cfg.vocab_size,), logits.shape)
+        # bf16 logits near 6 sit 2^-5 apart: the tolerance is 2^-5 of the
+        # row's scale, five or six such steps (three were seen on the chip)
+        rel = 2.0 ** -5 if cfg.dtype == jnp.bfloat16 else 1e-4
+        checked, mismatches, worst, scale, by_request = 0, 0, 0.0, 0.0, []
+        for i, (rid, (prompt, n_new)) in enumerate(asked.items()):
+            # greedy tokens must be the reference's argmax, or lose to it
+            # by no more than bf16 rounding of the logits
+            got = np.asarray(reqs[rid].tokens, np.int32)
+            lg = np.asarray(logits[i, len(prompt) - 1:len(prompt) - 1 + n_new],
+                            np.float32)
+            check(np.isfinite(lg).all(), f"request {rid}: logits not finite")
+            want = lg.argmax(-1)
+            at = np.arange(n_new)
+            margin = lg[at, want] - lg[at, got]
+            tol = rel * np.maximum(1.0, np.abs(lg).max(-1))
+            check((margin <= tol).all(),
+                  f"request {rid} (prompt {len(prompt)}): engine tokens "
+                  f"{got.tolist()} vs reference argmax {want.tolist()}: "
+                  f"logit margins {margin.tolist()} exceed {tol.tolist()}")
+            checked += n_new
+            mismatches += int((want != got).sum())
+            worst = max(worst, float(margin.max()))
+            scale = max(scale, float(np.abs(lg).max()))
+            by_request.append([len(prompt), n_new, int((want != got).sum()),
+                               float(margin.max())])
+        check(mismatches <= checked // 4,
+              f"{mismatches} of {checked} greedy tokens are not the "
+              "reference's argmax: near-ties cannot explain that many")
+        if not tiny:
+            check({"chunk:pallas", "decode:pallas"} <= events
+                  and not {"chunk:xla", "decode:xla"} & events, events)
+        out.update(
+            depth=cfg.num_hidden_layers, hidden=cfg.hidden_size,
+            heads=cfg.num_attention_heads, vocab=cfg.vocab_size,
+            requests=len(asked), prompt_tokens=int(sum(
+                len(p) for p, _ in asked.values())),
+            tokens_served=int(sum(n for _, n in asked.values())),
+            ticks=engine.stats["ticks"], radix_token_hits=int(hits),
+            reference_requests=len(rows), reference_positions=checked,
+            reference_argmax_mismatches=mismatches,
+            reference_max_margin=worst, reference_logit_scale=scale,
+            reference_by_request_prompt_new_mismatches_margin=by_request,
+            trace_events=sorted(events),
+            kernel_downgrades=len({"chunk:xla", "decode:xla"} & events)
+            if not tiny else None,
+            memory=mem(dev))
+    del engine, model, reqs
+    free_device()
+
+
+def make_train(tiny, seed, cfg, mesh=None):
+    """(state, step, ids, labels) built as examples/train_llama.py does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as pt
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu.models.llama import LlamaForCausalLM
+    from paddle_tpu.train import make_train_step
+    from paddle_tpu.train.step import init_state
+
+    pt.seed(seed)
+    model = LlamaForCausalLM(cfg)
+    optimizer = opt.AdamW(learning_rate=3e-4, weight_decay=0.1,
+                          grad_clip=opt.ClipGradByGlobalNorm(1.0),
+                          multi_precision=True)
+    state = init_state(model, optimizer, mesh)
+    step = make_train_step(lambda m, i, l: m.loss(i, l), optimizer, mesh)
+    batch, seq = (TRAIN_BATCH, 128) if tiny else (TRAIN_BATCH, TRAIN_SEQ)
+    ids = jnp.asarray(np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch, seq)))
+    labels = jnp.concatenate(
+        [ids[:, 1:], -100 * jnp.ones((batch, 1), ids.dtype)], axis=1)
+    if mesh is not None:
+        ids = jax.device_put(ids, mesh.batch_sharding())
+        labels = jax.device_put(labels, mesh.batch_sharding())
+    return state, step, ids, labels
+
+
+def run_steps(state, step, ids, labels, n):
+    """n optimizer steps on one repeated batch -> (state, losses, step_s).
+    Only the first step may compile: a later one that does means the state
+    the step returns is laid out differently from the state it was given."""
+    import numpy as np
+    losses, times = [], []
+    for i in range(n):
+        t0, c0 = time.perf_counter(), _compile["seconds"]
+        state, loss = step(state, ids, labels)
+        loss.block_until_ready()
+        times.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+        check(i == 0 or _compile["seconds"] == c0,
+              f"step {i + 1} compiled again "
+              f"({_compile['seconds'] - c0:.2f}s)")
+    check(np.isfinite(losses).all(), losses)
+    return state, losses, times
+
+
+def train(tiny, seed, dev):
+    cfg = llama_cfg(tiny, TRAIN_DEPTH)
+    with Phase("train") as out:
+        state, step, ids, labels = make_train(tiny, seed, cfg)
+        compiled = step.lower(state, ids, labels).compile()
+        kernels = compiled.as_text().count("tpu_custom_call")
+        ma = compiled.memory_analysis()
+        state, losses, times = run_steps(state, step, ids, labels,
+                                         TRAIN_STEPS)
+        check(losses[-1] < losses[0], losses)
+        if not tiny:
+            check(kernels > 0, "no Pallas kernel in the compiled train step")
+        out.update(
+            depth=cfg.num_hidden_layers, hidden=cfg.hidden_size,
+            batch=list(ids.shape), steps=TRAIN_STEPS, losses=losses,
+            tpu_custom_calls=kernels,
+            step_s_smoke=[round(t, 4) for t in times],
+            memory_analysis=None if ma is None else {
+                "argument_bytes": ma.argument_size_in_bytes,
+                "output_bytes": ma.output_size_in_bytes,
+                "alias_bytes": ma.alias_size_in_bytes,
+                "temp_bytes": ma.temp_size_in_bytes},
+            memory=mem(dev))
+    del state, step, compiled
+    free_device()
+
+
+def multichip(tiny, seed, n):
+    """The cross-chip legs and what they are compared with, nothing else:
+    the one-device loss from the same seed, then the same train step on
+    HybridMesh(fsdp=2, tp=2), then with ring attention over sp=4."""
+    import jax
+    import numpy as np
+
+    from paddle_tpu.distributed import HybridMesh
+
+    devs = jax.devices()[:n]
+    steps = 2
+    rtol = 2e-4 if tiny else 2e-2
+
+    def kernel_path(text):
+        """Mosaic kernels in a compiled step, and the path that means: under
+        a mesh XLA partitions, dispatchers take the XLA formulations."""
+        k = text.count("tpu_custom_call")
+        return dict(tpu_custom_calls=k,
+                    attention_and_norm_path="pallas" if k else "xla")
+
+    with Phase("one_device_reference") as out:
+        state, step, ids, labels = make_train(
+            tiny, seed, llama_cfg(tiny, TRAIN_DEPTH))
+        text = step.lower(state, ids, labels).compile().as_text()
+        state, ref, _ = run_steps(state, step, ids, labels, steps)
+        out.update(losses=ref, **kernel_path(text))
+    del state, step
+    free_device()
+
+    def leg(name, mesh, cfg, expect):
+        with Phase(name) as out, mesh:
+            state, step, ids, labels = make_train(tiny, seed, cfg, mesh)
+            text = step.lower(state, ids, labels).compile().as_text()
+            found = sorted(c for c in (
+                "all-gather", "all-reduce", "reduce-scatter",
+                "collective-permute", "all-to-all") if c in text)
+            check(set(expect) <= set(found), expect, found)
+            leaves = [l for l in jax.tree_util.tree_leaves(state.model)
+                      if hasattr(l, "sharding")]
+            on = [len(l.sharding.device_set) for l in leaves]
+            sharded = [l for l in leaves
+                       if not l.sharding.is_fully_replicated]
+            check(min(on) == n, f"a parameter sits on {min(on)} devices")
+            check(len(ids.sharding.device_set) == n, ids.sharding)
+            state, losses, times = run_steps(state, step, ids, labels, steps)
+            np.testing.assert_allclose(losses, ref, rtol=rtol, err_msg=name)
+            per_dev = [(d.memory_stats() or {}).get("bytes_in_use")
+                       for d in devs]
+            if not tiny:
+                check(all(b and b > 2 ** 28 for b in per_dev), per_dev)
+            out.update(losses=losses, reference=ref, collectives=found,
+                       parameters=len(leaves), sharded_parameters=len(sharded),
+                       bytes_in_use_per_device=per_dev,
+                       step_s_smoke=[round(t, 4) for t in times],
+                       **kernel_path(text))
+        del state, step
+        free_device()
+        return len(sharded)
+
+    sharded = leg("fsdp2_tp2", HybridMesh(fsdp=2, tp=2, devices=devs),
+                  llama_cfg(tiny, TRAIN_DEPTH), ["all-reduce"])
+    check(sharded > 0, "fsdp x tp sharded no parameter")
+    ring_kv(tiny, seed, devs)
+    leg("ring_sp4", HybridMesh(sp=n, devices=devs),
+        llama_cfg(tiny, TRAIN_DEPTH, sequence_parallel="ring"),
+        ["collective-permute"])
+
+
+def ring_kv(tiny, seed, devs):
+    """Ring attention on its own: q/k/v laid out over the sp axis must sit
+    on every device, and the result must equal plain attention."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.distributed import HybridMesh
+    from paddle_tpu.distributed.ring_attention import make_ring_attention
+    from paddle_tpu.ops.attention import xla_attention
+
+    with Phase("ring_attention_kv") as out:
+        mesh = HybridMesh(sp=len(devs), devices=devs)
+        b, s, h, d = (1, 256, 2, 16) if tiny else (1, 2048, 32, 128)
+        dt = jnp.float32 if tiny else jnp.bfloat16
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q, k, v = (jax.random.normal(kk, (b, s, h, d), dt) for kk in ks)
+        want = np.asarray(xla_attention(q, k, v, is_causal=True), np.float32)
+        lay = mesh.sharding(None, "sp", None, None)
+        q, k, v = (jax.device_put(x, lay) for x in (q, k, v))
+        with mesh:
+            got = jax.jit(make_ring_attention(mesh, causal=True))(q, k, v)
+        for x in (k, v, got):
+            shards = x.addressable_shards
+            check(len(x.sharding.device_set) == len(devs)
+                  and len({sh.device for sh in shards}) == len(devs)
+                  and shards[0].data.shape[1] == s // len(devs), x.sharding)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   atol=1e-4 if tiny else 3e-2)
+        out.update(kv_shape=[b, s, h, d], kv_devices=len(devs),
+                   kv_shard_shape=list(k.addressable_shards[0].data.shape))
+    free_device()
+
+
+# -------------------------------------------------------------------- main
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="CPU rehearsal at toy widths; never prints the "
+                         "chip's result line")
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: run only the cross-chip legs")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    import jax
+    devs = jax.devices()
+    dev = devs[0]
+    if not args.tiny and dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, found {dev.platform}",
+              file=sys.stderr)
+        return 2
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but {len(devs)} device(s)",
+              file=sys.stderr)
+        return 2
+
+    import jaxlib
+
+    from paddle_tpu.core.device import enable_compilation_cache
+    cache_dir = enable_compilation_cache()
+    before = cache_entries(cache_dir)
+    _listen()
+    try:
+        from importlib.metadata import version
+        libtpu = version("libtpu")
+    except Exception:
+        libtpu = None
+    say(jax=jax.__version__, jaxlib=jaxlib.__version__, libtpu=libtpu,
+        platform=dev.platform, device_kind=dev.device_kind,
+        devices=len(devs), tiny=args.tiny, chips=args.chips, seed=args.seed,
+        compile_cache_dir=cache_dir, cache_entries_before=before,
+        cache_warm=before > 0, memory=mem(dev))
+
+    if args.chips == 1:
+        check_block_until_ready(args.tiny)
+        serve(args.tiny, args.seed, dev)
+        train(args.tiny, args.seed, dev)
+    else:
+        multichip(args.tiny, args.seed, args.chips)
+
+    say(compile_cache_dir=cache_dir, cache_entries_before=before,
+        cache_entries_after=cache_entries(cache_dir),
+        compile_s_total=round(_compile["seconds"], 3),
+        cache_hits=_compile["cache_hits"],
+        cache_misses=_compile["cache_misses"], memory=mem(dev))
+    if args.tiny:
+        say(rehearsal="passed", tiny=True, platform=dev.platform)
+    else:
+        say(ok=True, device={"platform": dev.platform,
+                             "kind": dev.device_kind,
+                             "count": args.chips})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

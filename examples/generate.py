@@ -36,6 +36,8 @@ def main():
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--device", choices=["cpu", "tpu"], default="cpu")
     args = ap.parse_args()
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
 
     pt.seed(0)
     if args.model == "llama":

@@ -48,6 +48,8 @@ def main():
     ap.add_argument("--dp", type=int, default=2)
     ap.add_argument("--device", choices=["cpu", "tpu"], default="cpu")
     args = ap.parse_args()
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
 
     pt.seed(0)
     cfg = LlamaConfig.tiny(

@@ -15,6 +15,8 @@ from paddle_tpu.serving import LLMEngine, Request
 
 
 def main(ckpt_dir):
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
     model = auto_from_pretrained(ckpt_dir)
     prompts = [np.arange(3, 11), np.arange(5, 12), np.arange(2, 8)]
 

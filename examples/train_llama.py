@@ -26,6 +26,8 @@ def main():
     ap.add_argument("--size", default="tiny", choices=["tiny", "0.8b"])
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
 
     import paddle_tpu as pt
     import paddle_tpu.optimizer as opt

@@ -1,9 +1,9 @@
 """Hybrid-parallel LLaMA training on a device mesh (dp x fsdp x tp).
 
-Runs on real chips when available, or on a virtual CPU mesh:
+Runs on the attached chips, and fails if there are fewer than --devices.
+The CPU dry run on virtual devices is an explicit mode:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python examples/train_multichip.py --devices 8 --steps 3
+    python examples/train_multichip.py --cpu-dryrun --devices 8 --steps 3
 """
 import argparse
 import os
@@ -22,19 +22,26 @@ def main():
     ap.add_argument("--ep", type=int, default=2,
                     help="expert-parallel width for the MoE loss-equality "
                          "leg (0/1 skips it)")
+    ap.add_argument("--cpu-dryrun", action="store_true",
+                    help="run on --devices virtual CPU devices instead of "
+                         "the attached chips")
     args = ap.parse_args()
 
-    # flags must be in place BEFORE the backend initialises (first
-    # jax.devices() call) — same dance as __graft_entry__.dryrun_multichip
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-        f" --xla_force_host_platform_device_count={args.devices}"
+    if args.cpu_dryrun:
+        # both must be in place BEFORE the backend initialises (the first
+        # jax.devices() call)
+        os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
+            f" --xla_force_host_platform_device_count={args.devices}"
     import jax
-    if jax.default_backend() != "tpu" or len(jax.devices()) < args.devices:
+    if args.cpu_dryrun:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.clear_backends()
-        except Exception:
-            pass
+    if len(jax.devices()) < args.devices:
+        raise SystemExit(
+            f"train_multichip: asked for {args.devices} devices, found "
+            f"{len(jax.devices())} ({jax.default_backend()}); pass "
+            "--cpu-dryrun for a virtual CPU mesh")
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -16,6 +16,8 @@ def main():
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    from paddle_tpu.core.device import enable_compilation_cache
+    enable_compilation_cache()
 
     import paddle_tpu as pt
     import paddle_tpu.nn as nn

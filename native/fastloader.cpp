@@ -7,7 +7,9 @@
 //   * a background thread pool cuts shuffled (input, label) windows into a
 //     lock-free-ish ring of pre-touched buffers so Python never blocks on
 //     page faults or memcpy — the feed thread only hands out pointers
-//   * deterministic xorshift shuffling keyed by (seed, epoch)
+//   * deterministic sampling: batch k draws its windows from an xorshift
+//     stream keyed by (seed, k) and batches are handed out in order of k,
+//     so the stream never depends on which worker cut a batch, or when
 //
 // Exposed as a C ABI for ctypes (no pybind11 in this image).
 //
@@ -18,6 +20,7 @@
 #include <cstdint>
 #include <cstring>
 #include <fcntl.h>
+#include <map>
 #include <mutex>
 #include <queue>
 #include <sys/mman.h>
@@ -30,6 +33,7 @@ namespace {
 
 struct Batch {
   std::vector<int32_t> tokens;  // [batch, seq+1] window; caller splits x/y
+  uint64_t index = 0;           // position in the stream
 };
 
 struct Loader {
@@ -47,25 +51,29 @@ struct Loader {
 
   // prefetch ring
   size_t capacity = 8;
-  std::queue<Batch*> ready;
+  std::map<uint64_t, Batch*> ready;  // by stream position
+  uint64_t next_fill = 0, next_out = 0;
   std::queue<Batch*> free_bufs;
   std::vector<Batch*> all_bufs;
   std::mutex mu;
   std::condition_variable cv_ready, cv_free;
   std::vector<std::thread> workers;
   std::atomic<bool> stop{false};
-  std::atomic<uint64_t> cursor{0};
 
-  uint64_t rng_state;
-
-  uint64_t next_rand() {
+  static uint64_t next_rand(uint64_t& state) {
     // xorshift64* — deterministic, fast, good enough for window sampling
-    uint64_t x = rng_state;
+    uint64_t x = state;
     x ^= x >> 12;
     x ^= x << 25;
     x ^= x >> 27;
-    rng_state = x;
+    state = x;
     return x * 0x2545F4914F6CDD1DULL;
+  }
+
+  static uint64_t mix(uint64_t z) {  // splitmix64 finalizer
+    z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;
+    z = (z ^ (z >> 27)) * 0x94D049BB133111EBULL;
+    return z ^ (z >> 31);
   }
 
   int32_t token_at(size_t i) const {
@@ -83,12 +91,10 @@ struct Loader {
     const size_t window = (size_t)seq + 1;
     const size_t max_start = n_tokens - window;
     b->tokens.resize((size_t)batch * window);
+    uint64_t rng = mix(seed + (b->index + 1) * 0x9E3779B97F4A7C15ULL);
+    if (!rng) rng = 0x9E3779B97F4A7C15ULL;  // xorshift must not start at 0
     for (int r = 0; r < batch; ++r) {
-      size_t start;
-      {
-        std::lock_guard<std::mutex> lk(mu);  // rng shared: serialize draws
-        start = (size_t)(next_rand() % (max_start + 1));
-      }
+      size_t start = (size_t)(next_rand(rng) % (max_start + 1));
       for (size_t t = 0; t < window; ++t)
         b->tokens[(size_t)r * window + t] = token_at(start + t);
     }
@@ -103,11 +109,12 @@ struct Loader {
         if (stop.load()) return;
         buf = free_bufs.front();
         free_bufs.pop();
+        buf->index = next_fill++;
       }
       fill(buf);
       {
         std::lock_guard<std::mutex> lk(mu);
-        ready.push(buf);
+        ready.emplace(buf->index, buf);
       }
       cv_ready.notify_one();
     }
@@ -135,7 +142,6 @@ void* fl_open(const char* path, int token_width, int batch, int seq,
   L->batch = batch;
   L->seq = seq;
   L->seed = seed;
-  L->rng_state = seed ? seed : 0x9E3779B97F4A7C15ULL;
   L->capacity = (size_t)(prefetch > 0 ? prefetch : 8);
   if ((size_t)seq + 1 > L->n_tokens) { munmap(m, L->file_bytes); ::close(L->fd); delete L; return nullptr; }
   for (size_t i = 0; i < L->capacity; ++i) {
@@ -157,10 +163,12 @@ int fl_next(void* h, int32_t* out) {
   Batch* b = nullptr;
   {
     std::unique_lock<std::mutex> lk(L->mu);
-    L->cv_ready.wait(lk, [&] { return L->stop.load() || !L->ready.empty(); });
+    // the lowest outstanding position is always with a worker or ready
+    L->cv_ready.wait(lk, [&] {
+      return L->stop.load() || L->ready.count(L->next_out); });
     if (L->stop.load()) return -1;
-    b = L->ready.front();
-    L->ready.pop();
+    b = L->ready[L->next_out];
+    L->ready.erase(L->next_out++);
   }
   std::memcpy(out, b->tokens.data(), b->tokens.size() * sizeof(int32_t));
   {

@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from paddle_tpu.distributed._compat import axis_size
+from jax.lax import axis_size
 from paddle_tpu.observability import METRICS
 from paddle_tpu.utils.faults import fault_point
 

@@ -18,6 +18,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddle_tpu.ops import pallas
+
 AXES = ("dp", "fsdp", "pp", "tp", "sp", "ep", "cp")
 
 
@@ -82,9 +84,13 @@ class HybridMesh:
     def __enter__(self):
         self.mesh.__enter__()
         _CURRENT.append(self)
+        # tell the kernel layer what XLA partitions over while this is
+        # active: Mosaic kernels apply only where it partitions nothing
+        pallas.enter_mesh(self.mesh.shape)
         return self
 
     def __exit__(self, *exc):
+        pallas.exit_mesh()
         _CURRENT.pop()
         return self.mesh.__exit__(*exc)
 

@@ -17,7 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from paddle_tpu.distributed._compat import axis_size
+from jax.lax import axis_size
 
 _NEG_INF = -1e30
 
@@ -263,7 +263,7 @@ def make_ring_attention(mesh, causal=True, head_spec=None, window=None,
     ``bias_shape``: pass the [B|1, H|1, S, S] shape of an ADDITIVE float
     bias (T5 relative bias, ALiBi) to accept it as the last argument —
     q rows sharded over sp, head dim over ``head_spec`` when per-head."""
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(("dp", "fsdp"), "sp", head_spec, None)
@@ -409,7 +409,7 @@ def zigzag_ring_attention(q, k, v, *, axis_name: str = "sp",
 def make_zigzag_ring_attention(mesh):
     """shard_map-wrapped zigzag ring attention (inputs already in zigzag
     layout, S sharded over sp)."""
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(("dp", "fsdp"), "sp", None, None)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.distributed._compat import axis_size
+from jax.lax import axis_size
 from paddle_tpu.ops import attention as A
 
 
@@ -145,7 +145,7 @@ def make_ulysses_attention(mesh, causal: bool = True, axis_name: str = "sp",
     as the last argument. A per-head bias is sharded over (tp, sp) on the
     head dim — tp-major, sp-minor, exactly the head range device
     (tp_j, sp_i) ends up computing after the all_to_all."""
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
 
     spec = P(batch_axes, axis_name, head_spec, None)
     in_specs = [spec, spec, spec]

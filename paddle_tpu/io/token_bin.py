@@ -6,7 +6,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-from pathlib import Path
 
 import numpy as np
 
@@ -19,13 +18,8 @@ def _load_lib():
     global _LIB
     if _LIB is not None:
         return _LIB
-    root = Path(__file__).resolve().parents[2]
-    so = root / "native" / "libfastloader.so"
-    if not so.exists():  # build on demand
-        import subprocess
-        subprocess.run(["make", "-C", str(root / "native")], check=True,
-                       capture_output=True)
-    lib = ctypes.CDLL(str(so))
+    from paddle_tpu.utils.native import build_native
+    lib = ctypes.CDLL(build_native("libfastloader.so"))
     lib.fl_open.restype = ctypes.c_void_p
     lib.fl_open.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
                             ctypes.c_int, ctypes.c_uint64, ctypes.c_int, ctypes.c_int]

@@ -620,11 +620,9 @@ def make_tp_layer_call(cos, sin, tp_axis: str = "tp"):
     permuted by ``tp_shuffle_llama_params``."""
     from jax import lax as _lax
 
-    from paddle_tpu.distributed._compat import axis_size as _axis_size
-
     def call(lyr, h):
         att, mlp = lyr.self_attn, lyr.mlp
-        tp = _axis_size(tp_axis)
+        tp = _lax.axis_size(tp_axis)
         hd = att.head_dim
         nh_l = att.num_heads // tp
         nkv_l = att.num_kv_heads // tp

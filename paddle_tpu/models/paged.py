@@ -20,6 +20,7 @@ lengths (rounded up per block) — NOT batch × max_len as in the static
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 
@@ -1279,16 +1280,13 @@ _TICK_JIT = jax.jit(llama_decode_tick, static_argnums=(10, 11),
 # pipeline the tick exists to feed (dispatch would block for the full
 # tick). On CPU the extra cache copy buys a dispatch that actually
 # returns; on TPU dispatch is async regardless and donation keeps the
-# KV pool single-buffered in HBM.
-def _async_tick_donate():
-    try:
-        return () if jax.default_backend() == "cpu" else (2,)
-    except RuntimeError:         # backend init failed — donate-free is safe
-        return ()
-
-
-_ASYNC_TICK_JIT = jax.jit(llama_decode_tick_async, static_argnums=(11,),
-                          donate_argnums=_async_tick_donate())
+# KV pool single-buffered in HBM. The backend is asked at first use,
+# never at import: importing this module must not claim a device.
+@functools.cache
+def _async_tick_jit():
+    donate = () if jax.default_backend() == "cpu" else (2,)
+    return jax.jit(llama_decode_tick_async, static_argnums=(11,),
+                   donate_argnums=donate)
 
 
 # jits registered by downstream serving modules (serving/quant.py,
@@ -1303,9 +1301,11 @@ def clear_jit_caches():
     context changes under the same call signature — flipping
     ``PT_GROUPED_GEMM`` or ``PT_MULTILORA_IMPL``, or entering/leaving a
     mesh re-routes layers, but the jit caches key on shapes only."""
-    for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _ASYNC_TICK_JIT,
-              _PREFILL_CHUNK_JIT, _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT,
-              _PREFIX_COW_JIT, *_EXTRA_CLEAR):
+    if _async_tick_jit.cache_info().currsize:   # built: backend exists
+        _async_tick_jit().clear_cache()
+    for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _PREFILL_CHUNK_JIT,
+              _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT, _PREFIX_COW_JIT,
+              *_EXTRA_CLEAR):
         f.clear_cache()
 
 

@@ -2,18 +2,14 @@
 Trainer, and ``utils.profiler.StepTimer`` all read (ISSUE 2 satellite:
 bench.py used to carry its own table and recompute MFU ad hoc).
 
-Import-light on purpose: bench.py's orchestrator process must never pull
-in jax, so nothing at this module's top level may import jax (or the
-``paddle_tpu`` root package — this file is reached via
-``paddle_tpu.observability.flops`` only from contexts that already paid
-that import, or standalone through sys.modules tricks bench does not
-need: ``from paddle_tpu.observability import flops`` inside the worker).
+Nothing at this module's top level imports jax.
 """
 from __future__ import annotations
 
 from paddle_tpu.observability.metrics import METRICS
 
-__all__ = ["PEAK_BF16", "chip_peak_flops", "mfu", "record_throughput"]
+__all__ = ["PEAK_BF16", "chip_peak", "chip_peak_flops", "mfu",
+           "record_throughput"]
 
 # Peak dense bf16 FLOP/s per chip, by device_kind prefix. (The serving
 # and training MFU numbers, bench.py's vs_baseline, and the profiler's
@@ -27,28 +23,32 @@ PEAK_BF16 = {
 }
 
 
-def chip_peak_flops(dev=None, kind: str = None) -> float:
-    """Peak bf16 FLOP/s for a jax device (or an explicit ``device_kind``
-    string). Unknown TPU kinds assume v5e-class; non-TPU backends (cpu
-    debugging runs) return 0.0 — callers treat 0 peak as "MFU undefined"
-    rather than dividing by a made-up number. An EMPTY kind earns the
-    v5e assumption only when the platform says ``tpu``: a mock/unknown
-    device with neither attribute must read 0.0, not a fabricated peak
-    (ISSUE 12 satellite)."""
+def chip_peak(table: dict, dev=None, kind: str = None) -> float:
+    """Look a jax device (or an explicit ``device_kind`` string) up in a
+    per-chip peak table. Non-TPU backends (cpu debugging runs) and
+    devices with no evidence of being a TPU return 0.0 — callers treat 0
+    peak as "utilisation undefined" rather than dividing by a made-up
+    number. A TPU whose kind is not in the table raises: a peak is a
+    published figure, never a default."""
     platform = None
     if kind is None:
         kind = getattr(dev, "device_kind", "") or ""
         platform = getattr(dev, "platform", "") or ""
         if platform and platform != "tpu":
             return 0.0
-    for k, v in PEAK_BF16.items():
+    for k, v in table.items():
         if kind.startswith(k) or k in kind:
             return v
-    if "TPU" in kind.upper():
-        return 197e12          # some TPU, kind string unrecognised
-    if kind == "" and platform == "tpu":
-        return 197e12          # TPU platform, no kind string exposed
+    if "TPU" in kind.upper() or platform == "tpu":
+        raise ValueError(
+            f"no published peak for TPU device kind {kind!r}; add it to "
+            f"the table (known: {sorted(table)})")
     return 0.0
+
+
+def chip_peak_flops(dev=None, kind: str = None) -> float:
+    """Peak bf16 FLOP/s — see :func:`chip_peak`."""
+    return chip_peak(PEAK_BF16, dev, kind)
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,

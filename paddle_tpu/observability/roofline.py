@@ -48,7 +48,8 @@ import threading
 from dataclasses import dataclass, asdict
 
 from paddle_tpu.observability.metrics import METRICS
-from paddle_tpu.observability.flops import PEAK_BF16, chip_peak_flops
+from paddle_tpu.observability.flops import (PEAK_BF16, chip_peak,
+                                            chip_peak_flops)
 
 __all__ = ["PEAK_HBM_BPS", "chip_peak_hbm_bw", "resolve_serving_peaks",
            "ModelGeometry", "weight_bytes", "kv_bytes_per_position",
@@ -74,22 +75,9 @@ assert set(PEAK_HBM_BPS) == set(PEAK_BF16), \
 def chip_peak_hbm_bw(dev=None, kind: str = None) -> float:
     """Peak HBM bytes/sec for a jax device (or an explicit
     ``device_kind`` string). Same convention as ``chip_peak_flops``:
-    unknown TPU kinds assume v5e-class, anything that is not known to be
-    a TPU returns 0.0 — callers treat 0 peak as "MBU undefined"."""
-    platform = None
-    if kind is None:
-        kind = getattr(dev, "device_kind", "") or ""
-        platform = getattr(dev, "platform", "") or ""
-        if platform and platform != "tpu":
-            return 0.0
-    for k, v in PEAK_HBM_BPS.items():
-        if kind.startswith(k) or k in kind:
-            return v
-    if "TPU" in kind.upper():
-        return PEAK_HBM_BPS["TPU v5e"]
-    if kind == "" and platform == "tpu":
-        return PEAK_HBM_BPS["TPU v5e"]
-    return 0.0
+    an unknown TPU kind raises, anything that is not known to be a TPU
+    returns 0.0 — callers treat 0 peak as "MBU undefined"."""
+    return chip_peak(PEAK_HBM_BPS, dev, kind)
 
 
 def resolve_serving_peaks(dev=None) -> tuple:

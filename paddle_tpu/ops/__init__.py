@@ -24,12 +24,11 @@ from paddle_tpu.ops.attention import (
 
 def fused_rms_norm(x, weight=None, epsilon=1e-6):
     """Dispatch: Pallas kernel on TPU for long rows, else jnp (XLA fuses it)."""
-    if jax.default_backend() == "tpu" and x.shape[-1] % 128 == 0 and x.shape[-1] >= 512:
-        try:
-            from paddle_tpu.ops.pallas.norms import rms_norm as pallas_rms
-            return pallas_rms(x, weight, epsilon)
-        except Exception:
-            pass
+    from paddle_tpu.ops.pallas import mosaic_kernels_apply
+    if (weight is not None and x.shape[-1] % 128 == 0
+            and x.shape[-1] >= 512 and mosaic_kernels_apply()):
+        from paddle_tpu.ops.pallas.norms import rms_norm as pallas_rms
+        return pallas_rms(x, weight, epsilon)
     from paddle_tpu.nn.functional import rms_norm
     return rms_norm(x, weight, epsilon)
 

@@ -21,7 +21,8 @@ def _use_pallas(q) -> bool:
     import os
     if os.environ.get("PADDLE_TPU_DISABLE_FLASH", "").lower() in ("1", "true", "yes"):
         return False  # escape hatch: force the XLA attention path
-    if jax.default_backend() != "tpu":
+    from paddle_tpu.ops.pallas import mosaic_kernels_apply
+    if not mosaic_kernels_apply():
         return False
     head_dim = q.shape[-1]
     seq = q.shape[1]
@@ -117,16 +118,18 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     h, kv = query.shape[2], key.shape[2]
     if (attn_mask is None and (dropout_p == 0.0 or not training)
             and _use_pallas(query)
-            and h % kv == 0 and (window is None or is_causal)):
-        try:
-            from paddle_tpu.ops.pallas.flash_attention import flash_attention
-            # GQA handled inside the kernel (kv row = q row // rep) — no
-            # materialised K/V repeat
-            return flash_attention(query, key, value, causal=is_causal, scale=scale,
-                                   window=window, kv_lens=kv_lens,
-                                   alibi_slopes=alibi_slopes)
-        except Exception:
-            pass
+            and h % kv == 0 and (window is None or is_causal)
+            # windowed decode against a padded cache: the banded grid
+            # refuses it, so it is not the kernel's shape
+            and not (window is not None and kv_lens is not None
+                     and query.shape[1] != key.shape[1])):
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention
+        # GQA handled inside the kernel (kv row = q row // rep) — no
+        # materialised K/V repeat. A kernel that raises is an error, not
+        # a reason to take the XLA path.
+        return flash_attention(query, key, value, causal=is_causal, scale=scale,
+                               window=window, kv_lens=kv_lens,
+                               alibi_slopes=alibi_slopes)
     return xla_attention(query, key, value, attn_mask=attn_mask, is_causal=is_causal,
                          scale=scale, dropout_p=dropout_p, training=training, rng=rng,
                          window=window, kv_lens=kv_lens,

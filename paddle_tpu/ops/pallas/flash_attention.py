@@ -45,10 +45,7 @@ _float0 = jax.dtypes.float0
 # w=1024, dispatch floor subtracted): full causal 3.25ms -> 0.92ms, banded
 # 2.12ms -> 0.77ms — and only WITH this declared does the banded O(S*W)
 # grid actually beat full causal on-chip (r3 finding: 6.5x slower without).
-# CompilerParams was TPUCompilerParams before the pallas API rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_GRID_SEMANTICS = _CompilerParams(
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY))
 
 

@@ -57,16 +57,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import mosaic_kernels_apply
+
 __all__ = ["grouped_matmul", "grouped_matmul_reference", "grouped_gemm_enabled"]
 
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_K = 512
 _float0 = jax.dtypes.float0
-
-# CompilerParams was TPUCompilerParams before the pallas API rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 
 def grouped_gemm_enabled() -> bool:
@@ -168,7 +166,7 @@ def _pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret):
                       pl.BlockSpec((1, k, bn), wmap)],
             out_specs=pl.BlockSpec((bm, bn), omap)),
         out_shape=jax.ShapeDtypeStruct((w * bm, n), lhs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
     )(gid, total.reshape(1), xp, rhs)
@@ -225,7 +223,7 @@ def _pallas_dw(lhs, g, group_sizes, block_m, block_n, block_k, interpret):
                       pl.BlockSpec((bm, bn), gmap)],
             out_specs=pl.BlockSpec((1, bk, bn), omap)),
         out_shape=jax.ShapeDtypeStruct((e, k, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
@@ -283,7 +281,7 @@ def grouped_matmul(lhs, rhs, group_sizes, *, block_m=DEFAULT_BLOCK_M,
     if not grouped_gemm_enabled():
         impl = "dense"
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+        impl = "pallas" if mosaic_kernels_apply() else "xla"
     if impl == "dense":
         return grouped_matmul_reference(lhs, rhs, group_sizes)
     if impl == "xla":

@@ -32,13 +32,24 @@ def _rows(x):
     return r
 
 
+def _block_rows(rows, h, itemsize):
+    """Rows per tile: at most 256, and few enough that the in and out
+    tiles (double-buffered) plus two f32 temporaries fit the 16 MiB of
+    scoped VMEM. Rows are independent, so a ragged last tile is harmless:
+    what it reads past the end is never written back."""
+    block = min(256, (16 << 20) // (h * (4 * itemsize + 8)) // 8 * 8)
+    if block < 8:
+        raise ValueError(f"rms_norm: a row of {h} does not fit a VMEM tile")
+    return rows if rows <= block else block
+
+
 def _rms_fwd(x, weight, epsilon, interpret):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     h = x.shape[-1]
     x2 = x.reshape(_rows(x), h)
     rows = x2.shape[0]
-    block = min(256, rows) if rows % min(256, rows) == 0 else rows
+    block = _block_rows(rows, h, x.dtype.itemsize)
     out = pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=epsilon),
         grid=(pl.cdiv(rows, block),),

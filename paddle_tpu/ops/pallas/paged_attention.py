@@ -27,9 +27,8 @@ it, the compute is masked off by the length scalars.
 
 Dispatch functions (``paged_decode_attention`` /
 ``paged_chunk_attention``) pick Pallas on TPU and the XLA gather
-reference elsewhere. A Pallas trace/lower failure is cached per process
-(one ``warnings.warn`` + a ``serving_pallas_fallback_total{kernel}``
-increment — NOT retried every call), and ``PT_PAGED_CHUNK=0`` force-kills
+reference elsewhere. On TPU a kernel that fails to trace or lower raises:
+there is no downgrade to the gather path. ``PT_PAGED_CHUNK=0`` force-kills
 the chunk kernel (``=interpret`` forces the interpreted kernel off-TPU,
 the engine-level parity mode).
 """
@@ -37,33 +36,15 @@ from __future__ import annotations
 
 import functools
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.observability.metrics import METRICS
-
-# CompilerParams was TPUCompilerParams before the pallas API rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from paddle_tpu.ops.pallas import mosaic_kernels_apply
 
 _NEG_INF = -1e30
-
-_PALLAS_FALLBACK = METRICS.counter(
-    "serving_pallas_fallback_total",
-    "paged-attention Pallas kernels that failed to trace/lower and were "
-    "replaced by the XLA gather path for the rest of the process, by "
-    "kernel (decode/chunk)",
-    labelnames=("kernel",))
-
-# kernel -> first failure, recorded by the dispatch functions: once a
-# kernel fails to trace/lower on this process it is NOT retried on every
-# call (the old bare ``except: pass`` re-paid the trace failure per
-# dispatch and hid the downgrade entirely)
-_pallas_disabled: dict[str, str] = {}
 
 # trace-time breadcrumbs ("chunk:xla-forced", "chunk:pallas", ...): one
 # entry per DISPATCH TRACE, so tests can assert which implementation a
@@ -76,15 +57,6 @@ def _note_trace(event: str):
     if len(_trace_events) >= 512:
         del _trace_events[:256]
     _trace_events.append(event)
-
-
-def _disable_pallas(kernel: str, err: Exception):
-    _pallas_disabled[kernel] = f"{type(err).__name__}: {err}"
-    _PALLAS_FALLBACK.inc(kernel=kernel)
-    warnings.warn(
-        f"paged {kernel} attention: Pallas kernel failed to trace/lower "
-        f"({type(err).__name__}: {err}); using the XLA gather path for "
-        "the rest of the process", RuntimeWarning, stacklevel=3)
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
@@ -262,7 +234,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lens, *,
         # (sequence-head, block) grid: rows are independent; declaring the
         # row axis parallel lets Mosaic pipeline pool-block DMAs across rows
         # (measured 3.5x on the flash grids — benchmarks/_perf_banded.py)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
     )(tables_bh, lens_bh, *operands)
@@ -337,22 +309,21 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
     paths. ``partials=True`` (context parallelism) returns the raw
     (acc, m, l) online-softmax triple over OWNED table entries only
     (< N; non-owned entries hold the OOB sentinel) — the caller merges
-    across shards. A Pallas failure downgrades this process to the XLA
-    path permanently (cached, warned, counted — see ``_disable_pallas``)."""
+    across shards. On TPU a Pallas failure raises."""
     if k_scale is not None:
         # breadcrumb ONLY on the quantized branch, so bf16 traces stay
         # byte-identical to pre-quantization builds
         _note_trace("decode:int8-kv")
     if partials:
         _note_trace("decode:partials")
-    if jax.default_backend() == "tpu" and "decode" not in _pallas_disabled:
-        try:
-            return paged_decode_attention_pallas(
-                q, k_pool, v_pool, block_tables, lens, scale=scale,
-                window=window, k_scale=k_scale, v_scale=v_scale,
-                partials=partials, interpret=interpret)
-        except Exception as e:
-            _disable_pallas("decode", e)
+    if mosaic_kernels_apply():
+        out = paged_decode_attention_pallas(
+            q, k_pool, v_pool, block_tables, lens, scale=scale,
+            window=window, k_scale=k_scale, v_scale=v_scale,
+            partials=partials, interpret=interpret)
+        _note_trace("decode:pallas")
+        return out
+    _note_trace("decode:xla")
     return paged_decode_attention_xla(q, k_pool, v_pool, block_tables, lens,
                                       scale=scale, window=window,
                                       k_scale=k_scale, v_scale=v_scale,
@@ -584,7 +555,7 @@ def paged_chunk_attention_pallas(q, k_pool, v_pool, block_tables, offsets,
         out_shape=out_shape,
         # rows and q tiles are independent; only the kv-block axis carries
         # the online-softmax state
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
@@ -669,8 +640,7 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, offsets,
 
     ``k_scale``/``v_scale`` [N, bs, H_kv] f32 mark an int8 pool —
     dequantize-on-read in every implementation. Like the decode
-    dispatch, a Pallas failure downgrades the process permanently
-    (cached + warned + counted, never silently retried)."""
+    dispatch, a Pallas failure on TPU raises."""
     if k_scale is not None:
         _note_trace("chunk:int8-kv")
     if partials:
@@ -688,16 +658,13 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, offsets,
             q, k_pool, v_pool, block_tables, offsets, chunk_lens,
             scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
             partials=partials, interpret=True)
-    if jax.default_backend() == "tpu" and "chunk" not in _pallas_disabled:
-        try:
-            out = paged_chunk_attention_pallas(
-                q, k_pool, v_pool, block_tables, offsets, chunk_lens,
-                scale=scale, window=window, k_scale=k_scale,
-                v_scale=v_scale, partials=partials, interpret=interpret)
-            _note_trace("chunk:pallas")
-            return out
-        except Exception as e:
-            _disable_pallas("chunk", e)
+    if mosaic_kernels_apply():
+        out = paged_chunk_attention_pallas(
+            q, k_pool, v_pool, block_tables, offsets, chunk_lens,
+            scale=scale, window=window, k_scale=k_scale,
+            v_scale=v_scale, partials=partials, interpret=interpret)
+        _note_trace("chunk:pallas")
+        return out
     _note_trace("chunk:xla")
     return paged_chunk_attention_xla(
         q, k_pool, v_pool, block_tables, offsets, chunk_lens,

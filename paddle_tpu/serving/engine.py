@@ -350,11 +350,8 @@ class LLMEngine:
         self._geom = _geom(model, self.exe.cache)
         self._draft_geom = _geom(draft_model) if draft_model is not None \
             else None
-        try:
-            dev0 = jax.devices()[0]
-        except Exception:
-            dev0 = None
-        self._peak_flops, self._peak_hbm = resolve_serving_peaks(dev0)
+        self._peak_flops, self._peak_hbm = resolve_serving_peaks(
+            jax.devices()[0])
         self._phase_acc = {p: [0.0, 0, 0, 0] for p in
                            ("prefill", "decode", "spec_draft", "spec_verify")}
         self._tick_phase: dict[str, float] = {}

@@ -3,42 +3,23 @@ GPT-2-style BPE).
 
 Training (offline) is Python; the per-text encode hot loop runs in
 ``native/libfastbpe.so`` via ctypes (calls release the GIL, so a thread
-pool scales batch encoding across cores). A pure-Python encoder backs the
-same algorithm for environments without the native build and for tests.
+pool scales batch encoding across cores). The library is built from
+source on first use; ``use_native=False`` selects the pure-Python encoder
+of the same algorithm (no compiler needed, and the tests' reference).
 """
 from __future__ import annotations
 
 import ctypes
 import json
-import os
 from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "native")
-
 
 def _load_native():
-    path = os.path.join(_NATIVE_DIR, "libfastbpe.so")
-    src = os.path.join(_NATIVE_DIR, "fast_bpe.cpp")
-    stale = (os.path.exists(path) and os.path.exists(src)
-             and os.path.getmtime(src) > os.path.getmtime(path))
-    if (not os.path.exists(path) or stale) and os.path.exists(src):
-        import subprocess
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR, "-B", "libfastbpe.so"],
-                           check=True, capture_output=True)
-        except Exception:
-            if not os.path.exists(path):
-                return None
-    if not os.path.exists(path):
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-    except OSError:  # wrong arch / platform: pure-Python fallback
-        return None
+    from paddle_tpu.utils.native import build_native
+    lib = ctypes.CDLL(build_native("libfastbpe.so"))
     lib.bpe_new.restype = ctypes.c_void_p
     lib.bpe_new.argtypes = [ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
                             ctypes.POINTER(ctypes.c_int32)]
@@ -73,17 +54,16 @@ class BPETokenizer:
             global _LIB
             if _LIB is None:
                 _LIB = _load_native()
-            if _LIB is not None:
-                flat = np.asarray([[a, b, 256 + r] for r, (a, b)
-                                   in enumerate(self.merges)],
-                                  np.int32).reshape(-1)
-                byte_ids = np.arange(256, dtype=np.int32)
-                self._merges_buf = flat  # keep alive
-                self._bytes_buf = byte_ids
-                self._handle = _LIB.bpe_new(
-                    flat.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-                    len(self.merges),
-                    byte_ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+            flat = np.asarray([[a, b, 256 + r] for r, (a, b)
+                               in enumerate(self.merges)],
+                              np.int32).reshape(-1)
+            byte_ids = np.arange(256, dtype=np.int32)
+            self._merges_buf = flat  # keep alive
+            self._bytes_buf = byte_ids
+            self._handle = _LIB.bpe_new(
+                flat.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+                len(self.merges),
+                byte_ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
 
     def __del__(self):
         if getattr(self, "_handle", None) and _LIB is not None:

@@ -20,19 +20,37 @@ from paddle_tpu.observability.compile import instrumented_jit
 
 @jax.tree_util.register_pytree_node_class
 class TrainState:
-    """(model, opt_state, step) bundle that flattens as one pytree."""
+    """(model, opt_state, rng) bundle that flattens as one pytree.
+    ``layout`` is static: the sharding of every leaf of a state that
+    ``init_state`` laid out over a mesh (None otherwise). It travels with
+    the state so the step can hand the new state back in the same layout."""
 
-    def __init__(self, model, opt_state, rng=None):
+    def __init__(self, model, opt_state, rng=None, layout=None):
         self.model = model
         self.opt_state = opt_state
         self.rng = rng
+        self.layout = layout
 
     def tree_flatten(self):
-        return (self.model, self.opt_state, self.rng), None
+        return (self.model, self.opt_state, self.rng), self.layout
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, layout=aux)
+
+    def updated(self, model, opt_state, rng) -> "TrainState":
+        """The state a step returns, held to this state's layout. Left to
+        itself XLA re-shards small replicated leaves on the way out (norm
+        weights and their slots, over fsdp), and a state that comes back
+        laid out differently from the one the step was compiled for
+        compiles it a second time."""
+        new = TrainState(model, opt_state, rng, self.layout)
+        if self.layout is None:
+            return new
+        leaves, treedef = jax.tree_util.tree_flatten(new)
+        return treedef.unflatten(
+            [jax.lax.with_sharding_constraint(l, s)
+             for l, s in zip(leaves, self.layout, strict=True)])
 
     @property
     def step(self):
@@ -52,7 +70,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[HybridMesh] = N
             rng = state.rng
             loss, grads = value_and_grad(loss_fn)(state.model, *batch)
         model, opt_state = optimizer.step(state.model, grads, state.opt_state)
-        return TrainState(model, opt_state, rng), loss
+        return state.updated(model, opt_state, rng), loss
 
     return instrumented_jit(step, name="train.step",
                             donate_argnums=(0,) if donate else ())
@@ -62,9 +80,17 @@ def init_state(model: Module, optimizer, mesh: Optional[HybridMesh] = None,
                seed: int = 0) -> TrainState:
     if mesh is not None:
         model = shard_module(model, mesh)
-    opt_state = optimizer.init(model)
-    if mesh is not None:
-        # slots inherit param shardings automatically (they are created by
-        # tree_map over sharded params under the mesh context)
-        pass
-    return TrainState(model, opt_state, jax.random.PRNGKey(seed))
+    state = TrainState(model, optimizer.init(model), jax.random.PRNGKey(seed))
+    if mesh is None:
+        return state
+    # slots inherit param shardings (tree_map over sharded params); what has
+    # no parameter to inherit from (step counter, learning rate, rng key) is
+    # replicated over the mesh. The layout is then recorded in the state, so
+    # that the step returns it as it got it and is compiled once.
+    on_mesh = set(mesh.mesh.devices.flat)
+    state = jax.tree_util.tree_map(
+        lambda l: jax.device_put(l, mesh.replicated())
+        if isinstance(l, jax.Array) and l.sharding.device_set != on_mesh
+        else l, state)
+    state.layout = tuple(l.sharding for l in jax.tree_util.tree_leaves(state))
+    return state

@@ -138,7 +138,7 @@ class Trainer:
                 new_opt = jax.tree_util.tree_map(
                     lambda new, old: old if new is None else jnp.where(ok, new, old),
                     new_opt, state.opt_state, is_leaf=lambda x: x is None)
-            return TrainState(new_model, new_opt, state.rng), loss
+            return state.updated(new_model, new_opt, state.rng), loss
 
         # compile introspection (ISSUE 4): spans + compile_seconds +
         # cache hit/miss counters, and cost_analysis FLOPs that back the

@@ -5,7 +5,7 @@ Two detectors:
   * NaN/inf sentinel — the Trainer skips poisoned updates in-graph (see
     trainer.py nan_guard) and raises WatchdogTrip after N bad steps.
   * Stall watchdog — a host thread that trips if the step callback hasn't
-    been poked within `timeout_s` (hung collective / dead tunnel), running
+    been poked within `timeout_s` (hung collective / lost device), running
     an emergency callback (e.g. checkpoint) before raising in the main
     thread via a flag the loop checks.
 """

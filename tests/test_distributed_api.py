@@ -34,7 +34,7 @@ def test_group_and_env():
 
 def test_alltoall_single():
     from functools import partial
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = D.HybridMesh(dp=4, devices=jax.devices()[:4])
     x = jnp.arange(16.0).reshape(4, 4)  # member i holds row i (4 cols)

@@ -35,7 +35,8 @@ def test_train_resnet_example():
 
 
 def test_train_multichip_example():
-    out = _run("train_multichip.py", "--devices", "8", "--steps", "2")
+    out = _run("train_multichip.py", "--cpu-dryrun", "--devices", "8",
+               "--steps", "2")
     assert "mesh dp=2 fsdp=2 tp=2" in out
     losses = [float(l.split("loss ")[1].split(" ")[0])
               for l in out.splitlines() if l.startswith("step")]

@@ -92,6 +92,22 @@ def test_native_token_bin(tmp_path):
     assert any((joined[p:p + 64] == x0).all() for p in pos if p + 64 <= len(joined))
 
 
+def test_native_token_bin_stream_depends_on_the_seed_alone(tmp_path):
+    """Batch k is keyed by (seed, k) and handed out in order: the stream
+    must not depend on how many workers cut it, or on their timing."""
+    path = tmp_path / "toks.bin"
+    np.arange(4096, dtype=np.uint16).tofile(path)
+
+    def stream(seed, workers):
+        ds = TokenBinDataset(str(path), batch_size=2, seq_len=16, seed=seed,
+                             num_batches=50, num_workers=workers)
+        return np.concatenate([x for x, _ in ds], axis=None).tolist()
+
+    one = stream(7, 1)
+    assert stream(7, 4) == one and stream(7, 2) == one
+    assert stream(8, 2) != one
+
+
 # -- multiprocess workers ----------------------------------------------------
 
 class _SquareDataset:

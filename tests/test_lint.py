@@ -1,6 +1,7 @@
 """Collective-deadlock lint: catches divergent-cond collectives and
 collective while-predicates; passes clean SPMD code. Plus a source-level
 clock lint: durations must never come from the wall clock."""
+import os
 import pathlib
 import re
 
@@ -318,3 +319,22 @@ def test_pipeline_divergent_handoff_flagged():
     rep = lint_collectives(bad_stage, jnp.ones((2, 2)), axis_env=[("pp", 4)])
     assert not rep.ok
     assert any(i.kind == "cond-divergence" for i in rep.issues)
+
+
+def test_importing_the_package_claims_no_device():
+    """Importing paddle_tpu, the paged model file or the serving package
+    must not initialise a JAX backend: a launcher that merely imports
+    them would otherwise hold the chip (the async tick's donation used
+    to ask ``jax.default_backend()`` while the module was imported)."""
+    import subprocess
+    import sys
+    code = ("import jax, paddle_tpu, paddle_tpu.models.paged, "
+            "paddle_tpu.serving\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n"
+            "print('no backend')")
+    root = pathlib.Path(__file__).resolve().parent.parent
+    r = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0 and "no backend" in r.stdout, r.stderr[-2000:]

@@ -115,7 +115,7 @@ def test_optimizers_jit_and_multiprecision():
 
 def test_reduce_scatter_gather_p2p():
     from functools import partial
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.distributed import HybridMesh
     from paddle_tpu.distributed import collective as C

@@ -6,7 +6,6 @@ the PT_PAGED_CHUNK kill switch actually changing the traced path only
 through ``clear_jit_caches``; and engine-level greedy identity with the
 kernel on, off, and interpreted — incl. spec decode, chunked prefill,
 and preempt-replay."""
-import warnings
 
 import numpy as np
 import jax.numpy as jnp
@@ -138,10 +137,9 @@ def test_dispatch_interpret_mode(monkeypatch):
 
 
 @pytest.mark.parametrize("kernel", ["decode", "chunk"])
-def test_pallas_failure_cached_per_process(monkeypatch, kernel):
-    """A Pallas trace failure must warn ONCE, bump the fallback counter,
-    and pin the process to the XLA path — no silent per-call retry."""
-    monkeypatch.setattr(pa, "_pallas_disabled", {})
+def test_pallas_failure_raises_no_downgrade(monkeypatch, kernel):
+    """On TPU a Pallas trace failure is the caller's error: nothing is
+    cached, nothing downgrades to the XLA gather path, every call raises."""
     monkeypatch.setattr(pa.jax, "default_backend", lambda: "tpu")
     calls = []
 
@@ -156,7 +154,6 @@ def test_pallas_failure_cached_per_process(monkeypatch, kernel):
             rng, 2, 4, 4, 2, 16, 8, 4, 16, offs=[0, 9], cls=[4, 3])
         call = lambda: pa.paged_chunk_attention(q, kp, vp, tables, offs,
                                                 cls)
-        ref = pa.paged_chunk_attention_xla(q, kp, vp, tables, offs, cls)
     else:
         monkeypatch.setattr(pa, "paged_decode_attention_pallas", boom)
         q = jnp.asarray(rng.normal(size=(2, 4, 16)), jnp.float32)
@@ -165,20 +162,14 @@ def test_pallas_failure_cached_per_process(monkeypatch, kernel):
         tables = jnp.asarray([[0, 1, 16, 16], [2, 3, 16, 16]], jnp.int32)
         lens = jnp.asarray([10, 13], jnp.int32)
         call = lambda: pa.paged_decode_attention(q, kp, vp, tables, lens)
-        ref = pa.paged_decode_attention_xla(q, kp, vp, tables, lens)
 
-    c0 = pa._PALLAS_FALLBACK.value(kernel=kernel)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        out1 = call()
-        out2 = call()
-    assert len(calls) == 1, "fallback decision not cached"
-    assert kernel in pa._pallas_disabled
-    assert pa._PALLAS_FALLBACK.value(kernel=kernel) == c0 + 1
-    warned = [x for x in w if "Pallas kernel failed" in str(x.message)]
-    assert len(warned) == 1
-    for out in (out1, out2):
-        assert np.array_equal(np.asarray(out), np.asarray(ref))
+    pa._trace_events.clear()
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="mosaic says no"):
+            call()
+    assert len(calls) == 2, "a failed kernel must not be remembered"
+    assert "chunk:xla" not in pa._trace_events
+    assert not hasattr(pa, "_pallas_disabled")
 
 
 # --------------------------------------- traced-path flip via jit cache

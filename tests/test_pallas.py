@@ -140,6 +140,19 @@ def test_rms_norm_kernel():
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("rows", [5, 256, 300, 515])
+def test_rms_norm_kernel_ragged_rows(rows):
+    """Any row count: more than one 256-row tile with a ragged last one
+    (300, 515) must equal the reference on every real row."""
+    rs = np.random.RandomState(rows)
+    x = jnp.asarray(rs.randn(rows, 128).astype(np.float32))
+    w = jnp.asarray(rs.rand(128).astype(np.float32) + 0.5)
+    ref = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
+    got = rms_norm(x, w, 1e-6, True)
+    assert got.shape == x.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
 def test_fused_rope_matches_reference():
     rs = np.random.RandomState(0)
     x = jnp.asarray(rs.randn(2, 16, 4, 64).astype(np.float32))

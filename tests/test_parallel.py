@@ -125,6 +125,34 @@ def test_hybrid_training_matches_single_device():
     np.testing.assert_allclose(losses_par, losses_ref, rtol=3e-4)
 
 
+def test_init_state_is_laid_out_as_the_step_returns_it():
+    """A state that comes back laid out differently compiles the step
+    twice: the step counter, lr and rng key left on one device, and (at
+    hidden >= 1024) the norm weights and their slots, which XLA re-shards
+    over fsdp on the way out unless the step holds them to the layout the
+    state carries."""
+    cfg = LlamaConfig.tiny(hidden_size=1024, num_hidden_layers=1,
+                           intermediate_size=256)
+    pt.seed(0)
+    model = LlamaForCausalLM(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (8, 32)))
+    labels = ids
+    optimizer = opt.AdamW(learning_rate=1e-3, multi_precision=True)
+    mesh = HybridMesh(fsdp=2, tp=2, devices=jax.devices()[:4])
+    with mesh:
+        state = init_state(model, optimizer, mesh)
+        before = [l.sharding for l in jax.tree_util.tree_leaves(state)]
+        step = make_train_step(lambda m, i, l: m.loss(i, l), optimizer, mesh)
+        state, _ = step(state, jax.device_put(ids, mesh.batch_sharding()),
+                        jax.device_put(labels, mesh.batch_sharding()))
+    after = jax.tree_util.tree_leaves(state)
+    assert len(before) == len(after)
+    for was, leaf in zip(before, after):
+        assert len(was.device_set) == 4
+        assert was.is_equivalent_to(leaf.sharding, leaf.ndim), (was, leaf.sharding)
+
+
 def test_column_row_parallel_match_dense():
     pt.seed(1)
     col = ColumnParallelLinear(16, 32, gather_output=False)
@@ -173,7 +201,7 @@ def test_partition_specs_respect_tp_annotations():
 
 
 def test_collectives_shard_map():
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import paddle_tpu.distributed as dist
 

@@ -1,10 +1,10 @@
-"""Bench-history perf ledger (ISSUE 12): the tier-1 gate that the
-ledger parses every ``BENCH_r0*.json`` the repo has accumulated, plus
-synthetic-history coverage of the regression verdicts, comparability
+"""Bench-history perf ledger (ISSUE 12): the ledger parses every
+``BENCH_r0*.json`` of a tree (fixture files under ``tmp_path``, shaped
+like the driver's artifacts), plus synthetic-history coverage of the regression verdicts, comparability
 rules, the history append path, and the CLI exit codes.
 
-The module under test is deliberately pure stdlib (bench.py's
-orchestrator loads it by file path and must never import jax); the
+The module under test is deliberately pure stdlib (``bench.py
+--ledger-check`` loads it by file path and must never import jax); the
 import here goes through the package like any other test."""
 import json
 import os
@@ -14,23 +14,41 @@ import pytest
 
 from paddle_tpu.observability import perfledger as pl
 
-_ROOT = str(pathlib.Path(__file__).resolve().parent.parent)
+
+# --------------------------------------------- a history of five rounds
+@pytest.fixture
+def history(tmp_path):
+    """Five round artifacts shaped like the driver's: a crashed round
+    with no result line, two degraded CPU smokes, two on-chip rounds."""
+    root = str(tmp_path)
+    with open(os.path.join(root, "BENCH_r01.json"), "w") as f:
+        json.dump({"n": 1, "cmd": "python bench.py", "rc": 1,
+                   "tail": "Traceback (most recent call last): ...",
+                   "parsed": None}, f)
+    cpu = {"flash": False, "mfu": 0.0, "device": "TFRT_CPU_0"}
+    chip = {"flash": True, "mfu": 0.589, "device": "TPU v5 lite0",
+            "configs": {"resnet50": {"images_per_sec": 1020.0}}}
+    _write_round(root, 2, 17662.7, degraded=True, extra=cpu)
+    _write_round(root, 3, 20677.3, degraded=True, extra=cpu)
+    _write_round(root, 4, 25012.0, extra=chip)
+    _write_round(root, 5, 25024.2, extra=chip)
+    return root
 
 
-# ------------------------------------------------------ the repo's history
-def test_ledger_parses_every_bench_round_in_the_tree():
-    """Acceptance criterion: every BENCH_r0*.json in the tree parses
-    into the trajectory — a malformed artifact fails tier-1."""
-    files = sorted(p.name for p in pathlib.Path(_ROOT).glob("BENCH_r*.json"))
-    assert len(files) >= 5
-    rounds = pl.load_rounds(_ROOT)
+def test_ledger_parses_every_bench_round_in_the_tree(history):
+    """Acceptance criterion: every BENCH_r0*.json in a tree parses
+    into the trajectory, a round without a result line included."""
+    files = sorted(p.name for p in pathlib.Path(history).glob("BENCH_r*.json"))
+    assert len(files) == 5
+    rounds = pl.load_rounds(history)
     labels = [r["label"] for r in rounds]
     for f in files:
         assert os.path.splitext(f)[0] in labels
     by_label = {r["label"]: r for r in rounds}
+    assert not by_label["BENCH_r01"]["parsed_ok"]
     # rounds that recorded a parseable result line must flatten to legs
     parseable = [r for r in rounds if r["parsed_ok"]]
-    assert len(parseable) >= 2
+    assert len(parseable) == 4
     for r in parseable:
         assert r["legs"], f"{r['label']} parsed but yielded no legs"
         assert all(isinstance(v, float) for v in r["legs"].values())
@@ -41,8 +59,8 @@ def test_ledger_parses_every_bench_round_in_the_tree():
         assert "headline" in by_label[lbl]["legs"]
 
 
-def test_ledger_report_and_markdown_render_from_repo_history():
-    rounds = pl.load_rounds(_ROOT)
+def test_ledger_report_and_markdown_render_from_repo_history(history):
+    rounds = pl.load_rounds(history)
     report = pl.build_report(rounds)
     n = len(rounds)
     assert report["trajectory"], "no legs tracked at all"
@@ -57,11 +75,11 @@ def test_ledger_report_and_markdown_render_from_repo_history():
         assert r["label"] in md
 
 
-def test_ledger_cli_runs_on_the_repo(capsys):
-    assert pl.main(["--dir", _ROOT]) == 0           # report always renders
+def test_ledger_cli_runs_on_the_repo(history, capsys):
+    assert pl.main(["--dir", history]) == 0         # report always renders
     out = capsys.readouterr().out
     assert "# bench trajectory" in out
-    assert pl.main(["--dir", _ROOT, "--json"]) == 0
+    assert pl.main(["--dir", history, "--json"]) == 0
     doc = json.loads(capsys.readouterr().out)
     assert set(doc) >= {"rounds", "trajectory", "legs", "status"}
 

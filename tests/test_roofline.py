@@ -45,11 +45,19 @@ def test_peak_tables_cover_the_same_chips():
 @pytest.mark.parametrize("kind,bw", [
     ("TPU v5 lite", 819e9), ("TPU v5e", 819e9), ("TPU v5p", 2765e9),
     ("TPU v4", 1228e9), ("TPU v6", 1640e9),
-    ("TPU v99", 819e9),          # unknown TPU → v5e-class assumption
     ("cpu", 0.0), ("NVIDIA H100", 0.0),
 ])
 def test_chip_peak_hbm_bw_by_kind(kind, bw):
     assert chip_peak_hbm_bw(kind=kind) == pytest.approx(bw)
+
+
+def test_unknown_tpu_kind_raises():
+    """A TPU that is not in the tables is an error, never a v5e by
+    default — on both tables."""
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_peak_hbm_bw(kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_peak_flops(kind="TPU v99")
 
 
 def test_empty_kind_is_undefined_not_v5e():
@@ -66,12 +74,14 @@ def test_empty_kind_is_undefined_not_v5e():
     assert chip_peak_hbm_bw(None) == 0.0
 
 
-def test_tpu_platform_with_empty_kind_assumes_v5e():
+def test_tpu_platform_with_empty_kind_raises():
     """A device that says platform=tpu but reports no kind string IS a
-    TPU — the v5e-class assumption is evidence-based there."""
+    TPU of unknown peak — an error, not a v5e."""
     dev = _Dev(kind="", platform="tpu")
-    assert chip_peak_flops(dev) == pytest.approx(PEAK_BF16["TPU v5e"])
-    assert chip_peak_hbm_bw(dev) == pytest.approx(PEAK_HBM_BPS["TPU v5e"])
+    with pytest.raises(ValueError):
+        chip_peak_flops(dev)
+    with pytest.raises(ValueError):
+        chip_peak_hbm_bw(dev)
 
 
 def test_non_tpu_platform_is_undefined_even_with_tpu_kind():

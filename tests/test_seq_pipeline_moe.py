@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from paddle_tpu.distributed._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as pt
@@ -162,7 +162,7 @@ def test_zigzag_ring_attention_matches_full():
     """Zigzag layout + ring == full causal attention (after inverse perm)."""
     import numpy as np
     import jax, jax.numpy as jnp
-    from paddle_tpu.distributed._compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.distributed.ring_attention import (
         zigzag_inverse_permutation, zigzag_permutation, zigzag_ring_attention)
