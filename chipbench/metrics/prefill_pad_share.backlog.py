@@ -1,0 +1,3 @@
+from _lib import pad_share as read
+
+UNIT = "%"
