@@ -113,7 +113,8 @@ class LlamaRMSNorm(Module):
         self.eps = eps
 
     def __call__(self, x):
-        return fused_rms_norm(x, self.weight, self.eps)
+        with jax.named_scope("norm"):
+            return fused_rms_norm(x, self.weight, self.eps)
 
 
 class LlamaAttention(Module):
@@ -267,8 +268,13 @@ class LlamaDecoderLayer(Module):
         self.mlp = LlamaMLP(cfg)
 
     def __call__(self, x, cos, sin, attn_mask=None):
-        x = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # scopes are metadata: they split %fusion by part in a profile
+        h = self.input_layernorm(x)
+        with jax.named_scope("attention"):
+            x = x + self.self_attn(h, cos, sin, attn_mask)
+        h = self.post_attention_layernorm(x)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(h)
         return x
 
 
@@ -344,7 +350,8 @@ class LlamaForCausalLM(Module):
 
     def __call__(self, input_ids, attn_mask=None, position_ids=None):
         hidden = self.model(input_ids, attn_mask, position_ids)
-        return self.logits(hidden)
+        with jax.named_scope("lm_head"):
+            return self.logits(hidden)
 
     def loss(self, input_ids, labels, attn_mask=None):
         """Causal LM loss; labels = input shifted, ignore_index=-100."""

@@ -965,10 +965,11 @@ def _backbone(model):
 def _model_logits(model, x):
     """LM head: ``model.logits`` where it exists (weight-only-quant aware),
     the plain ``lm_head`` matmul otherwise (MoE families)."""
-    fn = getattr(model, "logits", None)
-    if callable(fn):
-        return fn(x)
-    return _wo(x, model.lm_head)
+    with jax.named_scope("lm_head"):
+        fn = getattr(model, "logits", None)
+        if callable(fn):
+            return fn(x)
+        return _wo(x, model.lm_head)
 
 
 def _mlp_out(lyr, h):
@@ -977,7 +978,8 @@ def _mlp_out(lyr, h):
     ``.mlp``. MoE blocks return ``(y, aux_loss)`` — the aux loss is a
     training regulariser, dropped at inference."""
     blk = lyr.moe if hasattr(lyr, "moe") else lyr.mlp
-    out = blk(h)
+    with jax.named_scope("mlp"):
+        out = blk(h)
     return out[0] if isinstance(out, (tuple, list)) else out
 
 
@@ -1080,35 +1082,36 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     k_pools, v_pools, k_scales, v_scales = [], [], [], []
     for li, lyr in enumerate(_backbone(model).layers):
         h = lyr.input_layernorm(x)
-        att = lyr.self_attn
-        qkv = _wo(h, att.qkv_proj)
-        if lora is not None:
-            qkv = qkv + _lora_delta(h, lora, "qkv", li)
-        if getattr(att, "qkv_bias", None) is not None:
-            qkv = qkv + att.qkv_bias
-        nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = A.apply_rope(q.reshape(b, s, nh, hd), cos, sin)
-        k = A.apply_rope(k.reshape(b, s, nkv, hd), cos, sin)
-        v = v.reshape(b, s, nkv, hd)
-        # the prompt's own attention is dense over the LOCAL pre-
-        # quantization k/v — only the pool writes quantize, so prefill
-        # quality is exactly the decode dequantization error, never worse
-        out = A.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                             kv_lens=prompt_lens,
-                                             window=getattr(cfg, "sliding_window", None))
-        kp, vp, ks, vs = _scatter_kv(cache, li, k, v, _scatter_prefill,
-                                     rtables, prompt_lens, nb, bs)
-        k_pools.append(kp)
-        v_pools.append(vp)
-        if ks is not None:
-            k_scales.append(ks)
-            v_scales.append(vs)
-        attn_out = out.reshape(b, s, nh * hd)
-        proj = _wo(attn_out, att.o_proj)
-        if lora is not None:
-            proj = proj + _lora_delta(attn_out, lora, "o", li)
-        x = x + proj
+        with jax.named_scope("attention"):
+            att = lyr.self_attn
+            qkv = _wo(h, att.qkv_proj)
+            if lora is not None:
+                qkv = qkv + _lora_delta(h, lora, "qkv", li)
+            if getattr(att, "qkv_bias", None) is not None:
+                qkv = qkv + att.qkv_bias
+            nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
+            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q = A.apply_rope(q.reshape(b, s, nh, hd), cos, sin)
+            k = A.apply_rope(k.reshape(b, s, nkv, hd), cos, sin)
+            v = v.reshape(b, s, nkv, hd)
+            # the prompt's own attention is dense over the LOCAL pre-
+            # quantization k/v — only the pool writes quantize, so prefill
+            # quality is exactly the decode dequantization error, never worse
+            out = A.scaled_dot_product_attention(
+                q, k, v, is_causal=True, kv_lens=prompt_lens,
+                window=getattr(cfg, "sliding_window", None))
+            kp, vp, ks, vs = _scatter_kv(cache, li, k, v, _scatter_prefill,
+                                         rtables, prompt_lens, nb, bs)
+            k_pools.append(kp)
+            v_pools.append(vp)
+            if ks is not None:
+                k_scales.append(ks)
+                v_scales.append(vs)
+            attn_out = out.reshape(b, s, nh * hd)
+            proj = _wo(attn_out, att.o_proj)
+            if lora is not None:
+                proj = proj + _lora_delta(attn_out, lora, "o", li)
+            x = x + proj
         x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
     x = _backbone(model).norm(x)
     logits = _model_logits(model, x)
@@ -1138,49 +1141,50 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
     rtables = _cp_local_tables(cache.block_tables, cp_axis, nb)
     for li, lyr in enumerate(_backbone(model).layers):
         h = lyr.input_layernorm(x)
-        att = lyr.self_attn
-        qkv = _wo(h, att.qkv_proj)
-        if lora is not None:
-            qkv = qkv + _lora_delta(h, lora, "qkv", li)
-        if getattr(att, "qkv_bias", None) is not None:
-            qkv = qkv + att.qkv_bias
-        nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = _apply_rope_rows(q.reshape(b, 1, nh, hd), cos, sin)
-        k = _apply_rope_rows(k.reshape(b, 1, nkv, hd), cos, sin)
-        v = v.reshape(b, 1, nkv, hd)
-        k_pool, v_pool, ks, vs = _scatter_kv(
-            cache, li, k, v, _scatter_decode, rtables,
-            cache.lens, active, nb, bs)
-        k_pools.append(k_pool)
-        v_pools.append(v_pool)
-        if ks is not None:
-            k_scales.append(ks)
-            v_scales.append(vs)
-        # sliding-window configs: the pool retains all tokens (blocks
-        # below the window could be recycled — not done yet) but decode
-        # attends only the last `window` positions, matching prefill
-        if cp_axis is None:
-            out = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                         rtables, new_lens,
-                                         window=window, k_scale=ks,
-                                         v_scale=vs)
-        else:
-            # per-shard partials over the locally-owned blocks + ONE
-            # psum-style merge: O(heads*dim) cross-shard bytes per step,
-            # bit-identical on every member (replicated sampling)
-            from paddle_tpu.distributed.ring_attention import (
-                finalize_partials, psum_merge_partials)
-            o_p, m_p, l_p = paged_decode_attention(
-                q[:, 0], k_pool, v_pool, rtables, new_lens,
-                window=window, k_scale=ks, v_scale=vs, partials=True)
-            o_p, m_p, l_p = psum_merge_partials(o_p, m_p, l_p, cp_axis)
-            out = finalize_partials(o_p, l_p, q.dtype)
-        attn_out = out.reshape(b, 1, nh * hd)
-        proj = _wo(attn_out, att.o_proj)
-        if lora is not None:
-            proj = proj + _lora_delta(attn_out, lora, "o", li)
-        x = x + proj
+        with jax.named_scope("attention"):
+            att = lyr.self_attn
+            qkv = _wo(h, att.qkv_proj)
+            if lora is not None:
+                qkv = qkv + _lora_delta(h, lora, "qkv", li)
+            if getattr(att, "qkv_bias", None) is not None:
+                qkv = qkv + att.qkv_bias
+            nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
+            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q = _apply_rope_rows(q.reshape(b, 1, nh, hd), cos, sin)
+            k = _apply_rope_rows(k.reshape(b, 1, nkv, hd), cos, sin)
+            v = v.reshape(b, 1, nkv, hd)
+            k_pool, v_pool, ks, vs = _scatter_kv(
+                cache, li, k, v, _scatter_decode, rtables,
+                cache.lens, active, nb, bs)
+            k_pools.append(k_pool)
+            v_pools.append(v_pool)
+            if ks is not None:
+                k_scales.append(ks)
+                v_scales.append(vs)
+            # sliding-window configs: the pool retains all tokens (blocks
+            # below the window could be recycled — not done yet) but decode
+            # attends only the last `window` positions, matching prefill
+            if cp_axis is None:
+                out = paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                             rtables, new_lens,
+                                             window=window, k_scale=ks,
+                                             v_scale=vs)
+            else:
+                # per-shard partials over the locally-owned blocks + ONE
+                # psum-style merge: O(heads*dim) cross-shard bytes per step,
+                # bit-identical on every member (replicated sampling)
+                from paddle_tpu.distributed.ring_attention import (
+                    finalize_partials, psum_merge_partials)
+                o_p, m_p, l_p = paged_decode_attention(
+                    q[:, 0], k_pool, v_pool, rtables, new_lens,
+                    window=window, k_scale=ks, v_scale=vs, partials=True)
+                o_p, m_p, l_p = psum_merge_partials(o_p, m_p, l_p, cp_axis)
+                out = finalize_partials(o_p, l_p, q.dtype)
+            attn_out = out.reshape(b, 1, nh * hd)
+            proj = _wo(attn_out, att.o_proj)
+            if lora is not None:
+                proj = proj + _lora_delta(attn_out, lora, "o", li)
+            x = x + proj
         x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
     x = _backbone(model).norm(x)
     logits = _model_logits(model, x)[:, 0]
@@ -1213,8 +1217,9 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
                                             lora, cp_axis=cp_axis)
     logp = (jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
             if want_logp else ())
-    nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
-                       top_k, logit_bias)
+    with jax.named_scope("sampler"):
+        nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
+                           top_k, logit_bias)
     nxt = jnp.where(active, nxt.astype(jnp.int32), tokens)
     return nxt, logp, cache
 
@@ -1249,8 +1254,9 @@ def llama_decode_tick_async(model, tokens, cache: PagedKVCache, active,
     ran = active & ~stop
     logits, cache = llama_decode_step_paged(model, tokens, cache, ran,
                                             None)
-    nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
-                       top_k, None)
+    with jax.named_scope("sampler"):
+        nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
+                           top_k, None)
     nxt = jnp.where(ran, nxt.astype(jnp.int32), tokens)
     new_gen = gen + ran.astype(gen.dtype)
     stopped = ran & ((nxt == eos_id) | (new_gen >= max_gen))
@@ -1682,43 +1688,44 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
     k_pools, v_pools, k_scales, v_scales = [], [], [], []
     for li, lyr in enumerate(_backbone(model).layers):
         h = lyr.input_layernorm(x)
-        att = lyr.self_attn
-        qkv = _wo(h, att.qkv_proj)
-        if lora is not None:
-            qkv = qkv + _lora_delta(h, lora, "qkv", li)
-        if getattr(att, "qkv_bias", None) is not None:
-            qkv = qkv + att.qkv_bias
-        nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = rope(q.reshape(a, c, nh, hd))
-        k = rope(k.reshape(a, c, nkv, hd))
-        v = v.reshape(a, c, nkv, hd)
-        # scatter the chunk FIRST so the gathered view holds prefix+chunk
-        k_pool, v_pool, ks, vs = _scatter_kv(
-            cache, li, k, v, _scatter_decode_chunk, rtables, offsets,
-            chunk_lens, nb, bs)
-        k_pools.append(k_pool)
-        v_pools.append(v_pool)
-        if ks is not None:
-            k_scales.append(ks)
-            v_scales.append(vs)
-        # ragged pool-direct attention: the kernel reads only each row's
-        # live blocks (the XLA fallback reconstructs the old full
-        # gather + dense-mask view, bit-compatible)
-        if cp_axis is None:
-            out = paged_chunk_attention(q, k_pool, v_pool, rtables,
-                                        offsets, chunk_lens, window=window,
-                                        k_scale=ks, v_scale=vs)
-        else:
-            o_p, m_p, l_p = paged_chunk_attention(
-                q, k_pool, v_pool, rtables, offsets, chunk_lens,
-                window=window, k_scale=ks, v_scale=vs, partials=True)
-            out = _cp_merge_chunk(o_p, m_p, l_p, cp_axis, q.dtype)
-        attn_out = out.reshape(a, c, nh * hd)
-        proj = _wo(attn_out, att.o_proj)
-        if lora is not None:
-            proj = proj + _lora_delta(attn_out, lora, "o", li)
-        x = x + proj
+        with jax.named_scope("attention"):
+            att = lyr.self_attn
+            qkv = _wo(h, att.qkv_proj)
+            if lora is not None:
+                qkv = qkv + _lora_delta(h, lora, "qkv", li)
+            if getattr(att, "qkv_bias", None) is not None:
+                qkv = qkv + att.qkv_bias
+            nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
+            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q = rope(q.reshape(a, c, nh, hd))
+            k = rope(k.reshape(a, c, nkv, hd))
+            v = v.reshape(a, c, nkv, hd)
+            # scatter the chunk FIRST so the gathered view holds prefix+chunk
+            k_pool, v_pool, ks, vs = _scatter_kv(
+                cache, li, k, v, _scatter_decode_chunk, rtables, offsets,
+                chunk_lens, nb, bs)
+            k_pools.append(k_pool)
+            v_pools.append(v_pool)
+            if ks is not None:
+                k_scales.append(ks)
+                v_scales.append(vs)
+            # ragged pool-direct attention: the kernel reads only each row's
+            # live blocks (the XLA fallback reconstructs the old full
+            # gather + dense-mask view, bit-compatible)
+            if cp_axis is None:
+                out = paged_chunk_attention(q, k_pool, v_pool, rtables,
+                                            offsets, chunk_lens, window=window,
+                                            k_scale=ks, v_scale=vs)
+            else:
+                o_p, m_p, l_p = paged_chunk_attention(
+                    q, k_pool, v_pool, rtables, offsets, chunk_lens,
+                    window=window, k_scale=ks, v_scale=vs, partials=True)
+                out = _cp_merge_chunk(o_p, m_p, l_p, cp_axis, q.dtype)
+            attn_out = out.reshape(a, c, nh * hd)
+            proj = _wo(attn_out, att.o_proj)
+            if lora is not None:
+                proj = proj + _lora_delta(attn_out, lora, "o", li)
+            x = x + proj
         x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
     x = _backbone(model).norm(x)
     logits = _model_logits(model, x)

@@ -16,7 +16,10 @@ pulls the XLA ``cost_analysis`` FLOPs estimate into the
 ``flops.record_throughput`` when no analytic FLOPs model was given),
 and drops a ``compile`` event on the flight recorder. A hit is one
 dict lookup and a ``compile_cache_hits_total`` increment — no new
-compile span.
+compile span. Every call is one span under the function's name (so
+``train.step``) with a child ``jit.signature`` around the signature
+pass, which flattens all arguments in Python on every call: the two
+say what the wrapper itself costs a step.
 
 Robustness: jax's own dispatch cache stays the backstop. If the AOT
 path fails for a function (an exotic backend, a remote-compile quirk),
@@ -144,10 +147,15 @@ class InstrumentedJit:
         return compiled
 
     def __call__(self, *args, **kwargs):
+        with _span(self.name):
+            return self._call(args, kwargs)
+
+    def _call(self, args, kwargs):
         if self._broken:
             return self._jit(*args, **kwargs)
         try:
-            key = self._sig(args, kwargs)
+            with _span("jit.signature"):
+                key = self._sig(args, kwargs)
         except Exception:
             self._broken = True
             return self._jit(*args, **kwargs)

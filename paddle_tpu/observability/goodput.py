@@ -15,9 +15,15 @@ work into
       ``spec_rejected``     draft tokens the target model refused
       ``replay_prefill``    re-prefilled positions after a preemption
                             replay (minus prefix-cache hits)
-      ``pad_rows``          whole padding rows in chunked-prefill and
-                            spec-verify batches (row slots launched
-                            with no live sequence)
+      ``pad_rows``          whole padding rows in the admission
+                            prefill, chunked-prefill and spec-verify
+                            batches (row slots launched with no live
+                            sequence, times the row's width). Not the
+                            benchmark's ``pad_row_share``: that counts,
+                            from the ``rows``/``useful`` args of the
+                            ``exe.prefill*`` spans, every token-row
+                            without a prompt token, so also the unused
+                            tail of a row that does hold a sequence
       ``moe_capacity_drop`` MoE routing assignments dropped at expert
                             capacity
       ``chaos_abort``       drafted-but-never-verified tokens when a
@@ -68,7 +74,10 @@ _WASTE = METRICS.counter(
     "serving_waste_total",
     "device token-positions computed then thrown away, by cause "
     "(spec_rejected, replay_prefill, pad_rows, moe_capacity_drop, "
-    "chaos_abort, async_overrun)",
+    "chaos_abort, async_overrun). pad_rows counts WHOLE unused rows of "
+    "the padded admission, chunk and verify batches; the benchmark's "
+    "pad_row_share counts every token-row without a prompt token, the "
+    "unused tail of a used row too",
     labelnames=("why",))
 _RATIO = METRICS.gauge(
     "serving_goodput_ratio",

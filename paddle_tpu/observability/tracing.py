@@ -11,15 +11,28 @@ RecordEvent + chrome-trace export; host-side complement to XLA's own
     def save(...): ...
 
 Spans clock with ``time.monotonic_ns`` (never wall clock — a stepped
-NTP correction inside a span would report negative durations), record
-their thread id, and nest naturally: Chrome's "X" (complete) events
-reconstruct the hierarchy from ts/dur containment per thread.
+NTP correction inside a span would report negative durations; on Linux
+it is ``CLOCK_MONOTONIC``, the clock ``time.perf_counter`` reads, so a
+span's ``ts`` lies on the axis of a benchmark's ``perf_counter``
+stamps), record their thread id, and nest: every span event carries an
+``id`` and the ``parent`` id of the span that was open on its thread
+when it was entered (``None`` at the top), so a reader rebuilds the
+tree without guessing from containment.
 
-The global :data:`TRACER` starts DISABLED. Enablement is checked when a
-span is ENTERED (not when it is created), so a ``@span(...)`` decorator
-applied at import time starts tracing the moment the tracer is turned
-on; a span entered while tracing is off is one bool read and no buffer
-write. :func:`instant` emits zero-duration "i" events — fault
+One span, two sinks, one switch. The global :data:`TRACER` starts
+DISABLED. A span records iff, when it is ENTERED (not when it is
+created, so a ``@span(...)`` decorator applied at import time starts
+tracing the moment tracing is turned on), the tracer is enabled **or a
+``jax.profiler`` trace is running**. A recording span does both: it
+appends its event to the in-memory buffer, and it opens a
+``jax.profiler.TraceAnnotation`` of the same name, which puts it into
+the profiler's ``.xplane.pb`` host plane on the profiler's clock,
+beside the device operations (a no-op without a running profile). So
+``jax.profiler.start_trace`` alone turns the program's spans on, and no
+call site opens an annotation of its own. A span entered while both
+are off is one object and two flag reads, no clock read and no buffer
+write. ``cat="device_wait"`` marks a span in which the host waits for
+the device. :func:`instant` emits zero-duration "i" events — fault
 injections use it so a chaos run's timeline shows exactly where each
 fault landed.
 
@@ -36,12 +49,32 @@ real OS thread.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
 __all__ = ["TRACER", "Tracer", "span", "instant", "export_chrome_trace"]
+
+
+_IDS = itertools.count(1)          # next() is atomic under the GIL
+_OPEN = threading.local()          # .stack: ids of this thread's open spans
+_ANNOTATION = None                 # jax.profiler.TraceAnnotation, once seen
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` if the process has imported
+    ``jax.profiler`` (no profile can run before that), else None. Never
+    imports jax: this module stays importable without a backend."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        _ANNOTATION = mod.TraceAnnotation
+    return _ANNOTATION
 
 
 class _Span:
@@ -49,36 +82,84 @@ class _Span:
     decorator form re-opens a fresh span per call, so one decoration is
     safe under recursion and concurrent threads."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_id", "_parent",
+                 "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, args: dict,
+                 cat: str = "host"):
         self._tracer = tracer
         self.name = name
+        self.cat = cat
         self.args = args
         self._t0 = None
 
-    def __enter__(self):
-        self._t0 = time.monotonic_ns() if self._tracer._enabled else None
+    def begin(self, t_ns: int = None):
+        """Enter. A caller that has just read ``time.monotonic_ns()`` for
+        its own accounting hands the reading in, so that the span and
+        that accounting share one clock read per edge."""
+        ann = _annotation()
+        if not (self._tracer._enabled
+                or (ann is not None and ann.is_enabled())):
+            self._t0 = None              # off at entry: nothing recorded
+            return self
+        self._t0 = time.monotonic_ns() if t_ns is None else t_ns
+        stack = _OPEN.__dict__.setdefault("stack", [])
+        self._parent = stack[-1] if stack else None
+        self._id = next(_IDS)
+        stack.append(self._id)
+        self._ann = None
+        if ann is not None:
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
         return self
 
-    def __exit__(self, *exc):
+    @property
+    def recording(self) -> bool:
+        """True between a recording entry and its exit: guard work done
+        only to fill :meth:`set`."""
+        return self._t0 is not None
+
+    def set(self, **args):
+        """Counts known only inside the span (tokens emitted, requests
+        admitted). Dropped, like the span, when it does not record."""
+        if self._t0 is not None:
+            self.args = {**self.args, **args}
+            if self._ann is not None:
+                self._ann.set_metadata(**args)
+
+    def end(self, t_ns: int = None):
         if self._t0 is None:             # tracing was off at entry
-            return False
-        t1 = time.monotonic_ns()
+            return
+        t1 = time.monotonic_ns() if t_ns is None else t_ns
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _OPEN.stack
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        elif self._id in stack:          # exited out of order
+            stack.remove(self._id)
         self._tracer._emit({
-            "name": self.name, "ph": "X", "cat": "host",
+            "name": self.name, "ph": "X", "cat": self.cat,
             "ts": self._t0 / 1e3, "dur": (t1 - self._t0) / 1e3,
             "pid": self._tracer._pid, "tid": threading.get_ident(),
+            "id": self._id, "parent": self._parent,
             **({"args": self.args} if self.args else {}),
         })
+        self._t0 = None
+
+    def __enter__(self):
+        return self.begin()
+
+    def __exit__(self, *exc):
+        self.end()
         return False
 
     def __call__(self, fn):
-        tracer, name, args = self._tracer, self.name, self.args
+        tracer, name, args, cat = self._tracer, self.name, self.args, self.cat
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            with _Span(tracer, name, args):
+            with _Span(tracer, name, args, cat):
                 return fn(*a, **kw)
         return wrapped
 
@@ -124,8 +205,8 @@ class Tracer:
         return False
 
     # ----------------------------------------------------------- record
-    def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args)
+    def span(self, name: str, *, cat: str = "host", **args) -> _Span:
+        return _Span(self, name, args, cat)
 
     def instant(self, name: str, **args):
         """Zero-duration marker ("i" event) — fault injections, restarts."""
@@ -217,9 +298,10 @@ class Tracer:
 TRACER = Tracer()
 
 
-def span(name: str, **args) -> _Span:
-    """Module-level sugar over the global tracer."""
-    return TRACER.span(name, **args)
+def span(name: str, *, cat: str = "host", **args) -> _Span:
+    """Module-level sugar over the global tracer: the one way the
+    program marks an interval."""
+    return _Span(TRACER, name, args, cat)
 
 
 def instant(name: str, **args):

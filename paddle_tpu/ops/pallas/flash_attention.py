@@ -261,6 +261,7 @@ def _flash_fwd(q, k, v, lens, slopes, *, scale, causal, window, kv_rep,
         ],
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     return out, lse
 
@@ -433,6 +434,7 @@ def _flash_bwd(res, g, *, scale, causal, window, kv_rep, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
+        name="flash_attention_dq",
     )(*dq_args)
 
     dkv_in_specs = [
@@ -473,6 +475,7 @@ def _flash_bwd(res, g, *, scale, causal, window, kv_rep, block_q, block_k,
         ],
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*dkv_args)
     if kv_rep > 1:
         # per-q-head partials -> sum over each kv group (rows are contiguous)

@@ -139,7 +139,8 @@ def _fwd_kernel(gid_ref, tot_ref, x_ref, w_ref, o_ref):
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret):
+def _pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret,
+                name="grouped_matmul"):
     m, k = lhs.shape
     e, _, n = rhs.shape
     bm, bn = block_m, _fit(block_n, n)
@@ -169,6 +170,7 @@ def _pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        name=name,        # the kernel's name in a device trace
     )(gid, total.reshape(1), xp, rhs)
     return yp[dest]
 
@@ -227,6 +229,7 @@ def _pallas_dw(lhs, g, group_sizes, block_m, block_n, block_k, interpret):
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
+        name="grouped_matmul_dw",
     )(gid, total.reshape(1), xp, gp)
     # blocks of never-visited (empty) experts are uninitialised memory
     return jnp.where((group_sizes > 0)[:, None, None], dw, 0.0)
@@ -245,7 +248,8 @@ def _gmm_fwd(lhs, rhs, group_sizes, block_m, block_n, block_k, interpret):
 def _gmm_bwd(block_m, block_n, block_k, interpret, res, g):
     lhs, rhs, group_sizes = res
     dlhs = _pallas_fwd(g, rhs.transpose(0, 2, 1).astype(rhs.dtype),
-                       group_sizes, block_m, block_n, interpret)
+                       group_sizes, block_m, block_n, interpret,
+                       name="grouped_matmul_dx")
     drhs = _pallas_dw(lhs, g, group_sizes, block_m, block_n, block_k,
                       interpret).astype(rhs.dtype)
     return dlhs, drhs, np.zeros(group_sizes.shape, _float0)

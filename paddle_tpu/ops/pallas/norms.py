@@ -58,6 +58,7 @@ def _rms_fwd(x, weight, epsilon, interpret):
         out_specs=pl.BlockSpec((block, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
         interpret=interpret,
+        name="rms_norm_fwd",
     )(x2, weight)
     return out.reshape(x.shape), (x, weight)
 

@@ -237,6 +237,10 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        # the kernel is handed over as a functools.partial, which has no
+        # __name__: without this the custom call prints under the name of
+        # the jitted program around it
+        name="paged_decode_attention",
     )(tables_bh, lens_bh, *operands)
     if partials:
         acc, m, l = out
@@ -559,6 +563,7 @@ def paged_chunk_attention_pallas(q, k_pool, v_pool, block_tables, offsets,
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
+        name="paged_chunk_attention",
     )(tables, offs, cls, *operands)
 
     def unfold(x, last):

@@ -39,5 +39,6 @@ def fused_rope(x, cos, sin, interpret=None):
         out_specs=pl.BlockSpec((1, h, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * s, h, d), x.dtype),
         interpret=interpret,
+        name="fused_rope",
     )(xr, cs, sn)
     return out.reshape(b, s, h, d)

@@ -355,6 +355,7 @@ class LLMEngine:
         self._phase_acc = {p: [0.0, 0, 0, 0] for p in
                            ("prefill", "decode", "spec_draft", "spec_verify")}
         self._tick_phase: dict[str, float] = {}
+        self._tick_no = 0                 # the ``serving.step`` span's arg
 
         # ---- async pipeline window (ISSUE 20) ----
         # _async_win: oldest-first list of dispatched-but-unfetched ticks,
@@ -929,6 +930,9 @@ class LLMEngine:
             ids, lens, slots, rows,
             lora=self._lora_arg(row_aidx, self.max_prompt_len))
         self._staged_admits = frozenset()   # scatter landed: evictable again
+        # padded sentinel rows burned device FLOPs on no request's behalf
+        GOODPUT.waste("pad_rows", (a_cap - n - len(beams))
+                      * self.max_prompt_len)
         # roofline: one weight pass; prompts attend causally from offset 0
         self._acc_phase("prefill", int(lens.sum()), 1,
                         self._ctx_causal(lens, np.zeros_like(lens)))
@@ -1545,8 +1549,7 @@ class LLMEngine:
             return handled, emitted
 
         seqs = {s: self._committed_seq(s) for s, _, _ in staged}
-        with self._tick_timer("draft"), \
-                _span("serving.draft", slots=len(staged)):
+        with self._tick_timer("draft", "serving.draft", slots=len(staged)):
             props, qs = self._spec_draft(staged, seqs)
 
         # ---- verify: ONE batched target chunk over (slots, k_eff+1) ----
@@ -1603,8 +1606,8 @@ class LLMEngine:
                                          // self.block_size)
             return np.zeros(self.num_slots, bool), []
         t_dev = time.perf_counter()
-        with self._tick_timer("verify"), \
-                _span("serving.verify", slots=len(staged)):
+        with self._tick_timer("verify", "serving.verify",
+                              slots=len(staged)):
             logits = np.asarray(self.exe.verify_chunk(
                 ids, clens, offs, slot_ids, rows,
                 lora=self._lora_arg(v_aidx, C)).astype(jnp.float32))
@@ -2012,15 +2015,20 @@ class LLMEngine:
 
     # ------------------------------------------------- roofline anatomy
     @contextmanager
-    def _tick_timer(self, name: str):
+    def _tick_timer(self, name: str, span_name: str, **args):
         """Accumulate a named slice of the CURRENT tick's wall time
-        (same clock as the tick total, so the breakdown reconciles)."""
-        t = time.monotonic()
+        (same clock as the tick total, so the breakdown reconciles) and
+        mark it as the span ``span_name``: the span and the slice come
+        from the same two clock reads."""
+        t = time.monotonic_ns()
+        sp = _span(span_name, **args).begin(t)
         try:
-            yield
+            yield sp
         finally:
+            t1 = time.monotonic_ns()
+            sp.end(t1)
             self._tick_phase[name] = (self._tick_phase.get(name, 0.0)
-                                      + time.monotonic() - t)
+                                      + (t1 - t) * 1e-9)
 
     def _acc_phase(self, phase: str, tokens: int, passes: int, ctx: int):
         """Add one forward's roofline counts to a phase's cumulative
@@ -2148,7 +2156,8 @@ class LLMEngine:
 
     def step(self):
         """One engine tick — see :meth:`_step_impl`. Wrapped here so the
-        tick lands in the trace timeline and the tick-duration histogram
+        tick lands in the trace timeline (the ``serving.step`` span, whose
+        children are the tick's slices) and the tick-duration histogram
         even when a chaos rule or a dry pool raises out of the middle.
         The tick's anatomy (prefill/draft/verify/sample slices timed by
         :meth:`_tick_timer`, host = the remainder) goes to the breakdown
@@ -2156,40 +2165,48 @@ class LLMEngine:
         observations sum to the tick's total by construction."""
         t0 = time.monotonic()
         self._tick_phase = {}
-        try:
-            with _span("serving.step"):
+        self._tick_no += 1
+        with _span("serving.step", tick=self._tick_no):
+            try:
                 return self._step_impl()
-        finally:
-            total = time.monotonic() - t0
-            ph = self._tick_phase
-            timed = sum(ph.values())
-            for name in ("prefill", "draft", "verify", "sample"):
-                _TICK_BREAKDOWN.observe(ph.get(name, 0.0), phase=name)
-            _TICK_BREAKDOWN.observe(max(0.0, total - timed), phase="host")
-            _TICK.observe(total)
-            # usage metering (ISSUE 19): bill this tick's device time
-            # and KV occupancy to the tenants holding state — the same
-            # `total` the histogram just observed, so the ledger's
-            # device-seconds reconcile with serving_tick_seconds
-            # tick-for-tick
-            if self.slo is not None:
-                self.slo.charge_tick(self, total)
-            acc = self._phase_acc
-            acc["prefill"][0] += ph.get("prefill", 0.0)
-            acc["spec_draft"][0] += ph.get("draft", 0.0)
-            acc["spec_verify"][0] += ph.get("verify", 0.0)
-            acc["decode"][0] += ph.get("sample", 0.0)
-            # overlap-aware anatomy (ISSUE 20): host work done under an
-            # in-flight device dispatch was folded into the "sample"
-            # slice above (it is device-overlapped wall time, mirroring
-            # PR 4's overlap-aware MFU) — surface it separately here so
-            # "host" reports only EXPOSED host time while the five-phase
-            # sum still equals the tick total
-            if self.async_depth:
-                _TICK_HIDDEN.observe(self._hidden_acc)
-                self._hidden_acc = 0.0
-            force, self._gauge_force = self._gauge_force, False
-            self._refresh_gauges(force=force)
+            finally:
+                total = time.monotonic() - t0
+                with _span("serving.bookkeeping"):
+                    self._tick_bookkeeping(total)
+
+    def _tick_bookkeeping(self, total: float):
+        """What every tick owes the instruments once its work is done
+        (the ``serving.bookkeeping`` span): histograms, the tenants'
+        bill, the roofline accumulators, the gauges."""
+        ph = self._tick_phase
+        timed = sum(ph.values())
+        for name in ("prefill", "draft", "verify", "sample"):
+            _TICK_BREAKDOWN.observe(ph.get(name, 0.0), phase=name)
+        _TICK_BREAKDOWN.observe(max(0.0, total - timed), phase="host")
+        _TICK.observe(total)
+        # usage metering (ISSUE 19): bill this tick's device time
+        # and KV occupancy to the tenants holding state — the same
+        # `total` the histogram just observed, so the ledger's
+        # device-seconds reconcile with serving_tick_seconds
+        # tick-for-tick
+        if self.slo is not None:
+            self.slo.charge_tick(self, total)
+        acc = self._phase_acc
+        acc["prefill"][0] += ph.get("prefill", 0.0)
+        acc["spec_draft"][0] += ph.get("draft", 0.0)
+        acc["spec_verify"][0] += ph.get("verify", 0.0)
+        acc["decode"][0] += ph.get("sample", 0.0)
+        # overlap-aware anatomy (ISSUE 20): host work done under an
+        # in-flight device dispatch was folded into the "sample"
+        # slice above (it is device-overlapped wall time, mirroring
+        # PR 4's overlap-aware MFU) — surface it separately here so
+        # "host" reports only EXPOSED host time while the five-phase
+        # sum still equals the tick total
+        if self.async_depth:
+            _TICK_HIDDEN.observe(self._hidden_acc)
+            self._hidden_acc = 0.0
+        force, self._gauge_force = self._gauge_force, False
+        self._refresh_gauges(force=force)
 
     def _step_impl(self):
         """Exception-atomicity shim around :meth:`_step_inner` for the
@@ -2295,9 +2312,10 @@ class LLMEngine:
         eos = -1 if self.eos_token_id is None else int(self.eos_token_id)
         rng_before = self.exe.rng
         t0 = time.perf_counter()
-        with self._tick_timer("sample"):
+        with self._tick_timer("sample", "serving.decode",
+                              slots=int(act.sum())):
             nxt, ran, stop, gen = self.exe.decode_tick_async(
-                dev["tokens"], jnp.asarray(act), dev["stop"], dev["gen"],
+                dev["tokens"], act, dev["stop"], dev["gen"],
                 dev["max_gen"], self.temps, self.top_ps, eos)
         self.stats["device_s"] += time.perf_counter() - t0
         dev["tokens"], dev["stop"], dev["gen"] = nxt, stop, gen
@@ -2327,15 +2345,17 @@ class LLMEngine:
         rewinds the executor rng to its pre-split state: the sync engine
         never ran that tick, so it never consumed that key."""
         e = self._async_win.pop(0)
-        t0 = time.monotonic()
+        t0 = time.monotonic_ns()
+        fetch = _span("serving.fetch", cat="device_wait").begin(t0)
         nxt = np.asarray(e["nxt"])
         ran = np.asarray(e["ran"])
-        t1 = time.monotonic()
+        t1 = time.monotonic_ns()
+        fetch.end(t1)
         # the fetch blocks until that tick's device work completes:
         # device-overlapped wall time, billed to the "sample" slice
         self._tick_phase["sample"] = (self._tick_phase.get("sample", 0.0)
-                                      + t1 - t0)
-        self.stats["device_s"] += t1 - t0
+                                      + (t1 - t0) * 1e-9)
+        self.stats["device_s"] += (t1 - t0) * 1e-9
         if not ran.any():
             if not self._async_rewound:
                 self.exe.rng = e["rng_before"]
@@ -2354,21 +2374,25 @@ class LLMEngine:
             # emitted
             GOODPUT.waste("async_overrun", over)
         self.cur += live
-        t2 = time.monotonic()
+        slots = np.nonzero(live)[0]
+        t2 = time.monotonic_ns()
+        emit = _span("serving.emit", tokens=len(slots)).begin(t2)
         emitted = []
-        for slot in np.nonzero(live)[0]:
+        for slot in slots:
             emitted += self._emit(int(slot), int(nxt[slot]))
-        t3 = time.monotonic()
-        self.stats["host_s"] += t3 - t2
+        t3 = time.monotonic_ns()
+        emit.end(t3)
+        host = (t3 - t2) * 1e-9
+        self.stats["host_s"] += host
         if self._async_win:
             # successors are still in flight: this host work is hidden
             # under device dispatch. Fold it into the "sample" slice
             # (device-overlapped time) and surface it in the hidden-host
             # histogram; the final entry's emit is exposed host time and
             # falls through to the "host" remainder.
-            self._hidden_acc += t3 - t2
+            self._hidden_acc += host
             self._tick_phase["sample"] = (
-                self._tick_phase.get("sample", 0.0) + t3 - t2)
+                self._tick_phase.get("sample", 0.0) + host)
         return emitted
 
     def _drain_async(self, why: str):
@@ -2402,7 +2426,8 @@ class LLMEngine:
         # induce a preemption the pool never asked for
         fault_point("serving.tick", engine=self)
         fault_point("serving.preempt", engine=self)
-        self._expire()
+        with _span("serving.expire"):
+            self._expire()
         emitted = []
         if self.async_depth:
             why = self._async_block_reason()
@@ -2415,8 +2440,14 @@ class LLMEngine:
                 emitted += self._drain_async(why)
         for rid in list(self.groups):
             emitted += self._beam_advance(rid, self.groups[rid])
-        admits, beam_admits = self._admit()
-        with self._tick_timer("prefill"):
+        with _span("serving.admit") as sp:
+            chunked = set(self.prefilling) if sp.recording else ()
+            admits, beam_admits = self._admit()
+            if sp.recording:     # long prompts go straight to prefilling
+                rids = [r.req_id for _, r in (*admits, *beam_admits)]
+                rids += [r for r in self.prefilling if r not in chunked]
+                sp.set(admitted=len(rids), queued=len(self.queue), rids=rids)
+        with self._tick_timer("prefill", "serving.prefill"):
             if admits or beam_admits:
                 emitted += self._prefill(admits, beam_admits)
             emitted += self._prefill_chunks()
@@ -2475,28 +2506,31 @@ class LLMEngine:
         run_mask = self.active & ~spec_handled
         # roofline: one weight pass over the batch; every running slot
         # reads its whole block-rounded context and writes one position
-        self._acc_phase("decode", int(run_mask.sum()), 1,
-                        self._ctx_blocks(run_mask))
+        n_run = int(run_mask.sum())
+        self._acc_phase("decode", n_run, 1, self._ctx_blocks(run_mask))
         t1 = time.perf_counter()
         d_aidx = np.where(run_mask, self.slot_aidx, -1)
         d_bias = self._grammar_bias_rows(
             [(int(s), int(s)) for s in np.nonzero(run_mask)[0]],
             self.num_slots)
-        with self._tick_timer("sample"):
+        with self._tick_timer("sample", "serving.decode", slots=n_run):
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),
                 lora=self._lora_arg(d_aidx, 1), bias=d_bias)
             was_active = run_mask.copy()
-            nxt = np.asarray(nxt)             # the one per-tick host fetch
+            with _span("serving.fetch", cat="device_wait"):
+                nxt = np.asarray(nxt)         # the one per-tick host fetch
         t2 = time.perf_counter()
         if self.cp > 1:
             _CP_GATHER_S.observe(t2 - t1)
         for g in self.groups.values():        # device-resident, lazy gather
             g.logp = logp[np.asarray(g.slots)]
         self.cur += was_active                # vectorised mirrors
-        for slot in np.nonzero(was_active & ~self.is_beam)[0]:
-            emitted += self._emit(slot, int(nxt[slot]))
+        plain = np.nonzero(was_active & ~self.is_beam)[0]
+        with _span("serving.emit", tokens=len(plain)):
+            for slot in plain:
+                emitted += self._emit(slot, int(nxt[slot]))
         t3 = time.perf_counter()
         self.stats["host_s"] += (t1 - t0) + (t3 - t2)
         self.stats["device_s"] += t2 - t1

@@ -29,9 +29,16 @@ from paddle_tpu.models.paged import (PagedKVCache, _BEAM_GROUP_UPDATE_JIT,
                                      llama_verify_chunk_paged,
                                      spec_rewind_lens)
 from paddle_tpu.models.speculative import _FWD_ROWS_JIT
+from paddle_tpu.observability import span as _span
 
 # module-level so its compile cache persists across admissions
 _SAMPLE_ROWS_JIT = jax.jit(_sample_rows, static_argnums=(4,))
+
+
+def _token_rows(ids, lens) -> dict:
+    """What a prefill program is sent, counted at its entry: ``rows``
+    token-rows in the padded batch, ``useful`` of them a prompt token."""
+    return {"rows": int(np.size(ids)), "useful": int(np.sum(lens))}
 
 
 class ModelExecutor:
@@ -165,32 +172,34 @@ class ModelExecutor:
         their cache slots while other slots keep decoding state.
         ``lora`` (optional pytree, see ``models.paged._lora_delta``)
         applies the batched multi-LoRA correction per row."""
-        if self.cp > 1:
-            self._no_cp_lora(lora)
-            logits, self.cache = self._cp_prefill(
+        with _span("exe.prefill", **_token_rows(ids, lens)):
+            if self.cp > 1:
+                self._no_cp_lora(lora)
+                logits, self.cache = self._cp_prefill(
+                    self.model, jnp.asarray(ids), jnp.asarray(lens),
+                    self.cache, jnp.asarray(slots), jnp.asarray(rows))
+                return logits
+            logits, self.cache = _PREFILL_JIT(
                 self.model, jnp.asarray(ids), jnp.asarray(lens),
-                self.cache, jnp.asarray(slots), jnp.asarray(rows))
+                self.cache, jnp.asarray(slots), jnp.asarray(rows), lora=lora)
             return logits
-        logits, self.cache = _PREFILL_JIT(
-            self.model, jnp.asarray(ids), jnp.asarray(lens),
-            self.cache, jnp.asarray(slots), jnp.asarray(rows), lora=lora)
-        return logits
 
     def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
         """One chunk per row, written from an arbitrary offset over the
         slot's pool prefix (chunked prefill / prefix-cache resume)."""
-        if self.cp > 1:
-            self._no_cp_lora(lora)
-            logits, self.cache = self._cp_prefill_chunk(
+        with _span("exe.prefill_chunk", **_token_rows(ids, lens)):
+            if self.cp > 1:
+                self._no_cp_lora(lora)
+                logits, self.cache = self._cp_prefill_chunk(
+                    self.model, jnp.asarray(ids), jnp.asarray(lens),
+                    jnp.asarray(offs), self.cache, jnp.asarray(slots),
+                    jnp.asarray(rows))
+                return logits
+            logits, self.cache = _PREFILL_CHUNK_JIT(
                 self.model, jnp.asarray(ids), jnp.asarray(lens),
                 jnp.asarray(offs), self.cache, jnp.asarray(slots),
-                jnp.asarray(rows))
+                jnp.asarray(rows), lora=lora)
             return logits
-        logits, self.cache = _PREFILL_CHUNK_JIT(
-            self.model, jnp.asarray(ids), jnp.asarray(lens),
-            jnp.asarray(offs), self.cache, jnp.asarray(slots),
-            jnp.asarray(rows), lora=lora)
-        return logits
 
     def verify_chunk(self, ids, clens, offs, slot_ids, rows, lora=None):
         """Target forward over each slot's proposal window (spec decode);
@@ -225,42 +234,46 @@ class ModelExecutor:
         logp [num_slots, vocab] or None per ``need_logp``). ``lora`` is
         the per-slot multi-LoRA pytree; ``bias`` a [num_slots, V]
         grammar-mask logit bias applied before sampling."""
-        sub = self.next_key()
-        if self.cp > 1:
-            self._no_cp_lora(lora)
-            if need_logp:
-                raise NotImplementedError(
-                    "beam search (want_logp) under cp > 1 is not supported")
-            nxt, logp, self.cache = self._cp_tick(
+        with _span("exe.decode_tick", slots=int(np.sum(run_mask))):
+            sub = self.next_key()
+            if self.cp > 1:
+                self._no_cp_lora(lora)
+                if need_logp:
+                    raise NotImplementedError(
+                        "beam search (want_logp) under cp > 1 is not "
+                        "supported")
+                nxt, logp, self.cache = self._cp_tick(
+                    self.model, jnp.asarray(last_tok), self.cache,
+                    jnp.asarray(run_mask), jnp.asarray(rows),
+                    jnp.asarray(cols), jnp.asarray(vals), sub,
+                    jnp.asarray(temps), jnp.asarray(top_ps),
+                    None if bias is None else jnp.asarray(bias))
+                return nxt, logp
+            nxt, logp, self.cache = _TICK_JIT(
                 self.model, jnp.asarray(last_tok), self.cache,
-                jnp.asarray(run_mask), jnp.asarray(rows),
-                jnp.asarray(cols), jnp.asarray(vals), sub,
-                jnp.asarray(temps), jnp.asarray(top_ps),
-                None if bias is None else jnp.asarray(bias))
+                jnp.asarray(run_mask), jnp.asarray(rows), jnp.asarray(cols),
+                jnp.asarray(vals), sub, jnp.asarray(temps),
+                jnp.asarray(top_ps), self.top_k, need_logp, lora=lora,
+                logit_bias=(None if bias is None else jnp.asarray(bias)))
             return nxt, logp
-        nxt, logp, self.cache = _TICK_JIT(
-            self.model, jnp.asarray(last_tok), self.cache,
-            jnp.asarray(run_mask), jnp.asarray(rows), jnp.asarray(cols),
-            jnp.asarray(vals), sub, jnp.asarray(temps),
-            jnp.asarray(top_ps), self.top_k, need_logp, lora=lora,
-            logit_bias=(None if bias is None else jnp.asarray(bias)))
-        return nxt, logp
 
     def decode_tick_async(self, tokens, active, stop, gen, max_gen,
                           temps, top_ps, eos_id):
         """Depth-K pipelined tick (ISSUE 20): ``tokens``/``stop``/``gen``
-        are DEVICE arrays threaded from the previous call — the sampled
+        are DEVICE arrays threaded from the previous call (``active`` is
+        the host's mask) — the sampled
         token array feeds the next call without a host round trip, and
         EOS/max-gen stop is evaluated in the jit via the stop mask. No
         table updates, grammar bias, LoRA, or beam logp: the engine
         drains its window and takes :meth:`decode_tick` for any tick
         needing them. Returns (nxt, ran, stop', gen'), all on device."""
-        sub = self.next_key()
-        nxt, ran, stop, gen, self.cache = _async_tick_jit()(
-            self.model, tokens, self.cache, active, stop, gen, max_gen,
-            sub, jnp.asarray(temps), jnp.asarray(top_ps),
-            jnp.int32(eos_id), self.top_k)
-        return nxt, ran, stop, gen
+        with _span("exe.decode_tick", slots=int(np.sum(active))):
+            sub = self.next_key()
+            nxt, ran, stop, gen, self.cache = _async_tick_jit()(
+                self.model, tokens, self.cache, jnp.asarray(active), stop,
+                gen, max_gen, sub, jnp.asarray(temps), jnp.asarray(top_ps),
+                jnp.int32(eos_id), self.top_k)
+            return nxt, ran, stop, gen
 
     def apply_block_copies(self, pairs):
         """Radix prefix cache COW plan: copy each (src, dst) pool block
@@ -294,10 +307,11 @@ class ModelExecutor:
         """Per-row temperature/top-k/top-p sampling (host fetch).
         ``bias`` ([rows, V], 0 / -1e30) is the grammar-mask addend."""
         sub = self.next_key() if key is None else key
-        return np.asarray(_SAMPLE_ROWS_JIT(
-            logits.astype(jnp.float32), sub, jnp.asarray(temps),
-            jnp.asarray(top_ps), self.top_k,
-            bias=(None if bias is None else jnp.asarray(bias))))
+        with _span("exe.sample", cat="device_wait", rows=logits.shape[0]):
+            return np.asarray(_SAMPLE_ROWS_JIT(
+                logits.astype(jnp.float32), sub, jnp.asarray(temps),
+                jnp.asarray(top_ps), self.top_k,
+                bias=(None if bias is None else jnp.asarray(bias))))
 
     # -------------------------------------------------------------- draft
     def draft_rows(self, ids, rp, cl):

@@ -204,7 +204,7 @@ class Trainer:
             fault_point("train.step", step=int(self.state.step),
                         trainer=self)
             t_step = time.monotonic()
-            with _span("train.step", step=int(self.state.step)):
+            with _span("train.loop", step=int(self.state.step)):
                 micro = [self._to_batch(next(it)) for _ in range(accum)]
                 self.state, loss = self._step_fn(self.state, *micro)
                 if self.watchdog is not None:
@@ -410,7 +410,7 @@ class Trainer:
                         trainer=self)
             in_flight_before = len(window)
             t_disp = time.monotonic()
-            with _span("train.step", step=drained + len(window)):
+            with _span("train.loop", step=drained + len(window)):
                 if staged_next is not None:
                     micro, staged_next = staged_next, None
                 else:

@@ -7,8 +7,8 @@ step-timer with MFU accounting, and HLO/jaxpr dump helpers for graph debug.
 
 Since the observability subsystem landed, the names here are THIN
 DELEGATES: Profiler also drives the host-side span tracer (and writes
-its Chrome trace next to the XLA artifact on stop), RecordEvent opens an
-observability span alongside the XLA annotation, and StepTimer feeds the
+its Chrome trace next to the XLA artifact on stop), RecordEvent is an
+observability span (which annotates the XLA trace itself), and StepTimer feeds the
 shared ``train_tokens_per_sec``/``train_mfu`` gauges through the same
 :func:`~paddle_tpu.observability.flops.record_throughput` choke point the
 Trainer and bench.py use.
@@ -93,9 +93,9 @@ class Profiler:
 
 @contextlib.contextmanager
 def record_event(name: str):
-    """Ref: paddle.profiler.RecordEvent — annotates the XLA trace and the
-    host span timeline."""
-    with jax.profiler.TraceAnnotation(name), _span(name):
+    """Ref: paddle.profiler.RecordEvent — one observability span, which
+    lands in the host span timeline and in the XLA trace."""
+    with _span(name):
         yield
 
 
@@ -183,27 +183,20 @@ class ProfilerTarget:
 
 
 class RecordEvent:
-    """Ref profiler.RecordEvent: context manager/decorator annotating the
-    trace (maps onto jax.profiler.TraceAnnotation plus a host span)."""
+    """Ref profiler.RecordEvent: context manager annotating the trace
+    (an observability span, which reaches the XLA trace by itself)."""
 
     def __init__(self, name: str):
         self.name = name
-        self._cm = None
         self._span = None
 
     def begin(self):
-        self._cm = jax.profiler.TraceAnnotation(self.name)
-        self._cm.__enter__()
-        self._span = _span(self.name)
-        self._span.__enter__()
+        self._span = _span(self.name).begin()
 
     def end(self):
         if self._span is not None:
-            self._span.__exit__(None, None, None)
+            self._span.end()
             self._span = None
-        if self._cm is not None:
-            self._cm.__exit__(None, None, None)
-            self._cm = None
 
     def __enter__(self):
         self.begin()

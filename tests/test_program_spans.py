@@ -1,0 +1,446 @@
+"""The program's own names in the trace (ISSUE 25): one span mechanism
+that reaches both the tracer's buffer and the profiler's host plane, the
+spans of the serving tick and of the train step's call, the pad counter
+at the executor's entries, and a name on every Pallas kernel.
+
+All CPU, none timing-sensitive: times are only compared with each other.
+"""
+import ast
+import glob
+import re
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.observability import GOODPUT, TRACER, span
+from paddle_tpu.serving import LLMEngine, Request
+
+PALLAS_DIR = Path(pt.__file__).parent / "ops" / "pallas"
+KERNEL_NAMES = {
+    "paged_decode_attention", "paged_chunk_attention", "flash_attention_fwd",
+    "flash_attention_dq", "flash_attention_dkv", "rms_norm_fwd", "fused_rope",
+    "grouped_matmul", "grouped_matmul_dx", "grouped_matmul_dw"}
+
+
+@pytest.fixture(scope="module")
+def model():
+    pt.seed(0)
+    cfg = LlamaConfig.tiny(num_hidden_layers=2, hidden_size=32,
+                           num_attention_heads=4, num_key_value_heads=2,
+                           vocab_size=64)
+    return LlamaForCausalLM(cfg)
+
+
+def _engine(model, **kw):
+    opts = dict(num_slots=3, block_size=4, max_prompt_len=8, max_seq_len=48,
+                eos_token_id=None)
+    opts.update(kw)
+    return LLMEngine(model, **opts)
+
+
+def _traffic(eng, seed=0):
+    """Two short prompts (one padded admission forward) and one of 19
+    tokens (three chunk forwards), six tokens each."""
+    rs = np.random.RandomState(seed)
+    for n in (5, 19, 3):
+        eng.add_request(Request(rs.randint(0, 64, (n,)), max_new_tokens=6))
+
+
+def _run(eng):
+    ticks = 0
+    while eng.has_work():
+        eng.step()
+        ticks += 1
+    return ticks
+
+
+def _spans():
+    return [e for e in TRACER.export()["traceEvents"] if e["ph"] == "X"]
+
+
+def _descendants(evs, root):
+    kids = {}
+    for e in evs:
+        kids.setdefault(e["parent"], []).append(e)
+    out, todo = [], [root["id"]]
+    while todo:
+        for e in kids.get(todo.pop(), ()):
+            out.append(e)
+            todo.append(e["id"])
+    return out
+
+
+class PadCounter:
+    """The benchmark's rule (``chipbench/drivers/serve.py``): token-rows
+    sent to the two prefill entries, and how many carried a prompt token."""
+
+    def __init__(self, exe):
+        self.rows = self.useful = self.calls = 0
+        for name in ("prefill", "prefill_chunk"):
+            setattr(exe, name, self._wrap(getattr(exe, name)))
+
+    def _wrap(self, fn):
+        def counted(ids, lens, *a, **kw):
+            self.calls += 1
+            self.rows += int(np.size(ids))
+            self.useful += int(np.sum(lens))
+            return fn(ids, lens, *a, **kw)
+        return counted
+
+
+# ------------------------------------------------------------- the span
+
+def test_buffer_clock_is_perf_counters_clock():
+    """``time.monotonic_ns`` (the buffer's clock) and ``time.perf_counter``
+    (the benchmark's) read one clock on Linux, so their stamps interleave."""
+    mono = time.get_clock_info("monotonic")
+    perf = time.get_clock_info("perf_counter")
+    assert mono.implementation == perf.implementation
+    a = time.perf_counter()
+    m = time.monotonic_ns() * 1e-9
+    b = time.perf_counter()
+    assert a - 1e-6 <= m <= b + 1e-6
+
+
+def test_span_off_records_nothing_and_reads_no_clock(monkeypatch):
+    reads = []
+    real = time.monotonic_ns
+    monkeypatch.setattr(time, "monotonic_ns",
+                        lambda: reads.append(1) or real())
+    with span("ghost", n=1) as sp:
+        assert not sp.recording
+        sp.set(more=2)
+    assert reads == [] and TRACER.export()["traceEvents"] == []
+
+
+def test_span_ids_parents_cat_and_set():
+    TRACER.enable()
+    with span("outer") as outer:
+        with span("wait", cat="device_wait", rows=4) as inner:
+            inner.set(useful=3)
+        with span("second"):
+            pass
+        assert outer.recording
+    with span("alone"):
+        pass
+    by = {e["name"]: e for e in _spans()}
+    assert by["outer"]["parent"] is None and by["alone"]["parent"] is None
+    assert by["wait"]["parent"] == by["second"]["parent"] == by["outer"]["id"]
+    assert len({e["id"] for e in by.values()}) == 4
+    assert by["wait"]["cat"] == "device_wait" and by["outer"]["cat"] == "host"
+    assert by["wait"]["args"] == {"rows": 4, "useful": 3}
+    assert "args" not in by["outer"]
+
+
+def test_span_takes_the_callers_clock_reads():
+    """``begin(t)``/``end(t)``: a caller's accounting and the span share
+    one read per edge, so the two agree to the nanosecond."""
+    TRACER.enable()
+    t0 = time.monotonic_ns()
+    sp = span("slice").begin(t0)
+    t1 = time.monotonic_ns()
+    sp.end(t1)
+    (ev,) = _spans()
+    assert ev["ts"] == t0 / 1e3 and ev["dur"] == (t1 - t0) / 1e3
+
+
+def test_decorated_span_nests_under_the_callers_span():
+    @span("callee")
+    def f():
+        return 1
+
+    TRACER.enable()
+    with span("caller"):
+        f()
+    f()
+    evs = _spans()
+    caller = next(e for e in evs if e["name"] == "caller")
+    callees = [e for e in evs if e["name"] == "callee"]
+    assert [e["parent"] for e in callees] == [caller["id"], None]
+
+
+# ------------------------------------------------------ the serving tick
+
+def test_engine_run_with_everything_off_leaves_the_buffer_empty(model):
+    eng = _engine(model)
+    _traffic(eng)
+    _run(eng)
+    assert TRACER.export()["traceEvents"] == []
+
+
+def test_every_tick_is_one_step_span_with_its_children_inside(model):
+    eng = _engine(model)
+    _traffic(eng)
+    TRACER.enable()
+    ticks = _run(eng)
+    TRACER.disable()
+    evs = _spans()
+    ids = {e["id"] for e in evs}
+    assert len(ids) == len(evs)
+    assert all(e["parent"] is None or e["parent"] in ids for e in evs)
+    steps = [e for e in evs if e["name"] == "serving.step"]
+    assert len(steps) == ticks
+    assert [e["args"]["tick"] for e in steps] == list(range(1, ticks + 1))
+    assert all(e["parent"] is None for e in steps)
+    tid = steps[0]["tid"]
+    seen = set()
+    for st in steps:
+        inside = _descendants(evs, st)
+        names = [e["name"] for e in inside]
+        seen.update(names)
+        assert names.count("serving.bookkeeping") == 1
+        assert names.count("serving.expire") == 1
+        for e in inside:
+            assert e["tid"] == tid
+            assert st["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= st["ts"] + st["dur"] + 1e-3
+    # every span of the tick belongs to some tick
+    in_ticks = {e["id"] for st in steps for e in _descendants(evs, st)}
+    assert in_ticks | {s["id"] for s in steps} == ids
+    assert seen >= {"serving.expire", "serving.admit", "serving.prefill",
+                    "exe.prefill", "exe.prefill_chunk", "exe.sample",
+                    "serving.decode", "exe.decode_tick", "serving.fetch",
+                    "serving.emit", "serving.bookkeeping"}
+    waits = {e["name"] for e in evs if e["cat"] == "device_wait"}
+    assert waits == {"exe.sample", "serving.fetch"}
+    by_id = {e["id"]: e for e in evs}
+    for e in evs:
+        if e["name"].startswith("exe.prefill"):
+            assert by_id[e["parent"]]["name"] == "serving.prefill"
+        if e["name"] in ("exe.decode_tick", "serving.fetch"):
+            assert by_id[e["parent"]]["name"] == "serving.decode"
+    # one emit span a decode tick; its tokens are the tick's tokens
+    emits = [e for e in evs if e["name"] == "serving.emit"]
+    decodes = [e for e in evs if e["name"] == "serving.decode"]
+    assert len(emits) == len(decodes)
+    assert ([e["args"]["tokens"] for e in emits]
+            == [e["args"]["slots"] for e in decodes])
+    admitted = sum(e["args"]["admitted"] for e in evs
+                   if e["name"] == "serving.admit")
+    assert admitted == 3
+    total = sum(len(r.tokens) for r in eng.requests.values())
+    first = 3                     # each request's first token comes from
+    assert sum(e["args"]["tokens"] for e in emits) == total - first  # prefill
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_span_pad_counts_equal_the_wrappers_exactly(model, depth):
+    eng = _engine(model, async_depth=depth)
+    pads = PadCounter(eng.exe)
+    _traffic(eng, seed=depth)
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    sent = [e for e in _spans() if e["name"].startswith("exe.prefill")]
+    assert len(sent) == pads.calls > 2
+    assert sum(e["args"]["rows"] for e in sent) == pads.rows
+    assert sum(e["args"]["useful"] for e in sent) == pads.useful == 27
+    assert {e["name"] for e in sent} == {"exe.prefill", "exe.prefill_chunk"}
+
+
+def test_async_ticks_fetch_and_emit_under_the_tick(model):
+    eng = _engine(model, async_depth=2)
+    _traffic(eng)
+    TRACER.enable()
+    ticks = _run(eng)
+    TRACER.disable()
+    evs = _spans()
+    steps = [e for e in evs if e["name"] == "serving.step"]
+    assert len(steps) == ticks
+    step_ids = {e["id"] for e in steps}
+    fetches = [e for e in evs if e["name"] == "serving.fetch"]
+    assert fetches and all(e["cat"] == "device_wait" for e in fetches)
+    # a drained tick's fetch hangs off the tick itself, a synchronous
+    # tick's off its decode slice; both lie inside some tick
+    inside = {e["id"] for st in steps for e in _descendants(evs, st)}
+    assert {e["id"] for e in fetches} <= inside
+    assert any(e["parent"] in step_ids for e in fetches)
+    total = sum(len(r.tokens) for r in eng.requests.values())
+    emitted = sum(e["args"]["tokens"] for e in evs
+                  if e["name"] == "serving.emit")
+    assert emitted == total - 3
+
+
+def test_pad_rows_waste_counts_the_admission_forward_too(model):
+    """``_prefill``'s whole unused rows count as ``_prefill_chunks``' do."""
+    eng = _engine(model)          # 3 slots x 8 tokens a padded forward
+    eng.add_request(Request(np.arange(1, 6), max_new_tokens=2))
+    eng.step()
+    assert GOODPUT.waste_by_why().get("pad_rows") == (3 - 1) * 8
+    rs = np.random.RandomState(4)
+    eng.add_request(Request(rs.randint(0, 64, (19,)), max_new_tokens=2))
+    _run(eng)
+    # three chunk forwards of one row each on top of the admission's
+    assert GOODPUT.waste_by_why()["pad_rows"] == (3 - 1) * 8 * 4
+
+
+def test_profiler_alone_turns_the_spans_on_and_holds_them_by_name(
+        model, tmp_path):
+    """No tracer, no option: ``jax.profiler.start_trace`` is the switch.
+    The spans land in the buffer and in the trace's host plane."""
+    from jax.profiler import ProfileData
+    eng = _engine(model)
+    _traffic(eng)
+    eng.step()                               # compiled before the trace
+    assert not TRACER.enabled and _spans() == []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ticks = _run(eng)
+    finally:
+        jax.profiler.stop_trace()
+    steps = [e for e in _spans() if e["name"] == "serving.step"]
+    assert len(steps) == ticks > 3
+    eng.add_request(Request(np.arange(1, 5), max_new_tokens=2))
+    _run(eng)                                # the profile is over:
+    assert len(_spans()) == len(TRACER.export()["traceEvents"])
+    assert sum(e["name"] == "serving.step" for e in _spans()) == ticks
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:CPU")]
+    names = [ev.name for p in host for line in p.lines for ev in line.events]
+    assert names.count("serving.step") == ticks
+    for name in ("serving.decode", "exe.decode_tick", "serving.fetch",
+                 "exe.prefill_chunk", "serving.bookkeeping"):
+        assert name in names, name
+
+
+# ------------------------------------------------------- the train step
+
+def test_instrumented_jit_call_is_a_span_with_its_signature_pass():
+    from paddle_tpu.observability.compile import instrumented_jit
+    step = instrumented_jit(lambda s, x: (s + x, (s * x).sum()),
+                            name="train.step")
+    s, x = jnp.ones(4), jnp.ones(4)
+    step(s, x)                               # compiles; nothing recorded
+    assert _spans() == []
+    TRACER.enable()
+    for _ in range(3):
+        step(s, x)
+    TRACER.disable()
+    evs = _spans()
+    calls = [e for e in evs if e["name"] == "train.step"]
+    sigs = [e for e in evs if e["name"] == "jit.signature"]
+    assert len(calls) == len(sigs) == 3 and len(evs) == 6
+    assert [e["parent"] for e in sigs] == [e["id"] for e in calls]
+
+
+# ------------------------------------------------------ the kernel names
+
+def _pallas_calls():
+    for path in sorted(PALLAS_DIR.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                yield f"{path.name}:{node.lineno}", node
+
+
+def test_every_pallas_call_site_passes_a_name():
+    sites = dict(_pallas_calls())
+    assert len(sites) == 9
+    names = []
+    for where, call in sites.items():
+        kw = {k.arg: k.value for k in call.keywords}
+        assert "name" in kw, f"{where}: pallas_call without name="
+        v = kw["name"]
+        if isinstance(v, ast.Constant):
+            names.append(v.value)
+        else:                    # the one site two kernels reach: its
+            assert isinstance(v, ast.Name), where    # callers name it
+    assert len(set(names)) == len(names) == 8
+    assert set(names) <= KERNEL_NAMES
+
+
+def _tpu_lowering(fn, args, wrt):
+    """``fn`` lowered for a TPU from this CPU (Mosaic kernels and all),
+    as text with locations: a kernel's ``name`` is a scope of its call.
+    Called inside a scope, as every kernel is in a model (autodiff wraps
+    the outermost scope of a backward kernel: ``transpose(jvp(part))``);
+    with ``wrt``, forward and backward of its sum."""
+    def part(*a):
+        with jax.named_scope("part"):
+            out = fn(*a)
+        return out.astype(jnp.float32).sum() if wrt else out
+    if wrt:
+        part = jax.value_and_grad(part, wrt)
+    return jax.jit(part).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def _kernel_cases():
+    """(the names expected, a function, its arguments, what to
+    differentiate by or None)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    from paddle_tpu.ops.pallas import norms, paged_attention as pa, rope
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    S = jax.ShapeDtypeStruct
+    pool = S((16, 16, 2, 128), bf16)
+    tables, lens = S((2, 4), jnp.int32), S((2,), jnp.int32)
+    qkv = S((1, 256, 2, 128), bf16)
+    return [
+        (("paged_decode_attention",),
+         lambda q, k, v, t, l: pa.paged_decode_attention_pallas(
+             q, k, v, t, l, interpret=False),
+         (S((2, 4, 128), bf16), pool, pool, tables, lens), None),
+        (("paged_chunk_attention",),
+         lambda q, k, v, t, o, c: pa.paged_chunk_attention_pallas(
+             q, k, v, t, o, c, interpret=False),
+         (S((2, 16, 4, 128), bf16), pool, pool, tables, lens, lens), None),
+        (("rms_norm_fwd",),
+         lambda x, w: norms.rms_norm(x, w, 1e-5, interpret=False),
+         (S((16, 256), bf16), S((256,), bf16)), None),
+        (("fused_rope",),
+         lambda x, c, s: rope.fused_rope(x, c, s, interpret=False),
+         (S((1, 8, 4, 128), bf16), S((8, 64), f32), S((8, 64), f32)), None),
+        (("flash_attention_fwd", "flash_attention_dq",
+          "flash_attention_dkv"),
+         lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                            interpret=False),
+         (qkv, qkv, qkv), (0, 1, 2)),
+        (("grouped_matmul", "grouped_matmul_dx", "grouped_matmul_dw"),
+         lambda x, w, g: gmm.grouped_matmul(x, w, g, interpret=False,
+                                            impl="pallas"),
+         (S((256, 128), bf16), S((2, 128, 128), bf16), S((2,), jnp.int32)),
+         (0, 1)),
+    ]
+
+
+@pytest.mark.parametrize("case", range(6))
+def test_kernel_names_reach_the_tpu_lowering(case):
+    """What the device trace prints for a Mosaic custom call is the last
+    scope of its ``op_name``: the kernel's ``name``, else the enclosing
+    scope or jitted function. Every kernel's call must carry its own."""
+    names, fn, args, wrt = _kernel_cases()[case]
+    text = _tpu_lowering(fn, args, wrt)
+    scopes = re.findall(r'loc\("[^"]*?/([^/"]+)/pallas_call"', text)
+    assert "tpu_custom_call" in text
+    assert set(scopes) == set(names), scopes
+
+
+def test_model_parts_carry_their_scopes(model):
+    """attention / mlp / norm / lm_head / sampler on the serving tick."""
+    from paddle_tpu.models.paged import PagedKVCache, llama_decode_tick
+    cfg = model.cfg
+    cache = PagedKVCache.init(cfg.num_hidden_layers, 8, 4,
+                              cfg.num_key_value_heads, 8, 2, 4, cfg.dtype)
+    i32 = jnp.int32
+    text = jax.jit(llama_decode_tick, static_argnums=(10, 11)).trace(
+        model, jnp.zeros(2, i32), cache, jnp.ones(2, bool),
+        jnp.full(1, 2, i32), jnp.zeros(1, i32), jnp.zeros(1, i32),
+        jax.random.PRNGKey(0), jnp.zeros(2), jnp.ones(2), None, False
+    ).lower().as_text(debug_info=True)
+    for scope in ("attention", "mlp", "norm", "lm_head", "sampler"):
+        assert re.search(rf'loc\("jit\(llama_decode_tick\)/{scope}/', text), \
+            scope
+    train = jax.jit(lambda m, i: m.loss(i, i)).trace(
+        model, jnp.zeros((1, 8), i32)).lower().as_text(debug_info=True)
+    for scope in ("attention", "mlp", "norm", "lm_head"):
+        assert f"/{scope}/" in train, scope
