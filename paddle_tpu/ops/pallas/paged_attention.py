@@ -5,32 +5,42 @@ fused_multi_transformer_op.cu`` block KV cache).
 TPU-first design: the KV cache is a POOL of fixed-size blocks
 ([num_blocks, block_size, H_kv, D]) shared by all sequences; each sequence
 owns a row of ``block_tables`` (pool indices). Attention reads a
-sequence's blocks pool-directly via a scalar-prefetched block table
-(``pltpu.PrefetchScalarGridSpec``) — the kernel's index_map picks the
-physical block for each grid step, so the gathered K/V is NEVER
-materialised: HBM holds pool ≈ Σ actual lengths (not B × max_len) and VMEM
-holds one block at a time.
+sequence's blocks pool-directly through a scalar-prefetched block table
+(``pltpu.PrefetchScalarGridSpec``), so the gathered K/V is NEVER
+materialised: HBM holds pool ≈ Σ actual lengths (not B × max_len).
 
 Two kernels share that scheme:
 
-* **decode** — q [B, H, D] (one token per sequence), grid
-  (B*H, kv-block), lens [B] masking the ragged tail.
+* **decode** — q [B, H, D] (one token per sequence), grid (B,): one step
+  per sequence. The pools stay in HBM as stored; the kernel walks the
+  row's LIVE blocks only (lens [B], the window, cp ownership), copying
+  ``decode_blocks_per_step`` ``[bs, H_kv, D]`` slabs at a time (every K/V
+  head of a block in one copy, so GQA fetches nothing twice) into two
+  VMEM slots a pool, the next compute block in flight under this one's
+  arithmetic. VMEM holds four such buffers (2 MB in all) and the
+  sequence's ``[H, D]`` queries. Mosaic copies a slab only as whole
+  tiles of the pool's layout (``decode_slab_is_tiled``: D in 128 lanes,
+  H_kv in whole sublane tiles, which every 128-wide GQA/MHA family
+  meets); head_dim 64 and bf16/int8 MQA pools take the XLA gather.
 * **chunk** (ISSUE 11) — the ragged MULTI-query forward behind chunked
   prefill and the spec-decode ``(slots, k+1)`` verify batch: q
   [A, C, H, D] chunk queries at positions ``offsets[a] ..
   offsets[a]+chunk_lens[a]-1``, attending causally over the slot's whole
-  pool prefix. Grid (A*H_kv, q-tile, kv-block); the H/H_kv query heads of
-  a KV head fold into the q tile, so GQA needs no repeated K/V.
+  pool prefix. Grid (A*H_kv, q-tile, kv-block), one ``[bs, D]`` tile of a
+  head-major copy of the pool a step; the H/H_kv query heads of a KV head
+  fold into the q tile, so GQA needs no repeated K/V.
 
-Unused table slots hold the OOB sentinel (= num_blocks): index maps clamp
-it, the compute is masked off by the length scalars.
+Unused table slots hold the OOB sentinel (= num_blocks): the decode
+kernel never reads them, the chunk kernel's index maps clamp them and the
+length scalars mask the compute off.
 
 Dispatch functions (``paged_decode_attention`` /
 ``paged_chunk_attention``) pick Pallas on TPU and the XLA gather
-reference elsewhere. On TPU a kernel that fails to trace or lower raises:
-there is no downgrade to the gather path. ``PT_PAGED_CHUNK=0`` force-kills
-the chunk kernel (``=interpret`` forces the interpreted kernel off-TPU,
-the engine-level parity mode).
+reference elsewhere, from the backend and the shapes alone. On TPU a
+kernel that fails to trace or lower raises: there is no downgrade to the
+gather path. ``PT_PAGED_CHUNK=0`` force-kills the chunk kernel
+(``=interpret`` forces the interpreted kernel off-TPU, the engine-level
+parity mode).
 """
 from __future__ import annotations
 
@@ -59,91 +69,178 @@ def _note_trace(event: str):
     _trace_events.append(event)
 
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                         block_size, scale, max_blocks, window, quantized,
-                         partials, n_pool=0):
-    """Grid (B*H, max_blocks); block j of row bh is pool block
-    tables[bh, j] (resolved by the BlockSpec index maps). ``quantized``
-    (static) adds two per-position scale refs after v_ref: the pool holds
-    int8 and K/V are dequantized in-kernel (f32 multiply — the matmul
-    already upcasts, so the bf16 trace is unchanged when off).
-    ``partials`` (static) is the context-parallel output mode: instead
-    of the normalised output, emit the raw online-softmax triple
-    (acc, m, l) and skip table entries this shard does not own (the
-    caller translated non-owned global block ids to the OOB sentinel) —
-    the cross-shard merge renormalises. Off, the trace is byte-identical
-    to the pre-cp kernel."""
+# VMEM one K (or V) buffer of the decode kernel may fill; the kernel keeps
+# four (K and V, two slots each). The number of pool blocks a compute
+# block gathers follows from it and from the shape of a block.
+_DECODE_BUFFER_BYTES = 512 * 1024
+
+
+def decode_blocks_per_step(block_size, h_kv, d, dtype, max_blocks):
+    """Pool blocks the decode kernel gathers and scores at once: as many
+    ``[block_size, H_kv, D]`` slabs as fit ``_DECODE_BUFFER_BYTES``, at
+    least one, at most the table's width."""
+    slab = block_size * h_kv * d * jnp.dtype(dtype).itemsize
+    return int(max(1, min(_DECODE_BUFFER_BYTES // slab, max_blocks)))
+
+
+def decode_slab_is_tiled(h_kv, d, dtype):
+    """Whether Mosaic can copy a ``[bs, H_kv, D]`` slab of the pool. A
+    copy moves whole tiles of the last two dims: D has to fill 128-lane
+    rows, and H_kv the sublane tile Mosaic gives the dtype (the rows one
+    32-bit sublane packs: 1 f32, 2 bf16, 4 int8; doubled up to H_kv or
+    8). Off it (head_dim 64, bf16 or int8 MQA) the slice is refused:
+    ``tests/test_tpu_compile.py`` compiles both sides of this rule."""
+    tile = 4 // jnp.dtype(dtype).itemsize
+    while tile < min(h_kv, 8):
+        tile *= 2
+    return d % 128 == 0 and h_kv % tile == 0
+
+
+def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest,
+                         block_size, scale, max_blocks, per_step, kv_rep,
+                         window, quantized, partials, n_pool):
+    """Grid (B,): one step per sequence, the whole ``[H, D]`` query tile
+    in VMEM. The pools stay in HBM as stored (``[N, bs, H_kv, D]``); a
+    loop over the row's live compute blocks gathers ``per_step`` table
+    entries each (one ``[bs, H_kv, D]`` slab per entry and pool, every
+    K/V head in it) into one of two VMEM slots, the next compute block's
+    copies in flight under this one's arithmetic. Blocks past the live
+    length, below the window, or (``partials``) owned by another shard
+    are neither fetched nor walked.
+
+    A compute block is scored in one matmul over its ``[T*H_kv, D]`` rows
+    (T tokens x every K/V head, the slab order): row h of the
+    ``[H, T*H_kv]`` scores keeps the columns of its own K/V head
+    (h // kv_rep), the mask drops the rest with the ragged tail. Online
+    softmax state (m, l, acc) is float32 and carried by the loop.
+    ``quantized`` (static): int8 pools, the per-(position, head) scales
+    arrive gathered along the table and multiply the scores (K) and the
+    probabilities (V) column-wise. ``partials`` (static): emit the raw
+    (acc, m, l) triple for the cross-shard merge."""
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
     if partials:
-        o_ref, m_ref, l_ref, acc, m_sc, l_sc = rest
+        o_ref, m_ref, l_ref, kbuf, vbuf, sems = rest
     else:
-        o_ref, acc, m_sc, l_sc = rest
-    bh = pl.program_id(0)
-    j = pl.program_id(1)
+        o_ref, kbuf, vbuf, sems = rest
+    b = pl.program_id(0)
+    bs, P = block_size, per_step
+    h, d = q_ref.shape[1:]
+    h_kv = h // kv_rep
+    cols = P * bs * h_kv              # K/V rows of one compute block
 
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_sc[0, 0] = _NEG_INF
-        l_sc[0, 0] = 0.0
-
-    seq_len = lens_ref[bh, 0]
-    n_live = pl.cdiv(seq_len, block_size)
-    live = j < n_live
-    if partials:
-        # ownership mask: under cp the table row interleaves blocks of
-        # every shard; non-owned entries were translated to the local
-        # sentinel (= local num_blocks) and contribute NOTHING here —
-        # their positions are covered by the owning shard's partial
-        live &= tables_ref[bh, j] < n_pool
+    seq_len = lens_ref[b]
+    n_live = pl.cdiv(seq_len, bs)
+    first = 0
     if window is not None:
-        # sliding window: only the last `window` positions are visible —
-        # blocks entirely below seq_len - window are dead
-        live &= (j + 1) * block_size > seq_len - window
+        # blocks entirely below seq_len - window are invisible
+        first = jnp.maximum(seq_len - window, 0) // bs
+    c_lo = first // P
+    c_hi = pl.cdiv(n_live, P)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]          # [1, D] — this head's single query row
-        k = k_ref[0, 0].astype(jnp.float32)   # [block_size, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # per-(position, head) absmax scales: [block_size, 1]
-            # broadcasts over D
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(q.astype(jnp.float32), k,
+    def entry(j):
+        return tables_ref[b, jnp.minimum(j, max_blocks - 1)]
+
+    def each_copy(c, slot, act):
+        """``act`` on the K and the V copy of every entry of compute
+        block c that is fetched (a scalar loop: one copy's code)."""
+        def one(p, _):
+            j = c * P + p
+            go = (j >= first) & (j < n_live)
+            if partials:
+                go &= entry(j) < n_pool
+            blk = jnp.minimum(entry(j), n_pool - 1)
+            rows = pl.ds(p * bs, bs)
+
+            @pl.when(go)
+            def _():
+                act(pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, rows],
+                                          sems.at[0, slot]))
+                act(pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, rows],
+                                          sems.at[1, slot]))
+        jax.lax.fori_loop(0, P, one, None)
+
+    def start(c, slot):
+        each_copy(c, slot, lambda copy: copy.start())
+
+    def wait(c, slot):
+        each_copy(c, slot, lambda copy: copy.wait())
+
+    # rows no copy fills (the ragged tail of the last compute block) meet
+    # probability 0 in the P.V matmul: they must be finite, and fresh
+    # VMEM need not be
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(c_lo < c_hi)
+    def _():
+        start(c_lo, c_lo % 2)
+
+    q = q_ref[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    tok = col // h_kv                 # token of a column, in the block
+    tok_block = tok // bs             # and which of the P entries holds it
+    own_head = (jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1) % h_kv
+                == jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0)
+                // kv_rep)
+
+    def body(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < c_hi)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k = kbuf[slot]
+        v = vbuf[slot].astype(jnp.float32).reshape(cols, d)
+        if k.dtype != q.dtype:
+            k = k.astype(jnp.float32)
+        # [H, D] x [T*H_kv, D]^T: bf16 operands keep every product exact
+        # in the float32 accumulator
+        s = jax.lax.dot_general(q.astype(k.dtype), k.reshape(cols, d),
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        # mask positions beyond the sequence length within the last block
-        pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if quantized:
+            s = s * ks_ref[0, pl.ds(c, 1), :]
+        pos = c * (P * bs) + tok
         keep = pos < seq_len
         if window is not None:
             keep &= pos >= seq_len - window
-        s = jnp.where(keep, s, _NEG_INF)
-        m_prev = m_sc[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[0, 0] = l_sc[0, 0] * corr + jnp.sum(p)
-        m_sc[0, 0] = m_new
-        pv = jax.lax.dot_general(p, v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc[:] = acc[:] * corr + pv
-
-    @pl.when(j == max_blocks - 1)
-    def _finalize():
         if partials:
-            # emit the raw triple; m/l lane-replicated (vector store —
-            # scalar VMEM stores hit Mosaic layout restrictions)
-            o_ref[0] = acc[:].astype(o_ref.dtype)
-            m_ref[0] = jnp.full((1, 128), m_sc[0, 0], jnp.float32)
-            l_ref[0] = jnp.full((1, 128), l_sc[0, 0], jnp.float32)
-        else:
-            o_ref[0] = (acc[:] / jnp.maximum(l_sc[0, 0], 1e-30)
-                        ).astype(o_ref.dtype)
+            # columns of entries this shard does not own: not fetched,
+            # the slot still holds an older block there
+            owned = jnp.zeros_like(keep)
+            for p in range(P):      # unrolled: a loop cannot carry a mask
+                owned |= (tok_block == p) & (entry(c * P + p) < n_pool)
+            keep &= owned
+        keep = own_head & keep
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        # a row with no visible column here (partials) has m_new ==
+        # _NEG_INF and exp(0) == 1 everywhere: the select zeroes it
+        prob = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * corr + jnp.sum(prob, axis=1, keepdims=True)
+        if quantized:
+            prob = prob * vs_ref[0, pl.ds(c, 1), :]
+        pv = jax.lax.dot_general(prob, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    m, l, acc = jax.lax.fori_loop(
+        c_lo, c_hi, body,
+        (jnp.full((h, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+    if partials:
+        # m/l lane-replicated: a [H, 1] store is a masked one
+        o_ref[0] = acc
+        m_ref[0] = jnp.broadcast_to(m, (h, 128))
+        l_ref[0] = jnp.broadcast_to(l, (h, 128))
+    else:
+        # a row of length 0 walked nothing: l == 0, emit 0, not NaN
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lens, *,
@@ -159,94 +256,86 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, lens, *,
     ``partials=True`` (context parallelism), the un-normalised
     online-softmax triple (acc [B, H, D] f32, m [B, H] f32, l [B, H]
     f32) over the table entries < N only (non-owned entries hold the
-    OOB sentinel and are skipped)."""
+    OOB sentinel and are skipped).
+
+    The pools are handed to the kernel in HBM as they are stored: no
+    transpose, no pool-sized temporary. VMEM holds two slots of
+    ``decode_blocks_per_step`` blocks for K and for V. Compiled
+    (``interpret=False``) the shape has to meet
+    ``decode_slab_is_tiled``."""
     b, h, d = q.shape
     n, bs, h_kv, _ = k_pool.shape
-    kv_rep = h // h_kv
     max_blocks = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scale is not None
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    per_step = decode_blocks_per_step(bs, h_kv, d, k_pool.dtype, max_blocks)
+    cols = per_step * bs * h_kv
+    tables = block_tables.astype(jnp.int32)
 
-    # one grid row per (sequence, q head)
-    qf = q.reshape(b * h, 1, d)
-    tables_bh = jnp.repeat(block_tables.astype(jnp.int32), h, axis=0)
-    lens_bh = jnp.repeat(lens.astype(jnp.int32), h)[:, None]
-
-    # pool per kv head: [H_kv, N, bs, D] — one (head, block) tile is a
-    # contiguous [bs, D] slice
-    kp = jnp.moveaxis(k_pool, 2, 0)
-    vp = jnp.moveaxis(v_pool, 2, 0)
-
-    def kv_index(bh, j, tables, lens):
-        # unused slots hold the OOB sentinel (num_blocks) — clamp; their
-        # compute is masked off by lens in the kernel
-        return ((bh % h) // kv_rep, jnp.minimum(tables[bh, j], n - 1), 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, d), lambda bh, j, t, l: (bh, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
-    ]
-    operands = [qf, kp, vp]
+    row = lambda i, t, l: (i, 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, h, d), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q, k_pool, v_pool]
     if quantized:
-        # scale pools ride the same index map as their int8 pools:
-        # [H_kv, N, bs, 1], one lane per position
-        in_specs += [pl.BlockSpec((1, 1, bs, 1), kv_index),
-                     pl.BlockSpec((1, 1, bs, 1), kv_index)]
-        operands += [jnp.moveaxis(k_scale, 2, 0)[..., None],
-                     jnp.moveaxis(v_scale, 2, 0)[..., None]]
+        # the scales are small (4 bytes a position and head): gathered
+        # along the table here, a row of compute blocks a sequence
+        n_steps = -(-max_blocks // per_step)
+        clamped = jnp.minimum(tables, n - 1)
 
-    out_idx = lambda bh, j, t, l: (bh, 0, 0)  # noqa: E731
-    out_specs = pl.BlockSpec((1, 1, d), out_idx)
-    out_shape = jax.ShapeDtypeStruct((b * h, 1, d), q.dtype)
+        def along_table(pool):
+            g = jnp.take(pool, clamped, axis=0).reshape(b, max_blocks, -1)
+            g = jnp.pad(g, ((0, 0), (0, n_steps * per_step - max_blocks),
+                            (0, 0)))
+            return g.reshape(b, n_steps, cols)
+
+        in_specs += [pl.BlockSpec((1, n_steps, cols), row)] * 2
+        operands += [along_table(k_scale), along_table(v_scale)]
+
+    out_specs = pl.BlockSpec((1, h, d), row)
+    out_shape = jax.ShapeDtypeStruct((b, h, d), q.dtype)
     if partials:
         # acc in f32 (the merge renormalises before the dtype cast) plus
         # lane-replicated m/l rows
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, 1, 128), out_idx),
-                     pl.BlockSpec((1, 1, 128), out_idx)]
-        out_shape = [jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32),
-                     jax.ShapeDtypeStruct((b * h, 1, 128), jnp.float32),
-                     jax.ShapeDtypeStruct((b * h, 1, 128), jnp.float32)]
+        out_specs = [out_specs, pl.BlockSpec((1, h, 128), row),
+                     pl.BlockSpec((1, h, 128), row)]
+        out_shape = [jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+                     jax.ShapeDtypeStruct((b, h, 128), jnp.float32),
+                     jax.ShapeDtypeStruct((b, h, 128), jnp.float32)]
+    slots = (2, per_step * bs, h_kv, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * h, max_blocks),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            # running max / denom are SCALARS: Mosaic rejects scalar stores
-            # to VMEM, so they live in SMEM scratch
-            pltpu.SMEM((1, 1), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(slots, k_pool.dtype),
+                        pltpu.VMEM(slots, v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))],
     )
     kernel = functools.partial(_paged_decode_kernel, block_size=bs,
                                scale=scale, max_blocks=max_blocks,
+                               per_step=per_step, kv_rep=h // h_kv,
                                window=window, quantized=quantized,
                                partials=partials, n_pool=n)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        # (sequence-head, block) grid: rows are independent; declaring the
-        # row axis parallel lets Mosaic pipeline pool-block DMAs across rows
-        # (measured 3.5x on the flash grids — benchmarks/_perf_banded.py)
+        # sequences are independent: every step sets up its own state
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
+            dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
         # the kernel is handed over as a functools.partial, which has no
         # __name__: without this the custom call prints under the name of
         # the jitted program around it
         name="paged_decode_attention",
-    )(tables_bh, lens_bh, *operands)
+    )(tables, lens.astype(jnp.int32), *operands)
     if partials:
         acc, m, l = out
-        return (acc.reshape(b, h, d), m[:, 0, 0].reshape(b, h),
-                l[:, 0, 0].reshape(b, h))
-    return out.reshape(b, h, d)
+        return acc, m[..., 0], l[..., 0]
+    return out
 
 
 def paged_decode_attention_xla(q, k_pool, v_pool, block_tables, lens, *,
@@ -306,7 +395,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
                            scale=None, window=None, k_scale=None,
                            v_scale=None, partials=False,
                            interpret: bool | None = None):
-    """Dispatch: Pallas on TPU (pool-direct block reads), XLA elsewhere.
+    """Dispatch: Pallas on TPU (pool-direct block reads) for pools whose
+    slabs Mosaic can copy (``decode_slab_is_tiled``), XLA elsewhere.
     ``window``: sliding-window bound — only the last `window` positions
     are visible (Mistral decode semantics). ``k_scale``/``v_scale``
     [N, bs, H_kv] f32 mark an int8 pool — dequantize-on-read in both
@@ -321,12 +411,14 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
     if partials:
         _note_trace("decode:partials")
     if mosaic_kernels_apply():
-        out = paged_decode_attention_pallas(
-            q, k_pool, v_pool, block_tables, lens, scale=scale,
-            window=window, k_scale=k_scale, v_scale=v_scale,
-            partials=partials, interpret=interpret)
-        _note_trace("decode:pallas")
-        return out
+        if decode_slab_is_tiled(*k_pool.shape[2:], k_pool.dtype):
+            out = paged_decode_attention_pallas(
+                q, k_pool, v_pool, block_tables, lens, scale=scale,
+                window=window, k_scale=k_scale, v_scale=v_scale,
+                partials=partials, interpret=interpret)
+            _note_trace("decode:pallas")
+            return out
+        _note_trace("decode:slab-off-tiling")
     _note_trace("decode:xla")
     return paged_decode_attention_xla(q, k_pool, v_pool, block_tables, lens,
                                       scale=scale, window=window,

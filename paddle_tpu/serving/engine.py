@@ -2507,13 +2507,17 @@ class LLMEngine:
         # roofline: one weight pass over the batch; every running slot
         # reads its whole block-rounded context and writes one position
         n_run = int(run_mask.sum())
-        self._acc_phase("decode", n_run, 1, self._ctx_blocks(run_mask))
+        ctx = self._ctx_blocks(run_mask)
+        self._acc_phase("decode", n_run, 1, ctx)
         t1 = time.perf_counter()
         d_aidx = np.where(run_mask, self.slot_aidx, -1)
         d_bias = self._grammar_bias_rows(
             [(int(s), int(s)) for s in np.nonzero(run_mask)[0]],
             self.num_slots)
-        with self._tick_timer("sample", "serving.decode", slots=n_run):
+        # kv_blocks: the pool blocks the decode kernel walks this tick
+        # (what is left of slots x table width)
+        with self._tick_timer("sample", "serving.decode", slots=n_run,
+                              kv_blocks=ctx // self.block_size):
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),

@@ -95,14 +95,16 @@ def _paged(kernel):
     def build(monkeypatch):
         from paddle_tpu.ops.pallas import paged_attention as pa
         monkeypatch.setattr(pa, f"paged_{kernel}_attention_pallas", _boom)
-        pool = jnp.ones((4, 8, 2, 16), jnp.float32)
+        # head_dim 128: a slab Mosaic can copy, so the decode dispatcher
+        # takes its kernel on a TPU
+        pool = jnp.ones((4, 8, 2, 128), jnp.float32)
         tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
         lens = jnp.asarray([10, 13], jnp.int32)
         if kernel == "decode":
-            q = jnp.ones((2, 4, 16), jnp.float32)
+            q = jnp.ones((2, 4, 128), jnp.float32)
             return lambda: pa.paged_decode_attention(q, pool, pool, tables,
                                                      lens)
-        q = jnp.ones((2, 4, 4, 16), jnp.float32)
+        q = jnp.ones((2, 4, 4, 128), jnp.float32)
         return lambda: pa.paged_chunk_attention(
             q, pool, pool, tables, lens - 4, jnp.asarray([4, 3], jnp.int32))
     return build

@@ -156,9 +156,10 @@ def test_pallas_failure_raises_no_downgrade(monkeypatch, kernel):
                                                 cls)
     else:
         monkeypatch.setattr(pa, "paged_decode_attention_pallas", boom)
-        q = jnp.asarray(rng.normal(size=(2, 4, 16)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(16, 8, 2, 16)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(16, 8, 2, 16)), jnp.float32)
+        # head_dim 128: a slab the kernel can copy (decode_slab_is_tiled)
+        q = jnp.asarray(rng.normal(size=(2, 4, 128)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(16, 8, 2, 128)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(16, 8, 2, 128)), jnp.float32)
         tables = jnp.asarray([[0, 1, 16, 16], [2, 3, 16, 16]], jnp.int32)
         lens = jnp.asarray([10, 13], jnp.int32)
         call = lambda: pa.paged_decode_attention(q, kp, vp, tables, lens)

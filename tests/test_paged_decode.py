@@ -11,7 +11,115 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.paged import (BlockManager, PagedKVCache,
                                      llama_prefill_paged, paged_generate)
 from paddle_tpu.ops.pallas.paged_attention import (
-    paged_decode_attention_pallas, paged_decode_attention_xla)
+    decode_blocks_per_step, paged_decode_attention_pallas,
+    paged_decode_attention_xla)
+
+
+# ---- the decode kernel against the gather reference (interpreted) ----
+_BS, _D = 16, 128
+# (H, H_kv, pool dtype): the slab of a block, and with it the compute
+# block, differ (MHA f32: 4 blocks = 64 tokens; GQA bf16: 16 = 256; int8
+# pools twice their float ones)
+_HEADS = {"mha16": (16, 16, jnp.float32), "gqa32_8": (32, 8, jnp.bfloat16)}
+_VARIANTS = ("plain", "window", "int8", "partials")
+_LENS = ("one", "block_less_one", "block", "step", "step_less_one",
+         "two_steps_and_one", "full_table", "ragged")
+
+
+def _lens_case(name, step, full):
+    """Lengths of the batch's rows; ``step`` is the compute block and
+    ``full`` the table, in tokens. Every batch ends in an idle row."""
+    one = {"one": 1, "block_less_one": _BS - 1, "block": _BS, "step": step,
+           "step_less_one": step - 1, "two_steps_and_one": 2 * step + 1,
+           "full_table": full}
+    if name == "ragged":
+        return [full, 1, step, 2 * step - 1, _BS + 3, 0]
+    return [one[name], 0]
+
+
+def _scattered_tables(rs, lens, width, pool, owner=None):
+    """Live entries drawn over the whole pool, unused ones the sentinel;
+    ``owner`` (cp): every second live entry belongs to another shard and
+    holds the sentinel here."""
+    tables = np.full((len(lens), width), pool, np.int32)
+    taken = rs.permutation(pool)
+    for i, n in enumerate(lens):
+        need = -(-n // _BS)
+        tables[i, :need], taken = taken[:need], taken[need:]
+        if owner is not None:
+            tables[i, :need][np.arange(need) % 2 != owner] = pool
+    return tables
+
+
+@pytest.mark.parametrize("lens_name", _LENS)
+@pytest.mark.parametrize("variant", _VARIANTS)
+@pytest.mark.parametrize("heads", sorted(_HEADS))
+def test_paged_decode_kernel_matches_gather_reference(heads, variant,
+                                                      lens_name):
+    h, h_kv, dtype = _HEADS[heads]
+    rs = np.random.RandomState(len(heads) + len(variant) + len(lens_name))
+    pool_dtype = jnp.int8 if variant == "int8" else dtype
+    per_step = decode_blocks_per_step(_BS, h_kv, _D, pool_dtype, 10 ** 6)
+    assert per_step > 1
+    width = 5 * per_step // 2                # 2.5 compute blocks a row
+    pool = 6 * per_step + 4                  # what the ragged batch holds
+    lens = _lens_case(lens_name, per_step * _BS, width * _BS)
+    b = len(lens)
+    q = jnp.asarray(rs.randn(b, h, _D), dtype)
+    kw = {}
+    if variant == "int8":
+        def quantized():
+            f = rs.randn(pool, _BS, h_kv, _D).astype(np.float32)
+            scale = np.abs(f).max(axis=-1) / 127.0
+            return (jnp.asarray(np.round(f / scale[..., None]), jnp.int8),
+                    jnp.asarray(scale))
+        (k_pool, kw["k_scale"]), (v_pool, kw["v_scale"]) = (quantized(),
+                                                            quantized())
+    else:
+        k_pool = jnp.asarray(rs.randn(pool, _BS, h_kv, _D), dtype)
+        v_pool = jnp.asarray(rs.randn(pool, _BS, h_kv, _D), dtype)
+    if variant == "window":
+        kw["window"] = 2 * per_step * _BS - 5  # starts inside a block
+    if variant == "partials":
+        kw["partials"] = True
+    tables = jnp.asarray(_scattered_tables(
+        rs, lens, width, pool, owner=1 if variant == "partials" else None))
+    lens = jnp.asarray(lens, jnp.int32)
+    ref = paged_decode_attention_xla(q, k_pool, v_pool, tables, lens, **kw)
+    got = paged_decode_attention_pallas(q, k_pool, v_pool, tables, lens,
+                                        interpret=True, **kw)
+    tol = 2e-2 if dtype == jnp.bfloat16 and variant != "partials" else 2e-5
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert g.shape == r.shape and g.dtype == r.dtype
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.isfinite(g).all()
+        # the reference's idle row is a mean over garbage: live rows only
+        np.testing.assert_allclose(g[:-1], r[:-1], rtol=tol, atol=tol)
+    out = np.asarray(jax.tree.leaves(got)[0], np.float32)
+    assert not out[-1].any()                 # the idle row: zeros, not NaN
+
+
+def test_paged_decode_kernel_never_fetches_an_unused_entry():
+    """Entries past a row's live blocks may hold anything: the kernel
+    reads the table only as far as the length says."""
+    rs = np.random.RandomState(5)
+    h, h_kv, dtype = _HEADS["gqa32_8"]
+    lens, width, pool = [3 * _BS + 1, 0], 40, 64
+    q = jnp.asarray(rs.randn(2, h, _D), dtype)
+    k_pool = jnp.asarray(rs.randn(pool, _BS, h_kv, _D), dtype)
+    v_pool = jnp.asarray(rs.randn(pool, _BS, h_kv, _D), dtype)
+    tables = _scattered_tables(rs, lens, width, pool)
+    wild = tables.copy()
+    wild[0, 4:] = 2 ** 30                    # far outside the pool
+    wild[1, :] = -7
+    args = (q, k_pool, v_pool)
+    lens = jnp.asarray(lens, jnp.int32)
+    want = paged_decode_attention_pallas(*args, jnp.asarray(tables), lens,
+                                         interpret=True)
+    got = paged_decode_attention_pallas(*args, jnp.asarray(wild), lens,
+                                        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 def test_paged_kernel_matches_gather_reference():

@@ -229,6 +229,33 @@ def test_every_tick_is_one_step_span_with_its_children_inside(model):
     assert sum(e["args"]["tokens"] for e in emits) == total - first  # prefill
 
 
+def test_decode_span_counts_the_pool_blocks_the_kernel_walks(model):
+    """``serving.decode`` carries ``kv_blocks``: ceil(len / block) summed
+    over the slots the tick runs, len counting the token being written."""
+    eng = _engine(model)
+    _traffic(eng)
+    walked = []
+    real = eng.exe.decode_tick
+
+    def counted(tokens, run_mask, *a, **kw):
+        lens = eng.cur[np.asarray(run_mask, bool)] + 1
+        walked.append((int(np.sum(run_mask)),
+                       int(np.sum(-(-lens // eng.block_size)))))
+        return real(tokens, run_mask, *a, **kw)
+
+    eng.exe.decode_tick = counted
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    decodes = [e for e in _spans() if e["name"] == "serving.decode"]
+    assert len(decodes) == len(walked) > 5
+    assert [(e["args"]["slots"], e["args"]["kv_blocks"])
+            for e in decodes] == walked
+    # ragged lengths: more than one block a slot, fewer than the table
+    assert max(k for _, k in walked) > 3 * 2
+    assert all(k <= s * eng.max_blocks_per_seq for s, k in walked)
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 def test_span_pad_counts_equal_the_wrappers_exactly(model, depth):
     eng = _engine(model, async_depth=depth)

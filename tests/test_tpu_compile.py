@@ -10,7 +10,9 @@ or a test; no ``autouse``, no child process, one file. A compile that
 passes is not a chip run: ``chip_smoke.py`` is.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +22,10 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.pallas.norms import rms_norm
+from paddle_tpu.ops.pallas import paged_attention
 from paddle_tpu.ops.pallas.paged_attention import (
-    paged_chunk_attention_pallas, paged_decode_attention_pallas)
+    decode_slab_is_tiled, paged_chunk_attention_pallas,
+    paged_decode_attention, paged_decode_attention_pallas)
 from paddle_tpu.ops.pallas.rope import fused_rope
 
 bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -66,32 +70,108 @@ def _pool(n, bs, h_kv, d, dtype=bf16):
     return [((n, bs, h_kv, d), dtype)] * 2
 
 
-# (id, B, H, H_kv, pool blocks, block size, table width, int8 pool)
+# "%name = dtype[dims]{layout} opcode(" of an HLO instruction
+_HLO_RESULT = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+
+# (id, B, H, H_kv, head dim, pool blocks, block size, table width, pool
+#  dtype, variant)
 DECODE = [
-    ("b8_h16", 8, 16, 16, 512, 16, 32, False),
-    ("b8_gqa32_8", 8, 32, 8, 512, 16, 32, False),
-    ("b8_h16_int8", 8, 16, 16, 512, 16, 32, True),
+    ("b8_h16", 8, 16, 16, 128, 512, 16, 32, bf16, {}),
+    ("b8_gqa32_8", 8, 32, 8, 128, 512, 16, 32, bf16, {}),
+    ("b8_h16_int8", 8, 16, 16, 128, 512, 16, 32, i8, {}),
     # chip_smoke.py's tick: 32 heads of 128, 2048 x 16 pool, 256-wide table
-    ("smoke_b8_h32_table256", 8, 32, 32, 2048, 16, 256, False),
-    ("b8_h32_table512", 8, 32, 32, 4096, 16, 512, False),
+    ("smoke_b8_h32_table256", 8, 32, 32, 128, 2048, 16, 256, bf16, {}),
+    ("b8_h32_table512", 8, 32, 32, 128, 4096, 16, 512, bf16, {}),
+    # mistral7b.serve.backlog's tick: 16 slots, 3072 x 16 pool; its variants
+    ("b16_gqa32_8_pool3072_table256", 16, 32, 8, 128, 3072, 16, 256, bf16,
+     {}),
+    ("cell_window", 16, 32, 8, 128, 3072, 16, 256, bf16, {"window": 1024}),
+    ("cell_partials", 16, 32, 8, 128, 3072, 16, 256, bf16,
+     {"partials": True}),
+    ("cell_int8", 16, 32, 8, 128, 3072, 16, 256, i8, {}),
+    # the edges of decode_slab_is_tiled: the fewest K/V heads a dtype's
+    # sublane tile allows, a head of two lane rows, a head count that is
+    # no power of two
+    ("gqa16_2", 8, 16, 2, 128, 512, 16, 32, bf16, {}),
+    ("gqa16_4_int8", 8, 16, 4, 128, 512, 16, 32, i8, {}),
+    ("mqa_f32", 4, 8, 1, 128, 64, 16, 8, f32, {}),
+    ("gqa16_2_d256", 8, 16, 2, 256, 512, 16, 32, bf16, {}),
+    ("h40", 8, 40, 40, 128, 512, 16, 32, bf16, {}),
 ]
+
+# (id, H, H_kv, head dim, pool dtype): slabs off the pool's tiling, which
+# the dispatcher sends to the gather
+DECODE_UNTILED = [
+    ("h16_d64", 16, 16, 64, bf16),
+    ("gqa32_4_d64", 32, 4, 64, bf16),
+    ("mqa_h8", 8, 1, 128, bf16),
+    ("mqa_h71_d64", 71, 1, 64, bf16),
+    ("mqa_h8_d256", 8, 1, 256, bf16),
+    ("gqa16_2_int8", 16, 2, 128, i8),
+    ("h12", 12, 12, 128, bf16),
+]
+
+
+def _decode_shapes(b, h, h_kv, d, n, bs, width, dtype):
+    shapes = [((b, h, d), f32 if dtype == f32 else bf16),
+              *_pool(n, bs, h_kv, d, dtype), ((b, width), i32), ((b,), i32)]
+    if dtype == i8:
+        shapes += [((n, bs, h_kv), f32)] * 2
+    return shapes
 
 
 @pytest.mark.parametrize("case", DECODE, ids=[c[0] for c in DECODE])
 def test_paged_decode_attention_compiles(one_chip, case):
-    _, b, h, h_kv, n, bs, width, int8 = case
-    shapes = [((b, h, 128), bf16), *_pool(n, bs, h_kv, 128,
-                                          i8 if int8 else bf16),
-              ((b, width), i32), ((b,), i32)]
-    if int8:
-        shapes += [((n, bs, h_kv), f32)] * 2
+    _, b, h, h_kv, d, n, bs, width, dtype, variant = case
+    assert decode_slab_is_tiled(h_kv, d, dtype)
 
     def fn(q, kp, vp, tables, lens, ks=None, vs=None):
         return paged_decode_attention_pallas(
             q, kp, vp, tables, lens, k_scale=ks, v_scale=vs,
-            interpret=False)
+            interpret=False, **variant)
 
-    _compile(fn, one_chip, *shapes)
+    compiled = _compile(fn, one_chip,
+                        *_decode_shapes(b, h, h_kv, d, n, bs, width, dtype))
+    # the kernel reads the pool where it lies: no transposed or reshaped
+    # copy of it, in HBM (a temporary of 32 MB and up) or anywhere else
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    for line in compiled.as_text().splitlines():
+        m = _HLO_RESULT.match(line)
+        if m and m.group(2) in ("copy", "transpose", "reshape", "fusion"):
+            elems = math.prod(map(int, m.group(1).split(",")))
+            assert elems < n * bs * h_kv * d, line.strip()[:160]
+
+
+@pytest.mark.parametrize("case", DECODE_UNTILED,
+                         ids=[c[0] for c in DECODE_UNTILED])
+def test_paged_decode_off_the_tiling_takes_the_gather(one_chip, case,
+                                                      monkeypatch):
+    """Mosaic refuses to copy these slabs (the rule is not wider than it
+    has to be); the dispatcher, on a TPU, says so and compiles the XLA
+    formulation for them."""
+    _, h, h_kv, d, dtype = case
+    assert not decode_slab_is_tiled(h_kv, d, dtype)
+    shapes = _decode_shapes(8, h, h_kv, d, 512, 16, 32, dtype)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+
+    def kernel(q, kp, vp, tables, lens, ks=None, vs=None):
+        return paged_decode_attention_pallas(
+            q, kp, vp, tables, lens, k_scale=ks, v_scale=vs, interpret=False)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(kernel).lower(*args).compile()
+
+    def dispatched(q, kp, vp, tables, lens, ks=None, vs=None):
+        return paged_decode_attention(q, kp, vp, tables, lens, k_scale=ks,
+                                      v_scale=vs)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    del paged_attention._trace_events[:]
+    compiled = jax.jit(dispatched).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert {"decode:slab-off-tiling", "decode:xla"} <= set(
+        paged_attention._trace_events)
+    assert "decode:pallas" not in paged_attention._trace_events
 
 
 # (id, rows A, chunk C, H, H_kv, pool blocks, table width, int8 pool)
