@@ -75,5 +75,6 @@ from paddle_tpu.models.conformer import (ConformerConfig, ConformerEncoder,
                                          ConformerForCTC)
 from paddle_tpu.models.mistral import MistralConfig, MistralForCausalLM, MistralModel
 from paddle_tpu.models.qwen import Qwen2Config, Qwen2ForCausalLM, Qwen2Model
+from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM, OuroModel
 from paddle_tpu.models.t5 import T5Config, T5ForConditionalGeneration
 from paddle_tpu.models import convert

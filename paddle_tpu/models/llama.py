@@ -332,10 +332,12 @@ class LlamaModel(Module):
 class LlamaForCausalLM(Module):
     """Decoder LM with parallel (tp-sharded) LM head + fused CE."""
 
+    backbone = LlamaModel        # a family with another stack names its own
+
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         self.cfg = cfg
-        self.model = LlamaModel(cfg)
+        self.model = self.backbone(cfg)
         if cfg.tie_word_embeddings:
             self.lm_head = None
         else:

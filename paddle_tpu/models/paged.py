@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import functools
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
@@ -46,21 +46,40 @@ class PagedKVCache:
     int8 (``init(..., kv_dtype="int8")``): element (n, o, h) is the
     absmax/127 scale of pool row (n, o, h, :). Tuple truthiness is
     STATIC pytree structure, so jitted forwards branch on
-    ``if cache.k_scales:`` at trace time — the bf16 trace is unchanged."""
-    k_pools: list   # [L] of [N_blocks, block_size, H_kv, D]
+    ``if cache.k_scales:`` at trace time — the bf16 trace is unchanged.
+
+    ``passes`` (static) is how often a token runs the whole layer stack
+    (``cache_passes(cfg)``: a looped model's ``total_ut_steps``, else 1).
+    Every (pass, layer) pair keeps K/V of its own: layer ``li``'s pool
+    holds ``passes * N_blocks`` rows, pass ``u``'s copy of block ``b`` at
+    row ``u * N_blocks + b`` (``_pass_tables``). Block ids, tables and the
+    host's managers stay per-block: one block is one span of tokens in
+    every cache layer."""
+    k_pools: list   # [L] of [passes * N_blocks, block_size, H_kv, D]
     v_pools: list
     block_tables: jnp.ndarray  # [B, max_blocks] int32 (pad = n_blocks)
     lens: jnp.ndarray          # [B] int32 — tokens currently in cache
-    k_scales: tuple = ()       # [L] of [N_blocks, block_size, H_kv] f32
+    k_scales: tuple = ()       # [L] of [passes * N_blocks, block_size, H_kv] f32
     v_scales: tuple = ()
+    passes: int = 1
 
     @property
     def block_size(self):
         return self.k_pools[0].shape[1]
 
     @property
-    def num_blocks(self):
+    def pool_rows(self):
+        """Rows of one pool: every pass's copy of every block."""
         return self.k_pools[0].shape[0]
+
+    @property
+    def num_blocks(self):
+        return self.pool_rows // self.passes
+
+    @property
+    def cache_layers(self):
+        """K/V layers a token keeps: one a (pass, layer) pair."""
+        return len(self.k_pools) * self.passes
 
     def pool_tokens(self):
         """Total cache capacity in tokens (the HBM bound)."""
@@ -68,8 +87,9 @@ class PagedKVCache:
 
     @staticmethod
     def init(num_layers, num_blocks, block_size, num_kv_heads, head_dim,
-             batch, max_blocks_per_seq, dtype, kv_dtype=None):
+             batch, max_blocks_per_seq, dtype, kv_dtype=None, passes=1):
         pool_dtype = dtype
+        rows = passes * num_blocks
         k_scales = v_scales = ()
         if kv_dtype is not None:
             if jnp.dtype(kv_dtype) != jnp.int8:
@@ -77,24 +97,51 @@ class PagedKVCache:
                     f"unsupported kv_dtype {kv_dtype!r}: only 'int8' "
                     "(per-position absmax scales) or None (model dtype)")
             pool_dtype = jnp.int8
-            zs = lambda: jnp.zeros((num_blocks, block_size, num_kv_heads),
+            zs = lambda: jnp.zeros((rows, block_size, num_kv_heads),
                                    jnp.float32)
             k_scales = tuple(zs() for _ in range(num_layers))
             v_scales = tuple(zs() for _ in range(num_layers))
-        z = lambda: jnp.zeros((num_blocks, block_size, num_kv_heads,
+        z = lambda: jnp.zeros((rows, block_size, num_kv_heads,
                                head_dim), pool_dtype)
         return PagedKVCache(
             [z() for _ in range(num_layers)],
             [z() for _ in range(num_layers)],
             jnp.full((batch, max_blocks_per_seq), num_blocks, jnp.int32),
-            jnp.zeros((batch,), jnp.int32), k_scales, v_scales)
+            jnp.zeros((batch,), jnp.int32), k_scales, v_scales, passes)
+
+    @staticmethod
+    def init_for(cfg, num_blocks, block_size, batch, max_blocks_per_seq,
+                 kv_dtype=None):
+        """The cache of the model ``cfg`` describes: its layers, its
+        passes over them, its K/V heads."""
+        return PagedKVCache.init(
+            cfg.num_hidden_layers, num_blocks, block_size,
+            cfg.num_key_value_heads,
+            cfg.hidden_size // cfg.num_attention_heads, batch,
+            max_blocks_per_seq, cfg.dtype, kv_dtype=kv_dtype,
+            passes=cache_passes(cfg))
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
     lambda c: ((c.k_pools, c.v_pools, c.block_tables, c.lens,
-                c.k_scales, c.v_scales), None),
-    lambda aux, ch: PagedKVCache(*ch))
+                c.k_scales, c.v_scales), c.passes),
+    lambda passes, ch: PagedKVCache(*ch, passes))
+
+
+def cache_passes(cfg) -> int:
+    """Times a token runs the whole layer stack: a looped model's
+    ``total_ut_steps``, 1 for every other."""
+    return int(getattr(cfg, "total_ut_steps", 1))
+
+
+def _pass_tables(tables, u, num_blocks, passes):
+    """Block ids -> pool rows of pass ``u``: its copy of block ``b`` lies
+    at row ``u * num_blocks + b``. The sentinel (any id >= num_blocks)
+    goes past the last row, where scatters drop it and the kernels never
+    read."""
+    return jnp.where(tables < num_blocks, tables + u * num_blocks,
+                     passes * num_blocks)
 
 
 # ------------------------------------------------ int8 KV quantization
@@ -123,22 +170,23 @@ def _quantize_kv(vals):
     return q, scale
 
 
-def _scatter_kv(cache, li, k, v, scatter, *args):
-    """Scatter layer ``li``'s new K/V through ``scatter`` (one of the
-    three scatter primitives below — all are ``(pool, vals, *rest)`` and
-    trailing-dim generic). bf16 pool: plain writes, scale slots None.
-    int8 pool: quantize-on-write — the int8 codes land in the pools and
-    the absmax scales in the parallel scale pools via the SAME scatter
-    (same table/len/active masking, so codes and scales never desync)."""
-    if not cache.k_scales:
-        return (scatter(cache.k_pools[li], k, *args),
-                scatter(cache.v_pools[li], v, *args), None, None)
+def _scatter_kv(pools, k, v, scatter, *args):
+    """Scatter one layer's new K/V into its ``pools`` (k_pool, v_pool,
+    k_scale, v_scale; the scales None for a bf16 pool) through ``scatter``
+    (one of the three scatter primitives below — all are ``(pool, vals,
+    *rest)`` and trailing-dim generic). bf16 pool: plain writes, scale
+    slots None. int8 pool: quantize-on-write — the int8 codes land in the
+    pools and the absmax scales in the parallel scale pools via the SAME
+    scatter (same table/len/active masking, so codes and scales never
+    desync)."""
+    k_pool, v_pool, k_scale, v_scale = pools
+    if k_scale is None:
+        return (scatter(k_pool, k, *args), scatter(v_pool, v, *args),
+                None, None)
     qk, sk = _quantize_kv(k)
     qv, sv = _quantize_kv(v)
-    return (scatter(cache.k_pools[li], qk, *args),
-            scatter(cache.v_pools[li], qv, *args),
-            scatter(cache.k_scales[li], sk, *args),
-            scatter(cache.v_scales[li], sv, *args))
+    return (scatter(k_pool, qk, *args), scatter(v_pool, qv, *args),
+            scatter(k_scale, sk, *args), scatter(v_scale, sv, *args))
 
 
 class BlockManager:
@@ -983,6 +1031,64 @@ def _mlp_out(lyr, h):
     return out[0] if isinstance(out, (tuple, list)) else out
 
 
+def _residual(x, branch, lyr, norm_name):
+    """``x + branch``, the branch through the layer's own norm of it where
+    the layer has one (a sandwich layer's ``input_layernorm_2`` after
+    attention, ``post_attention_layernorm_2`` after the MLP)."""
+    norm = getattr(lyr, norm_name, None)
+    return x + (branch if norm is None else norm(branch))
+
+
+def _mlp_residual(x, lyr):
+    return _residual(x, _mlp_out(lyr, lyr.post_attention_layernorm(x)), lyr,
+                     "post_attention_layernorm_2")
+
+
+def _run_stack(model, cache, x, tables, layer):
+    """The decoder stack over ``x``, shared by the three paged forwards:
+    every layer once and then the final norm; for a looped model
+    (``cache.passes`` > 1) that whole pass ``passes`` times under one
+    ``lax.fori_loop``, each pass starting from the normed output of the
+    one before and writing pool rows of its own (``_pass_tables``), so the
+    program holds one body a layer however many passes there are.
+
+    ``layer(x, li, lyr, pools, tables) -> (x, pools)`` is the caller's
+    layer body; ``pools`` is the layer's (k_pool, v_pool, k_scale,
+    v_scale), the scales None for a bf16 cache. Returns (the normed x,
+    k_pools, v_pools, k_scales, v_scales)."""
+    bb = _backbone(model)
+    if cache.passes != cache_passes(model.cfg):
+        raise ValueError(
+            f"the cache holds {cache.passes} pass(es) a layer, the model "
+            f"runs {cache_passes(model.cfg)}: build it with "
+            "PagedKVCache.init_for(model.cfg, ...)")
+
+    def one_pass(x, pools, tables):
+        k, v, ks, vs = (list(p) for p in pools)
+        for li, lyr in enumerate(bb.layers):
+            x, (k[li], v[li], ks[li], vs[li]) = layer(
+                x, li, lyr, (k[li], v[li], ks[li], vs[li]), tables)
+        return bb.norm(x), (k, v, ks, vs)
+
+    # a bf16 cache has no scale pools: a None a layer stands in for them
+    no_scales = [None] * len(bb.layers)
+    pools = (cache.k_pools, cache.v_pools, list(cache.k_scales) or no_scales,
+             list(cache.v_scales) or no_scales)
+    if cache.passes == 1:
+        x, pools = one_pass(x, pools, tables)
+    else:
+        def body(u, carry):
+            with jax.named_scope("ut_step"):
+                return one_pass(*carry, _pass_tables(
+                    tables, u, cache.num_blocks, cache.passes))
+        x, pools = jax.lax.fori_loop(
+            0, cache.passes, body, (x, tuple(list(p) for p in pools)))
+    k, v, ks, vs = pools
+    if not cache.k_scales:
+        ks = vs = ()
+    return x, k, v, tuple(ks), tuple(vs)
+
+
 def is_moe_model(model) -> bool:
     """True when any decoder layer routes through an MoE block (drives
     the ``serving.moe_dispatch`` chaos site in LLMEngine)."""
@@ -1053,7 +1159,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
             "decoder forward runs bf16 matmuls); serve an fp8-trained "
             "model with fp8=False weights, or use weight-only quantization")
     b, s = input_ids.shape
-    nb, bs = cache.num_blocks, cache.block_size
+    bs = cache.block_size
     prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
     if slot_ids is None:
         tables = cache.block_tables          # row i == slot i (legacy)
@@ -1079,8 +1185,9 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
         cur_len=(prompt_lens if (scaling or {}).get("type") == "dynamic"
                  else None),
         allow_dynamic=False)
-    k_pools, v_pools, k_scales, v_scales = [], [], [], []
-    for li, lyr in enumerate(_backbone(model).layers):
+    rows = cache.pool_rows
+
+    def layer(x, li, lyr, pools, rtables):
         h = lyr.input_layernorm(x)
         with jax.named_scope("attention"):
             att = lyr.self_attn
@@ -1100,26 +1207,24 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
             out = A.scaled_dot_product_attention(
                 q, k, v, is_causal=True, kv_lens=prompt_lens,
                 window=getattr(cfg, "sliding_window", None))
-            kp, vp, ks, vs = _scatter_kv(cache, li, k, v, _scatter_prefill,
-                                         rtables, prompt_lens, nb, bs)
-            k_pools.append(kp)
-            v_pools.append(vp)
-            if ks is not None:
-                k_scales.append(ks)
-                v_scales.append(vs)
+            pools = _scatter_kv(pools, k, v, _scatter_prefill, rtables,
+                                prompt_lens, rows, bs)
             attn_out = out.reshape(b, s, nh * hd)
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
-            x = x + proj
-        x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
-    x = _backbone(model).norm(x)
+            x = _residual(x, proj, lyr, "input_layernorm_2")
+        return _mlp_residual(x, lyr), pools
+
+    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
+        model, cache, x, rtables, layer)
     logits = _model_logits(model, x)
     last = jnp.take_along_axis(
         logits, jnp.maximum(prompt_lens - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)[:, 0]
-    new_cache = PagedKVCache(k_pools, v_pools, new_tables, new_lens,
-                             tuple(k_scales), tuple(v_scales))
+    new_cache = replace(cache, k_pools=k_pools, v_pools=v_pools,
+                        block_tables=new_tables, lens=new_lens,
+                        k_scales=k_scales, v_scales=v_scales)
     return last, new_cache
 
 
@@ -1136,10 +1241,11 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
                           getattr(cfg, "rope_scaling", None),
                           getattr(cfg, "max_position_embeddings", None))
     window = getattr(cfg, "sliding_window", None)
-    k_pools, v_pools, k_scales, v_scales = [], [], [], []
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
     rtables = _cp_local_tables(cache.block_tables, cp_axis, nb)
-    for li, lyr in enumerate(_backbone(model).layers):
+    rows = cache.pool_rows
+
+    def layer(x, li, lyr, pools, rtables):
         h = lyr.input_layernorm(x)
         with jax.named_scope("attention"):
             att = lyr.self_attn
@@ -1153,14 +1259,9 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
             q = _apply_rope_rows(q.reshape(b, 1, nh, hd), cos, sin)
             k = _apply_rope_rows(k.reshape(b, 1, nkv, hd), cos, sin)
             v = v.reshape(b, 1, nkv, hd)
-            k_pool, v_pool, ks, vs = _scatter_kv(
-                cache, li, k, v, _scatter_decode, rtables,
-                cache.lens, active, nb, bs)
-            k_pools.append(k_pool)
-            v_pools.append(v_pool)
-            if ks is not None:
-                k_scales.append(ks)
-                v_scales.append(vs)
+            pools = k_pool, v_pool, ks, vs = _scatter_kv(
+                pools, k, v, _scatter_decode, rtables, cache.lens, active,
+                rows, bs)
             # sliding-window configs: the pool retains all tokens (blocks
             # below the window could be recycled — not done yet) but decode
             # attends only the last `window` positions, matching prefill
@@ -1184,12 +1285,15 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
-            x = x + proj
-        x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
-    x = _backbone(model).norm(x)
+            x = _residual(x, proj, lyr, "input_layernorm_2")
+        return _mlp_residual(x, lyr), pools
+
+    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
+        model, cache, x, rtables, layer)
     logits = _model_logits(model, x)[:, 0]
-    return logits, PagedKVCache(k_pools, v_pools, cache.block_tables,
-                                new_lens, tuple(k_scales), tuple(v_scales))
+    return logits, replace(cache, k_pools=k_pools, v_pools=v_pools,
+                           lens=new_lens, k_scales=k_scales,
+                           v_scales=v_scales)
 
 
 def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
@@ -1211,8 +1315,7 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
     from paddle_tpu.models.decoding import _sample_rows
     tables = cache.block_tables.at[upd_rows, upd_cols].set(upd_vals,
                                                            mode="drop")
-    cache = PagedKVCache(cache.k_pools, cache.v_pools, tables, cache.lens,
-                         cache.k_scales, cache.v_scales)
+    cache = replace(cache, block_tables=tables)
     logits, cache = llama_decode_step_paged(model, tokens, cache, active,
                                             lora, cp_axis=cp_axis)
     logp = (jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -1322,10 +1425,23 @@ def _copy_partial_blocks(pools, copy_src, copy_dst):
                                mode="drop") for p in pools]
 
 
+def _pass_rows(cache: PagedKVCache, ids):
+    """[K] block ids -> the pool rows that hold them: the ids as they are
+    for a one-pass cache, every pass's copy of each ([passes * K]) for a
+    looped model's."""
+    if cache.passes == 1:
+        return ids
+    return jnp.concatenate([
+        _pass_tables(ids, u, cache.num_blocks, cache.passes)
+        for u in range(cache.passes)])
+
+
 def _cow_pools(cache: PagedKVCache, copy_src, copy_dst):
     """COW-copy the K/V pools AND (when quantized) their scale pools —
     a partial block's int8 codes are meaningless without the matching
     scale rows, so the two must fork together."""
+    copy_src, copy_dst = _pass_rows(cache, copy_src), _pass_rows(cache,
+                                                                 copy_dst)
     return (_copy_partial_blocks(cache.k_pools, copy_src, copy_dst),
             _copy_partial_blocks(cache.v_pools, copy_src, copy_dst),
             tuple(_copy_partial_blocks(cache.k_scales, copy_src, copy_dst)),
@@ -1336,7 +1452,8 @@ def _beam_cache_update(cache: PagedKVCache, new_tables, copy_src, copy_dst):
     """Apply a beam reorder to the paged cache: install the forked block
     tables and copy the (at most one per beam) private partial blocks."""
     k, v, ks, vs = _cow_pools(cache, copy_src, copy_dst)
-    return PagedKVCache(k, v, new_tables, cache.lens, ks, vs)
+    return replace(cache, k_pools=k, v_pools=v, block_tables=new_tables,
+                   k_scales=ks, v_scales=vs)
 
 
 def _cp_copy_blocks(pools, copy_src, copy_dst, per, cp_axis):
@@ -1372,12 +1489,12 @@ def _prefix_cow_update(cache: PagedKVCache, copy_src, copy_dst,
         per = cache.num_blocks
         cp = lambda pools: _cp_copy_blocks(pools, copy_src, copy_dst,
                                            per, cp_axis)
-        return PagedKVCache(cp(cache.k_pools), cp(cache.v_pools),
-                            cache.block_tables, cache.lens,
-                            tuple(cp(cache.k_scales)),
-                            tuple(cp(cache.v_scales)))
+        return replace(cache, k_pools=cp(cache.k_pools),
+                       v_pools=cp(cache.v_pools),
+                       k_scales=tuple(cp(cache.k_scales)),
+                       v_scales=tuple(cp(cache.v_scales)))
     k, v, ks, vs = _cow_pools(cache, copy_src, copy_dst)
-    return PagedKVCache(k, v, cache.block_tables, cache.lens, ks, vs)
+    return replace(cache, k_pools=k, v_pools=v, k_scales=ks, v_scales=vs)
 
 
 _PREFIX_COW_JIT = jax.jit(_prefix_cow_update, donate_argnums=(0,))
@@ -1403,7 +1520,8 @@ def _beam_group_update(cache: PagedKVCache, slot_ids, rows, lens_val,
     tables = cache.block_tables.at[slot_ids].set(rows)
     lens = cache.lens.at[slot_ids].set(jnp.int32(lens_val))
     k, v, ks, vs = _cow_pools(cache, copy_src, copy_dst)
-    return PagedKVCache(k, v, tables, lens, ks, vs)
+    return replace(cache, k_pools=k, v_pools=v, block_tables=tables,
+                   lens=lens, k_scales=ks, v_scales=vs)
 
 
 def _beam_finalize(running_lp, seqs, fin_seqs, fin_scores, prompt_len,
@@ -1446,16 +1564,17 @@ def paged_beam_search(model, prompt, max_new_tokens=32, num_beams=4,
     prompt = np.asarray(prompt, np.int32).reshape(-1)
     s = len(prompt)
     cfg = model.cfg
+    if cache_passes(cfg) > 1:
+        raise NotImplementedError(
+            "paged_beam_search on a looped model: no test has forked the "
+            "blocks of a cache that holds a row a pass")
     K = num_beams
     max_len = s + max_new_tokens
     max_blocks = -(-max_len // block_size)
     if num_blocks is None:
         num_blocks = K * max_blocks
     mgr = RefBlockManager(num_blocks, block_size)
-    cache = PagedKVCache.init(cfg.num_hidden_layers, num_blocks, block_size,
-                              cfg.num_key_value_heads,
-                              cfg.hidden_size // cfg.num_attention_heads,
-                              K, max_blocks, cfg.dtype)
+    cache = PagedKVCache.init_for(cfg, num_blocks, block_size, K, max_blocks)
 
     # prefill once into beam 0's blocks, then fork the other beams
     sid = {j: j for j in range(K)}          # beam j -> mgr sequence id
@@ -1476,10 +1595,8 @@ def paged_beam_search(model, prompt, max_new_tokens=32, num_beams=4,
         model, jnp.asarray(prompt[None, :]), jnp.asarray([s], jnp.int32),
         cache, jnp.asarray([0], jnp.int32),
         jnp.asarray(rows[:1]))
-    cache = PagedKVCache(cache.k_pools, cache.v_pools,
-                         jnp.asarray(rows),
-                         jnp.full((K,), s, jnp.int32),
-                         cache.k_scales, cache.v_scales)
+    cache = replace(cache, block_tables=jnp.asarray(rows),
+                    lens=jnp.full((K,), s, jnp.int32))
     cache = _BEAM_UPDATE_JIT(cache, jnp.asarray(rows),
                              jnp.asarray(copy_src), jnp.asarray(copy_dst))
 
@@ -1564,10 +1681,7 @@ def paged_generate(model, input_ids, prompt_lens, max_new_tokens=32,
     mgr = BlockManager(num_blocks, block_size)
     for sid in range(b):
         mgr.allocate(sid, int(lens_np[sid]))
-    cache = PagedKVCache.init(cfg.num_hidden_layers, num_blocks, block_size,
-                              cfg.num_key_value_heads,
-                              cfg.hidden_size // cfg.num_attention_heads,
-                              b, max_blocks, cfg.dtype)
+    cache = PagedKVCache.init_for(cfg, num_blocks, block_size, b, max_blocks)
     cache.block_tables = mgr.table_array(range(b), max_blocks)
 
     prefill = _PREFILL_JIT
@@ -1651,7 +1765,7 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
             "paged serving ignores the fp8 training path (see "
             "llama_prefill_paged); serve with fp8=False weights")
     a, c = input_ids.shape
-    nb, bs = cache.num_blocks, cache.block_size
+    bs = cache.block_size
     chunk_lens = jnp.asarray(chunk_lens, jnp.int32)
     offsets = jnp.asarray(offsets, jnp.int32)
     slot_ids = jnp.asarray(slot_ids, jnp.int32)
@@ -1685,8 +1799,9 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
         return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
                                axis=-1).astype(t.dtype)
 
-    k_pools, v_pools, k_scales, v_scales = [], [], [], []
-    for li, lyr in enumerate(_backbone(model).layers):
+    rows = cache.pool_rows
+
+    def layer(x, li, lyr, pools, rtables):
         h = lyr.input_layernorm(x)
         with jax.named_scope("attention"):
             att = lyr.self_attn
@@ -1701,14 +1816,9 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
             k = rope(k.reshape(a, c, nkv, hd))
             v = v.reshape(a, c, nkv, hd)
             # scatter the chunk FIRST so the gathered view holds prefix+chunk
-            k_pool, v_pool, ks, vs = _scatter_kv(
-                cache, li, k, v, _scatter_decode_chunk, rtables, offsets,
-                chunk_lens, nb, bs)
-            k_pools.append(k_pool)
-            v_pools.append(v_pool)
-            if ks is not None:
-                k_scales.append(ks)
-                v_scales.append(vs)
+            pools = k_pool, v_pool, ks, vs = _scatter_kv(
+                pools, k, v, _scatter_decode_chunk, rtables, offsets,
+                chunk_lens, rows, bs)
             # ragged pool-direct attention: the kernel reads only each row's
             # live blocks (the XLA fallback reconstructs the old full
             # gather + dense-mask view, bit-compatible)
@@ -1725,12 +1835,15 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
-            x = x + proj
-        x = x + _mlp_out(lyr, lyr.post_attention_layernorm(x))
-    x = _backbone(model).norm(x)
+            x = _residual(x, proj, lyr, "input_layernorm_2")
+        return _mlp_residual(x, lyr), pools
+
+    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
+        model, cache, x, rtables, layer)
     logits = _model_logits(model, x)
-    new_cache = PagedKVCache(k_pools, v_pools, new_tables, new_lens,
-                             tuple(k_scales), tuple(v_scales))
+    new_cache = replace(cache, k_pools=k_pools, v_pools=v_pools,
+                        block_tables=new_tables, lens=new_lens,
+                        k_scales=k_scales, v_scales=v_scales)
     if full_logits:
         return logits, new_cache
     last = jnp.take_along_axis(
@@ -1792,8 +1905,7 @@ def spec_rewind_lens(cache: PagedKVCache, slot_ids, new_lens):
     slot_ids = jnp.asarray(slot_ids, jnp.int32)
     lens = cache.lens.at[slot_ids].set(
         jnp.asarray(new_lens, jnp.int32), mode="drop")
-    return PagedKVCache(cache.k_pools, cache.v_pools, cache.block_tables,
-                        lens, cache.k_scales, cache.v_scales)
+    return replace(cache, lens=lens)
 
 
 def spec_advance_frontiers(pos, draft_pos, n_new):

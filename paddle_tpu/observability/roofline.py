@@ -97,7 +97,7 @@ class ModelGeometry:
     """The shape facts the FLOPs/bytes models need — duck-typed off any
     of the repo's LLM configs via :meth:`from_config`, never a live
     model (so the roofline stays importable without jax)."""
-    num_layers: int
+    num_layers: int               # layer applications a token (x passes)
     hidden: int
     intermediate: int
     vocab: int
@@ -129,7 +129,10 @@ class ModelGeometry:
                       or getattr(cfg, "experts_per_tok", 0) or 0)
         inter = int(getattr(cfg, "moe_intermediate_size", 0)
                     or cfg.intermediate_size)
-        return cls(num_layers=int(cfg.num_hidden_layers), hidden=h,
+        # a looped model applies its layers ``total_ut_steps`` times a
+        # token: its weights stream that often and every pass keeps K/V
+        passes = int(getattr(cfg, "total_ut_steps", 1))
+        return cls(num_layers=int(cfg.num_hidden_layers) * passes, hidden=h,
                    intermediate=inter, vocab=int(cfg.vocab_size), heads=nh,
                    kv_heads=int(getattr(cfg, "num_key_value_heads", nh)),
                    head_dim=h // nh, dtype_bytes=int(dtype_bytes),

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models.paged import (_beam_finalize, _BEAM_SELECT_JIT,
+                                     cache_passes,
                                      greedy_accept_length, is_moe_model,
                                      kv_quant_enabled,
                                      stochastic_accept_row)
@@ -88,6 +89,11 @@ from paddle_tpu.utils.faults import fault_point
 from paddle_tpu.utils.profiler import device_memory_stats
 
 
+_LOOPED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
+                   "its payload holds one row a block and layer, a looped "
+                   "pool one a pass as well")
+
+
 class LLMEngine:
     """Continuous-batching engine over a shared paged KV pool.
 
@@ -105,8 +111,9 @@ class LLMEngine:
                  spec_k=4, spec_adaptive=True, prefill_only=False,
                  adapter_store=None, degrade=None, slo=None, kv_dtype=None,
                  cp=1, async_depth=0):
-        cfg = model.cfg
-        self.model = model
+        # the model itself goes to the executor, which flattens it once:
+        # the engine serves the weights it was built with
+        cfg = self.cfg = model.cfg
         # quantized KV cache (ISSUE 17): kv_dtype="int8" stores the block
         # pools as int8 with per-(position, kv-head) f32 scale pools.
         # PT_QUANT_KV=0 is the kill switch — checked HERE (construction)
@@ -243,6 +250,22 @@ class LLMEngine:
             # uniform source, so this stream need not match the engine key
             self._spec_rs = np.random.RandomState((seed ^ 0x5eed) & 0x7fffffff)
 
+        # a looped model (its stack run ``total_ut_steps`` times a token,
+        # K/V of every pass kept): what does not compose with it yet
+        self.ut_steps = cache_passes(cfg)
+        if self.ut_steps > 1:
+            self._refuse_looped(
+                self.cp > 1 and "context parallelism (cp > 1): the pools' "
+                "rows are laid out pass by pass, not shard by shard",
+                adapter_store is not None and "multi-LoRA (adapter_store): "
+                "its stacked adapter tensors are indexed by layer, not by "
+                "(pass, layer)",
+                draft_model is not None and "a draft model: a rejected "
+                "proposal would have to rewind every pass",
+                getattr(cfg, "early_exit_threshold", 1.0) < 1 and
+                "early_exit_threshold < 1: a token's passes would vary, "
+                "and the scheduler counts one fixed cost a slot and tick")
+
         # ---- the three extracted layers ----
         self.kv = KVManager(num_blocks, block_size)
         self._block_bytes = None     # per-block HBM bytes, lazily computed
@@ -314,7 +337,12 @@ class LLMEngine:
         self.stats = {"host_s": 0.0, "device_s": 0.0, "ticks": 0,
                       "preemptions": 0, "timeouts": 0, "cancelled": 0,
                       "rejected": 0, "spec_ticks": 0, "spec_proposed": 0,
-                      "spec_accepted": 0, "spec_fallbacks": 0}
+                      "spec_accepted": 0, "spec_fallbacks": 0,
+                      # not a counter: HBM bytes one token holds in the
+                      # pool, over every cache layer (a (pass, layer) pair
+                      # of a looped model), scale pools included
+                      "cache_bytes_per_token":
+                          cache_block_bytes(self.cache) // block_size}
         self._adm_counter = 0                # admission recency, per slot
         self.adm_order = np.zeros(num_slots, np.int64)
 
@@ -470,6 +498,16 @@ class LLMEngine:
     def _has_deadlines(self, value):
         self.sched.has_deadlines = value
 
+    def _refuse_looped(self, *reasons):
+        """Raise for the first of ``reasons`` that is a message: the one
+        place a looped model is told what it cannot be served with."""
+        for why in reasons:
+            if why:
+                raise NotImplementedError(
+                    f"a looped model ({self.ut_steps} passes over "
+                    f"{self.cfg.num_hidden_layers} layers) is not "
+                    f"served with {why}")
+
     # ------------------------------------------------------------- intake
     def add_request(self, req: Request) -> int:
         self.sched.check_backpressure(self.stats)
@@ -491,6 +529,9 @@ class LLMEngine:
         if req.num_beams < 1:
             raise ValueError("num_beams must be >= 1")
         if req.num_beams > 1:
+            self._refuse_looped(
+                self.ut_steps > 1 and "beam search (num_beams > 1): no "
+                "test has forked a looped model's blocks")
             if req.num_beams > self.num_slots:
                 raise ValueError(f"num_beams {req.num_beams} exceeds "
                                  f"num_slots={self.num_slots}")
@@ -520,7 +561,7 @@ class LLMEngine:
             raise NotImplementedError(
                 "chunked prefill + sliding-window recycling not combined")
         if len(req.prompt) > self.max_prompt_len and \
-                (getattr(self.model.cfg, "rope_scaling", None)
+                (getattr(self.cfg, "rope_scaling", None)
                  or {}).get("type") == "dynamic":
             # refuse HERE: a trace-time raise inside step() would leave
             # the slot claimed and the request wedged in self.prefilling
@@ -572,10 +613,10 @@ class LLMEngine:
                     and hasattr(req.grammar, "advance")):
                 raise ValueError("req.grammar must be a "
                                  "serving.grammar.TokenMaskAutomaton")
-            if len(req.grammar.vocab) != self.model.cfg.vocab_size:
+            if len(req.grammar.vocab) != self.cfg.vocab_size:
                 raise ValueError(
                     f"grammar vocab {len(req.grammar.vocab)} != model "
-                    f"vocab {self.model.cfg.vocab_size}")
+                    f"vocab {self.cfg.vocab_size}")
         rid = self.sched.enqueue(req)
         REQUESTS.submit(req, source="engine")        # idempotent re-submit
         REQUESTS.event(req, "queued", replica=self.trace_name,
@@ -839,7 +880,7 @@ class LLMEngine:
         bound = [(i, s) for i, s in rows_slots if s in self._grammar]
         if not bound:
             return None
-        bias = np.zeros((n_rows, self.model.cfg.vocab_size), np.float32)
+        bias = np.zeros((n_rows, self.cfg.vocab_size), np.float32)
         for i, s in bound:
             aut, st = self._grammar[s]
             bias[i] = aut.bias(st)
@@ -1014,7 +1055,7 @@ class LLMEngine:
         req, s, rid, k = g.req, g.s, g.req.req_id, g.req.num_beams
         self.exe.beam_group_update(g.slots, rows, s, copy_src, copy_dst)
         neg = jnp.float32(-1e9)
-        vocab = self.model.cfg.vocab_size
+        vocab = self.cfg.vocab_size
         logp0 = jax.nn.log_softmax(logits_row.astype(jnp.float32))
         g.logp = jnp.broadcast_to(logp0[None], (k, vocab))
         g.running_lp = jnp.asarray([0.0] + [float(neg)] * (k - 1),
@@ -1834,6 +1875,7 @@ class LLMEngine:
         requests — only ACTIVE greedy slots are extractable (the router
         extracts after the final prefill chunk activates the slot)."""
         self._drain_async("boundary")
+        self._refuse_looped(self.ut_steps > 1 and _LOOPED_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -1930,6 +1972,7 @@ class LLMEngine:
                 "engine is draining — finishing in-flight requests, "
                 "admitting nothing new")
         req = payload.req
+        self._refuse_looped(self.ut_steps > 1 and _LOOPED_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2313,7 +2356,7 @@ class LLMEngine:
         rng_before = self.exe.rng
         t0 = time.perf_counter()
         with self._tick_timer("sample", "serving.decode",
-                              slots=int(act.sum())):
+                              slots=int(act.sum()), **self.exe.span_args):
             nxt, ran, stop, gen = self.exe.decode_tick_async(
                 dev["tokens"], act, dev["stop"], dev["gen"],
                 dev["max_gen"], self.temps, self.top_ps, eos)
@@ -2517,7 +2560,8 @@ class LLMEngine:
         # kv_blocks: the pool blocks the decode kernel walks this tick
         # (what is left of slots x table width)
         with self._tick_timer("sample", "serving.decode", slots=n_run,
-                              kv_blocks=ctx // self.block_size):
+                              kv_blocks=ctx // self.block_size,
+                              **self.exe.span_args):
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),

@@ -33,6 +33,46 @@ from paddle_tpu.observability import span as _span
 
 # module-level so its compile cache persists across admissions
 _SAMPLE_ROWS_JIT = jax.jit(_sample_rows, static_argnums=(4,))
+# the engine key's split as ONE dispatch (split, then unpacked on the host,
+# is three); the two keys are the same bits
+_SPLIT_JIT = jax.jit(lambda key: tuple(jax.random.split(key)))
+
+
+class _ById:
+    """Static data compared by identity: one object for each distinct
+    treedef (``_KEYS``), so engines over models of one structure share
+    their traced programs as before."""
+
+    def __init__(self, treedef):
+        self.treedef = treedef
+
+    __hash__ = object.__hash__
+
+
+_KEYS: dict = {}          # treedef -> its _ById
+
+
+class _FlatModel:
+    """The model as the jitted programs are handed it: flattened once.
+
+    Every call of a jitted program walks its arguments' pytrees. For a
+    ``Module`` tree that walk is a Python flatten and a Python comparison
+    of static data for every module: 48 layers of six modules cost about
+    as much as the rest of a decode tick's dispatch together, every tick,
+    with the device idle meanwhile. This node's children are the model's
+    leaves and its static data the model's treedef, compared by identity;
+    unflattening gives the ``Module`` back, so a traced program sees the
+    model it always saw and traces as before. The weights are read when
+    the executor is built: to serve other weights, build another."""
+
+    def __init__(self, model):
+        self.leaves, treedef = jax.tree_util.tree_flatten(model)
+        self.key = _KEYS.setdefault(treedef, _ById(treedef))
+
+
+jax.tree_util.register_pytree_node(
+    _FlatModel, lambda m: (m.leaves, m.key),
+    lambda key, leaves: jax.tree_util.tree_unflatten(key.treedef, leaves))
 
 
 def _token_rows(ids, lens) -> dict:
@@ -58,7 +98,9 @@ class ModelExecutor:
                  max_blocks_per_seq, top_k=None, seed=0, draft_model=None,
                  spec_k=4, max_seq_len=None, kv_dtype=None, cp=1):
         cfg = model.cfg
-        self.model = model
+        # the one copy of the model the executor keeps, and what every
+        # program is handed: the weights as they were when it was built
+        self._model = _FlatModel(model)
         self.top_k = top_k
         self.rng = jax.random.PRNGKey(seed)
         self.cp = int(cp)
@@ -66,11 +108,13 @@ class ModelExecutor:
         # kv_dtype="int8": int8 block pools + parallel per-(position,
         # kv-head) f32 scale pools; every jit here quantizes on write and
         # dequantizes on read (ISSUE 17). None = pools in the model dtype.
-        self.cache = PagedKVCache.init(
-            cfg.num_hidden_layers, num_blocks, block_size,
-            cfg.num_key_value_heads,
-            cfg.hidden_size // cfg.num_attention_heads,
-            num_slots, max_blocks_per_seq, cfg.dtype, kv_dtype=kv_dtype)
+        self.cache = PagedKVCache.init_for(
+            cfg, num_blocks, block_size, num_slots, max_blocks_per_seq,
+            kv_dtype=kv_dtype)
+        # on every program span, so a reader need not know the family:
+        # passes over the stack a token, and the K/V layers it keeps
+        self.span_args = {"ut_steps": self.cache.passes,
+                        "cache_layers": self.cache.cache_layers}
         if self.cp > 1:
             self._init_cp(num_blocks)
         self.draft_model = draft_model
@@ -113,7 +157,7 @@ class ModelExecutor:
             jax.device_put(c.block_tables, rep_s),
             jax.device_put(c.lens, rep_s),
             tuple(jax.device_put(p, pool_s) for p in c.k_scales),
-            tuple(jax.device_put(p, pool_s) for p in c.v_scales))
+            tuple(jax.device_put(p, pool_s) for p in c.v_scales), c.passes)
         # pytree-PREFIX spec: each field leaf broadcasts over its subtree
         cs = PagedKVCache(P("cp"), P("cp"), P(), P(), P("cp"), P("cp"))
         R = P()
@@ -156,7 +200,7 @@ class ModelExecutor:
             (cs, R, R), cs), donate_argnums=(0,))
 
     def next_key(self):
-        self.rng, sub = jax.random.split(self.rng)
+        self.rng, sub = _SPLIT_JIT(self.rng)
         return sub
 
     def _no_cp_lora(self, lora):
@@ -172,31 +216,32 @@ class ModelExecutor:
         their cache slots while other slots keep decoding state.
         ``lora`` (optional pytree, see ``models.paged._lora_delta``)
         applies the batched multi-LoRA correction per row."""
-        with _span("exe.prefill", **_token_rows(ids, lens)):
+        with _span("exe.prefill", **_token_rows(ids, lens), **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._cp_prefill(
-                    self.model, jnp.asarray(ids), jnp.asarray(lens),
+                    self._model, jnp.asarray(ids), jnp.asarray(lens),
                     self.cache, jnp.asarray(slots), jnp.asarray(rows))
                 return logits
             logits, self.cache = _PREFILL_JIT(
-                self.model, jnp.asarray(ids), jnp.asarray(lens),
+                self._model, jnp.asarray(ids), jnp.asarray(lens),
                 self.cache, jnp.asarray(slots), jnp.asarray(rows), lora=lora)
             return logits
 
     def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
         """One chunk per row, written from an arbitrary offset over the
         slot's pool prefix (chunked prefill / prefix-cache resume)."""
-        with _span("exe.prefill_chunk", **_token_rows(ids, lens)):
+        with _span("exe.prefill_chunk", **_token_rows(ids, lens),
+                   **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._cp_prefill_chunk(
-                    self.model, jnp.asarray(ids), jnp.asarray(lens),
+                    self._model, jnp.asarray(ids), jnp.asarray(lens),
                     jnp.asarray(offs), self.cache, jnp.asarray(slots),
                     jnp.asarray(rows))
                 return logits
             logits, self.cache = _PREFILL_CHUNK_JIT(
-                self.model, jnp.asarray(ids), jnp.asarray(lens),
+                self._model, jnp.asarray(ids), jnp.asarray(lens),
                 jnp.asarray(offs), self.cache, jnp.asarray(slots),
                 jnp.asarray(rows), lora=lora)
             return logits
@@ -207,12 +252,12 @@ class ModelExecutor:
         if self.cp > 1:
             self._no_cp_lora(lora)
             logits, self.cache = self._cp_verify_chunk(
-                self.model, jnp.asarray(ids), jnp.asarray(clens),
+                self._model, jnp.asarray(ids), jnp.asarray(clens),
                 jnp.asarray(offs), self.cache, jnp.asarray(slot_ids),
                 jnp.asarray(rows))
             return logits
         logits, self.cache = _VERIFY_CHUNK_JIT(
-            self.model, jnp.asarray(ids), jnp.asarray(clens),
+            self._model, jnp.asarray(ids), jnp.asarray(clens),
             jnp.asarray(offs), self.cache, jnp.asarray(slot_ids),
             jnp.asarray(rows), lora=lora)
         return logits
@@ -234,7 +279,8 @@ class ModelExecutor:
         logp [num_slots, vocab] or None per ``need_logp``). ``lora`` is
         the per-slot multi-LoRA pytree; ``bias`` a [num_slots, V]
         grammar-mask logit bias applied before sampling."""
-        with _span("exe.decode_tick", slots=int(np.sum(run_mask))):
+        with _span("exe.decode_tick", slots=int(np.sum(run_mask)),
+                   **self.span_args):
             sub = self.next_key()
             if self.cp > 1:
                 self._no_cp_lora(lora)
@@ -243,18 +289,19 @@ class ModelExecutor:
                         "beam search (want_logp) under cp > 1 is not "
                         "supported")
                 nxt, logp, self.cache = self._cp_tick(
-                    self.model, jnp.asarray(last_tok), self.cache,
+                    self._model, jnp.asarray(last_tok), self.cache,
                     jnp.asarray(run_mask), jnp.asarray(rows),
                     jnp.asarray(cols), jnp.asarray(vals), sub,
                     jnp.asarray(temps), jnp.asarray(top_ps),
                     None if bias is None else jnp.asarray(bias))
                 return nxt, logp
+            # the staging arrays go in as the numpy arrays they are: the
+            # call uploads them together, where a ``jnp.asarray`` each is
+            # a dispatch each, with the device idle meanwhile
             nxt, logp, self.cache = _TICK_JIT(
-                self.model, jnp.asarray(last_tok), self.cache,
-                jnp.asarray(run_mask), jnp.asarray(rows), jnp.asarray(cols),
-                jnp.asarray(vals), sub, jnp.asarray(temps),
-                jnp.asarray(top_ps), self.top_k, need_logp, lora=lora,
-                logit_bias=(None if bias is None else jnp.asarray(bias)))
+                self._model, last_tok, self.cache, run_mask, rows, cols,
+                vals, sub, temps, top_ps, self.top_k, need_logp, lora=lora,
+                logit_bias=bias)
             return nxt, logp
 
     def decode_tick_async(self, tokens, active, stop, gen, max_gen,
@@ -267,10 +314,11 @@ class ModelExecutor:
         table updates, grammar bias, LoRA, or beam logp: the engine
         drains its window and takes :meth:`decode_tick` for any tick
         needing them. Returns (nxt, ran, stop', gen'), all on device."""
-        with _span("exe.decode_tick", slots=int(np.sum(active))):
+        with _span("exe.decode_tick", slots=int(np.sum(active)),
+                   **self.span_args):
             sub = self.next_key()
             nxt, ran, stop, gen, self.cache = _async_tick_jit()(
-                self.model, tokens, self.cache, jnp.asarray(active), stop,
+                self._model, tokens, self.cache, jnp.asarray(active), stop,
                 gen, max_gen, sub, jnp.asarray(temps), jnp.asarray(top_ps),
                 jnp.int32(eos_id), self.top_k)
             return nxt, ran, stop, gen

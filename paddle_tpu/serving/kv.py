@@ -35,7 +35,9 @@ def cache_block_bytes(cache) -> int:
     pools = (*cache.k_pools, *cache.v_pools,
              *getattr(cache, "k_scales", ()),
              *getattr(cache, "v_scales", ()))
-    return sum(int(np.prod(p.shape[1:])) * p.dtype.itemsize for p in pools)
+    # a looped model's pool holds a row of every pass for each block
+    return getattr(cache, "passes", 1) * sum(
+        int(np.prod(p.shape[1:])) * p.dtype.itemsize for p in pools)
 
 
 class KVManager:

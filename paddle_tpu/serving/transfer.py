@@ -239,7 +239,8 @@ def _install_blocks(cache, idx, k, v, ks, vs, slot, row, cur):
                          for li, p in enumerate(cache.v_scales))
     tables = cache.block_tables.at[slot].set(row)
     lens = cache.lens.at[slot].set(cur)
-    return type(cache)(k_pools, v_pools, tables, lens, k_scales, v_scales)
+    return type(cache)(k_pools, v_pools, tables, lens, k_scales, v_scales,
+                       cache.passes)
 
 
 _INSTALL_BLOCKS_JIT = jax.jit(_install_blocks, donate_argnums=(0,))

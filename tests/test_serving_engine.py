@@ -270,3 +270,30 @@ def test_paged_beam_shares_prompt_blocks(model):
     assert mgr2.num_blocks - mgr2.free_blocks == used0 + 1
     mgr2.free(1)
     assert mgr2.num_blocks - mgr2.free_blocks == used0
+
+
+def test_the_programs_are_handed_the_model_flattened_once(model):
+    """The executor flattens the model when it is built: a jitted program
+    then walks a flat list and not a tree of modules on every tick, sees
+    the Module it always saw when it is traced, and engines over models
+    of one structure still share one trace."""
+    pt.seed(1)
+    other = LlamaForCausalLM(model.cfg)
+    a = LLMEngine(model, num_slots=2, block_size=4, max_prompt_len=16,
+                  max_seq_len=24)
+    b = LLMEngine(other, num_slots=2, block_size=4, max_prompt_len=16,
+                  max_seq_len=24)
+    flat = a.exe._model
+    assert flat.key is b.exe._model.key
+    assert len(flat.leaves) == len(jax.tree_util.tree_leaves(model))
+
+    seen = []
+
+    @jax.jit
+    def head_sum(m):
+        seen.append(type(m))
+        return m.lm_head.sum()
+
+    np.testing.assert_allclose(head_sum(flat), model.lm_head.sum(), rtol=1e-6)
+    head_sum(b.exe._model)                      # same structure: no retrace
+    assert seen == [LlamaForCausalLM]
