@@ -31,6 +31,7 @@ import numpy as np
 from paddle_tpu.observability.memledger import MemLedger
 from paddle_tpu.ops import attention as A
 from paddle_tpu.ops.pallas.paged_attention import (_note_trace,
+                                                   _paged_chunk_call,
                                                    paged_chunk_attention,
                                                    paged_decode_attention)
 from paddle_tpu.quantization import wo_matmul as _wo
@@ -1409,12 +1410,14 @@ def clear_jit_caches():
     """Drop every module-level serving jit cache. Needed when trace-time
     context changes under the same call signature — flipping
     ``PT_GROUPED_GEMM`` or ``PT_MULTILORA_IMPL``, or entering/leaving a
-    mesh re-routes layers, but the jit caches key on shapes only."""
+    mesh re-routes layers, but the jit caches key on shapes only. The
+    chunk kernel's own ``jit`` (one traced call for every layer of a
+    program) goes with the programs that hold it."""
     if _async_tick_jit.cache_info().currsize:   # built: backend exists
         _async_tick_jit().clear_cache()
     for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _PREFILL_CHUNK_JIT,
               _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT, _PREFIX_COW_JIT,
-              *_EXTRA_CLEAR):
+              _paged_chunk_call, *_EXTRA_CLEAR):
         f.clear_cache()
 
 

@@ -1,15 +1,12 @@
-"""Paged-attention kernels (ref capability: PaddleNLP ``llm``
-block-attention / ``paddle/phi/kernels/fusion/gpu/
-fused_multi_transformer_op.cu`` block KV cache).
+"""Paged-attention kernels (ref capability: PaddleNLP ``llm`` block KV
+cache, ``fused_multi_transformer_op.cu``).
 
-TPU-first design: the KV cache is a POOL of fixed-size blocks
-([num_blocks, block_size, H_kv, D]) shared by all sequences; each sequence
-owns a row of ``block_tables`` (pool indices). Attention reads a
-sequence's blocks pool-directly through a scalar-prefetched block table
-(``pltpu.PrefetchScalarGridSpec``), so the gathered K/V is NEVER
-materialised: HBM holds pool ≈ Σ actual lengths (not B × max_len).
-
-Two kernels share that scheme:
+The KV cache is a POOL of fixed-size blocks ([num_blocks, block_size,
+H_kv, D]) shared by all sequences; each sequence owns a row of
+``block_tables`` (pool indices, scalar-prefetched). Both kernels read a
+sequence's blocks where the pool stores them: the gathered K/V is NEVER
+materialised, HBM holds pool ≈ Σ actual lengths (not B × max_len), and
+neither asks for a transposed or reshaped copy of a pool.
 
 * **decode** — q [B, H, D] (one token per sequence), grid (B,): one step
   per sequence. The pools stay in HBM as stored; the kernel walks the
@@ -17,30 +14,33 @@ Two kernels share that scheme:
   ``decode_blocks_per_step`` ``[bs, H_kv, D]`` slabs at a time (every K/V
   head of a block in one copy, so GQA fetches nothing twice) into two
   VMEM slots a pool, the next compute block in flight under this one's
-  arithmetic. VMEM holds four such buffers (2 MB in all) and the
-  sequence's ``[H, D]`` queries. Mosaic copies a slab only as whole
-  tiles of the pool's layout (``decode_slab_is_tiled``: D in 128 lanes,
-  H_kv in whole sublane tiles, which every 128-wide GQA/MHA family
-  meets); head_dim 64 and bf16/int8 MQA pools take the XLA gather.
+  arithmetic, and scores a compute block in one masked matmul.
 * **chunk** (ISSUE 11) — the ragged MULTI-query forward behind chunked
   prefill and the spec-decode ``(slots, k+1)`` verify batch: q
   [A, C, H, D] chunk queries at positions ``offsets[a] ..
   offsets[a]+chunk_lens[a]-1``, attending causally over the slot's whole
-  pool prefix. Grid (A*H_kv, q-tile, kv-block), one ``[bs, D]`` tile of a
-  head-major copy of the pool a step; the H/H_kv query heads of a KV head
-  fold into the q tile, so GQA needs no repeated K/V.
+  pool prefix. Grid (A, q tiles): one step a row and ``chunk_q_tile``
+  folded query rows (the H/H_kv query heads of a KV head fold into the
+  rows, so GQA repeats no K/V and scores no other head's keys). A dead
+  row (``chunk_lens`` 0) copies and computes nothing and emits zeros; a
+  live one walks the same slabs by the same copies, from the window's
+  first block to the tile's causal frontier, a loop over the K/V heads
+  inside, the scores transposed (keys down the sublanes). The wrapper's
+  ``pallas_call`` sits under a ``jit`` of its own: the layers of a
+  program share one traced and lowered call.
 
-Unused table slots hold the OOB sentinel (= num_blocks): the decode
-kernel never reads them, the chunk kernel's index maps clamp them and the
-length scalars mask the compute off.
+Mosaic copies a slab only as whole tiles of the pool's layout
+(``decode_slab_is_tiled``: D in 128 lanes, H_kv in whole sublane tiles,
+which every 128-wide GQA/MHA family meets); head_dim 64 and bf16/int8
+MQA pools take the XLA gather, in both dispatchers. Unused table slots
+hold the OOB sentinel (= num_blocks): neither kernel reads them.
 
 Dispatch functions (``paged_decode_attention`` /
 ``paged_chunk_attention``) pick Pallas on TPU and the XLA gather
 reference elsewhere, from the backend and the shapes alone. On TPU a
 kernel that fails to trace or lower raises: there is no downgrade to the
 gather path. ``PT_PAGED_CHUNK=0`` force-kills the chunk kernel
-(``=interpret`` forces the interpreted kernel off-TPU, the engine-level
-parity mode).
+(``=interpret`` forces the interpreted kernel off-TPU).
 """
 from __future__ import annotations
 
@@ -430,117 +430,362 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
 # The ragged multi-query forward (ISSUE 11): chunked prefill writes C
 # tokens per row at offsets[a]..offsets[a]+chunk_lens[a]-1 and each of
 # them attends causally over the row's WHOLE pool prefix. The spec-decode
-# verify batch is the same program at C = k+1. The q tile folds the
-# H/H_kv query heads of one KV head (GQA without repeating K/V), and the
-# kv-block axis walks the row's block table with dead tiles skipped:
-# blocks past the causal frontier of a q tile (and past the row's live
-# length) clamp their index map to the last live block, so Mosaic never
-# issues a fresh DMA for them, and their compute is @pl.when-masked.
+# verify batch is the same program at C = k+1. The kernel is the decode
+# kernel's scheme with a tile of queries in place of one token: rows (and
+# q tiles) are the grid, the pools stay in HBM as stored, and a loop walks
+# the row's live compute blocks only, up to the tile's causal frontier.
 
-def _paged_chunk_kernel(tables_ref, offs_ref, cls_ref, q_ref, k_ref, v_ref,
-                        *rest, block_size, scale, max_blocks, q_tile,
-                        group, n_kv, window, quantized, partials,
-                        n_pool=0):
-    """Grid (A*H_kv, q-tiles, kv-blocks). Row r serves sequence
-    a = r // n_kv, KV head r % n_kv; its q tile holds ``q_tile`` folded
-    rows (folded row t = query position t // group, grouped head
-    t % group). Online-softmax accumulation across the kv-block axis.
-    ``quantized`` (static) adds two per-position scale refs after v_ref
-    (int8 pool, dequantize in-kernel). ``partials`` (static, context
-    parallelism): emit the raw (acc, m, l) triple instead of the
-    normalised output and skip non-owned table entries (translated to
-    the OOB sentinel by the caller)."""
+# VMEM the float32 accumulator of one grid step may fill: it holds a
+# ``[D]`` row for every folded query row (position x grouped head) of
+# every K/V head, so the q tile follows from it and from the shape.
+_CHUNK_ACC_BYTES = 4 << 20
+_CHUNK_Q_TILE_MAX = 1024
+# scoped VMEM the kernel may ask for (a v5e core has 128 MiB): at the
+# Mistral cell's shapes the q and o tiles (two buffers each), the
+# accumulator, four slab buffers, the compute block in float32, its mask
+# and the scores in flight come to some 22 MB
+_CHUNK_VMEM_LIMIT = 48 << 20
+
+
+def chunk_q_tile(cg, h_kv, d):
+    """Folded query rows (positions x grouped heads) one grid step of the
+    chunk kernel scores: what ``_CHUNK_ACC_BYTES`` holds for ``h_kv``
+    heads, in whole 128-lane rows (the queries lie along the lanes of the
+    scores), no more than the folded chunk ``cg`` needs."""
+    fit = _CHUNK_ACC_BYTES // (h_kv * d * 4) // 128 * 128
+    return min(max(fit, 128), _CHUNK_Q_TILE_MAX, -(-cg // 128) * 128)
+
+
+def _paged_chunk_kernel(tables_ref, offs_ref, cls_ref, q_ref, k_hbm, v_hbm,
+                        *rest, block_size, scale, max_blocks, per_step,
+                        group, window, quantized, partials, n_pool):
+    """Grid (A, q tiles): one step scores ``q_tile`` folded query rows
+    (folded row r = query position r // group, grouped head r % group) of
+    every K/V head of one sequence; ``q_ref`` is ``[1, H_kv, q_tile, D]``.
+    A tile past ``chunk_lens`` (a dead row: every tile) copies nothing,
+    computes nothing and emits zeros.
+
+    The pools stay in HBM as stored (``[N, bs, H_kv, D]``). A loop over
+    the compute blocks from the window's first block to the tile's causal
+    frontier (the block of its last live query, nothing past it) gathers
+    ``per_step`` table entries each, one ``[bs, H_kv, D]`` slab per entry
+    and pool, into one of two VMEM slots, the next compute block's copies
+    in flight under this one's arithmetic. An inner loop over the K/V
+    heads scores the head's folded queries against the head's own
+    ``[T, D]`` keys (GQA spends no FLOP on another head's columns): the
+    compute block is widened to float32 once, because a sublane of a
+    packed (bf16, int8) tile cannot be addressed by a traced head index
+    and one of a 32-bit tile can.
+
+    The scores are kept TRANSPOSED, ``[T, q_tile]``: keys down the
+    sublanes, queries along the lanes. The softmax's reductions over the
+    keys are then elementwise over vregs (a reduction along the lanes is
+    half the time of the kernel written the other way), and its state is
+    dense: m and l one ``[1, q_tile]`` row a head, the accumulator
+    ``[D, q_tile]`` (``V^T P^T``), transposed back once when the tile is
+    emitted. Online softmax in float32; the matmuls take their operands in
+    the queries' dtype.
+
+    ``quantized`` (static): int8 pools; the scales arrive gathered along
+    the table, a ``[1, T]`` row a head and compute block, turned into a
+    column here, and multiply scores (K) and probabilities (V) by key.
+    ``partials`` (static, context parallelism): table entries >=
+    ``n_pool`` belong to another shard and are neither fetched nor
+    scored; emit the raw (acc, m, l)."""
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
     if partials:
-        o_ref, m_ref, l_ref, acc, m_scr, l_scr = rest
+        o_ref, m_ref, l_ref = rest[:3]
+        rest = rest[3:]
     else:
-        o_ref, acc, m_scr, l_scr = rest
-    r = pl.program_id(0)
-    qt = pl.program_id(1)
-    j = pl.program_id(2)
+        o_ref = rest[0]
+        rest = rest[1:]
+    kbuf, vbuf, sems, kf, vf, bias, acc, m_scr, l_scr = rest
+    i = pl.program_id(0)
+    t = pl.program_id(1)
+    bs, P = block_size, per_step
+    h_kv, qt, d = q_ref.shape[1:]
+    T = P * bs                        # keys of one compute block
 
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    off = offs_ref[i]
+    live_rows = cls_ref[i] * group    # folded rows that carry a query
+    r0 = t * qt                       # the tile's first folded row
+    tile_live = r0 < live_rows
 
-    a_idx = r // n_kv
-    off = offs_ref[a_idx, 0]
-    cl = cls_ref[a_idx, 0]
-    row_len = off + cl                     # this row's live pool length
-    n_live = pl.cdiv(row_len, block_size)
-    q0 = qt * q_tile                       # first folded row of the tile
-    last_q = off + (q0 + q_tile - 1) // group   # tile's last query position
-    live = (j < n_live) & (q0 < cl * group)
-    if partials:
-        # ownership mask: non-owned table entries were translated to the
-        # local sentinel — the owning shard's partial covers them
-        live &= tables_ref[a_idx, j] < n_pool
-    # causal dead-tile skip: a block whose FIRST key position is past the
-    # tile's LAST query position contributes nothing
-    live &= j * block_size <= last_q
-    if window is not None:
-        # sliding window: a block entirely below the tile's first query's
-        # window is invisible to every query in the tile
-        first_q = off + q0 // group
-        live &= (j + 1) * block_size - 1 > first_q - window
+    @pl.when(jnp.logical_not(tile_live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        if partials:
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]                       # [q_tile, D] folded queries
-        k = k_ref[0, 0].astype(jnp.float32)    # [block_size, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]           # [block_size, 1] over D
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(q.astype(jnp.float32), k,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        row_t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qpos = off + (q0 + row_t) // group
-        kpos = j * block_size + col
-        # causal + ragged: key visible iff it is at/before the query AND
-        # inside the row's live length; folded rows past chunk_lens*group
-        # are padding (their tile output is discarded by the caller)
-        keep = (kpos <= qpos) & (kpos < row_len)
-        keep &= (q0 + row_t) < cl * group
+    @pl.when(tile_live)
+    def _():
+        # the causal frontier: the block of the tile's last live query
+        q_last = off + (jnp.minimum(r0 + qt, live_rows) - 1) // group
+        n_live = q_last // bs + 1
+        first = 0
         if window is not None:
-            keep &= (qpos - kpos) < window
-        s = jnp.where(keep, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if partials:
-            # a row whose visible keys ALL live on other shards is fully
-            # masked here: m_new == _NEG_INF and exp(s - m_new) == 1 —
-            # the explicit keep multiply zeroes it so the merged triple
-            # stays (acc=0, l=0) instead of garbage (cp=1 never hits
-            # this: block 0 always holds visible keys for a real row)
-            p = p * keep.astype(jnp.float32)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        pv = jax.lax.dot_general(p, v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc[:] = acc[:] * corr + pv
+            # blocks entirely below the first query's window are invisible
+            # to every query of the tile
+            first = jnp.maximum(off + r0 // group - window + 1, 0) // bs
+        c_lo = first // P
+        c_hi = pl.cdiv(n_live, P)
 
-    @pl.when(j == max_blocks - 1)
-    def _finalize():
+        def entry(j):
+            return tables_ref[i, jnp.minimum(j, max_blocks - 1)]
+
+        def each_copy(c, slot, act):
+            """``act`` on the K and the V copy of every entry of compute
+            block c that is fetched (a scalar loop: one copy's code)."""
+            def one(p, _):
+                j = c * P + p
+                go = (j >= first) & (j < n_live)
+                if partials:
+                    go &= entry(j) < n_pool
+                blk = jnp.minimum(entry(j), n_pool - 1)
+                rows = pl.ds(p * bs, bs)
+
+                @pl.when(go)
+                def _():
+                    act(pltpu.make_async_copy(
+                        k_hbm.at[blk], kbuf.at[slot, rows], sems.at[0, slot]))
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[blk], vbuf.at[slot, rows], sems.at[1, slot]))
+            jax.lax.fori_loop(0, P, one, None)
+
+        def start(c, slot):
+            each_copy(c, slot, lambda copy: copy.start())
+
+        def wait(c, slot):
+            each_copy(c, slot, lambda copy: copy.wait())
+
+        # rows no copy fills meet a mask that is ADDED to the scores (K)
+        # and probability 0 in the matmul with V: they must be finite, and
+        # fresh VMEM need not be
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        start(c_lo, c_lo % 2)        # a live tile has a block: c_lo < c_hi
+        acc[...] = jnp.zeros_like(acc)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, qt), 1)
+        qpos = off + (r0 + lane) // group                 # [1, qt]
+        key = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+
+        def column(row):
+            """A scale row [1, T] -> [T, 1], a scale a key."""
+            return jnp.broadcast_to(row, (128, T)).T[:, :1]
+
+        def block(c, _):
+            slot = c % 2
+
+            @pl.when(c + 1 < c_hi)
+            def _():
+                start(c + 1, 1 - slot)
+
+            wait(c, slot)
+            kf[...] = kbuf[slot].astype(jnp.float32)
+            vf[...] = vbuf[slot].astype(jnp.float32)
+            # the mask of the compute block, every head's alike: causal
+            # (which keeps a live query inside the row's length too), the
+            # window, and under ``partials`` the entries this shard owns
+            kpos = c * T + key                            # [T, 1]
+            if partials:
+                owned = jnp.zeros(kpos.shape, jnp.bool_)
+                for p in range(P):  # unrolled: a loop cannot carry a mask
+                    owned |= ((key // bs == p)
+                              & (entry(c * P + p) < n_pool))
+                kpos = jnp.where(owned, kpos, jnp.iinfo(jnp.int32).max)
+            keep = kpos <= qpos                           # [T, qt]
+            if window is not None:
+                keep &= kpos > qpos - window
+            bias[...] = jnp.where(keep, 0.0, _NEG_INF)
+
+            def head(h, _):
+                q = q_ref[0, h]                           # [qt, D]
+                k = kf[:, h, :].astype(q.dtype)           # [T, D]
+                v = vf[:, h, :].astype(q.dtype)
+                s = jax.lax.dot_general(
+                    k, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    s = s * column(ks_ref[0, h, pl.ds(c, 1), :])
+                s = s + bias[...]     # a masked score is _NEG_INF exactly
+                m_prev = m_scr[h]                         # [1, qt]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=0, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                prob = jnp.exp(s - m_new)                 # [T, qt]
+                if partials:
+                    # a query whose visible keys all lie on other shards
+                    # has m_new == _NEG_INF and exp(0) == 1 everywhere
+                    prob = jnp.where(bias[...] < 0.0, 0.0, prob)
+                l_scr[h] = (l_scr[h] * corr
+                            + jnp.sum(prob, axis=0, keepdims=True))
+                m_scr[h] = m_new
+                if quantized:
+                    prob = prob * column(vs_ref[0, h, pl.ds(c, 1), :])
+                pv = jax.lax.dot_general(                 # V^T P^T
+                    v, prob.astype(v.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc[h] = acc[h] * corr + pv               # [D, qt]
+
+            jax.lax.fori_loop(0, h_kv, head, None)
+
+        jax.lax.fori_loop(c_lo, c_hi, block, None)
+
+        # folded rows past chunk_lens are padding: they attended over
+        # whatever lay past the row's length, and emit zeros
+        real = (r0 + lane) < live_rows                    # [1, qt]
         if partials:
-            o_ref[0] = acc[:].astype(o_ref.dtype)
-            m_ref[0] = m_scr[:]
-            l_ref[0] = l_scr[:]
-        else:
-            # fully-masked rows (dead/padding) have l == 0: emit 0, not NaN
-            o_ref[0] = (acc[:] / jnp.maximum(l_scr[:, :1], 1e-30)
-                        ).astype(o_ref.dtype)
+            m_ref[0] = jnp.where(real, m_scr[...], _NEG_INF)
+            l_ref[0] = jnp.where(real, l_scr[...], 0.0)
+
+        def emit(h, _):
+            out = acc[h]
+            if not partials:
+                # a row that walked nothing has l == 0: emit 0, not NaN
+                out = out / jnp.maximum(l_scr[h], 1e-30)
+            o_ref[0, h] = jnp.where(real, out, 0.0).T.astype(o_ref.dtype)
+
+        jax.lax.fori_loop(0, h_kv, emit, None)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "window", "q_tile", "partials", "interpret"))
+def _paged_chunk_call(q, k_pool, v_pool, block_tables, offsets, chunk_lens,
+                      k_scale, v_scale, *, scale, window, q_tile, partials,
+                      interpret):
+    """The ``pallas_call`` of the chunk kernel and the folding around it,
+    under one ``jit`` of their own: every layer of a program (and every
+    layer body of a looped model) calls the same traced function, so the
+    kernel is traced once and lowered to Mosaic once a program, not once a
+    call site."""
+    a, c, h, d = q.shape
+    n, bs, h_kv, _ = k_pool.shape
+    group = h // h_kv
+    max_blocks = block_tables.shape[1]
+    quantized = k_scale is not None
+    per_step = decode_blocks_per_step(bs, h_kv, d, k_pool.dtype, max_blocks)
+    keys = per_step * bs
+
+    cg = c * group
+    if q_tile is None:
+        q_tile = chunk_q_tile(cg, h_kv, d)
+    n_qt = -(-cg // q_tile)
+    # fold the grouped query heads into the row axis: row r of (a, kv) is
+    # query position r // group, grouped head r % group — the
+    # (head // kv_rep) GQA convention of the decode kernel
+    qf = q.reshape(a, c, h_kv, group, d).transpose(0, 2, 1, 3, 4)
+    qf = qf.reshape(a, h_kv, cg, d)
+    if n_qt * q_tile != cg:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, n_qt * q_tile - cg), (0, 0)))
+
+    tables = block_tables.astype(jnp.int32)
+    offs = offsets.astype(jnp.int32)
+    cls = chunk_lens.astype(jnp.int32)
+
+    def out_tile(i, t, tables, offs, cls):
+        return (i, 0, t, 0)
+
+    def stat_tile(i, t, tables, offs, cls):
+        return (i, 0, 0, t)
+
+    def q_tile_of(i, t, tables, offs, cls):
+        # a dead tile names the first block (the one the steps before it
+        # named, past the first live row): the pipeline fetches no queries
+        # for it
+        live = t * q_tile < cls[i] * group
+        return (jnp.where(live, i, 0), 0, jnp.where(live, t, 0), 0)
+
+    def row_of(i, t, tables, offs, cls):
+        return (jnp.where(cls[i] > 0, i, 0), 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, h_kv, q_tile, d), q_tile_of),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [qf, k_pool, v_pool]
+    if quantized:
+        # the scales are small (4 bytes a position and head): gathered
+        # along the table here, a row of compute blocks a sequence and
+        # head (an entry past the row's blocks reads as scale 0, not as
+        # whatever the block the sentinel clamps onto holds)
+        n_steps = -(-max_blocks // per_step)
+        clamped = jnp.minimum(tables, n - 1)
+
+        def along_table(pool):
+            g = jnp.where((tables < n)[:, :, None, None],
+                          jnp.take(pool, clamped, axis=0), 0.0)
+            g = g.reshape(a, -1, h_kv)
+            g = jnp.pad(jnp.moveaxis(g, 2, 1),
+                        ((0, 0), (0, 0), (0, n_steps * keys - g.shape[1])))
+            return g.reshape(a, h_kv, n_steps, keys)
+
+        in_specs += [pl.BlockSpec((1, h_kv, n_steps, keys), row_of)] * 2
+        operands += [along_table(k_scale), along_table(v_scale)]
+
+    rows = n_qt * q_tile
+    out_specs = pl.BlockSpec((1, h_kv, q_tile, d), out_tile)
+    out_shape = jax.ShapeDtypeStruct((a, h_kv, rows, d), q.dtype)
+    if partials:
+        # acc in f32 (the merge renormalises before the dtype cast) plus
+        # the m and l rows
+        out_specs = [out_specs] + [
+            pl.BlockSpec((1, h_kv, 1, q_tile), stat_tile)] * 2
+        out_shape = [jax.ShapeDtypeStruct((a, h_kv, rows, d), jnp.float32),
+                     jax.ShapeDtypeStruct((a, h_kv, 1, rows), jnp.float32),
+                     jax.ShapeDtypeStruct((a, h_kv, 1, rows), jnp.float32)]
+    slots = (2, keys, h_kv, d)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(a, n_qt),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM(slots, k_pool.dtype),
+            pltpu.VMEM(slots, v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            # the current compute block in float32, K and V
+            pltpu.VMEM(slots[1:], jnp.float32),
+            pltpu.VMEM(slots[1:], jnp.float32),
+            # its mask as an additive bias, shared by the heads
+            pltpu.VMEM((keys, q_tile), jnp.float32),
+            # per-head accumulator (transposed), running max, denominator
+            pltpu.VMEM((h_kv, d, q_tile), jnp.float32),
+            pltpu.VMEM((h_kv, 1, q_tile), jnp.float32),
+            pltpu.VMEM((h_kv, 1, q_tile), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_paged_chunk_kernel, block_size=bs,
+                               scale=scale, max_blocks=max_blocks,
+                               per_step=per_step, group=group,
+                               window=window, quantized=quantized,
+                               partials=partials, n_pool=n)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        # rows and q tiles are independent: every step sets up its own
+        # state
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_chunk_attention",
+    )(tables, offs, cls, *operands)
+
+    def unfold(x):
+        x = x[:, :, :cg].reshape(a, h_kv, c, group, *x.shape[3:])
+        x = jnp.moveaxis(x, 1, 2)                  # [A, C, H_kv, group, ..]
+        return x.reshape(a, c, h, *x.shape[4:])
+
+    if partials:
+        acc, m, l = out
+        return unfold(acc), unfold(m[:, :, 0]), unfold(l[:, :, 0])
+    return unfold(out)
 
 
 def paged_chunk_attention_pallas(q, k_pool, v_pool, block_tables, offsets,
@@ -555,119 +800,23 @@ def paged_chunk_attention_pallas(q, k_pool, v_pool, block_tables, offsets,
     int32 — row a's queries sit at positions offsets[a] ..
     offsets[a]+chunk_lens[a]-1 and attend over pool positions
     [0, offsets[a]+chunk_lens[a]) causally. Rows with chunk_lens == 0 are
-    dead (output 0). Returns [A, C, H, D] — or, with ``partials=True``
-    (context parallelism), the raw (acc [A, C, H, D] f32, m [A, C, H]
-    f32, l [A, C, H] f32) triple over owned table entries only."""
-    a, c, h, d = q.shape
-    n, bs, h_kv, _ = k_pool.shape
-    group = h // h_kv
-    max_blocks = block_tables.shape[1]
-    scale = scale if scale is not None else d ** -0.5
-    quantized = k_scale is not None
+    dead, and so are a live row's positions past chunk_lens (output 0).
+    Returns [A, C, H, D] — or, with ``partials=True`` (context
+    parallelism), the raw (acc [A, C, H, D] f32, m [A, C, H] f32,
+    l [A, C, H] f32) triple over owned table entries only.
+
+    The pools are handed to the kernel in HBM as they are stored: no
+    transpose, no pool-sized temporary. Compiled (``interpret=False``)
+    the shape has to meet ``decode_slab_is_tiled``, and a ``q_tile``
+    given by hand (``chunk_q_tile`` otherwise) to be whole 128-lane rows."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-
-    cg = c * group
-    if q_tile is None:
-        # sublane-aligned tile; one tile unless the folded chunk is large
-        q_tile = min(256, -(-cg // 8) * 8)
-    n_qt = -(-cg // q_tile)
-    pad = n_qt * q_tile - cg
-
-    # fold the grouped query heads into the row axis: row t of (a, kv) is
-    # query position t // group, grouped head t % group — matches the
-    # (head // kv_rep) GQA convention of the decode kernel
-    qf = q.reshape(a, c, h_kv, group, d).transpose(0, 2, 1, 3, 4)
-    qf = qf.reshape(a * h_kv, cg, d)
-    if pad:
-        qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0)))
-
-    tables = jnp.asarray(block_tables, jnp.int32)
-    offs = jnp.asarray(offsets, jnp.int32)[:, None]
-    cls = jnp.asarray(chunk_lens, jnp.int32)[:, None]
-
-    kp = jnp.moveaxis(k_pool, 2, 0)        # [H_kv, N, bs, D]
-    vp = jnp.moveaxis(v_pool, 2, 0)
-
-    def q_index(r, qt, j, tables, offs, cls):
-        return (r, qt, 0)
-
-    def kv_index(r, qt, j, tables, offs, cls):
-        a_i = r // n_kv_s
-        row_len = offs[a_i, 0] + cls[a_i, 0]
-        n_live = (row_len + bs - 1) // bs
-        last_q = offs[a_i, 0] + (qt * q_tile + q_tile - 1) // group
-        # dead trailing steps (past the causal frontier or the live
-        # length) revisit the last live block: same index -> no new DMA
-        hi = jnp.minimum(n_live - 1, last_q // bs)
-        jl = jnp.minimum(j, jnp.maximum(hi, 0))
-        return (r % n_kv_s, jnp.minimum(tables[a_i, jl], n - 1), 0, 0)
-
-    n_kv_s = h_kv
-    in_specs = [
-        pl.BlockSpec((1, q_tile, d), q_index),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
-        pl.BlockSpec((1, 1, bs, d), kv_index),
-    ]
-    operands = [qf, kp, vp]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bs, 1), kv_index),
-                     pl.BlockSpec((1, 1, bs, 1), kv_index)]
-        operands += [jnp.moveaxis(k_scale, 2, 0)[..., None],
-                     jnp.moveaxis(v_scale, 2, 0)[..., None]]
-    out_specs = pl.BlockSpec((1, q_tile, d), q_index)
-    out_shape = jax.ShapeDtypeStruct((a * h_kv, n_qt * q_tile, d), q.dtype)
-    if partials:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, q_tile, 128), q_index),
-                     pl.BlockSpec((1, q_tile, 128), q_index)]
-        out_shape = [
-            jax.ShapeDtypeStruct((a * h_kv, n_qt * q_tile, d), jnp.float32),
-            jax.ShapeDtypeStruct((a * h_kv, n_qt * q_tile, 128),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((a * h_kv, n_qt * q_tile, 128),
-                                 jnp.float32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(a * h_kv, n_qt, max_blocks),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((q_tile, d), jnp.float32),
-            # per-folded-row running max / denom, lane-replicated (scalar
-            # (x, 1) VMEM stores hit Mosaic layout restrictions)
-            pltpu.VMEM((q_tile, 128), jnp.float32),
-            pltpu.VMEM((q_tile, 128), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_chunk_kernel, block_size=bs,
-                               scale=scale, max_blocks=max_blocks,
-                               q_tile=q_tile, group=group, n_kv=h_kv,
-                               window=window, quantized=quantized,
-                               partials=partials, n_pool=n)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        # rows and q tiles are independent; only the kv-block axis carries
-        # the online-softmax state
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
-                                 pltpu.ARBITRARY)),
-        interpret=interpret,
-        name="paged_chunk_attention",
-    )(tables, offs, cls, *operands)
-
-    def unfold(x, last):
-        x = x[:, :cg].reshape(a, h_kv, c, group, *((last,) if last else ()))
-        if last:
-            return x.transpose(0, 2, 1, 3, 4).reshape(a, c, h, last)
-        return x.transpose(0, 2, 1, 3).reshape(a, c, h)
-
-    if partials:
-        acc, m, l = out
-        return unfold(acc, d), unfold(m[..., 0], 0), unfold(l[..., 0], 0)
-    return unfold(out, d)
+    return _paged_chunk_call(
+        q, k_pool, v_pool, jnp.asarray(block_tables), jnp.asarray(offsets),
+        jnp.asarray(chunk_lens), k_scale, v_scale,
+        scale=float(scale if scale is not None else q.shape[-1] ** -0.5),
+        window=window, q_tile=q_tile, partials=partials,
+        interpret=bool(interpret))
 
 
 def paged_chunk_attention_xla(q, k_pool, v_pool, block_tables, offsets,
@@ -731,7 +880,9 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, offsets,
     (read at TRACE time — flip it between engine constructions together
     with ``models.paged.clear_jit_caches``):
 
-      unset/1     Pallas kernel on TPU, XLA gather elsewhere (default)
+      unset/1     Pallas kernel on TPU for pools whose slabs Mosaic can
+                  copy (``decode_slab_is_tiled``), XLA gather elsewhere
+                  (default)
       0/off/xla   force the XLA gather path (kill switch)
       interpret   force the interpreted Pallas kernel (off-TPU parity)
 
@@ -756,12 +907,14 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, offsets,
             scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
             partials=partials, interpret=True)
     if mosaic_kernels_apply():
-        out = paged_chunk_attention_pallas(
-            q, k_pool, v_pool, block_tables, offsets, chunk_lens,
-            scale=scale, window=window, k_scale=k_scale,
-            v_scale=v_scale, partials=partials, interpret=interpret)
-        _note_trace("chunk:pallas")
-        return out
+        if decode_slab_is_tiled(*k_pool.shape[2:], k_pool.dtype):
+            out = paged_chunk_attention_pallas(
+                q, k_pool, v_pool, block_tables, offsets, chunk_lens,
+                scale=scale, window=window, k_scale=k_scale,
+                v_scale=v_scale, partials=partials, interpret=interpret)
+            _note_trace("chunk:pallas")
+            return out
+        _note_trace("chunk:slab-off-tiling")
     _note_trace("chunk:xla")
     return paged_chunk_attention_xla(
         q, k_pool, v_pool, block_tables, offsets, chunk_lens,

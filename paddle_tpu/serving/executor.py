@@ -81,6 +81,14 @@ def _token_rows(ids, lens) -> dict:
     return {"rows": int(np.size(ids)), "useful": int(np.sum(lens))}
 
 
+def _chunk_kv_blocks(lens, offs, block_size) -> int:
+    """Pool blocks a cache layer the chunk kernel walks in one call: every
+    live row's blocks up to the end of its chunk, from the host's lengths
+    (``serving.decode`` carries the same count for the decode kernel)."""
+    lens, offs = np.asarray(lens), np.asarray(offs)
+    return int(np.sum(-(-(offs + lens)[lens > 0] // block_size)))
+
+
 class ModelExecutor:
     """Jitted prefill/decode/verify programs over one paged KV pool.
 
@@ -232,6 +240,8 @@ class ModelExecutor:
         """One chunk per row, written from an arbitrary offset over the
         slot's pool prefix (chunked prefill / prefix-cache resume)."""
         with _span("exe.prefill_chunk", **_token_rows(ids, lens),
+                   kv_blocks=_chunk_kv_blocks(lens, offs,
+                                              self.cache.block_size),
                    **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)

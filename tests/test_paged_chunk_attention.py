@@ -106,6 +106,146 @@ def test_chunk_parity_verify_shape():
     _assert_live_parity(out_p, out_x, cls)
 
 
+# ------------------------------------------ the grid of shapes and rows
+# One case a line: (heads, chunk C, rows, offsets, window, pool, partials).
+# The lines cover every pair of values of any two axes, then the two
+# cells' own combinations; head_dim 16 and a block of 16 keep the
+# interpreter quick, and a buffer of four blocks makes every row with
+# more than 64 positions walk several compute blocks.
+HEADS = {"mha16": (16, 16), "gqa32_8": (32, 8), "gqa8_2": (8, 2)}
+GRID = [
+    ("gqa32_8", 256, "all_live", "table_end", 1024, "int8", False),
+    ("gqa32_8", 256, "one_of_16", "zero", 1024, "int8", True),
+    ("gqa32_8", 44, "ragged", "mid_block", None, "int8", True),
+    ("gqa32_8", 5, "dead_between", "table_end", None, "int8", False),
+    ("gqa32_8", 5, "ragged", "zero", 1024, "bf16", False),
+    ("gqa8_2", 256, "all_live", "mid_block", None, "int8", False),
+    ("gqa8_2", 256, "ragged", "table_end", 1024, "bf16", False),
+    ("gqa8_2", 44, "dead_between", "mid_block", None, "bf16", False),
+    ("gqa8_2", 44, "one_of_16", "zero", 1024, "int8", False),
+    ("gqa8_2", 5, "all_live", "mid_block", 1024, "bf16", True),
+    ("mha16", 256, "dead_between", "zero", 1024, "int8", True),
+    ("mha16", 256, "ragged", "table_end", 1024, "int8", False),
+    ("mha16", 44, "all_live", "zero", None, "bf16", False),
+    ("mha16", 44, "one_of_16", "table_end", None, "bf16", True),
+    ("mha16", 5, "one_of_16", "mid_block", None, "int8", False),
+    # mistral7b.serve.backlog's and ouro2.6b.serve.reasoning's own
+    ("gqa32_8", 256, "one_of_16", "mid_block", None, "bf16", False),
+    ("mha16", 256, "dead_between", "mid_block", None, "bf16", False),
+    ("gqa32_8", 256, "dead_between", "table_end", 1024, "bf16", True),
+]
+_BS, _WIDTH = 16, 84          # 1,344 positions a row: past a 1,024 window
+
+
+def _grid_case(rng, heads, c, rows, offsets, pool, partials, poison=False):
+    """-> (args, scales, live): q bf16, a pool of ``pool`` whose blocks no
+    live table entry names hold NaN when ``poison`` (an int8 pool: NaN
+    scales), tables padded with the sentinel, every third live entry of a
+    row another shard's under ``partials``."""
+    h, h_kv = HEADS[heads]
+    d, bs, mb = 16, _BS, _WIDTH
+    a = {"all_live": 3, "one_of_16": 16, "dead_between": 3, "ragged": 4}[rows]
+    cls = {"all_live": [c] * 3, "one_of_16": [0] * 9 + [c] + [0] * 6,
+           "dead_between": [c, 0, max(1, c - 3)],
+           "ragged": [c, 1, max(1, c // 2), max(1, c - 1)]}[rows]
+    room = mb * bs - c
+    off = {"zero": 0, "mid_block": 5 * bs + 7, "table_end": room}[offsets]
+    offs = [max(0, off - 3 * i) if n else 0 for i, n in enumerate(cls)]
+    n = 16 + sum(-(-(o + l) // bs) for o, l in zip(offs, cls))
+    q = jnp.asarray(rng.normal(size=(a, c, h, d)), jnp.bfloat16)
+    kf = rng.normal(size=(2, n, bs, h_kv, d)).astype(np.float32)
+    tables = np.full((a, mb), n, np.int32)
+    free, at = rng.permutation(n), 0
+    for i in range(a):
+        need = -(-(offs[i] + cls[i]) // bs) if cls[i] else 0
+        tables[i, :need] = free[at:at + need]
+        at += need
+        if partials:
+            tables[i, 2:need:3] = n
+    named = np.zeros(n, bool)
+    named[tables[tables < n]] = True
+    scales = {}
+    if pool == "int8":
+        sc = np.abs(kf).max(-1) / 127.0                  # [2, N, bs, H_kv]
+        kq = np.round(kf / sc[..., None]).astype(np.int8)
+        if poison:
+            sc[:, ~named] = np.nan
+        pools = [jnp.asarray(x) for x in kq]
+        scales = dict(k_scale=jnp.asarray(sc[0]), v_scale=jnp.asarray(sc[1]))
+    else:
+        if poison:
+            kf[:, ~named] = np.nan
+        pools = [jnp.asarray(x, jnp.bfloat16) for x in kf]
+    args = (q, *pools, jnp.asarray(tables), np.asarray(offs, np.int32),
+            np.asarray(cls, np.int32))
+    return args, scales, np.asarray(cls) > 0
+
+
+def _close_on_live(got, want, cls, tol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    for i, n in enumerate(cls):
+        assert not n or np.abs(got[i, :n] - want[i, :n]).max() < tol, i
+        assert np.all(got[i, n:] == 0), f"row {i}: padding not zero"
+
+
+@pytest.mark.parametrize(
+    "case", GRID, ids=["-".join(map(str, g)) for g in GRID])
+def test_chunk_kernel_grid(case, monkeypatch):
+    """The kernel against the gather on live positions; zeros on dead rows
+    and past ``chunk_lens``; under ``partials`` the raw triple."""
+    heads, c, rows, offsets, window, pool, partials = case
+    h_kv = HEADS[heads][1]
+    # four blocks a compute block: the loop, both slots, a ragged last one
+    monkeypatch.setattr(pa, "_DECODE_BUFFER_BYTES", 4 * _BS * h_kv * 16
+                        * (1 if pool == "int8" else 2))
+    rng = np.random.default_rng(GRID.index(case))
+    args, scales, live = _grid_case(rng, heads, c, rows, offsets, pool,
+                                    partials)
+    cls = args[5]
+    out = pa.paged_chunk_attention_pallas(
+        *args, window=window, partials=partials, interpret=True, **scales)
+    # the gather on the live rows alone: a dead row costs it a full table
+    q, kp, vp, tables, offs, _ = args
+    ref = pa.paged_chunk_attention_xla(
+        q[live], kp, vp, tables[live], offs[live], cls[live], window=window,
+        partials=partials, **scales)
+    outs, refs = (out, ref) if partials else ((out,), (ref,))
+    full = [np.zeros(x.shape, np.float32) for x in outs]
+    for f, r in zip(full, refs):
+        f[live] = np.asarray(r, np.float32)
+    if not partials:
+        _close_on_live(out, full[0], cls, 3e-2)
+        return
+    acc, m, l = out
+    scale = max(1.0, float(np.abs(full[2]).max()))
+    _close_on_live(acc, full[0], cls, 3e-2 * scale)
+    _close_on_live(l, full[2], cls, 1e-2 * scale)
+    # m where a query has an owned key in sight; nothing elsewhere
+    seen = (full[2] > 0) & (np.arange(c)[None, :, None] < cls[:, None, None])
+    assert np.abs(np.where(seen, np.asarray(m) - full[1], 0)).max() < 1e-2
+    assert np.all(np.asarray(m)[~seen] <= -1e29)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_chunk_kernel_reads_no_dead_kv(pool, monkeypatch):
+    """Every pool block that no live table entry names holds NaN (an int8
+    pool: NaN scales): a kernel that walked past a row's frontier, read a
+    dead row, or followed the sentinel would return it."""
+    monkeypatch.setattr(pa, "_DECODE_BUFFER_BYTES", 4 * _BS * 8 * 16 * 2)
+    outs = []
+    for poison in (False, True):
+        args, scales, _ = _grid_case(np.random.default_rng(11), "gqa32_8",
+                                     44, "dead_between", "mid_block", pool,
+                                     False, poison=poison)
+        outs.append(np.asarray(pa.paged_chunk_attention_pallas(
+            *args, interpret=True, **scales), np.float32))
+    clean, poisoned = outs
+    assert np.isfinite(poisoned).all()
+    assert np.array_equal(clean, poisoned)
+    assert np.abs(clean[0]).max() > 0 and np.all(clean[1] == 0)
+
+
 # ----------------------------------------------- dispatch + fallback
 
 def test_dispatch_kill_switch_forces_xla(monkeypatch):
@@ -150,13 +290,13 @@ def test_pallas_failure_raises_no_downgrade(monkeypatch, kernel):
     rng = np.random.default_rng(6)
     if kernel == "chunk":
         monkeypatch.setattr(pa, "paged_chunk_attention_pallas", boom)
+        # head_dim 128: a slab the kernels can copy (decode_slab_is_tiled)
         q, kp, vp, tables, offs, cls = _ragged_case(
-            rng, 2, 4, 4, 2, 16, 8, 4, 16, offs=[0, 9], cls=[4, 3])
+            rng, 2, 4, 4, 2, 128, 8, 4, 16, offs=[0, 9], cls=[4, 3])
         call = lambda: pa.paged_chunk_attention(q, kp, vp, tables, offs,
                                                 cls)
     else:
         monkeypatch.setattr(pa, "paged_decode_attention_pallas", boom)
-        # head_dim 128: a slab the kernel can copy (decode_slab_is_tiled)
         q = jnp.asarray(rng.normal(size=(2, 4, 128)), jnp.float32)
         kp = jnp.asarray(rng.normal(size=(16, 8, 2, 128)), jnp.float32)
         vp = jnp.asarray(rng.normal(size=(16, 8, 2, 128)), jnp.float32)

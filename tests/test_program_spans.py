@@ -256,6 +256,47 @@ def test_decode_span_counts_the_pool_blocks_the_kernel_walks(model):
     assert all(k <= s * eng.max_blocks_per_seq for s, k in walked)
 
 
+@pytest.mark.parametrize("traffic", ["two_chunks", "radix_hit"])
+def test_chunk_span_counts_the_pool_blocks_the_kernel_walks(model, traffic):
+    """``exe.prefill_chunk`` carries ``kv_blocks``: ceil((offset + chunk
+    length) / block) summed over the rows that carry a chunk, whatever
+    else the padded batch holds."""
+    eng = _engine(model)
+    rs = np.random.RandomState(5)
+    prompt = rs.randint(0, 64, (13,))
+    if traffic == "two_chunks":
+        # 8 tokens at offset 0, then 5 at offset 8: blocks of 4
+        eng.add_request(Request(prompt, max_new_tokens=2))
+        want = [2, 4]
+    else:
+        # the second prompt shares nine tokens with the first: two cached
+        # blocks and, copied on write, the first token of the third; one
+        # chunk of 2 at offset 9
+        eng.add_request(Request(prompt, max_new_tokens=2))
+        _run(eng)
+        eng.add_request(Request(np.concatenate([prompt[:9], [7, 7]]),
+                                max_new_tokens=2))
+        want = [3]
+    walked = []
+    real = eng.exe.prefill_chunk
+
+    def counted(ids, lens, offs, *a, **kw):
+        lens, offs = np.asarray(lens), np.asarray(offs)
+        walked.append(int(sum(-(-(o + n) // eng.block_size)
+                              for o, n in zip(offs, lens) if n)))
+        return real(ids, lens, offs, *a, **kw)
+
+    eng.exe.prefill_chunk = counted
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    chunks = [e for e in _spans() if e["name"] == "exe.prefill_chunk"]
+    assert [e["args"]["kv_blocks"] for e in chunks] == walked == want
+    assert all(e["args"]["rows"] == 3 * 8 for e in chunks)
+    if traffic == "radix_hit":
+        assert eng.mgr.cache_stats["token_hits"] == 9
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 def test_span_pad_counts_equal_the_wrappers_exactly(model, depth):
     eng = _engine(model, async_depth=depth)
@@ -447,7 +488,9 @@ def test_kernel_names_reach_the_tpu_lowering(case):
     scope or jitted function. Every kernel's call must carry its own."""
     names, fn, args, wrt = _kernel_cases()[case]
     text = _tpu_lowering(fn, args, wrt)
-    scopes = re.findall(r'loc\("[^"]*?/([^/"]+)/pallas_call"', text)
+    # a kernel under a jit of its own (the chunk kernel) is lowered in a
+    # function whose scopes start anew: its name may open the location
+    scopes = re.findall(r'loc\("(?:[^"]*?/)?([^/"]+)/pallas_call"', text)
     assert "tpu_custom_call" in text
     assert set(scopes) == set(names), scopes
 

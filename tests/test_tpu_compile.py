@@ -73,6 +73,18 @@ def _pool(n, bs, h_kv, d, dtype=bf16):
 # "%name = dtype[dims]{layout} opcode(" of an HLO instruction
 _HLO_RESULT = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
 
+
+def _assert_pool_read_in_place(compiled, pool_elems, but=()):
+    """A kernel that reads the pool where it lies leaves no transposed or
+    reshaped copy of it in the program, in HBM or anywhere else: no such
+    instruction yields as many elements as a pool holds (``but``: sizes
+    that are something else's, the folded queries')."""
+    for line in compiled.as_text().splitlines():
+        m = _HLO_RESULT.match(line)
+        if m and m.group(2) in ("copy", "transpose", "reshape", "fusion"):
+            elems = math.prod(map(int, m.group(1).split(",")))
+            assert elems < pool_elems or elems in but, line.strip()[:160]
+
 # (id, B, H, H_kv, head dim, pool blocks, block size, table width, pool
 #  dtype, variant)
 DECODE = [
@@ -132,14 +144,9 @@ def test_paged_decode_attention_compiles(one_chip, case):
 
     compiled = _compile(fn, one_chip,
                         *_decode_shapes(b, h, h_kv, d, n, bs, width, dtype))
-    # the kernel reads the pool where it lies: no transposed or reshaped
-    # copy of it, in HBM (a temporary of 32 MB and up) or anywhere else
+    # no temporary in HBM of a pool's size (32 MB and up), nor elsewhere
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
-    for line in compiled.as_text().splitlines():
-        m = _HLO_RESULT.match(line)
-        if m and m.group(2) in ("copy", "transpose", "reshape", "fusion"):
-            elems = math.prod(map(int, m.group(1).split(",")))
-            assert elems < n * bs * h_kv * d, line.strip()[:160]
+    _assert_pool_read_in_place(compiled, n * bs * h_kv * d)
 
 
 @pytest.mark.parametrize("case", DECODE_UNTILED,
@@ -186,12 +193,22 @@ CHUNK = [
     ("odd_chunk_a3_c7_gqa32_8", 3, 7, 32, 8, 512, 32, False),
     ("a2_c128_h32_int8", 2, 128, 32, 32, 2048, 256, True),
     ("verify_a8_c5_h32_int8", 8, 5, 32, 32, 2048, 256, True),
+    # mistral7b.serve.backlog's chunk forward: 16 rows x 256-token chunk
+    ("a16_c256_gqa32_8_table256_pool3072", 16, 256, 32, 8, 3072, 256, False),
+    ("cell_int8", 16, 256, 32, 8, 3072, 256, True),
+    # ouro2.6b.serve.reasoning's: 8 rows, a pool of 4 passes x 184 blocks
+    ("ouro_a8_c256_h16_table48_pool736", 8, 256, 16, 16, 736, 48, False),
+    # the fewest K/V heads a bf16 slab's tiling allows
+    ("a4_c64_gqa16_2", 4, 64, 16, 2, 512, 32, False),
+    # variants of the Mistral cell's shape that no cell runs
+    ("cell_window", 16, 256, 32, 8, 3072, 256, False, {"window": 1024}),
+    ("cell_partials", 16, 256, 32, 8, 3072, 256, False, {"partials": True}),
 ]
 
 
 @pytest.mark.parametrize("case", CHUNK, ids=[c[0] for c in CHUNK])
 def test_paged_chunk_attention_compiles(one_chip, case):
-    _, a, c, h, h_kv, n, width, int8 = case
+    _, a, c, h, h_kv, n, width, int8, *variant = case
     shapes = [((a, c, h, 128), bf16), *_pool(n, 16, h_kv, 128,
                                              i8 if int8 else bf16),
               ((a, width), i32), ((a,), i32), ((a,), i32)]
@@ -201,9 +218,82 @@ def test_paged_chunk_attention_compiles(one_chip, case):
     def fn(q, kp, vp, tables, offs, cls, ks=None, vs=None):
         return paged_chunk_attention_pallas(
             q, kp, vp, tables, offs, cls, k_scale=ks, v_scale=vs,
-            interpret=False)
+            interpret=False, **dict(*variant))
 
-    _compile(fn, one_chip, *shapes)
+    _assert_pool_read_in_place(_compile(fn, one_chip, *shapes),
+                               n * 16 * h_kv * 128, but=(a * c * h * 128,))
+
+
+@pytest.mark.parametrize("case", DECODE_UNTILED[:3] + DECODE_UNTILED[5:6],
+                         ids=[c[0] for c in DECODE_UNTILED[:3]
+                              + DECODE_UNTILED[5:6]])
+def test_paged_chunk_off_the_tiling_takes_the_gather(one_chip, case,
+                                                     monkeypatch):
+    """The chunk kernel copies the same slabs as the decode kernel: off
+    ``decode_slab_is_tiled`` the dispatcher, on a TPU, says so and
+    compiles the XLA formulation."""
+    _, h, h_kv, d, dtype = case
+    assert not decode_slab_is_tiled(h_kv, d, dtype)
+    shapes = [((4, 32, h, d), bf16), *_pool(512, 16, h_kv, d, dtype),
+              ((4, 32), i32), ((4,), i32), ((4,), i32)]
+    if dtype == i8:
+        shapes += [((512, 16, h_kv), f32)] * 2
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+
+    def dispatched(q, kp, vp, tables, offs, cls, ks=None, vs=None):
+        return paged_attention.paged_chunk_attention(
+            q, kp, vp, tables, offs, cls, k_scale=ks, v_scale=vs)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    del paged_attention._trace_events[:]
+    compiled = jax.jit(dispatched).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert {"chunk:slab-off-tiling", "chunk:xla"} <= set(
+        paged_attention._trace_events)
+    assert "chunk:pallas" not in paged_attention._trace_events
+
+
+@pytest.mark.parametrize("family", ["llama", "ouro"])
+def test_chunk_program_lowers_the_kernel_once(one_chip, monkeypatch, family):
+    """Every layer of a chunk program (a looped model's bodies too) calls
+    the chunk kernel through one jitted function, so the lowered module
+    holds ONE Mosaic custom call for it whatever the depth: the kernel is
+    traced and lowered to Mosaic once a program (``setup_s``), while the
+    dispatcher still leaves a breadcrumb a call site. Lowered for a TPU,
+    not compiled: a count of what the lowering holds."""
+    from paddle_tpu.models import paged
+    if family == "llama":
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        cfg = LlamaConfig.tiny(
+            num_hidden_layers=4, hidden_size=256, num_attention_heads=2,
+            num_key_value_heads=2, intermediate_size=512, vocab_size=128,
+            dtype=bf16)
+        model = jax.eval_shape(lambda: LlamaForCausalLM(cfg))
+    else:
+        from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+        cfg = OuroConfig.tiny(
+            num_hidden_layers=3, hidden_size=256, num_attention_heads=2,
+            num_key_value_heads=2, intermediate_size=512, vocab_size=128,
+            total_ut_steps=4, dtype=bf16)
+        model = jax.eval_shape(lambda: OuroForCausalLM(cfg))
+    cache = jax.eval_shape(lambda: paged.PagedKVCache.init_for(
+        cfg, 32, 16, 4, 8))
+    S = jax.ShapeDtypeStruct
+    args = (model, S((4, 32), i32), S((4,), i32), S((4,), i32), cache,
+            S((4,), i32), S((4, 8), i32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged.clear_jit_caches()
+    del paged_attention._trace_events[:]
+    try:
+        text = jax.jit(paged.llama_prefill_chunk_paged).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        paged.clear_jit_caches()     # traced under a patched backend
+    kernels = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert kernels.count("paged_chunk_attention") == 1, kernels
+    sites = len(re.findall(r"call @_paged_chunk_call", text))
+    assert sites == cfg.num_hidden_layers
+    assert paged_attention._trace_events.count("chunk:pallas") == sites
 
 
 # (id, B, S, H, H_kv, window, with backward)
@@ -333,6 +423,34 @@ def test_pipeline_step_compiles_with_flash_and_rms(v5e_2x2, monkeypatch,
                                         num_microbatches=2)
         ids = jax.ShapeDtypeStruct((4, 256), i32, sharding=mesh.replicated())
         assert _kernels_in(step, params, ost, ids, ids) > 0
+
+
+def test_chunk_partials_compile_inside_a_cp_shard_map(v5e_2x2):
+    """cp's chunked prefill: every shard scores the pool blocks it owns
+    and the triples are merged across the axis. The kernel (under its own
+    ``jit``) inside a manual region, compiled for the four chips."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(v5e_2x2), ("cp",))
+
+    def body(q, kp, vp, tables, offs, cls):
+        acc, m, l = paged_chunk_attention_pallas(
+            q, kp, vp, tables, offs, cls, partials=True, interpret=False)
+        w = jnp.exp(m - jax.lax.pmax(m, "cp"))
+        num = jax.lax.psum(acc * w[..., None], "cp")
+        den = jax.lax.psum(l * w, "cp")
+        return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
+
+    pool = P("cp")
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False, out_specs=P(),
+                       in_specs=(P(), pool, pool, P(), P(), P()))
+    shapes = [((4, 128, 32, 128), bf16, P()),
+              ((4 * 512, 16, 8, 128), bf16, pool),
+              ((4 * 512, 16, 8, 128), bf16, pool),
+              ((4, 128), i32, P()), ((4,), i32, P()), ((4,), i32, P())]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=NamedSharding(mesh, spec))
+            for s, t, spec in shapes]
+    assert _kernels_in(jax.jit(fn), *args) == 1
 
 
 def test_ulysses_attention_compiles_with_flash(v5e_2x2, monkeypatch):
