@@ -1,5 +1,5 @@
 """BERT (ref: PaddleNLP ``paddlenlp/transformers/bert/modeling.py`` and the
-reference's Fleet data-parallel BERT pretraining config in BASELINE.json).
+reference's Fleet data-parallel BERT pretraining config).
 
 TPU-first: post-LN encoder stack with fused attention dispatch; MLM+NSP
 pretraining heads; batch rides the (dp, fsdp) axes — pure data parallel is

@@ -146,23 +146,11 @@ def _pass_tables(tables, u, num_blocks, passes):
 
 
 # ------------------------------------------------ int8 KV quantization
-def kv_quant_enabled() -> bool:
-    """The ``PT_QUANT_KV`` kill switch, read at TRACE time (flip it
-    between engine constructions together with ``clear_jit_caches``)."""
-    return os.environ.get("PT_QUANT_KV", "1").strip().lower() \
-        not in ("0", "off")
-
-
 def _quantize_kv(vals):
     """Per-(position, head) symmetric int8: vals [..., H, D] ->
     (int8 [..., H, D], f32 scales [..., H]). absmax over D / 127; the
     epsilon floor keeps all-zero rows (padding) at scale ~0 without a
     0/0."""
-    if not kv_quant_enabled():
-        raise RuntimeError(
-            "PT_QUANT_KV=0 but an int8 KV pool is being traced — rebuild "
-            "the engine under the kill switch (bf16 pool) and call "
-            "models.paged.clear_jit_caches() so no stale int8 trace runs")
     _note_trace("kv:int8-write")
     f = vals.astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(f), axis=-1), 1e-8) / 127.0
@@ -356,141 +344,6 @@ class RefBlockManager(BlockManager):
         self._rc[blk] = self._rc.get(blk, 0) + 1
 
 
-class PrefixCachingBlockManager(RefBlockManager):
-    """RefBlockManager + cross-request prefix reuse (ref capability:
-    PaddleNLP ``llm/predict`` block-attention serving; vLLM-style
-    hash-block caching).
-
-    Every FULL block of a committed prompt gets a content chain hash
-    ``sha1(parent_digest || block token bytes)`` — the digest identifies
-    the whole prefix up to and including the block, so equal digests mean
-    equal KV contents (the pool is append-only and KV is a deterministic
-    function of the token prefix). Blocks whose refcount drops to zero
-    but that carry a digest are PARKED in an LRU ``evictable`` pool (still
-    resident in HBM) instead of the free list; a later request whose
-    prompt chain-hashes onto them re-shares the blocks (rc+1, zero
-    recompute) and prefills only the uncached suffix. When the free list
-    runs dry, allocation evicts parked blocks LRU-first — so caching
-    never reduces usable capacity."""
-
-    def __init__(self, num_blocks: int, block_size: int):
-        super().__init__(num_blocks, block_size)
-        import collections
-        self._hash_to_block: dict[bytes, int] = {}
-        self._block_hash: dict[int, bytes] = {}
-        self._evictable = collections.OrderedDict()   # blk -> None, LRU order
-        # hit_blocks / evictions / lookup_blocks are CUMULATIVE — the
-        # engine exports them as serving_prefix_* metrics (deltas pushed
-        # at each gauge refresh); lookup_blocks counts the full prompt
-        # blocks every match_prefix probe COULD have hit, the hit-rate
-        # denominator
-        self.cache_stats = {"hit_blocks": 0, "evictions": 0,
-                            "lookup_blocks": 0}
-        # bumped whenever the set of matchable blocks changes (eviction
-        # or a new commit) — the scheduler's per-request match memo keys
-        # on it, so a queued prompt is re-hashed only when a probe could
-        # actually return something different
-        self.cache_epoch = 0
-
-    # ---- capacity: parked blocks are reclaimable, so they count as free
-    @property
-    def free_blocks(self):
-        return len(self._free) + len(self._evictable)
-
-    def _pop_free(self):
-        if self._free:
-            return self._free.pop()
-        if self._evictable:
-            blk, _ = self._evictable.popitem(last=False)     # LRU eviction
-            h = self._block_hash.pop(blk, None)
-            if h is not None and self._hash_to_block.get(h) == blk:
-                del self._hash_to_block[h]
-            self.cache_stats["evictions"] += 1
-            self.cache_epoch += 1
-            self.ledger.unpark(blk)
-            return blk
-        raise MemoryError("paged cache out of blocks")
-
-    def _release(self, blk):
-        self._rc[blk] -= 1
-        if self._rc[blk] == 0:
-            del self._rc[blk]
-            if blk in self._block_hash:       # park, MRU end
-                self._evictable[blk] = None
-                self._evictable.move_to_end(blk)
-                self.ledger.park(blk)
-            else:
-                self._free.append(blk)
-
-    def _retain(self, blk):
-        if blk in self._evictable:            # revive a parked block
-            del self._evictable[blk]
-            self.ledger.unpark(blk)
-        super()._retain(blk)
-
-    # ------------------------------------------------------------ hashing
-    def _chain_digests(self, tokens, n_full, adapter=None):
-        import hashlib
-        toks = np.asarray(tokens, np.int32)
-        # adapter identity seeds the chain (ISSUE 14): KV computed under
-        # one LoRA adapter differs numerically from another tenant's, so
-        # two tenants' identical prompts must never share blocks. None
-        # keeps the legacy empty seed — old digests stay bit-identical.
-        digest = (b"" if adapter is None
-                  else hashlib.sha1(repr(adapter).encode()).digest())
-        out = []
-        for i in range(n_full):
-            digest = hashlib.sha1(
-                digest + toks[i * self.block_size:
-                              (i + 1) * self.block_size].tobytes()).digest()
-            out.append(digest)
-        return out
-
-    def match_prefix(self, tokens, adapter=None) -> list[int]:
-        """Longest run of resident full-block prefix matches for this
-        prompt. Capped at (len-1)//block_size so at least the last prompt
-        token is always prefilled — its logits seed the first sample."""
-        n_full = (len(tokens) - 1) // self.block_size
-        self.cache_stats["lookup_blocks"] += n_full
-        blocks = []
-        for d in self._chain_digests(tokens, n_full, adapter):
-            blk = self._hash_to_block.get(d)
-            if blk is None:
-                break
-            blocks.append(blk)
-        return blocks
-
-    def adopt_prefix(self, seq_id, blocks):
-        """Install shared cached blocks as seq_id's table prefix (rc+1
-        each; parked blocks are revived). The caller prefills from
-        ``len(blocks) * block_size`` onward."""
-        assert seq_id not in self.tables
-        for blk in blocks:
-            self._retain(blk)
-        self.tables[seq_id] = list(blocks)
-        for blk in self.tables[seq_id]:
-            self.ledger.table_enter(seq_id, blk)
-        self.cache_stats["hit_blocks"] += len(blocks)
-        return self.tables[seq_id]
-
-    def commit_prefix(self, seq_id, tokens, adapter=None):
-        """Register chain digests for seq_id's full prompt blocks so later
-        requests can share them. First-writer-wins per digest; safe to call
-        before the prefill has executed on device — any matching request's
-        program consumes the pool AFTER this one's writes (jax data
-        dependency orders them)."""
-        table = self.tables.get(seq_id, [])
-        n_full = min(len(tokens) // self.block_size, len(table))
-        for i, d in enumerate(self._chain_digests(tokens, n_full, adapter)):
-            blk = table[i]
-            if blk is None:
-                break                          # window-recycled: stop
-            if d not in self._hash_to_block and blk not in self._block_hash:
-                self._hash_to_block[d] = blk
-                self._block_hash[blk] = d
-                self.cache_epoch += 1
-
-
 class PrefixMatch:
     """Longest shared TOKEN span found by
     :meth:`RadixPrefixBlockManager.match_prefix`.
@@ -566,9 +419,8 @@ class RadixPrefixBlockManager(RefBlockManager):
     """RefBlockManager + a token-level radix trie over the block pool
     (SGLang RadixAttention on vLLM-style paging).
 
-    Where :class:`PrefixCachingBlockManager` matches whole aligned
-    blocks by chain hash, this trie matches the longest shared TOKEN
-    span: edges own ref-counted physical blocks, a partially-filled
+    The trie matches the longest shared TOKEN span, not whole aligned
+    blocks: edges own ref-counted physical blocks, a partially-filled
     boundary block is shared read-only and copied-on-write at first
     divergence (one fresh block; the engine applies the device copy via
     ``take_copy_plan`` before the adopter's prefill chunk), and
@@ -1108,27 +960,17 @@ def _lora_delta(x, lora, kind, li):
                                 the inverse permutation
       gs                   [cap] TOKEN count per cache index (row count
                                 × per-row width, in sorted order)
-      aidx                 [B]  original-order cache index, -1 = null
 
-    Default impl flattens the sorted rows to [B*S, k] and runs TWO
-    grouped GEMMs (``ops/pallas/grouped_matmul`` — Pallas on TPU, XLA
-    segment fallback elsewhere) so a heterogeneous batch is ragged
-    per-adapter segments through one kernel. Rows past ``sum(gs)`` (the
-    null-adapter tail) are UNSPECIFIED per the kernel contract and are
-    masked to zero here. ``PT_MULTILORA_IMPL=gather`` (trace-time; needs
-    ``clear_jit_caches()`` to flip) selects the naive per-row dense
-    path — the bench baseline the grouped path is measured against."""
+    Flattens the sorted rows to [B*S, k] and runs TWO grouped GEMMs
+    (``ops/pallas/grouped_matmul`` — Pallas on TPU, XLA segment fallback
+    elsewhere) so a heterogeneous batch is ragged per-adapter segments
+    through one kernel. Rows past ``sum(gs)`` (the null-adapter tail) are
+    UNSPECIFIED per the kernel contract and are masked to zero here."""
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
     a_stack = lora[kind + "_a"][li]          # [cap, k, r]
     b_stack = lora[kind + "_b"][li]          # [cap, r, n]
     bsz, s, kdim = x.shape
     xf = x.astype(jnp.float32)
-    if os.environ.get("PT_MULTILORA_IMPL", "grouped") == "gather":
-        sel = jnp.maximum(lora["aidx"], 0)
-        t = jnp.einsum("bsk,bkr->bsr", xf, a_stack[sel])
-        d = jnp.einsum("bsr,brn->bsn", t, b_stack[sel])
-        return jnp.where((lora["aidx"] >= 0)[:, None, None],
-                         d, 0.0).astype(x.dtype)
     xp = xf[lora["perm"]].reshape(bsz * s, kdim)
     t = grouped_matmul(xp, a_stack, lora["gs"])
     d = grouped_matmul(t, b_stack, lora["gs"])
@@ -1399,20 +1241,18 @@ def _async_tick_jit():
                    donate_argnums=donate)
 
 
-# jits registered by downstream serving modules (serving/quant.py,
-# serving/transfer.py) so ONE clear_jit_caches() call covers every
-# serving trace — the env-flip contract (PT_QUANT_KV, PT_QUANT_WEIGHTS,
-# PT_PAGED_CHUNK, ...) needs no second clearing entry point
+# jits registered by downstream serving modules (serving/transfer.py) so
+# ONE clear_jit_caches() call covers every serving trace
 _EXTRA_CLEAR: list = []
 
 
 def clear_jit_caches():
     """Drop every module-level serving jit cache. Needed when trace-time
     context changes under the same call signature — flipping
-    ``PT_GROUPED_GEMM`` or ``PT_MULTILORA_IMPL``, or entering/leaving a
-    mesh re-routes layers, but the jit caches key on shapes only. The
-    chunk kernel's own ``jit`` (one traced call for every layer of a
-    program) goes with the programs that hold it."""
+    ``PT_GROUPED_GEMM``, patching a dispatcher's rule in a test, or
+    entering/leaving a mesh re-routes layers, but the jit caches key on
+    shapes only. The chunk kernel's own ``jit`` (one traced call for
+    every layer of a program) goes with the programs that hold it."""
     if _async_tick_jit.cache_info().currsize:   # built: backend exists
         _async_tick_jit().clear_cache()
     for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _PREFILL_CHUNK_JIT,

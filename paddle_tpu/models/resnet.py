@@ -1,6 +1,6 @@
 """ResNet family (ref: ``python/paddle/vision/models/resnet.py`` —
 resnet18/34/50/101/152; the reference's single-device CPU-runnable baseline
-config in BASELINE.json).
+config).
 
 TPU notes: NCHW at the API for reference parity (XLA re-lays out convs for
 the MXU internally); BatchNorm in inference uses running stats; training

@@ -11,7 +11,7 @@ Three layers, all host-side and CPU-safe:
     :data:`TRACER`, exported as a Chrome-trace/Perfetto JSON timeline.
   * :mod:`paddle_tpu.observability.flops` — the peak-FLOPs table and
     :func:`record_throughput`, the single MFU choke point shared by the
-    Trainer, ``utils.profiler.StepTimer``, and bench.py.
+    Trainer and ``utils.profiler.StepTimer``.
 
 Built-in instrumentation (serving engine, Trainer, checkpoints, elastic
 restarts, collectives, fault injection) emits through these singletons;

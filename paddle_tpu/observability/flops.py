@@ -1,6 +1,5 @@
-"""Peak-FLOPs table + MFU accounting — the ONE copy bench.py, the
-Trainer, and ``utils.profiler.StepTimer`` all read (ISSUE 2 satellite:
-bench.py used to carry its own table and recompute MFU ad hoc).
+"""Peak-FLOPs table + MFU accounting — the ONE copy the Trainer and
+``utils.profiler.StepTimer`` both read.
 
 Nothing at this module's top level imports jax.
 """
@@ -12,8 +11,8 @@ __all__ = ["PEAK_BF16", "chip_peak", "chip_peak_flops", "mfu",
            "record_throughput"]
 
 # Peak dense bf16 FLOP/s per chip, by device_kind prefix. (The serving
-# and training MFU numbers, bench.py's vs_baseline, and the profiler's
-# StepTimer all divide by THIS table.)
+# and training MFU numbers and the profiler's StepTimer all divide by
+# THIS table.)
 PEAK_BF16 = {
     "TPU v5 lite": 197e12,   # v5e
     "TPU v5e": 197e12,
@@ -73,8 +72,8 @@ def record_throughput(tokens_per_sec: float, flops_per_token: float = 0.0,
                       window_s: float = 0.0) -> float:
     """Single choke point for throughput/MFU accounting: computes MFU
     from the shared table's peak, sets the ``train_tokens_per_sec`` and
-    ``train_mfu`` gauges, returns the (naive) MFU. Trainer, StepTimer,
-    and bench.py all land here — there is exactly one FLOPs model.
+    ``train_mfu`` gauges, returns the (naive) MFU. Trainer and StepTimer
+    both land here — there is exactly one FLOPs model.
 
     ``hidden_host_s``/``window_s`` enable the overlap-aware variant
     (ROADMAP leftover): the pipelined trainer measures how much host
@@ -83,7 +82,7 @@ def record_throughput(tokens_per_sec: float, flops_per_token: float = 0.0,
     neither the device nor the critical path, so the overlap-aware MFU
     removes it from the denominator —
     ``mfu(tps * window / (window - hidden), ...)``. With no overlap
-    information (sync loop, StepTimer, bench baseline) the overlap gauge
+    information (sync loop, StepTimer) the overlap gauge
     mirrors the naive value, so the two series are always comparable."""
     m = mfu(tokens_per_sec, flops_per_token, peak_flops)
     if window_s > 0.0 and 0.0 < hidden_host_s < window_s:

@@ -22,7 +22,6 @@ and the goodput waste ratio (ISSUE 9).
 from __future__ import annotations
 
 import math
-import os
 from typing import Callable, List, Optional, Sequence
 
 from paddle_tpu.observability.metrics import METRICS, Histogram
@@ -145,13 +144,8 @@ def gauge_deficit(name: str, registry=None, **labels) -> Callable[[], float]:
 def kv_parked_ratio(registry=None) -> Callable[[], float]:
     """serving_kv_blocks{state="parked"} / serving_kv_pool_blocks — the
     reclaimable prefix-cache share of the pool. NaN (→ OK) while the
-    radix cache is disabled (``PT_RADIX_CACHE=0`` — a flat-manager pool
-    parking ~everything after a burst is normal LRU behavior, and with
-    caching off entirely there is nothing to rule on) or while the pool
-    gauges are absent/zero."""
+    pool gauges are absent/zero."""
     def get():
-        if os.environ.get("PT_RADIX_CACHE", "1") == "0":
-            return float("nan")
         reg = registry if registry is not None else METRICS
         inst = reg.get("serving_kv_blocks")
         pool = reg.get("serving_kv_pool_blocks")
@@ -306,9 +300,8 @@ def install_default_rules(ev: HealthEvaluator,
             warn=0.9, crit=0.995,
             description="radix-parked blocks / KV pool size: near 1.0 "
                         "the whole pool is cache residue and every "
-                        "admission pays an eviction walk (skipped while "
-                        "PT_RADIX_CACHE=0 or before the pool gauges "
-                        "exist)")
+                        "admission pays an eviction walk (skipped before "
+                        "the pool gauges exist)")
     ev.rule("serving_tick_host_p95_s",
             histogram_quantile("serving_tick_breakdown_seconds", 0.95,
                                registry, phase="host"),

@@ -314,7 +314,7 @@ class MemLedger:
     def publish(self, bytes_per_block: int = None,
                 resident_tokens: int = None):
         """Fold the current breakdown into the gauges, the per-state
-        peaks (bench columns), and a Chrome-trace counter event ("C") —
+        peaks, and a Chrome-trace counter event ("C") —
         Perfetto stacks the five series into an occupancy-by-state track
         next to the serving.step spans."""
         if not self._enabled:

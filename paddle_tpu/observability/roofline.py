@@ -25,8 +25,8 @@ passes / KV-read positions and folds them through
 ``flops.record_throughput`` — at every gauge sweep.
 
 Conventions shared with ``flops.py``: import-light (nothing here may
-import jax or the ``paddle_tpu`` root — bench.py's orchestrator and the
-perfledger must be able to reason about rooflines off-device), and an
+import jax or the ``paddle_tpu`` root — a process that holds no chip
+must be able to reason about rooflines off-device), and an
 unknown chip yields peak 0.0 → every utilisation gauge reads 0.0 =
 "undefined", never a fabricated number. ``PT_ROOFLINE_KIND`` overrides
 the detected device kind (e.g. ``PT_ROOFLINE_KIND="TPU v5e"``) for

@@ -29,7 +29,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Swept on v5e (benchmarks/_perf_blocks.py, B4 S2048 H16 D128 causal):
+# Swept on v5e (round 4's sweep, script deleted in PR 30: B4 S2048 H16 D128 causal):
 # 128/128 ran 9.9ms fwd / 29.6ms fwd+bwd; 512/1024 runs 4.5 / 14.0 —
 # a single 128^3 MXU issue per grid step can't hide the loop overhead.
 # (1024/1024 measured equal within noise; 512 keeps the q tile usable
@@ -41,7 +41,7 @@ _float0 = jax.dtypes.float0
 
 # Declaring the (batch-head, major, minor) grid as (parallel, parallel,
 # arbitrary) lets Mosaic pipeline DMAs across grid steps instead of
-# serialising them. Measured on v5e (benchmarks/_perf_banded.py, S=4096
+# serialising them. Measured on v5e (round 4's sweep, script deleted in PR 30: S=4096
 # w=1024, dispatch floor subtracted): full causal 3.25ms -> 0.92ms, banded
 # 2.12ms -> 0.77ms — and only WITH this declared does the banded O(S*W)
 # grid actually beat full causal on-chip (r3 finding: 6.5x slower without).

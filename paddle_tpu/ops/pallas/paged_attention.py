@@ -37,15 +37,15 @@ hold the OOB sentinel (= num_blocks): neither kernel reads them.
 
 Dispatch functions (``paged_decode_attention`` /
 ``paged_chunk_attention``) pick Pallas on TPU and the XLA gather
-reference elsewhere, from the backend and the shapes alone. On TPU a
-kernel that fails to trace or lower raises: there is no downgrade to the
-gather path. ``PT_PAGED_CHUNK=0`` force-kills the chunk kernel
-(``=interpret`` forces the interpreted kernel off-TPU).
+reference elsewhere, from the backend and the shapes alone
+(``mosaic_kernels_apply``, ``decode_slab_is_tiled``); no environment
+variable or option chooses. On TPU a kernel that fails to trace or
+lower raises: there is no downgrade to the gather path. Off the TPU a
+test runs a kernel through ``*_pallas(..., interpret=True)``.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -56,10 +56,10 @@ from paddle_tpu.ops.pallas import mosaic_kernels_apply
 
 _NEG_INF = -1e30
 
-# trace-time breadcrumbs ("chunk:xla-forced", "chunk:pallas", ...): one
-# entry per DISPATCH TRACE, so tests can assert which implementation a
-# jitted program actually baked in (flipping PT_PAGED_CHUNK without
-# clearing jit caches appends nothing — the stale trace is reused)
+# trace-time breadcrumbs ("chunk:xla", "chunk:pallas", ...): one entry
+# per DISPATCH TRACE, so tests can assert which implementation a jitted
+# program actually baked in (a program served from a jit cache appends
+# nothing: it was traced once)
 _trace_events: list[str] = []
 
 
@@ -825,9 +825,9 @@ def paged_chunk_attention_xla(q, k_pool, v_pool, block_tables, offsets,
     """Gather-based reference path (CPU / fallback): materialise each
     row's whole ``max_blocks*bs`` pool view and run dense masked
     attention — exactly the pre-kernel ``llama_prefill_chunk_paged``
-    inner loop, kept bit-compatible for the PT_PAGED_CHUNK=0 kill
-    switch. ``partials=True`` returns the (acc, m, l) triple over owned
-    table entries only (context parallelism)."""
+    inner loop: the path off the TPU and for pools whose slabs Mosaic
+    cannot copy. ``partials=True`` returns the (acc, m, l) triple over
+    owned table entries only (context parallelism)."""
     from paddle_tpu.ops import attention as A
     a, c, h, d = q.shape
     n, bs, h_kv, _ = k_pool.shape
@@ -876,36 +876,15 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, offsets,
                           chunk_lens, *, scale=None, window=None,
                           k_scale=None, v_scale=None, partials=False,
                           interpret: bool | None = None):
-    """One dispatch for the ragged chunk path. ``PT_PAGED_CHUNK``
-    (read at TRACE time — flip it between engine constructions together
-    with ``models.paged.clear_jit_caches``):
-
-      unset/1     Pallas kernel on TPU for pools whose slabs Mosaic can
-                  copy (``decode_slab_is_tiled``), XLA gather elsewhere
-                  (default)
-      0/off/xla   force the XLA gather path (kill switch)
-      interpret   force the interpreted Pallas kernel (off-TPU parity)
-
+    """Dispatch for the ragged chunk path, as the decode dispatch: the
+    Pallas kernel on TPU for pools whose slabs Mosaic can copy
+    (``decode_slab_is_tiled``), the XLA gather elsewhere.
     ``k_scale``/``v_scale`` [N, bs, H_kv] f32 mark an int8 pool —
-    dequantize-on-read in every implementation. Like the decode
-    dispatch, a Pallas failure on TPU raises."""
+    dequantize-on-read in both paths. On TPU a Pallas failure raises."""
     if k_scale is not None:
         _note_trace("chunk:int8-kv")
     if partials:
         _note_trace("chunk:partials")
-    mode = os.environ.get("PT_PAGED_CHUNK", "1").strip().lower()
-    if mode in ("0", "off", "xla"):
-        _note_trace("chunk:xla-forced")
-        return paged_chunk_attention_xla(
-            q, k_pool, v_pool, block_tables, offsets, chunk_lens,
-            scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
-            partials=partials)
-    if mode == "interpret":
-        _note_trace("chunk:pallas-interpret")
-        return paged_chunk_attention_pallas(
-            q, k_pool, v_pool, block_tables, offsets, chunk_lens,
-            scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
-            partials=partials, interpret=True)
     if mosaic_kernels_apply():
         if decode_slab_is_tiled(*k_pool.shape[2:], k_pool.dtype):
             out = paged_chunk_attention_pallas(

@@ -18,8 +18,7 @@ pre-split import keeps working unchanged.
 """
 from paddle_tpu.models.decoding import KVCache, _sample_rows  # noqa: F401
 from paddle_tpu.models.paged import (  # noqa: F401
-    PagedKVCache, PrefixCachingBlockManager, PrefixMatch,
-    RadixPrefixBlockManager, _beam_finalize,
+    PagedKVCache, PrefixMatch, RadixPrefixBlockManager, _beam_finalize,
     _BEAM_GROUP_UPDATE_JIT, _BEAM_SELECT_JIT, _PREFILL_CHUNK_JIT,
     _PREFILL_JIT, _REWIND_LENS_JIT, _TICK_JIT, _VERIFY_CHUNK_JIT,
     greedy_accept_length, is_moe_model, stochastic_accept_row)

@@ -260,7 +260,7 @@ class DegradationController:
     # ------------------------------------------------------ windowed reads
     # thin delegations to the shared WindowedReads machinery — kept as
     # controller methods because custom signals receive the controller
-    # and call these directly (see default_signals and the bench legs)
+    # and call these directly (see default_signals)
     def window_counter(self, name: str) -> float:
         """Counter delta (summed over label series) since the previous
         poll. The first read of a name baselines it at the current

@@ -48,7 +48,6 @@ import numpy as np
 from paddle_tpu.models.paged import (_beam_finalize, _BEAM_SELECT_JIT,
                                      cache_passes,
                                      greedy_accept_length, is_moe_model,
-                                     kv_quant_enabled,
                                      stochastic_accept_row)
 from paddle_tpu.observability import span as _span
 from paddle_tpu.observability.flight import FLIGHT
@@ -116,22 +115,12 @@ class LLMEngine:
         cfg = self.cfg = model.cfg
         # quantized KV cache (ISSUE 17): kv_dtype="int8" stores the block
         # pools as int8 with per-(position, kv-head) f32 scale pools.
-        # PT_QUANT_KV=0 is the kill switch — checked HERE (construction)
-        # so the engine falls back to model-dtype pools, and again at
-        # trace time inside the quantize-on-write path, so a stale int8
-        # trace can never silently run with the switch off.
-        if kv_dtype is not None and not kv_quant_enabled():
-            kv_dtype = None
         self.kv_dtype = kv_dtype
         # context-parallel serving (ISSUE 18): cp>1 shards the paged KV
         # pool's physical blocks over a cp-wide mesh; prefill partials
-        # merge via ring/Ulysses and decode merges via psum. PT_CP=0 is
-        # the kill switch — checked HERE (construction) so the engine
-        # collapses to the single-device path with bit-identical traces.
+        # merge via ring/Ulysses and decode merges via psum. cp=1 is the
+        # single-device path.
         cp = int(cp)
-        if cp != 1 and os.environ.get(
-                "PT_CP", "1").strip().lower() in ("0", "off", "false"):
-            cp = 1
         if cp < 1:
             raise ValueError(f"cp must be >= 1, got {cp}")
         self.cp = cp
@@ -140,14 +129,9 @@ class LLMEngine:
         # array stays ON DEVICE feeding the next tick's last_tok while
         # the previous tick's tokens are fetched/emitted on the host,
         # hidden under the in-flight dispatch (PR 3's deferred-sync
-        # contract, serving-side). PT_ASYNC_DECODE=0 is the kill switch —
-        # checked HERE (construction) so depth collapses to 0 and the
-        # engine traces EXACTLY the synchronous pre-PR programs.
+        # contract, serving-side). async_depth=0 traces the synchronous
+        # programs alone.
         async_depth = int(async_depth)
-        if async_depth and os.environ.get(
-                "PT_ASYNC_DECODE", "1").strip().lower() in (
-                    "0", "off", "false"):
-            async_depth = 0
         if async_depth < 0:
             raise ValueError(
                 f"async_depth must be >= 0, got {async_depth}")
@@ -222,9 +206,7 @@ class LLMEngine:
         # ``draft_model`` enables it; each eligible slot drafts up to
         # spec_k tokens through a per-slot dense draft cache, then ONE
         # batched target chunk forward verifies them through the paged
-        # pool. PT_SPEC_DECODE=0 is the kill switch (checked every tick,
-        # so it also disables a live engine); beam slots always take the
-        # one-token path.
+        # pool. Beam slots always take the one-token path.
         self.draft_model = draft_model
         self.spec_k = int(spec_k)
         self.spec_adaptive = bool(spec_adaptive)
@@ -292,9 +274,8 @@ class LLMEngine:
         # adapter_id is admitted only once its adapter is device-resident
         # AND pinned (the scheduler acquires it), and every per-slot
         # forward adds the grouped rank-r correction for that slot's
-        # cache index. PT_MULTILORA=0 is the kill switch: with it off —
-        # or with no store, or no adapter-carrying rows — the forwards
-        # are handed lora=None and trace EXACTLY the base programs.
+        # cache index. With no store, or no adapter-carrying rows, the
+        # forwards are handed lora=None and trace EXACTLY the base programs.
         self.adapter_store = adapter_store
         self.slot_aidx = np.full(num_slots, -1, np.int64)  # cache idx / -1
         self._adapter_pins: dict[int, object] = {}   # rid -> adapter_id
@@ -399,7 +380,7 @@ class LLMEngine:
         self._async_draining = False
         # gauge-sweep throttle (PT_GAUGE_EVERY_S): wall-clock of the last
         # sweep, a force flag set at drain/finish boundaries so run()-end
-        # gauges are exact, and a sweep counter the bench leg reads.
+        # gauges are exact, and a sweep counter a test reads.
         self._gauge_t = None
         self._gauge_force = False
         self._gauge_sweeps = 0
@@ -815,13 +796,6 @@ class LLMEngine:
         return max(self.mgr.blocks_needed(p), live)
 
     # --------------------------------------- multi-LoRA / grammar state
-    def _multilora_on(self) -> bool:
-        """PT_MULTILORA=0 kill switch (checked per use, so it also
-        disables a live engine): off — or no store — means every forward
-        gets lora=None and traces the exact base program."""
-        return (self.adapter_store is not None
-                and os.environ.get("PT_MULTILORA", "1") != "0")
-
     def _release_adapter(self, rid: int):
         """Drop the ref-count pin the scheduler took at admission (idempotent
         — every detach/finish/preempt path calls it)."""
@@ -833,7 +807,7 @@ class LLMEngine:
         """Cache index of the request's pinned adapter (-1 = base path).
         Pinned entries are never evicted, so the index is stable for the
         request's whole slot tenure."""
-        if req.req_id in self._adapter_pins and self._multilora_on():
+        if req.req_id in self._adapter_pins:
             return self.adapter_store.index_of(req.adapter_id)
         return -1
 
@@ -844,7 +818,7 @@ class LLMEngine:
         per-row cache index (-1 = base); ``width``: padded tokens per row
         in the forward — rows are contiguous token spans after the
         perm+reshape, so group sizes are row-counts times width."""
-        if not self._multilora_on():
+        if self.adapter_store is None:
             return None
         aidx = np.asarray(aidx, np.int64)
         if not (aidx >= 0).any():
@@ -858,7 +832,6 @@ class LLMEngine:
         lora["perm"] = jnp.asarray(order, jnp.int32)
         lora["inv"] = jnp.asarray(inv, jnp.int32)
         lora["gs"] = jnp.asarray(gs, jnp.int32)
-        lora["aidx"] = jnp.asarray(aidx, jnp.int32)
         return lora
 
     def _bind_grammar(self, slot: int, req):
@@ -1405,14 +1378,12 @@ class LLMEngine:
         is capped at the adopted span: only radix-adopted tokens were
         ever drafted before, and the accept rule preserves the target
         law for ANY draft state, so a conservative cap costs nothing in
-        correctness. ``PT_DRAFT_REUSE=0`` kills the seeding (fresh
-        re-feed, exactly the old behaviour)."""
+        correctness."""
         p = self._pr(req)
         adopted = int(getattr(req, "_adopted", 0))
         self._adopted_span[slot] = min(adopted, len(p))
         reuse = 0
-        if (adopted > 0 and self.exe.draft_model is not None
-                and os.environ.get("PT_DRAFT_REUSE", "1") != "0"):
+        if adopted > 0 and self.exe.draft_model is not None:
             res = self._draft_resident.get(slot)
             if res is not None and len(res):
                 # cap below len(p): the steady feed needs >= 1 pending
@@ -2272,7 +2243,6 @@ class LLMEngine:
         the next tick would draft-and-verify (host sampling every tick —
         the window must drain for it)."""
         return (self.draft_model is not None
-                and os.environ.get("PT_SPEC_DECODE", "1") != "0"
                 and (self.degrade is None or self.degrade.spec_enabled())
                 and bool((self.active & ~self.is_beam
                           & (self.max_gen - self.gen >= 2)).any()))
@@ -2503,10 +2473,9 @@ class LLMEngine:
         # speculative draft-and-verify for eligible slots; the plain
         # one-token tick then covers only what speculation did not handle
         # (beam slots, final-token slots, fallback after an injected
-        # verify fault). PT_SPEC_DECODE=0 kills the whole path.
+        # verify fault).
         spec_handled = np.zeros(self.num_slots, bool)
         if (self.draft_model is not None
-                and os.environ.get("PT_SPEC_DECODE", "1") != "0"
                 and (self.degrade is None or self.degrade.spec_enabled())):
             elig = (self.active & ~self.is_beam
                     & (self.max_gen - self.gen >= 2))

@@ -2,21 +2,16 @@
 
 The middle layer of the decomposed engine (ISSUE 7). It owns the block
 manager — :class:`~paddle_tpu.models.paged.RadixPrefixBlockManager`
-(token-span radix trie, copy-on-write partial-block reuse) by default,
-or the flat :class:`~paddle_tpu.models.paged.PrefixCachingBlockManager`
-under the ``PT_RADIX_CACHE=0`` kill switch — plus the RESERVATION LEDGER the
-admission discipline runs on: ``need[rid]`` is a request's worst-case
-block count, ``resv[rid]`` the part not yet materialised as live table
-entries, and ``reserved`` their sum — the blocks the free list must
-keep clear of other requests. The scheduler decides WHO gets blocks;
-this layer tracks what was promised.
+(token-span radix trie, copy-on-write partial-block reuse) — plus the
+RESERVATION LEDGER the admission discipline runs on: ``need[rid]`` is a
+request's worst-case block count, ``resv[rid]`` the part not yet
+materialised as live table entries, and ``reserved`` their sum — the
+blocks the free list must keep clear of other requests. The scheduler
+decides WHO gets blocks; this layer tracks what was promised.
 """
 from __future__ import annotations
 
-import os
-
-from paddle_tpu.models.paged import (PrefixCachingBlockManager,
-                                     RadixPrefixBlockManager)
+from paddle_tpu.models.paged import RadixPrefixBlockManager
 from paddle_tpu.observability.flight import FLIGHT
 from paddle_tpu.serving.telemetry import (_PREFIX_EVICTIONS,
                                           _PREFIX_HIT_RATE, _PREFIX_HITS,
@@ -48,13 +43,9 @@ class KVManager:
         # copy-on-write; requests with equal prompt prefixes share the
         # prefix blocks outright (prefill only runs on the uncached
         # suffix); with no sharing it behaves exactly like BlockManager.
-        # Default is the radix trie (token-span matching + partial-block
-        # COW); PT_RADIX_CACHE=0 coerces back to the flat full-block
-        # hash map (checked at construction — per engine)
-        cls = (PrefixCachingBlockManager
-               if os.environ.get("PT_RADIX_CACHE", "1") == "0"
-               else RadixPrefixBlockManager)
-        self.mgr = cls(num_blocks, block_size)
+        # The radix trie matches token spans and reuses partial blocks
+        # copy-on-write.
+        self.mgr = RadixPrefixBlockManager(num_blocks, block_size)
         # the block manager owns the per-pool memory ledger (its own
         # mutation choke points notify it); this layer mirrors the
         # reservation count into it and exposes the forensic wrappers

@@ -9,8 +9,7 @@ The engine-facing entry points of the LLM.int8()/SmoothQuant recipe
   ``QuantizedWeight`` + ``wo_matmul`` dispatch from ``quantization.py``;
   Mixtral/Qwen2-MoE/MoE expert stacks get :class:`QuantizedExpertStack`
   (a 3-D [E, K, N] variant that ``distributed.moe`` dequantizes on the
-  fly inside the jitted forward). Honours the ``PT_QUANT_WEIGHTS=0``
-  kill switch by returning the model untouched.
+  fly inside the jitted forward).
 
 * :func:`smooth_for_serving` — SmoothQuant-style per-channel outlier
   migration: activation scale is folded OUT of the RMSNorm weight and
@@ -30,13 +29,11 @@ The engine-facing entry points of the LLM.int8()/SmoothQuant recipe
 
 The int8 KV-cache leg lives in ``models/paged.py`` (quantize-on-write /
 dequantize-on-read around the block pools — ``PagedKVCache.init(...,
-kv_dtype="int8")``, ``PT_QUANT_KV=0`` kill switch) and is wired through
+kv_dtype="int8")``) and is wired through
 ``LLMEngine(kv_dtype="int8")``; this module only hosts the weight side
 and the shared quality/capacity instruments.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +45,7 @@ from paddle_tpu.quantization import (QuantizedWeight, _capture_calib,
                                      quantize_llama_weights, weight_quantize)
 
 __all__ = [
-    "QuantizedExpertStack", "expert_stack_quantize", "weights_quant_enabled",
+    "QuantizedExpertStack", "expert_stack_quantize",
     "quantize_for_serving", "smooth_for_serving", "quant_quality",
     "quantized_weight_bytes",
 ]
@@ -57,7 +54,7 @@ __all__ = [
 _Q_BITS = METRICS.gauge(
     "serving_quant_weight_bits",
     "Weight-only quantization bit-width of the last model passed through "
-    "quantize_for_serving (0 = unquantized / kill switch active)")
+    "quantize_for_serving (0 = unquantized)")
 _Q_LAYERS = METRICS.gauge(
     "serving_quant_layers",
     "Decoder layers whose projections were converted to quantized weights "
@@ -78,15 +75,6 @@ _Q_MATCH = METRICS.gauge(
     "serving_quant_greedy_match_rate",
     "Fraction of positions whose argmax token matches the reference in "
     "the last quant_quality probe")
-
-
-def weights_quant_enabled() -> bool:
-    """``PT_QUANT_WEIGHTS=0`` kill switch. Checked when a model is
-    quantized (``quantize_for_serving`` becomes the identity), NOT per
-    trace — an already-quantized model keeps serving; rebuild from the
-    bf16 checkpoint to actually revert."""
-    return os.environ.get("PT_QUANT_WEIGHTS", "1").strip().lower() \
-        not in ("0", "off")
 
 
 # ---- 3-D expert stacks ------------------------------------------------------
@@ -286,13 +274,7 @@ def quantize_for_serving(model, algo: str = "weight_only_int8", *,
     gptq_int4 (GPTQ needs ``calib_ids`` and a dense Llama-family model —
     the Hessian capture forward is structure-specific). ``smooth=True``
     folds :func:`smooth_for_serving` in first.
-
-    Under ``PT_QUANT_WEIGHTS=0`` this is the identity (the model is
-    returned untouched and the gauges report bits=0).
     """
-    if not weights_quant_enabled():
-        _Q_BITS.set(0)
-        return model
     bb = _backbone(model)
     if any(getattr(lyr.self_attn, "fp8_meta", None) is not None
            for lyr in bb.layers):
@@ -331,7 +313,7 @@ def quantize_for_serving(model, algo: str = "weight_only_int8", *,
         if getattr(model, "lm_head", None) is not None:
             model.lm_head = weight_quantize(model.lm_head, rtn)
 
-    # roofline/geometry + bench read these back (engine _geom closure)
+    # roofline/geometry read these back (engine _geom closure)
     model._wo_bits = bits
     _Q_BITS.set(bits)
     _Q_LAYERS.set(len(bb.layers))
@@ -348,8 +330,7 @@ def quantize_for_serving(model, algo: str = "weight_only_int8", *,
 def quant_quality(ref_logits, q_logits) -> dict:
     """Logit MSE + greedy match-rate of quantized vs reference logits
     (any matching [..., V] shapes). Publishes both gauges and returns
-    ``{"logit_mse", "greedy_match_rate"}`` — bench embeds this dict in
-    its JSON so quality regressions ride the same history as perf."""
+    ``{"logit_mse", "greedy_match_rate"}``."""
     ref = np.asarray(ref_logits, np.float32)
     q = np.asarray(q_logits, np.float32)
     if ref.shape != q.shape:

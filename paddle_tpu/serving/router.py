@@ -39,8 +39,8 @@ The router is deliberately single-threaded per ``step()`` — replicas
 advance in one round-robin sweep, which keeps the chaos sites
 (``router.dispatch``, ``router.kv_transfer``, ``router.kv_stall``,
 ``router.kv_partial``, ``router.replica_death``) deterministic. ``run(parallel=True)`` is the throughput mode: one
-driver thread per replica free-runs its engine (pure scale-out; used by
-the bench), falling back to sequential rounds when disaggregation or
+driver thread per replica free-runs its engine (pure scale-out),
+falling back to sequential rounds when disaggregation or
 router-level work needs the orchestration loop.
 """
 from __future__ import annotations

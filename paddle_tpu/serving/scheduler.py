@@ -331,7 +331,7 @@ class Scheduler:
                 # (or slots) the queue head is waiting on
                 kv.record_stall(need, slots_short=(k > len(free_slots)))
                 break                      # do not starve the fair winner
-            if req.adapter_id is not None and eng._multilora_on():
+            if req.adapter_id is not None and eng.adapter_store is not None:
                 # make the adapter device-resident and PIN it before the
                 # request can touch a slot. Failure (cache fully pinned,
                 # or an injected serving.adapter_swap fault) defers the

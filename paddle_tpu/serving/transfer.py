@@ -245,9 +245,8 @@ def _install_blocks(cache, idx, k, v, ks, vs, slot, row, cur):
 
 _INSTALL_BLOCKS_JIT = jax.jit(_install_blocks, donate_argnums=(0,))
 
-# env-flip hygiene (ISSUE 17): these jits trace over the cache pytree,
-# whose quantize-on-write path reads PT_QUANT_KV at trace time —
-# clear_jit_caches() must reach them too
+# these jits trace over the cache pytree: clear_jit_caches() must reach
+# them too
 from paddle_tpu.models.paged import _EXTRA_CLEAR as _PAGED_EXTRA_CLEAR  # noqa: E402
 
 _PAGED_EXTRA_CLEAR.extend([_GATHER_BLOCKS_JIT, _INSTALL_BLOCKS_JIT])

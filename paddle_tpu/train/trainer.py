@@ -37,7 +37,7 @@ from paddle_tpu.utils.faults import fault_point, fault_value
 
 # Training telemetry (ISSUE 2). tokens/sec + MFU ride the SHARED gauges
 # in observability.flops (record_throughput) — the same choke point
-# bench.py reads, so there is exactly one FLOPs/MFU model.
+# StepTimer feeds, so there is exactly one FLOPs/MFU model.
 _STEPS = METRICS.counter("train_steps_total", "optimizer steps completed")
 _STEP_S = METRICS.histogram(
     "train_step_seconds", "wall time per training step (host-observed)")
@@ -259,7 +259,7 @@ class Trainer:
                 fpt = self._flops_per_token(steps_since, tokens_since)
                 if fpt and tokens_since and dt > 0:
                     rec["tokens_per_sec"] = tokens_since / dt
-                    # one MFU model for trainer, StepTimer, and bench.py:
+                    # one MFU model for trainer and StepTimer:
                     # the shared gauges in observability.flops
                     rec["mfu"] = record_throughput(
                         tokens_since / dt, fpt, args.peak_flops)

@@ -11,7 +11,7 @@ its Chrome trace next to the XLA artifact on stop), RecordEvent is an
 observability span (which annotates the XLA trace itself), and StepTimer feeds the
 shared ``train_tokens_per_sec``/``train_mfu`` gauges through the same
 :func:`~paddle_tpu.observability.flops.record_throughput` choke point the
-Trainer and bench.py use.
+Trainer uses.
 """
 from __future__ import annotations
 

@@ -9,7 +9,7 @@
   ``async_overrun`` waste
 * ``serving.tick`` chaos mid-window: exception-atomic drain, identical
   mid-fault and final streams, pool quiescent
-* ``PT_ASYNC_DECODE=0`` kill switch traces EXACTLY the pre-PR program
+* ``async_depth=0`` traces the synchronous program alone
   (breadcrumb-guarded)
 * ``async_overrun`` arithmetic: a stream-callback cancel mid-cruise
   bills exactly ``depth`` over-dispatched rows
@@ -17,8 +17,6 @@
   (fetched byte count asserted), ``PT_GAUGE_EVERY_S`` sweep throttle
   with exact forced sweeps at finish/run()-end boundaries
 """
-import os
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,7 +26,7 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.paged import clear_jit_caches
 from paddle_tpu.observability import GOODPUT, METRICS
 from paddle_tpu.ops.pallas import paged_attention as pa
-from paddle_tpu.serving import LLMEngine, Request
+from paddle_tpu.serving import DegradationController, LLMEngine, Request
 from paddle_tpu.utils.faults import FAULTS, InjectedFault
 
 
@@ -67,6 +65,14 @@ def _run(eng, prompts, new=10, **rkw):
     out = eng.run()
     eng.assert_quiescent()
     return {r: list(map(int, t)) for r, t in out.items()}
+
+
+def _spec_gate(level):
+    """A controller held at ``level`` (L1+: speculation off): no signals
+    and infinite down-patience, so the engine's polls never move it."""
+    c = DegradationController(signals=[], down_patience=10 ** 9)
+    c.force_level(level)
+    return c
 
 
 def _drains():
@@ -207,24 +213,26 @@ def test_spec_tick_forces_drain_not_permanent_depipelining(model, draft):
     assert gticks == bticks                    # same spec cadence, any depth
 
 
-def test_spec_toggle_mid_cruise_drains_with_why_spec(model, draft,
-                                                     monkeypatch):
-    """PT_SPEC_DECODE flipped on while the pipeline is cruising: the
-    next step must drain the standing window (why=spec) before the spec
-    tick runs — and greedy spec identity keeps the stream bit-equal to
-    the never-spec baseline."""
-    monkeypatch.setenv("PT_SPEC_DECODE", "0")
+def test_spec_toggle_mid_cruise_drains_with_why_spec(model, draft):
+    """``degrade.spec_enabled()`` turning true while the pipeline is
+    cruising: the next step must drain the standing window (why=spec)
+    before the spec tick runs — and greedy spec identity keeps the
+    stream bit-equal to the never-spec baseline."""
     rs = np.random.RandomState(7)
     p = rs.randint(2, 64, (6,))
     kw = dict(num_slots=1, block_size=16, max_seq_len=64,
               draft_model=draft, spec_k=3)
-    base = _run(_mk(model, **kw), [p], new=12)
+    never = _mk(model, degrade=_spec_gate(1), **kw)
+    base = _run(never, [p], new=12)
+    assert never.stats["spec_ticks"] == 0
+
+    gate = _spec_gate(1)
 
     def flip(req, tok):
         if len(req.tokens) == 3:
-            os.environ["PT_SPEC_DECODE"] = "1"
+            gate.force_level(0)
 
-    eng = _mk(model, async_depth=2, **kw)
+    eng = _mk(model, async_depth=2, degrade=gate, **kw)
     eng.add_request(Request(p, max_new_tokens=12, stream=flip))
     out = eng.run()
     eng.assert_quiescent()
@@ -297,31 +305,25 @@ def test_tick_chaos_mid_window_exception_atomic(model):
     assert _drains().get("exception", 0) > 0
 
 
-# -------------------------------------------------------- kill switch
-def test_kill_switch_traces_exact_pre_pr_program(model, monkeypatch):
-    """PT_ASYNC_DECODE=0 collapses async_depth at construction: the
-    engine never traces the async tick program (breadcrumb-guarded) and
-    the stream is bit-exact."""
+# --------------------------------------------------------- depth zero
+def test_depth_zero_traces_the_sync_program_alone(model):
+    """``async_depth=0`` (the default) never traces the async tick
+    program (breadcrumb-guarded) and never forms a window; depth 2
+    traces its twin and emits the bit-exact stream."""
     rs = np.random.RandomState(13)
     prompts = _prompts(rs, n=4)
-    base = _run(_mk(model), prompts)
+    clear_jit_caches()
+    pa._trace_events.clear()
+    eng = _mk(model, async_depth=0)
+    base = _run(eng, prompts)
+    assert "tick:async" not in pa._trace_events  # the sync program only
+    assert sum(_drains().values()) == 0          # no window ever formed
 
     clear_jit_caches()
     pa._trace_events.clear()
     got = _run(_mk(model, async_depth=2), prompts)
     assert got == base
     assert "tick:async" in pa._trace_events    # pipeline traced its twin
-
-    monkeypatch.setenv("PT_ASYNC_DECODE", "0")
-    before = sum(_drains().values())
-    clear_jit_caches()
-    pa._trace_events.clear()
-    eng = _mk(model, async_depth=2)
-    assert eng.async_depth == 0
-    killed = _run(eng, prompts)
-    assert killed == base
-    assert "tick:async" not in pa._trace_events  # the pre-PR program only
-    assert sum(_drains().values()) == before     # no window ever formed
 
 
 def test_async_depth_validation(model):
@@ -364,20 +366,18 @@ def test_async_overrun_arithmetic_exact(model):
 
 
 # ------------------------------------- satellite: spec fetch gathering
-def test_spec_fetch_bytes_gathers_only_nongreedy_rows(model, draft,
-                                                      monkeypatch):
+def test_spec_fetch_bytes_gathers_only_nongreedy_rows(model, draft):
     """Host spec sampling must fetch the full [rows, V] block only for
     the NON-greedy rows (gathered on device); greedy rows ride the [ns]
     argmax fetch. Byte count asserted exactly."""
-    monkeypatch.setenv("PT_SPEC_DECODE", "0")     # admit via the plain tick
     rs = np.random.RandomState(2)
-    eng = _mk(model, draft_model=draft, spec_k=3, num_slots=2)
+    eng = _mk(model, draft_model=draft, spec_k=3, num_slots=2,
+              degrade=_spec_gate(1))              # admit via the plain tick
     r0 = eng.add_request(Request(rs.randint(2, 64, (5,)),
                                  max_new_tokens=8))
     r1 = eng.add_request(Request(rs.randint(2, 64, (6,)),
                                  max_new_tokens=8, temperature=0.7))
     eng.step()
-    monkeypatch.delenv("PT_SPEC_DECODE")
     eng._spec_fetch_bytes = 0
     staged = [(0, r0, 3), (1, r1, 3)]
     seqs = {s: eng._committed_seq(s) for s in (0, 1)}
@@ -424,9 +424,9 @@ def test_gauge_throttle_skips_sweeps_forces_boundaries(model, monkeypatch):
     eng2.assert_quiescent()
 
 
-def test_gauge_throttle_async_bench_combo(model, monkeypatch):
-    """The bench-leg configuration: depth-2 pipeline + throttled sweep
-    still emits the bit-identical stream."""
+def test_gauge_throttle_async_combo(model, monkeypatch):
+    """Depth-2 pipeline + throttled sweep still emits the bit-identical
+    stream."""
     rs = np.random.RandomState(3)
     prompts = _prompts(rs)
     base = _run(_mk(model), prompts)

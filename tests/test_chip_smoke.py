@@ -50,13 +50,6 @@ def test_real_run_without_a_tpu_fails_and_prints_no_result():
     assert "needs a TPU" in r.stderr
 
 
-def test_bench_without_a_tpu_fails_and_prints_no_result():
-    r = _run("bench.py")
-    assert r.returncode != 0
-    assert r.stdout.strip() == ""
-    assert "needs a TPU" in r.stderr
-
-
 def test_compile_cache_default_is_a_fixed_path_in_the_checkout():
     r = _run("-c", "from paddle_tpu.core.device import "
              "enable_compilation_cache as e; import jax; print(e()); "

@@ -7,7 +7,7 @@ the tokens its cp=1 twin emits — through plain decode, chunked prefill,
 speculative decoding, preemption/replay, radix prefix reuse, and int8 KV
 pools — because every shard_map'd program merges per-shard online-softmax
 partials into the same replicated result the single-device program
-computes. Plus: the ``PT_CP=0`` kill switch, the ``too_long`` graceful
+computes. Plus: the ``cp=1`` single-device engine, the ``too_long`` graceful
 admission rejection, the ``serving.cp_gather`` chaos site's
 exception-atomicity, cp-scaled admission capacity, the cp metric gauges,
 and the roofline merge-traffic term.
@@ -175,25 +175,13 @@ def test_greedy_identity_int8_kv(model):
     assert eng.cache.k_scales                 # quantized pool actually on
 
 
-# ------------------------------------------------------- kill switches
-
-def test_pt_cp_zero_collapses_to_single_device(model, monkeypatch):
-    monkeypatch.setenv("PT_CP", "0")
-    eng = _mk(model, cp=4)
-    assert eng.cp == 1 and eng.exe.cp == 1 and eng.exe.mesh is None
-    rs = np.random.RandomState(7)
-    rid = eng.add_request(Request(rs.randint(1, 64, (6,)),
-                                  max_new_tokens=4))
-    out = eng.run()
-    assert len(out[rid]) == 4
-    eng.assert_quiescent()
-
+# ------------------------------------------------------------- cp == 1
 
 def test_cp1_engine_unchanged(model):
     """cp=1 must not build a mesh, shard anything, or register shard
     gauges — bit-identical to the pre-cp engine."""
     eng = _mk(model, cp=1)
-    assert eng.exe.mesh is None
+    assert eng.cp == 1 and eng.exe.cp == 1 and eng.exe.mesh is None
     assert not hasattr(eng.exe, "_cp_tick")
 
 

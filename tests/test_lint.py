@@ -266,6 +266,57 @@ def test_block_mutators_notify_the_memledger():
         "with a reason if a delegate notifies):\n" + "\n".join(offenders))
 
 
+# -------------------------------------------- no switch from the environment
+# A served program's path is chosen by the engine's constructor and by what
+# a dispatcher can observe (the backend, the shapes), never by a PT_*
+# variable: each such switch doubled the configurations somebody had to keep
+# alive (ISSUE 30). The names below are the debts ROADMAP.md's Queue 3 lists
+# for these paths, each with its reason there; the list only shrinks.
+_ENV_SWITCH_PATHS = ("paddle_tpu/serving", "paddle_tpu/models/paged.py",
+                     "paddle_tpu/ops/pallas")
+_ENV_SWITCH_ALLOWLIST = {
+    "PT_GROUPED_GEMM", "PT_CP_IMPL", "PT_ROUTER_DISAGG", "PT_DEGRADE",
+    "PT_GAUGE_EVERY_S", "PT_TENANT_LABEL_CAP",
+}
+
+
+def _pt_env_names(text):
+    """Every ``PT_*`` name a module hands to the environment: a string
+    constant that is such a name and nothing else (``os.environ.get``,
+    ``os.getenv``, a subscript, ``in os.environ``, or a name kept in a
+    variable on its way there; prose in a docstring is longer)."""
+    import ast
+    return {(n.value, n.lineno) for n in ast.walk(ast.parse(text))
+            if isinstance(n, ast.Constant) and isinstance(n.value, str)
+            and re.fullmatch(r"PT_[A-Z0-9_]+", n.value)}
+
+
+def test_no_pt_env_switch_in_the_serving_core():
+    root = pathlib.Path(__file__).resolve().parent.parent
+    files = []
+    for rel in _ENV_SWITCH_PATHS:
+        path = root / rel
+        files += sorted(path.rglob("*.py")) if path.is_dir() else [path]
+    seen, offenders = set(), []
+    for path in files:
+        for name, lineno in sorted(_pt_env_names(path.read_text())):
+            seen.add(name)
+            if name not in _ENV_SWITCH_ALLOWLIST:
+                offenders.append(f"{path.relative_to(root)}:{lineno}: {name}")
+    assert not offenders, (
+        "a PT_* environment variable read in the serving core (take the "
+        "decision in LLMEngine's constructor or from what the dispatcher "
+        "can observe):\n" + "\n".join(offenders))
+    assert seen == _ENV_SWITCH_ALLOWLIST, (
+        "allowlisted names no module reads any more (drop them): "
+        f"{sorted(_ENV_SWITCH_ALLOWLIST - seen)}")
+    # the rule sees each way of reading one
+    assert {n for n, _ in _pt_env_names(
+        'import os\nos.environ.get("PT_A", "1")\nos.getenv("PT_B")\n'
+        'os.environ["PT_C"]\n"PT_D" in os.environ\n"""PT_E in prose"""'
+    )} == {"PT_A", "PT_B", "PT_C", "PT_D"}
+
+
 # ----------------------------------------------- metrics-reference coverage
 # The generated metrics reference (``python -m paddle_tpu.observability``)
 # renders whatever _INSTRUMENT_MODULES imports — a module that registers

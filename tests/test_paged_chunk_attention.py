@@ -1,11 +1,11 @@
 """Fused ragged chunk attention (ISSUE 11): interpret-Pallas vs XLA
 gather parity over GQA/MHA, mid-block offsets, degenerate chunk_lens,
-sliding windows, and OOB-sentinel table slots; the cached per-process
-Pallas fallback (counter + single warning, no silent per-call retry);
-the PT_PAGED_CHUNK kill switch actually changing the traced path only
-through ``clear_jit_caches``; and engine-level greedy identity with the
-kernel on, off, and interpreted — incl. spec decode, chunked prefill,
-and preempt-replay."""
+sliding windows, and OOB-sentinel table slots; a kernel that raises
+surfaces from its dispatcher (no downgrade to the gather); the
+dispatcher choosing from the backend and the shapes alone; and
+engine-level greedy identity of the interpreted kernels against the
+gather — incl. spec decode, chunked prefill, int8 K/V and
+preempt-replay."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -20,8 +20,8 @@ from paddle_tpu.utils.faults import FAULTS
 
 @pytest.fixture(autouse=True)
 def _fresh_jits():
-    # PT_PAGED_CHUNK is read at trace time: tests that flip it must not
-    # inherit (or leak) traced programs keyed on another test's mode
+    # the dispatchers' rule is read at trace time: tests that patch it
+    # must not inherit (or leak) programs traced on the other side
     clear_jit_caches()
     yield
     clear_jit_caches()
@@ -248,32 +248,23 @@ def test_chunk_kernel_reads_no_dead_kv(pool, monkeypatch):
 
 # ----------------------------------------------- dispatch + fallback
 
-def test_dispatch_kill_switch_forces_xla(monkeypatch):
-    """PT_PAGED_CHUNK=0 must route to the gather path and leave a
-    breadcrumb, never touching the Pallas wrapper."""
-    monkeypatch.setenv("PT_PAGED_CHUNK", "0")
-    monkeypatch.setattr(pa, "paged_chunk_attention_pallas",
-                        lambda *a, **k: pytest.fail("pallas path taken"))
-    rng = np.random.default_rng(4)
-    q, kp, vp, tables, offs, cls = _ragged_case(
-        rng, 2, 4, 4, 2, 16, 8, 4, 16, offs=[0, 9], cls=[4, 3])
-    pa._trace_events.clear()
-    out = pa.paged_chunk_attention(q, kp, vp, tables, offs, cls)
-    ref = pa.paged_chunk_attention_xla(q, kp, vp, tables, offs, cls)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
-    assert "chunk:xla-forced" in pa._trace_events
-
-
 def test_dispatch_interpret_mode(monkeypatch):
-    monkeypatch.setenv("PT_PAGED_CHUNK", "interpret")
+    """The rule's two sides on one slab Mosaic can copy (head_dim 128):
+    off the TPU the gather; where ``mosaic_kernels_apply`` says so the
+    kernel (``interpret=None`` resolves to interpreted on the CPU)."""
     rng = np.random.default_rng(5)
     q, kp, vp, tables, offs, cls = _ragged_case(
-        rng, 2, 4, 4, 2, 16, 8, 4, 16, offs=[0, 9], cls=[4, 3])
+        rng, 2, 4, 4, 2, 128, 8, 4, 16, offs=[0, 9], cls=[4, 3])
+    ref = pa.paged_chunk_attention_xla(q, kp, vp, tables, offs, cls)
     pa._trace_events.clear()
     out = pa.paged_chunk_attention(q, kp, vp, tables, offs, cls)
-    ref = pa.paged_chunk_attention_xla(q, kp, vp, tables, offs, cls)
+    assert np.array_equal(np.asarray(out), np.asarray(ref))
+    assert pa._trace_events == ["chunk:xla"]
+    monkeypatch.setattr(pa, "mosaic_kernels_apply", lambda: True)
+    pa._trace_events.clear()
+    out = pa.paged_chunk_attention(q, kp, vp, tables, offs, cls)
     _assert_live_parity(out, ref, cls)
-    assert "chunk:pallas-interpret" in pa._trace_events
+    assert pa._trace_events == ["chunk:pallas"]
 
 
 @pytest.mark.parametrize("kernel", ["decode", "chunk"])
@@ -313,7 +304,7 @@ def test_pallas_failure_raises_no_downgrade(monkeypatch, kernel):
     assert not hasattr(pa, "_pallas_disabled")
 
 
-# --------------------------------------- traced-path flip via jit cache
+# ------------------------------------------------ engine-level helpers
 
 def _eng_kw(**kw):
     base = dict(num_slots=4, block_size=8, max_prompt_len=8,
@@ -336,68 +327,63 @@ def _prompts(n, rs, lo=12, hi=24):
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = LlamaConfig.tiny(num_hidden_layers=2, hidden_size=32,
-                           num_attention_heads=4, num_key_value_heads=2,
+    # head_dim 128 and 4 K/V heads: slabs Mosaic can copy from a float32
+    # and from an int8 pool, so the dispatchers' rule on shapes
+    # (decode_slab_is_tiled) takes the kernels where they apply
+    cfg = LlamaConfig.tiny(num_hidden_layers=2, hidden_size=1024,
+                           num_attention_heads=8, num_key_value_heads=4,
                            vocab_size=64)
     return LlamaForCausalLM(cfg)
 
 
-def test_env_flip_needs_clear_jit_caches(model, monkeypatch):
-    """PT_PAGED_CHUNK is read when the chunk program TRACES: flipping it
-    mid-process changes nothing until ``clear_jit_caches`` drops the
-    traced programs, after which the new mode's path is taken."""
-    rs = np.random.RandomState(7)
-    prompts = _prompts(2, rs)
-    pa._trace_events.clear()
-    _run(LLMEngine(model, **_eng_kw()), prompts)
-    assert "chunk:xla" in pa._trace_events          # CPU default path
-
-    monkeypatch.setenv("PT_PAGED_CHUNK", "interpret")
-    pa._trace_events.clear()
-    _run(LLMEngine(model, **_eng_kw()), prompts)
-    # same shapes -> jit cache hit -> the dispatch never re-ran
-    assert "chunk:pallas-interpret" not in pa._trace_events
-
-    clear_jit_caches()
-    pa._trace_events.clear()
-    _run(LLMEngine(model, **_eng_kw()), prompts)
-    assert "chunk:pallas-interpret" in pa._trace_events
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """Switch the engine's programs to the side of the dispatchers' rule
+    a TPU takes: both paged kernels, interpreted off the TPU."""
+    def on():
+        monkeypatch.setattr(pa, "mosaic_kernels_apply", lambda: True)
+        clear_jit_caches()
+        pa._trace_events.clear()
+    return on
 
 
 # --------------------------------------------- engine greedy identity
 
-@pytest.mark.parametrize("mode", ["0", "interpret"])
-def test_engine_identity_chunked_prefill(model, monkeypatch, mode):
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_engine_identity_chunked_prefill(model, kernels_on, kv_dtype):
     rs = np.random.RandomState(8)
     prompts = _prompts(5, rs)
-    base = _run(LLMEngine(model, **_eng_kw()), prompts)
-    monkeypatch.setenv("PT_PAGED_CHUNK", mode)
-    clear_jit_caches()
-    assert _run(LLMEngine(model, **_eng_kw()), prompts) == base
+    kw = _eng_kw(kv_dtype=kv_dtype)
+    pa._trace_events.clear()
+    base = _run(LLMEngine(model, **kw), prompts)
+    assert "chunk:xla" in pa._trace_events          # CPU: the gather
+    kernels_on()
+    assert _run(LLMEngine(model, **kw), prompts) == base
+    assert {"chunk:pallas", "decode:pallas"} <= set(pa._trace_events)
+    assert "chunk:xla" not in pa._trace_events
 
 
-@pytest.mark.parametrize("mode", ["0", "interpret"])
-def test_engine_identity_spec_decode(model, monkeypatch, mode):
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_engine_identity_spec_decode(model, kernels_on, kv_dtype):
     """Spec verify rides the same chunk program — identity must hold
     with a draft in the loop (draft == target: the all-accept extreme)."""
     rs = np.random.RandomState(9)
     prompts = _prompts(4, rs)
-    kw = _eng_kw(draft_model=model)
+    kw = _eng_kw(draft_model=model, kv_dtype=kv_dtype)
     base = _run(LLMEngine(model, **kw), prompts)
-    monkeypatch.setenv("PT_PAGED_CHUNK", mode)
-    clear_jit_caches()
+    kernels_on()
     assert _run(LLMEngine(model, **kw), prompts) == base
+    assert "chunk:pallas" in pa._trace_events
 
 
-def test_engine_identity_preempt_replay_interpret(model, monkeypatch):
+def test_engine_identity_preempt_replay_interpret(model, kernels_on):
     """Interpreted kernel under preemption chaos: replay re-prefills
     through the chunk program and must still match the baseline."""
     rs = np.random.RandomState(10)
     prompts = _prompts(4, rs, lo=10, hi=18)
     kw = _eng_kw(num_blocks=24, preemption=True)
     base = _run(LLMEngine(model, **kw), prompts)
-    monkeypatch.setenv("PT_PAGED_CHUNK", "interpret")
-    clear_jit_caches()
+    kernels_on()
     FAULTS.install("serving.preempt", every=3, times=4,
                    action=lambda ctx: ctx["engine"]._preempt())
     try:
@@ -405,3 +391,4 @@ def test_engine_identity_preempt_replay_interpret(model, monkeypatch):
     finally:
         FAULTS.clear()
     assert out == base
+    assert "chunk:pallas" in pa._trace_events

@@ -234,8 +234,8 @@ _BREAKDOWN_PHASES = ("prefill", "draft", "verify", "sample", "host")
 
 
 def _bench_shaped_engine(**kw):
-    """The bench's Llama-shaped serving config (bench_serving_spec) —
-    the acceptance criterion measures THIS engine."""
+    """A Llama-shaped serving config, eight layers deep — the
+    acceptance criterion measures THIS engine."""
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     cfg = LlamaConfig.tiny(num_hidden_layers=8, vocab_size=512,
                            hidden_size=128, intermediate_size=256,

@@ -400,7 +400,7 @@ def test_serving_import_surface_unchanged():
     for name in ("LLMEngine", "Request", "QueueFullError",
                  "EngineDrainingError", "_BeamGroup", "_SAMPLE_ROWS_JIT",
                  "_MOE_DROPPED", "KVCache", "_sample_rows", "PagedKVCache",
-                 "PrefixCachingBlockManager", "_beam_finalize",
+                 "RadixPrefixBlockManager", "_beam_finalize",
                  "_BEAM_GROUP_UPDATE_JIT", "_BEAM_SELECT_JIT",
                  "_PREFILL_CHUNK_JIT", "_PREFILL_JIT", "_REWIND_LENS_JIT",
                  "_TICK_JIT", "_VERIFY_CHUNK_JIT", "greedy_accept_length",

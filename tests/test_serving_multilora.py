@@ -3,8 +3,8 @@
 * AdapterStore: strict registration, device-cache LRU eviction /
   hot-swap, pin exhaustion, pinned re-register refused
 * null-adapter identity: an engine carrying an AdapterStore but serving
-  only base requests is bit-exact with a storeless engine, and
-  ``PT_MULTILORA=0`` forces the base path even for adapter requests
+  only base requests is bit-exact with a storeless engine (which
+  refuses an adapter request), and an adapter visibly changes the stream
 * mixed continuous batch: every request's stream equals a dedicated
   single-adapter engine's — heterogeneous adapters batched through the
   grouped ragged path change nothing per-tenant
@@ -124,18 +124,17 @@ def test_store_pins_block_eviction_and_reregister(model):
 
 
 # ----------------------------------------------------- engine: identity
-def test_null_adapter_and_kill_switch_identity(model, store, monkeypatch):
+def test_null_adapter_identity(model, store):
     p = np.arange(1, 6, dtype=np.int32)
-    base_eng = LLMEngine(model, **ENG)
+    base_eng = LLMEngine(model, **ENG)       # adapter_store=None
+    assert base_eng._lora_arg(np.zeros(2, np.int64), 1) is None
     rb = base_eng.add_request(Request(p, max_new_tokens=4))
     base = base_eng.run()[rb]
     # store attached, request base: bit-exact (lora arg never built)
+    eng = LLMEngine(model, adapter_store=store, **ENG)
+    assert eng._lora_arg(np.full(2, -1, np.int64), 1) is None
     assert _run_one(model, store, p, 4) == base
-    # kill switch: even an adapter request takes the base path
-    monkeypatch.setenv("PT_MULTILORA", "0")
-    assert _run_one(model, store, p, 4, adapter_id="t1") == base
-    monkeypatch.delenv("PT_MULTILORA")
-    # and with it off again, the adapter visibly changes the stream
+    # and an adapter request visibly changes the stream
     assert _run_one(model, store, p, 4, adapter_id="t1") != base
 
 

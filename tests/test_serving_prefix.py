@@ -8,7 +8,7 @@
   allows, preempts the youngest slot on out-of-blocks, and the victim
   resumes with recompute — all outputs stay exactly solo-greedy
 Ref capability: PaddleNLP llm/predict block-attention serving (vLLM-style
-hash-block reuse + recompute preemption).
+block reuse + recompute preemption).
 """
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +17,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.models.decoding import generate
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.models.paged import PrefixCachingBlockManager
+from paddle_tpu.models.paged import RadixPrefixBlockManager
 from paddle_tpu.serving import LLMEngine, Request
 
 
@@ -37,31 +37,35 @@ def _solo(model, p, n):
 
 # --------------------------------------------------------------- manager
 def test_manager_park_match_adopt_evict():
-    mgr = PrefixCachingBlockManager(num_blocks=6, block_size=4)
+    mgr = RadixPrefixBlockManager(num_blocks=6, block_size=4)
     toks = np.arange(10, dtype=np.int32)          # 2 full blocks + tail
     mgr.allocate(1, 10)
     mgr.commit_prefix(1, toks)
     t1 = list(mgr.tables[1])
-    # full match capped at (len-1)//bs so the last token always prefills
-    assert mgr.match_prefix(toks) == t1[:2]
-    assert mgr.match_prefix(np.arange(9, dtype=np.int32)) == t1[:2]
+    # capped at len-1 so the last token always prefills: the whole blocks
+    # are shared, one token of the tail block is offered copy-on-write
+    m = mgr.match_prefix(toks)
+    assert (m.blocks, m.token_count, m.cow) == (t1[:2], 9, (t1[2], 1))
+    m = mgr.match_prefix(np.arange(9, dtype=np.int32))
+    assert (m.blocks, m.token_count, m.cow) == (t1[:2], 8, None)
     # a diverging second block only matches the first
     other = np.concatenate([np.arange(4), np.full(6, 63)]).astype(np.int32)
-    assert mgr.match_prefix(other) == t1[:1]
-    # free -> full blocks park (still matchable), unhashed tail block frees
+    m = mgr.match_prefix(other)
+    assert (m.blocks, m.token_count, m.cow) == (t1[:1], 4, None)
+    # free -> every block the trie holds parks (still matchable)
     mgr.free(1)
-    assert mgr.match_prefix(toks) == t1[:2]
-    assert len(mgr._evictable) == 2
+    assert mgr.match_prefix(toks).blocks == t1[:2]
+    assert mgr._parked == set(t1)
     assert mgr.free_blocks == 6                    # parked counts as free
     # adopt revives the parked blocks
-    adopted = mgr.match_prefix(toks)
+    adopted = mgr.match_prefix(np.arange(9, dtype=np.int32))
     mgr.adopt_prefix(2, adopted)
-    assert all(b not in mgr._evictable for b in adopted)
+    assert not set(adopted.blocks) & mgr._parked
     mgr.free(2)
     # exhaust the free list: eviction reclaims parked blocks LRU-first
     mgr.allocate(3, 24)                            # all 6 blocks
-    assert mgr.cache_stats["evictions"] == 2
-    assert mgr.match_prefix(toks) == []            # digests dropped
+    assert mgr.cache_stats["evictions"] == 3
+    assert not mgr.match_prefix(toks)              # the trie is empty
 
 
 # ------------------------------------------------------- prefix caching

@@ -3,9 +3,9 @@
 the int8 paged KV cache with per-(position, kv-head) scale pools —
 kernel-level dequant parity, engine greedy identity, radix/COW
 semantics, the cross-replica extract→ship→install wire with sealed
-scale checksums, the ``PT_QUANT_KV`` trace-time kill-switch contract
-(env flip requires ``clear_jit_caches``), the ``serving.kv_quant``
-chaos site's exception-atomicity, and the actual-dtype bytes fixes in
+scale checksums, the trace-time breadcrumbs that tell an int8 program
+from a ``kv_dtype=None`` one, the ``serving.kv_quant`` chaos site's
+exception-atomicity, and the actual-dtype bytes fixes in
 ``cache_block_bytes`` / roofline ``ModelGeometry``."""
 import copy
 
@@ -28,8 +28,8 @@ from paddle_tpu.serving.kv import cache_block_bytes
 from paddle_tpu.serving.quant import (QuantizedExpertStack,
                                       expert_stack_quantize, quant_quality,
                                       quantize_for_serving,
-                                      smooth_for_serving,
-                                      weights_quant_enabled)
+                                      quantized_weight_bytes,
+                                      smooth_for_serving)
 from paddle_tpu.serving.transfer import (DeviceKVTransfer, KVTransferError,
                                          validate_payload)
 from paddle_tpu.utils.faults import FAULTS, InjectedFault
@@ -49,8 +49,8 @@ def _preserve_global_rng():
 
 @pytest.fixture(autouse=True)
 def _fresh_jits():
-    # PT_QUANT_KV is read at trace time: tests that flip it must not
-    # inherit (or leak) traced programs keyed on another test's mode
+    # the breadcrumb tests read what a program traced: none may inherit
+    # (or leak) programs another test traced
     clear_jit_caches()
     yield
     clear_jit_caches()
@@ -201,26 +201,22 @@ def test_int8_kv_engine_matches_bf16_greedy(model):
     assert _match_rate(ref, out) >= 0.85
 
 
-def test_kv_kill_switch_bitexact(model, monkeypatch):
-    """PT_QUANT_KV=0 at construction: kv_dtype='int8' falls back to
-    model-dtype pools and output is BIT-identical to the bf16 engine."""
-    rs = np.random.RandomState(1)
-    prompts = _prompts(4, rs)
-    ref, _ = _run(model, prompts)
-    monkeypatch.setenv("PT_QUANT_KV", "0")
-    out, eng = _run(model, prompts, kv_dtype="int8")
-    assert not eng.cache.k_scales          # bf16 pool: no scale pools
-    assert eng.cache.k_pools[0].dtype == model.cfg.dtype
-    assert out == ref
-
-
-def test_weights_kill_switch_identity(model, monkeypatch):
-    monkeypatch.setenv("PT_QUANT_WEIGHTS", "0")
-    assert not weights_quant_enabled()
-    m = quantize_for_serving(copy.deepcopy(model), "weight_only_int8")
-    assert getattr(m, "_wo_bits", None) is None
-    assert not isinstance(m.model.layers[0].self_attn.qkv_proj,
+def test_quantize_for_serving_publishes_what_it_did(model):
+    """The call to ``quantize_for_serving`` is the one decision to
+    quantize weights: a model that never passes through it serves its
+    own, and the call reports bits, layers and bytes on its gauges."""
+    g = lambda name: METRICS.get(name).value()
+    assert getattr(model, "_wo_bits", None) is None
+    assert g("serving_quant_weight_bits") == 0       # nothing quantized yet
+    q = quantize_for_serving(copy.deepcopy(model), "weight_only_int4")
+    assert q._wo_bits == 4
+    assert isinstance(q.model.layers[0].self_attn.qkv_proj, QuantizedWeight)
+    assert not isinstance(model.model.layers[0].self_attn.qkv_proj,
                           QuantizedWeight)
+    assert g("serving_quant_weight_bits") == 4
+    assert g("serving_quant_layers") == len(model.model.layers)
+    assert g("serving_quant_smoothed") == 0
+    assert g("serving_quant_weight_bytes") == quantized_weight_bytes(q) > 0
 
 
 def test_full_quant_stack_spec_chunked_prefill(model, draft):
@@ -363,13 +359,12 @@ def test_kv_dtype_mismatch_rejected(model):
         validate_payload(bpayload, int8_dst)
 
 
-# --------------------------------------- trace-time kill-switch contract
+# ------------------------------------------------ trace-time breadcrumbs
 
-def test_quant_kv_env_flip_needs_clear(model, monkeypatch):
-    """PT_QUANT_KV is read when the quantized scatter TRACES: flipping
-    it mid-process changes nothing (cached int8 programs keep running —
-    the PR-10 contract), and after ``clear_jit_caches`` the retrace
-    REFUSES to silently re-quantize, telling the caller to rebuild."""
+def test_int8_programs_trace_once_and_say_so(model):
+    """An int8 pool's programs leave the quantize-on-write and
+    dequantize-on-read breadcrumbs when they trace, and a second request
+    of the same shapes traces nothing again."""
     rs = np.random.RandomState(11)
     eng = _mk(model, kv_dtype="int8")
     pa._trace_events.clear()
@@ -378,23 +373,22 @@ def test_quant_kv_env_flip_needs_clear(model, monkeypatch):
     assert "kv:int8-write" in pa._trace_events     # quantized scatter
     assert "decode:int8-kv" in pa._trace_events    # dequant-on-read
 
-    monkeypatch.setenv("PT_QUANT_KV", "0")
     pa._trace_events.clear()
     eng.add_request(Request(rs.randint(1, 64, (5,)), max_new_tokens=4))
     eng.run()                       # cached traces: still the int8 path
     assert "kv:int8-write" not in pa._trace_events  # no retrace happened
     eng.assert_quiescent()
 
-    clear_jit_caches()              # now the flip takes effect: retrace
-    eng.add_request(Request(rs.randint(1, 64, (5,)), max_new_tokens=4))
-    with pytest.raises(RuntimeError, match="PT_QUANT_KV"):
-        eng.run()
-
 
 def test_bf16_traces_carry_no_quant_breadcrumbs(model):
+    """``kv_dtype=None`` (the default): model-dtype pools, no scale
+    pools, and no program of it traces a quantized branch."""
     rs = np.random.RandomState(12)
     pa._trace_events.clear()
-    _run(model, _prompts(2, rs))
+    _, eng = _run(model, _prompts(2, rs), kv_dtype=None)
+    assert not eng.cache.k_scales          # no scale pools
+    assert eng.cache.k_pools[0].dtype == model.cfg.dtype
+    assert pa._trace_events
     assert not any("int8" in e for e in pa._trace_events)
 
 

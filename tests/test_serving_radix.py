@@ -8,7 +8,6 @@
 * leaf-LRU eviction reclaims parked blocks least-recently-touched
   first; the ``serving.prefix_evict`` chaos site is exception-atomic
 * refcount conservation under adopt/free interleavings
-* ``PT_RADIX_CACHE=0`` restores the flat manager bit-for-bit
 * engine-level: greedy outputs identical cache-on vs cache-off vs fresh
   engine, including preempt+replay and chunked prefill
 Ref capability: SGLang RadixAttention over vLLM-style paging.
@@ -20,8 +19,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.models.decoding import generate
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.models.paged import (PrefixCachingBlockManager, PrefixMatch,
-                                     RadixPrefixBlockManager)
+from paddle_tpu.models.paged import PrefixMatch, RadixPrefixBlockManager
 from paddle_tpu.serving import LLMEngine, Request
 from paddle_tpu.utils.faults import FAULTS, InjectedFault
 
@@ -209,30 +207,17 @@ def test_chaos_prefix_evict_exception_atomic():
 
 def test_match_memo_invalidated_by_epoch():
     """cache_epoch bumps on commit AND eviction — the scheduler's memo
-    key — on both managers."""
-    for cls in (RadixPrefixBlockManager, PrefixCachingBlockManager):
-        mgr = cls(num_blocks=2, block_size=4)
-        e0 = mgr.cache_epoch
-        mgr.allocate(1, 8)
-        mgr.commit_prefix(1, np.arange(8, dtype=np.int32))
-        assert mgr.cache_epoch > e0, cls.__name__
-        e1 = mgr.cache_epoch
-        mgr.free(1)
-        mgr.allocate(2, 8)                         # forces eviction
-        assert mgr.cache_epoch > e1, cls.__name__
-        mgr.free(2)
-
-
-# ---------------------------------------------------------- kill switch
-def test_kill_switch_selects_flat_manager(model, monkeypatch):
-    monkeypatch.setenv("PT_RADIX_CACHE", "0")
-    eng = LLMEngine(model, num_slots=2, block_size=4, max_prompt_len=16,
-                    max_seq_len=24)
-    assert type(eng.mgr) is PrefixCachingBlockManager
-    monkeypatch.delenv("PT_RADIX_CACHE")
-    eng2 = LLMEngine(model, num_slots=2, block_size=4, max_prompt_len=16,
-                    max_seq_len=24)
-    assert type(eng2.mgr) is RadixPrefixBlockManager
+    key."""
+    mgr = RadixPrefixBlockManager(num_blocks=2, block_size=4)
+    e0 = mgr.cache_epoch
+    mgr.allocate(1, 8)
+    mgr.commit_prefix(1, np.arange(8, dtype=np.int32))
+    assert mgr.cache_epoch > e0
+    e1 = mgr.cache_epoch
+    mgr.free(1)
+    mgr.allocate(2, 8)                         # forces eviction
+    assert mgr.cache_epoch > e1
+    mgr.free(2)
 
 
 # --------------------------------------------------------- engine level
@@ -257,36 +242,31 @@ def test_engine_partial_tail_cow_reuse(model):
     eng.assert_quiescent()
 
 
-def test_engine_greedy_identity_on_vs_off(model, monkeypatch):
+def test_engine_greedy_identity_on_vs_off(model):
     """The same prompt stream produces bit-identical greedy tokens on a
-    warm radix engine, a flat-manager engine (PT_RADIX_CACHE=0), a
-    cache-disabled engine, and a fresh solo generate."""
+    warm radix engine, a cache-disabled engine (``prefix_caching=False``),
+    and a fresh solo generate — and only the first reuses anything."""
     rs = np.random.RandomState(12)
     pre = rs.randint(0, 64, (9,))
     prompts = [np.concatenate([pre, rs.randint(0, 64, (3,))])
                for _ in range(3)]
 
-    def run_stream(eng):
+    def run_stream(**kw):
+        eng = LLMEngine(model, num_slots=2, block_size=4,
+                        max_prompt_len=16, max_seq_len=24, **kw)
         outs = []
         for p in prompts:                          # sequential: warm cache
             rid = eng.add_request(Request(p, max_new_tokens=5))
             outs.append(eng.run()[rid])
-        return outs
+        return outs, eng.mgr.cache_stats["token_hits"]
 
-    radix = run_stream(LLMEngine(model, num_slots=2, block_size=4,
-                                 max_prompt_len=16, max_seq_len=24))
-    monkeypatch.setenv("PT_RADIX_CACHE", "0")
-    flat = run_stream(LLMEngine(model, num_slots=2, block_size=4,
-                                max_prompt_len=16, max_seq_len=24))
-    monkeypatch.delenv("PT_RADIX_CACHE")
-    off = run_stream(LLMEngine(model, num_slots=2, block_size=4,
-                               max_prompt_len=16, max_seq_len=24,
-                               prefix_caching=False))
-    for p, a, b, c in zip(prompts, radix, flat, off):
+    radix, radix_hits = run_stream()
+    off, off_hits = run_stream(prefix_caching=False)
+    assert radix_hits >= 2 * 9 and off_hits == 0
+    for p, a, b in zip(prompts, radix, off):
         sol = _solo(model, p, 5)
         np.testing.assert_array_equal(a, sol)
         np.testing.assert_array_equal(b, sol)
-        np.testing.assert_array_equal(c, sol)
 
 
 def test_engine_preempt_replay_radix_identity(model):

@@ -1,13 +1,13 @@
 """Speculative decoding inside the engine (ISSUE 5): greedy output
 identity with a draft model in the loop, composition with preemption
 chaos, exception-atomicity of the ``serving.spec_verify`` fault site,
-the PT_SPEC_DECODE kill switch, adaptive-k behaviour, and the metric
+the degrade gate, adaptive-k behaviour, and the metric
 surface (proposed/accepted counters + acceptance-rate gauge)."""
 import numpy as np
 import pytest
 
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import LLMEngine, Request
+from paddle_tpu.serving import DegradationController, LLMEngine, Request
 from paddle_tpu.utils.faults import FAULTS
 
 pytestmark = pytest.mark.chaos
@@ -151,17 +151,28 @@ def test_spec_verify_fault_is_exception_atomic(model):
     assert snap["serving_spec_fallbacks_total"] >= eng.stats["spec_fallbacks"]
 
 
-# ------------------------------------------------- kill switch / gating
+# ----------------------------------------------------------------- gating
 
-def test_kill_switch_disables_speculation(model, monkeypatch):
-    monkeypatch.setenv("PT_SPEC_DECODE", "0")
+def test_degrade_gate_off_then_on_between_runs(model):
+    """``degrade.spec_enabled()`` is asked every tick: one live engine
+    with a draft model never drafts while it says no, drafts again once
+    it says yes, and emits the non-spec stream both times."""
+    gate = DegradationController(signals=[], down_patience=10 ** 9)
+    gate.force_level(1)
     rs = np.random.RandomState(0)
     prompts = _prompts(4, rs)
+    base = _baseline(model, prompts)
     eng = LLMEngine(model, draft_model=model, spec_k=4, num_slots=4,
-                    block_size=8, max_prompt_len=16, max_seq_len=64)
-    spec = _run(eng, prompts)
+                    block_size=8, max_prompt_len=16, max_seq_len=64,
+                    degrade=gate)
+    off = _run(eng, prompts)
     assert eng.stats["spec_ticks"] == 0
-    assert spec == _baseline(model, prompts)
+    assert list(off.values()) == list(base.values())
+    gate.force_level(0)
+    on = _run(eng, prompts)
+    assert eng.stats["spec_ticks"] > 0
+    # run() returns every request the engine has served: the second four
+    assert list(on.values())[len(prompts):] == list(base.values())
     eng.assert_quiescent()
 
 

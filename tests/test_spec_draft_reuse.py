@@ -3,8 +3,7 @@ REMAINING): a radix prefix hit used to pay a draft-side re-prefill of
 the whole adopted span, counted as ``replay_prefill`` waste. The engine
 now seeds ``draft_cur`` from the slot's resident draft cache, so the
 catch-up feed embeds only the un-adopted suffix — asserted through the
-goodput ledger, the reuse counter, output identity, and the
-PT_DRAFT_REUSE kill switch."""
+goodput ledger, the reuse counter and output identity."""
 import numpy as np
 import pytest
 
@@ -55,22 +54,20 @@ def _two_phase(model, rs, **ekw):
     return {**o1, **o2}, reuse, replay
 
 
-def test_radix_hit_seeds_draft_and_kills_replay_waste(model, monkeypatch):
-    """With reuse on, the adopted span's draft re-embed disappears; with
-    PT_DRAFT_REUSE=0 it comes back token for token — and the outputs are
+def test_radix_hit_seeds_draft_and_kills_replay_waste(model):
+    """A radix hit with a draft model seeds the whole adopted span: its
+    draft re-embed disappears from the waste ledger. Without a radix hit
+    (``prefix_caching=False``) nothing is seeded — and the outputs are
     identical either way (reuse can only change speed, never tokens)."""
     out_on, reuse_on, replay_on = _two_phase(
         model, np.random.RandomState(3))
-    assert reuse_on > 0
+    assert reuse_on == 24          # the shared span, token for token
+    assert replay_on == 0          # none of it re-embedded draft-side
 
-    monkeypatch.setenv("PT_DRAFT_REUSE", "0")
-    out_off, reuse_off, replay_off = _two_phase(
-        model, np.random.RandomState(3))
+    out_off, reuse_off, _ = _two_phase(
+        model, np.random.RandomState(3), prefix_caching=False)
     assert reuse_off == 0
     assert list(out_on.values()) == list(out_off.values())
-    # every reused position is exactly one replay_prefill unit saved
-    assert replay_off - replay_on == reuse_on
-    assert replay_off >= 24        # the kill-switch run re-embeds the span
 
 
 def test_unrelated_prompt_reuses_nothing(model):
