@@ -1222,7 +1222,7 @@ moe_decode_tick = llama_decode_tick
 
 # module-level jit wrappers: their compile caches persist across
 # paged_generate calls (a per-call jax.jit would recompile every request)
-_PREFILL_JIT = jax.jit(llama_prefill_paged)
+_PREFILL_JIT = jax.jit(llama_prefill_paged, donate_argnums=(3,))
 _DECODE_JIT = jax.jit(llama_decode_step_paged)
 _TICK_JIT = jax.jit(llama_decode_tick, static_argnums=(10, 11),
                     donate_argnums=(2,))

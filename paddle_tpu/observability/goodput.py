@@ -17,8 +17,11 @@ work into
                             replay (minus prefix-cache hits)
       ``pad_rows``          whole padding rows in the admission
                             prefill, chunked-prefill and spec-verify
-                            batches (row slots launched with no live
-                            sequence, times the row's width). Not the
+                            batches (rows of the calls sent that hold no
+                            live sequence, times the row's width: the
+                            prefill programs are sent ``prefill_rows``
+                            rows a call, ``ceil(live / prefill_rows)``
+                            calls a tick). Not the
                             benchmark's ``pad_row_share``: that counts,
                             from the ``rows``/``useful`` args of the
                             ``exe.prefill*`` spans, every token-row

@@ -88,6 +88,11 @@ from paddle_tpu.utils.faults import fault_point
 from paddle_tpu.utils.profiler import device_memory_stats
 
 
+# token-rows of a matmul that fill one pass over bf16 weights on the chips
+# this serves from (v5e: 197 TFLOP/s over 819 GB/s is 240 FLOP a byte, a
+# token-row of a bf16 matmul 1 FLOP a weight byte), to the power of two above
+_RIDGE_TOKENS = 256
+
 _LOOPED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "its payload holds one row a block and layer, a looped "
                    "pool one a pass as well")
@@ -312,6 +317,13 @@ class LLMEngine:
         self.prefilling: dict[int, tuple] = {}
 
         self._staged_admits = frozenset()   # this tick's pre-scatter rows
+        # rows a call of the two prefill programs: the fewest that fill one
+        # pass over the weights (about _RIDGE_TOKENS token-rows), so a tick
+        # that admits one prompt does not compute a row for every slot; a
+        # tick with more live rows sends ceil(live / prefill_rows) calls
+        self.prefill_rows = min(num_slots,
+                                max(1, _RIDGE_TOKENS // max_prompt_len))
+        self._prefill_sent = [0, 0]    # this tick's [live rows, calls]
         # host-vs-device split of decode ticks (admission ticks excluded):
         # stats["host_s"] is scheduling/bookkeeping, stats["device_s"] the
         # jitted tick incl. the [num_slots] token fetch
@@ -882,28 +894,84 @@ class LLMEngine:
             if dead > 0 and self.mgr.free_prefix(rid, dead):
                 self._update_resv(rid)
 
+    def _req_sampling(self, req):
+        """(temperature, top_p) a request's tokens are sampled with."""
+        return (self.default_temp if req.temperature is None
+                else req.temperature,
+                self.default_top_p if req.top_p is None else req.top_p)
+
+    def _send_prefill_rows(self, live, chunked: bool):
+        """Send the rows that carry a prompt this tick to one of the two
+        prefill programs, ``prefill_rows`` of them a call, and sample the
+        first tokens asked for. ``live``: a ``(tokens, offset, slot, table,
+        adapter index, sampling)`` for each row, ``sampling`` its ``(temp,
+        top_p)`` where this call's last logit chooses the row's first
+        token, else None. Every call is dispatched before the one fetch,
+        so the device runs a call while the host stages the next.
+        -> (each call's logits, each row's first token or None)."""
+        R, cap = self.prefill_rows, self.max_prompt_len
+        nb, max_b = self.mgr.num_blocks, self.max_blocks_per_seq
+        logits, toks = [], []     # toks: (a call's first row, its tokens)
+        for g0 in range(0, len(live), R):
+            ids = np.zeros((R, cap), np.int32)
+            lens = np.zeros(R, np.int32)
+            offs = np.zeros(R, np.int32)
+            slots = np.full(R, self.num_slots, np.int32)  # sentinel = drop
+            rows = np.full((R, max_b), nb, np.int32)
+            row_aidx = np.full(R, -1, np.int64)
+            row_temps = np.zeros(R, np.float32)
+            row_tps = np.ones(R, np.float32)
+            sampled = []
+            for i, (tokens, off, slot, table, aidx, sampling) in enumerate(
+                    live[g0:g0 + R]):
+                ids[i, :len(tokens)] = tokens
+                lens[i] = len(tokens)
+                offs[i] = off
+                slots[i] = slot
+                rows[i, :len(table)] = table
+                row_aidx[i] = aidx
+                if sampling is not None:
+                    row_temps[i], row_tps[i] = sampling
+                    sampled.append((i, slot))
+            lora = self._lora_arg(row_aidx, cap)
+            if chunked:
+                out = self.exe.prefill_chunk(ids, lens, offs, slots, rows,
+                                             lora=lora)
+            else:
+                out = self.exe.prefill(ids, lens, slots, rows, lora=lora)
+            # roofline: one weight pass a call; a chunk attends its own
+            # tokens plus everything already consumed (its offset)
+            self._acc_phase("prefill", int(lens.sum()), 1,
+                            self._ctx_causal(lens, offs))
+            logits.append(out)
+            if sampled:
+                toks.append((g0, self.exe.sample_rows(
+                    out, row_temps, row_tps,
+                    bias=self._grammar_bias_rows(sampled, R))))
+        # the dead rows of the calls sent burned device FLOPs on no
+        # request's behalf
+        GOODPUT.waste("pad_rows", (R * len(logits) - len(live)) * cap)
+        self._prefill_sent[0] += len(live)
+        self._prefill_sent[1] += len(logits)
+        first = [None] * len(live)
+        if toks:
+            for (g0, _), got in zip(toks, self.exe.fetch_sampled(
+                    [t for _, t in toks])):
+                n = min(R, len(live) - g0)
+                first[g0:g0 + n] = got[:n].tolist()
+        return logits, first
+
     def _prefill(self, admits, beam_admits=()):
-        """ONE padded prefill forward for every prompt admitted this tick —
-        greedy prompts in rows 0..n-1, each beam request's prompt as one
-        more row (written into its beam-0 slot; the forks are installed
-        after, in ``_beam_init``)."""
+        """The whole-prompt forward for every prompt admitted this tick:
+        greedy prompts first, then each beam request's prompt as one more
+        row (written into its beam-0 slot; the forks are installed after,
+        in ``_beam_init``)."""
         if not admits and not beam_admits:
-            # nothing admitted: never pay the full (num_slots,
-            # max_prompt_len) padded forward on all-sentinel rows
             return []
-        a_cap = self.num_slots           # one compiled admission shape
-        ids = np.zeros((a_cap, self.max_prompt_len), np.int32)
-        lens = np.zeros(a_cap, np.int32)
-        slots = np.full(a_cap, self.num_slots, np.int32)   # sentinel = drop
-        rows = np.full((a_cap, self.max_blocks_per_seq),
-                       self.mgr.num_blocks, np.int32)
-        for i, (slot, req) in enumerate(admits):
+        live = []
+        for slot, req in admits:
             p = self._pr(req)
-            ids[i, :len(p)] = p
-            lens[i] = len(p)
-            slots[i] = slot
             t = self.mgr.tables[req.req_id]
-            rows[i, :len(t)] = t
             self.slot_req[slot] = req.req_id
             self.active[slot] = True
             self.cur[slot] = len(p)
@@ -912,10 +980,7 @@ class LLMEngine:
             self._adm_counter += 1
             self.adm_order[slot] = self._adm_counter
             self.table_len[slot] = len(t)
-            self.temps[slot] = (self.default_temp if req.temperature is None
-                                else req.temperature)
-            self.top_ps[slot] = (self.default_top_p if req.top_p is None
-                                 else req.top_p)
+            self.temps[slot], self.top_ps[slot] = self._req_sampling(req)
             self.slot_aidx[slot] = self._req_aidx(req)
             self._bind_grammar(slot, req)
             # fresh draft state unless the resident draft cache covers a
@@ -925,40 +990,20 @@ class LLMEngine:
             self.slot_k[slot] = self.spec_k
             self._acc_ema[slot] = 1.0
             REQUESTS.event(req, "prefill", replica=self.trace_name,
-                           slot=slot, tokens=int(lens[i]))
+                           slot=slot, tokens=len(p))
+            live.append((p, 0, slot, t, self.slot_aidx[slot],
+                         (self.temps[slot], self.top_ps[slot])))
         n = len(admits)
         beams = []
+        # every beam allocation lands before the first call is sent, so
+        # the guard covers all of the tick's calls
         self._staged_admits = frozenset(r.req_id for _, r in admits)
-        for bi, (bslots, req) in enumerate(beam_admits):
+        for bslots, req in beam_admits:
             g, grows, csrc, cdst = self._beam_alloc(bslots, req)
-            i = n + bi                   # every admit holds >= 1 slot, so
-            ids[i, :g.s] = req.prompt    # greedy + beam rows fit in a_cap
-            lens[i] = g.s
-            slots[i] = bslots[0]
-            rows[i] = grows[0]
+            live.append((req.prompt, 0, bslots[0], grows[0], -1, None))
             beams.append((g, grows, csrc, cdst))
-        row_aidx = np.full(a_cap, -1, np.int64)
-        for i, (slot, _) in enumerate(admits):
-            row_aidx[i] = self.slot_aidx[slot]
-        logits = self.exe.prefill(
-            ids, lens, slots, rows,
-            lora=self._lora_arg(row_aidx, self.max_prompt_len))
+        logits, first = self._send_prefill_rows(live, chunked=False)
         self._staged_admits = frozenset()   # scatter landed: evictable again
-        # padded sentinel rows burned device FLOPs on no request's behalf
-        GOODPUT.waste("pad_rows", (a_cap - n - len(beams))
-                      * self.max_prompt_len)
-        # roofline: one weight pass; prompts attend causally from offset 0
-        self._acc_phase("prefill", int(lens.sum()), 1,
-                        self._ctx_causal(lens, np.zeros_like(lens)))
-        row_temps = np.zeros(a_cap, np.float32)
-        row_tps = np.ones(a_cap, np.float32)
-        for i, (slot, req) in enumerate(admits):
-            row_temps[i] = self.temps[slot]
-            row_tps[i] = self.top_ps[slot]
-        first = self.exe.sample(
-            logits, row_temps, row_tps,
-            bias=self._grammar_bias_rows(
-                [(i, slot) for i, (slot, _) in enumerate(admits)], a_cap))
         if self.window is not None:
             # a long prompt's below-window blocks die the moment prefill
             # has scattered them — and from here on the sequence can never
@@ -974,9 +1019,12 @@ class LLMEngine:
                 self._update_resv(rid)
         emitted = []
         for i, (slot, req) in enumerate(admits):
-            emitted += self._emit(slot, int(first[i]))
+            emitted += self._emit(slot, first[i])
+        R = self.prefill_rows
         for bi, (g, grows, csrc, cdst) in enumerate(beams):
-            emitted += self._beam_init(g, grows, csrc, cdst, logits[n + bi])
+            i = n + bi
+            emitted += self._beam_init(g, grows, csrc, cdst,
+                                       logits[i // R][i % R])
         return emitted
 
     # ------------------------------------------------------------ beams
@@ -1130,44 +1178,42 @@ class LLMEngine:
         self._apply_prefix_copies()
         if not self.prefilling:
             return []
-        a_cap = self.num_slots
         cap = self.max_prompt_len
         # ladder L2: shrink the per-tick chunk budget, not the jitted
-        # geometry — the ids array keeps its (a_cap, cap) shape (lens
-        # just come up shorter), so degrading never recompiles
+        # geometry — the ids array keeps its (prefill_rows, cap) shape
+        # (lens just come up shorter), so degrading never recompiles
         budget = (cap if self.degrade is None
                   else min(cap, self.degrade.prefill_budget(cap)))
-        nb, max_b = self.mgr.num_blocks, self.max_blocks_per_seq
-        ids = np.zeros((a_cap, cap), np.int32)
-        lens = np.zeros(a_cap, np.int32)
-        offs = np.zeros(a_cap, np.int32)
-        slots = np.full(a_cap, self.num_slots, np.int32)
-        rows = np.full((a_cap, max_b), nb, np.int32)
-        batch = list(self.prefilling.items())[:a_cap]
-        row_aidx = np.full(a_cap, -1, np.int64)
-        progressed = False
-        staged = set()       # rows already in the jitted batch: their KV
-        for i, (rid, (slot, consumed)) in enumerate(batch):
-            if rid not in self.prefilling:   # scatter is pending — a later
-                continue     # row's preemption must never evict them
+        live, rids = [], []
+        # every row's blocks are allocated before the first call is sent:
+        # rows already staged (their KV scatter is pending) are protected
+        # from a later row's preemption, across all of the tick's calls
+        staged = set()
+        for rid, (slot, consumed) in list(self.prefilling.items()):
+            if rid not in self.prefilling:   # evicted by an earlier row's
+                continue                     # allocation this tick
             req = self.requests[rid]
-            chunk = self._pr(req)[consumed: consumed + budget]
+            p = self._pr(req)
+            chunk = p[consumed: consumed + budget]
             t = self._allocate_or_preempt(rid, consumed + len(chunk),
                                           protect=staged)
             if t is None:
                 continue         # no blocks this tick: row stays queued
-            progressed = True
             staged.add(rid)
             self._update_resv(rid)
             REQUESTS.event(req, "prefill_chunk", replica=self.trace_name,
                            slot=slot, offset=consumed, tokens=len(chunk))
-            ids[i, :len(chunk)] = chunk
-            lens[i] = len(chunk)
-            offs[i] = consumed
-            slots[i] = slot
-            rows[i, :len(t)] = t
-            row_aidx[i] = self._req_aidx(req)
-        if (not progressed and not self.active.any() and not self.groups):
+            sampling = None
+            if consumed + len(chunk) >= len(p):
+                # the last chunk: bind grammar BEFORE the first-token
+                # sample so the mask bias covers it (state replays
+                # req.tokens for resumes)
+                self._bind_grammar(slot, req)
+                sampling = self._req_sampling(req)
+            live.append((chunk, consumed, slot, t, self._req_aidx(req),
+                         sampling))
+            rids.append(rid)
+        if not live and not self.active.any() and not self.groups:
             # nothing decoded this tick and no prefill row got blocks even
             # though preemption could evict every OTHER prefill: the pool
             # cannot fit one chunk of the sole remaining request — no
@@ -1180,70 +1226,37 @@ class LLMEngine:
                 "paged pool cannot fit one prefill chunk of the remaining "
                 "request(s) even after preemption — increase num_blocks or "
                 "reduce max_prompt_len (chunk size)")
-        if not progressed:
+        if not live:
             # every prefilling row is starved of blocks this tick (decode
-            # keeps the engine alive): the batch is all-sentinel, so the
-            # padded chunk forward would scatter nothing — skip it
+            # keeps the engine alive): nothing to scatter, no call
             return []
-        logits = self.exe.prefill_chunk(ids, lens, offs, slots, rows,
-                                        lora=self._lora_arg(row_aidx, cap))
-        # padded sentinel rows burned device FLOPs on no request's behalf
-        GOODPUT.waste("pad_rows", (a_cap - len(staged)) * cap)
-        # roofline: one weight pass; each chunk attends its own tokens
-        # plus everything already consumed (its offset)
-        self._acc_phase("prefill", int(lens.sum()), 1,
-                        self._ctx_causal(lens, offs))
+        _, first = self._send_prefill_rows(live, chunked=True)
         emitted = []
-        done_rows = []
-        for i, (rid, (slot, consumed)) in enumerate(batch):
-            if rid not in self.prefilling:
-                continue     # evicted mid-batch: must not re-add its row
+        for rid, (chunk, consumed, slot, _, _, sampling), tok in zip(
+                rids, live, first):
             req = self.requests[rid]
-            consumed += int(lens[i])
-            if consumed < len(self._pr(req)):
-                self.prefilling[rid] = (slot, consumed)
+            if sampling is None:
+                self.prefilling[rid] = (slot, consumed + len(chunk))
                 continue
-            done_rows.append((i, rid, slot))
-        if done_rows:
-            row_t = np.zeros(a_cap, np.float32)
-            row_p = np.ones(a_cap, np.float32)
-            for i, rid, slot in done_rows:
-                req = self.requests[rid]
-                row_t[i] = (self.default_temp if req.temperature is None
-                            else req.temperature)
-                row_p[i] = (self.default_top_p if req.top_p is None
-                            else req.top_p)
-                # bind grammar BEFORE the first-token sample so the mask
-                # bias covers it (state replays req.tokens for resumes)
-                self._bind_grammar(slot, req)
-            first = self.exe.sample(
-                logits, row_t, row_p,
-                bias=self._grammar_bias_rows(
-                    [(i, s) for i, _, s in done_rows], a_cap))
-            for i, rid, slot in done_rows:
-                req = self.requests[rid]
-                del self.prefilling[rid]
-                p = self._pr(req)
-                if self.prefix_caching:
-                    self.mgr.commit_prefix(rid, p,
-                                           adapter=req.adapter_id)
-                t = self.mgr.tables[rid]
-                self.active[slot] = True
-                self.cur[slot] = len(p)
-                self.gen[slot] = 0
-                self.max_gen[slot] = self._remaining(req)
-                self._adm_counter += 1
-                self.adm_order[slot] = self._adm_counter
-                self.table_len[slot] = len(t)
-                self.temps[slot] = row_t[i]
-                self.top_ps[slot] = row_p[i]
-                self.slot_aidx[slot] = self._req_aidx(req)
-                # cached/long prompts land here — the site where a radix
-                # adoption can seed the draft frontier from resident K/V
-                self._seed_draft(slot, req)
-                self.slot_k[slot] = self.spec_k
-                self._acc_ema[slot] = 1.0
-                emitted += self._emit(slot, int(first[i]))
+            del self.prefilling[rid]
+            p = self._pr(req)
+            if self.prefix_caching:
+                self.mgr.commit_prefix(rid, p, adapter=req.adapter_id)
+            self.active[slot] = True
+            self.cur[slot] = len(p)
+            self.gen[slot] = 0
+            self.max_gen[slot] = self._remaining(req)
+            self._adm_counter += 1
+            self.adm_order[slot] = self._adm_counter
+            self.table_len[slot] = len(self.mgr.tables[rid])
+            self.temps[slot], self.top_ps[slot] = sampling
+            self.slot_aidx[slot] = self._req_aidx(req)
+            # cached/long prompts land here — the site where a radix
+            # adoption can seed the draft frontier from resident K/V
+            self._seed_draft(slot, req)
+            self.slot_k[slot] = self.spec_k
+            self._acc_ema[slot] = 1.0
+            emitted += self._emit(slot, tok)
         return emitted
 
     def _apply_prefix_copies(self):
@@ -2008,10 +2021,7 @@ class LLMEngine:
         self.max_gen[slot] = payload.gen + self._remaining(req)
         self.table_len[slot] = len(t)
         self.last_tok[slot] = payload.last_tok
-        self.temps[slot] = (self.default_temp if req.temperature is None
-                            else req.temperature)
-        self.top_ps[slot] = (self.default_top_p if req.top_p is None
-                             else req.top_p)
+        self.temps[slot], self.top_ps[slot] = self._req_sampling(req)
         self._adm_counter += 1
         self.adm_order[slot] = self._adm_counter
         self.slot_aidx[slot] = -1
@@ -2460,10 +2470,13 @@ class LLMEngine:
                 rids = [r.req_id for _, r in (*admits, *beam_admits)]
                 rids += [r for r in self.prefilling if r not in chunked]
                 sp.set(admitted=len(rids), queued=len(self.queue), rids=rids)
-        with self._tick_timer("prefill", "serving.prefill"):
+        with self._tick_timer("prefill", "serving.prefill") as sp:
+            self._prefill_sent = [0, 0]
             if admits or beam_admits:
                 emitted += self._prefill(admits, beam_admits)
             emitted += self._prefill_chunks()
+            sp.set(live_rows=self._prefill_sent[0],
+                   calls=self._prefill_sent[1])
         if self.prefill_only:
             # prefill-role replica: newly activated slots carry their
             # first token; the router extracts them — never decode here

@@ -180,7 +180,7 @@ class ModelExecutor:
 
         self._cp_prefill = jax.jit(smap(
             functools.partial(llama_prefill_paged, cp_axis="cp"),
-            (R, R, R, cs, R, R), (R, cs)))
+            (R, R, R, cs, R, R), (R, cs)), donate_argnums=(3,))
         self._cp_prefill_chunk = jax.jit(smap(
             functools.partial(llama_prefill_chunk_paged, cp_axis="cp"),
             (R, R, R, R, cs, R, R), (R, cs)), donate_argnums=(4,))
@@ -223,7 +223,9 @@ class ModelExecutor:
         """Slot-aware padded prefill: admitted prompts scattered into
         their cache slots while other slots keep decoding state.
         ``lora`` (optional pytree, see ``models.paged._lora_delta``)
-        applies the batched multi-LoRA correction per row."""
+        applies the batched multi-LoRA correction per row. The cache is
+        donated, as in the chunk program: a tick's several calls in
+        flight hold one pool, not one more for each."""
         with _span("exe.prefill", **_token_rows(ids, lens), **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)
@@ -361,15 +363,21 @@ class ModelExecutor:
             jnp.asarray(copy_dst))
 
     # ------------------------------------------------------------- sample
-    def sample(self, logits, temps, top_ps, key=None, bias=None):
-        """Per-row temperature/top-k/top-p sampling (host fetch).
-        ``bias`` ([rows, V], 0 / -1e30) is the grammar-mask addend."""
-        sub = self.next_key() if key is None else key
-        with _span("exe.sample", cat="device_wait", rows=logits.shape[0]):
-            return np.asarray(_SAMPLE_ROWS_JIT(
-                logits.astype(jnp.float32), sub, jnp.asarray(temps),
-                jnp.asarray(top_ps), self.top_k,
-                bias=(None if bias is None else jnp.asarray(bias))))
+    def sample_rows(self, logits, temps, top_ps, bias=None):
+        """Per-row temperature/top-k/top-p sampling, dispatched and not
+        waited for: -> the rows' tokens, on the device. ``bias`` ([rows,
+        V], 0 / -1e30) is the grammar-mask addend."""
+        return _SAMPLE_ROWS_JIT(
+            logits.astype(jnp.float32), self.next_key(), jnp.asarray(temps),
+            jnp.asarray(top_ps), self.top_k,
+            bias=(None if bias is None else jnp.asarray(bias)))
+
+    def fetch_sampled(self, sampled):
+        """The host's one wait of a prefill entry: each of
+        :meth:`sample_rows`' results, as numpy."""
+        with _span("exe.sample", cat="device_wait",
+                   rows=sum(t.shape[0] for t in sampled)):
+            return [np.asarray(t) for t in sampled]
 
     # -------------------------------------------------------------- draft
     def draft_rows(self, ids, rp, cl):

@@ -200,6 +200,11 @@ CHUNK = [
     ("ouro_a8_c256_h16_table48_pool736", 8, 256, 16, 16, 736, 48, False),
     # the fewest K/V heads a bf16 slab's tiling allows
     ("a4_c64_gqa16_2", 4, 64, 16, 2, 512, 32, False),
+    # the two cells' chunk forwards as they are sent since ISSUE 31: one
+    # row a call (``LLMEngine.prefill_rows`` at ``max_prompt_len`` 256)
+    ("mistral_a1_c256_gqa32_8_table256_pool3072", 1, 256, 32, 8, 3072, 256,
+     False),
+    ("ouro_a1_c256_h16_table48_pool736", 1, 256, 16, 16, 736, 48, False),
     # variants of the Mistral cell's shape that no cell runs
     ("cell_window", 16, 256, 32, 8, 3072, 256, False, {"window": 1024}),
     ("cell_partials", 16, 256, 32, 8, 3072, 256, False, {"partials": True}),
@@ -319,11 +324,21 @@ def test_flash_attention_compiles(one_chip, case):
              *[((b, s, h_kv, 128), bf16)] * 2)
 
 
-def test_flash_attention_kv_lens_compiles(one_chip):
-    """The padded-varlen path of the engine's first prefill (8 x 128)."""
+# (id, B, S, H, H_kv): the padded-varlen path of the engine's whole-prompt
+# prefill; the cells' since ISSUE 31 are one row of 256 a call
+FLASH_KV_LENS = [("smoke_8x128x32", 8, 128, 32, 32),
+                 ("mistral_cell_1x256_gqa32_8", 1, 256, 32, 8),
+                 ("ouro_cell_1x256x16", 1, 256, 16, 16)]
+
+
+@pytest.mark.parametrize("case", FLASH_KV_LENS,
+                         ids=[c[0] for c in FLASH_KV_LENS])
+def test_flash_attention_kv_lens_compiles(one_chip, case):
+    _, b, s, h, h_kv = case
     fn = lambda q, k, v, lens: flash_attention(
         q, k, v, causal=True, kv_lens=lens, interpret=False)
-    _compile(fn, one_chip, *[((8, 128, 32, 128), bf16)] * 3, ((8,), i32))
+    _compile(fn, one_chip, ((b, s, h, 128), bf16),
+             *[((b, s, h_kv, 128), bf16)] * 2, ((b,), i32))
 
 
 # (id, rows, experts, k, n, with backward)
