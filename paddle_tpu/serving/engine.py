@@ -375,6 +375,9 @@ class LLMEngine:
             jax.devices()[0])
         self._phase_acc = {p: [0.0, 0, 0, 0] for p in
                            ("prefill", "decode", "spec_draft", "spec_verify")}
+        # the same sums as the last whole tick left them: what a sweep in
+        # the shadow of a tick pushes, that tick's seconds being unknown
+        self._roofline_sums: dict[str, tuple] = {}
         self._tick_phase: dict[str, float] = {}
         self._tick_no = 0                 # the ``serving.step`` span's arg
 
@@ -391,10 +394,14 @@ class LLMEngine:
         self._async_rewound = False
         self._async_draining = False
         # gauge-sweep throttle (PT_GAUGE_EVERY_S): wall-clock of the last
-        # sweep, a force flag set at drain/finish boundaries so run()-end
-        # gauges are exact, and a sweep counter a test reads.
+        # sweep, a force flag set wherever a request leaves its place
+        # (finish, cancel, expiry, preemption, an async drain, a tick
+        # that raises) so that tick ends in an exact sweep, and a sweep
+        # counter a test reads. _gauge_shadowed: this tick swept between
+        # its decode dispatch and its fetch (_sweep_in_shadow).
         self._gauge_t = None
         self._gauge_force = False
+        self._gauge_shadowed = False
         self._gauge_sweeps = 0
         # hidden host time accumulated this tick (drain work overlapped
         # with in-flight device dispatch); observed once per step().
@@ -663,6 +670,7 @@ class LLMEngine:
         if not self._detach(req_id):
             return False                            # mid-transition: punt
         self._release_ledger(req_id)
+        self._gauge_force = True     # cancel or expiry: exact sweep
         # peak attribution survives the free above (the ledger keeps a
         # request's lifetime max past its table drop)
         peak = (sum(self.kv.take_peak(s) for s in sids) if sids
@@ -1277,18 +1285,21 @@ class LLMEngine:
         # preemption rewrites a victim's resume prompt from req.tokens —
         # tokens still in flight in the async window must land first or
         # the replayed stream would silently drop them
-        self._drain_async("boundary")
-        return self.sched.preempt(self, protect_rid)
+        return self._preempted(self.sched.preempt, protect_rid)
 
     _protect = staticmethod(Scheduler._protect)
 
     def _preempt_prefilling(self, protect_rid=None) -> bool:
-        self._drain_async("boundary")
-        return self.sched.preempt_prefilling(self, protect_rid)
+        return self._preempted(self.sched.preempt_prefilling, protect_rid)
 
     def _preempt_from(self, cand) -> bool:
+        return self._preempted(self.sched.preempt_from, cand)
+
+    def _preempted(self, preempt, arg) -> bool:
         self._drain_async("boundary")
-        return self.sched.preempt_from(self, cand)
+        done = preempt(self, arg)
+        self._gauge_force |= done    # a victim left its slot: exact sweep
+        return done
 
     def _allocate_or_preempt(self, rid: int, n_tokens: int, protect=None):
         """mgr.allocate with out-of-blocks recovery: preempt greedy slots
@@ -2079,13 +2090,13 @@ class LLMEngine:
         os_ = np.asarray(offs, np.int64)
         return int((ls * os_ + ls * (ls + 1) // 2).sum())
 
-    def _push_roofline(self):
-        """Fold the cumulative phase accumulators through the roofline
-        choke point (lifetime-average MFU/MBU per phase, same cumulative
-        convention as the spec acceptance-rate gauge)."""
+    def _push_roofline(self, sums):
+        """Fold the cumulative phase accumulators ``sums`` through the
+        roofline choke point (lifetime-average MFU/MBU per phase, same
+        cumulative convention as the spec acceptance-rate gauge)."""
         if self._geom is None:
             return
-        for phase, (sec, tok, passes, ctx) in self._phase_acc.items():
+        for phase, (sec, tok, passes, ctx) in sums.items():
             if sec <= 0.0 or tok <= 0:
                 continue
             geom = self._draft_geom if phase == "spec_draft" else self._geom
@@ -2096,13 +2107,30 @@ class LLMEngine:
                 kv_read_positions=ctx, geom=geom,
                 peak_flops=self._peak_flops, peak_hbm_bps=self._peak_hbm)
 
-    def _refresh_gauges(self, force=False):
+    def _sweep_in_shadow(self, run_mask):
+        """The tick's gauge sweep, between its decode dispatch and its
+        fetch: nothing a sweep reads needs the tick's tokens, and here the
+        host would only wait. Taken when the host knows the tick's end
+        state already: nothing has left its place this tick
+        (``_gauge_force``) and no running row is at its last token. A
+        finish it cannot foresee (EOS, a stream callback's cancel) sets
+        ``_gauge_force``, and the tick sweeps again at its end."""
+        last = run_mask & ~self.is_beam & (self.gen + 1 >= self.max_gen)
+        if self._gauge_force or last.any():
+            return
+        self._gauge_shadowed = True
+        self._refresh_gauges(in_flight=int(run_mask.sum()))
+
+    def _refresh_gauges(self, force=False, in_flight=0):
         """Point-in-time engine state → gauges (queue depth, active
-        slots, KV-pool utilization). Called after every tick and intake
-        mutation. ``PT_GAUGE_EVERY_S`` (default 0 = every tick, so dumps
-        and tests are unchanged) wall-clock-throttles the sweep for
+        slots, KV-pool utilization): once a tick, in the shadow of its
+        decode dispatch (:meth:`_sweep_in_shadow`, which gives
+        ``in_flight``: the rows running, whose token ``cur`` does not
+        count yet) or at its end. ``PT_GAUGE_EVERY_S`` (default 0 = every tick, so
+        dumps and tests are unchanged) wall-clock-throttles the sweep for
         host-bound decode loops; drain/finish boundaries and run()-end
         pass ``force=True`` so final gauge values are always exact."""
+        shadow = in_flight > 0
         if not force:
             try:
                 every = float(os.environ.get("PT_GAUGE_EVERY_S", "0") or 0)
@@ -2113,52 +2141,59 @@ class LLMEngine:
                 return
         self._gauge_t = time.monotonic()
         self._gauge_sweeps += 1
-        if self.async_depth:
-            _ASYNC_DEPTH.set(self.async_depth)
-        _QUEUE_DEPTH.set(len(self.queue))
-        _ACTIVE_SLOTS.set(int(self.active.sum()))
-        used = self.mgr.num_blocks - self.mgr.free_blocks
-        _KV_IN_USE.set(used)
-        _KV_UTIL.set(used / self.mgr.num_blocks if self.mgr.num_blocks
-                     else 0.0)
-        self.kv.push_prefix_metrics()
-        # context parallelism (ISSUE 18): axis size + per-shard block
-        # occupancy under the contiguous split. The gauge family stays
-        # silent at cp=1 (no shard labels registered) so single-device
-        # dumps are byte-identical to pre-cp runs.
-        if self.cp > 1:
-            _CP_AXIS.set(self.cp)
-            ids = (b for t in self.mgr.tables.values() for b in t)
-            for s, n in enumerate(shard_occupancy(
-                    ids, self.mgr.num_blocks, self.cp)):
-                _CP_SHARD_BLOCKS.set(n, shard=str(s))
-        led = self.kv.ledger
-        if led.enabled:
-            led.publish(bytes_per_block=self._kv_block_bytes(),
-                        resident_tokens=self._resident_tokens())
-            # HBM gauges ship continuously, but the jax query is not
-            # tick-cheap — refresh at most once a second (and on the
-            # first sweep, so short runs still export them)
-            now = time.monotonic()
-            if self._dev_mem_t is None or now - self._dev_mem_t >= 1.0:
-                self._dev_mem_t = now
-                try:
-                    device_memory_stats()
-                except Exception:
-                    pass
-        GOODPUT.refresh_gauge()
-        # degradation control loop: the gauge sweep doubles as the poll
-        # cadence. A router-owned controller is polled by the router
-        # only, so N replicas sharing it don't multiply the hysteresis
-        # clock by N.
-        if self.degrade is not None and self.degrade.owner in (None, self):
-            self.degrade.poll()
-        # SLO burn-rate sweep rides the same cadence and the same
-        # ownership protocol (a Router-claimed tracker is polled by the
-        # router only)
-        if self.slo is not None and self.slo.owner in (None, self):
-            self.slo.poll()
-        self._push_roofline()
+        with _span("serving.gauges", shadow=shadow):
+            if self.async_depth:
+                _ASYNC_DEPTH.set(self.async_depth)
+            _QUEUE_DEPTH.set(len(self.queue))
+            _ACTIVE_SLOTS.set(int(self.active.sum()))
+            used = self.mgr.num_blocks - self.mgr.free_blocks
+            _KV_IN_USE.set(used)
+            _KV_UTIL.set(used / self.mgr.num_blocks if self.mgr.num_blocks
+                         else 0.0)
+            self.kv.push_prefix_metrics()
+            # context parallelism (ISSUE 18): axis size + per-shard block
+            # occupancy under the contiguous split. The gauge family stays
+            # silent at cp=1 (no shard labels registered) so single-device
+            # dumps are byte-identical to pre-cp runs.
+            if self.cp > 1:
+                _CP_AXIS.set(self.cp)
+                ids = (b for t in self.mgr.tables.values() for b in t)
+                for s, n in enumerate(shard_occupancy(
+                        ids, self.mgr.num_blocks, self.cp)):
+                    _CP_SHARD_BLOCKS.set(n, shard=str(s))
+            led = self.kv.ledger
+            if led.enabled:
+                led.publish(bytes_per_block=self._kv_block_bytes(),
+                            resident_tokens=(self._resident_tokens()
+                                             + in_flight))
+                # HBM gauges ship continuously, but the jax query is not
+                # tick-cheap — refresh at most once a second (and on the
+                # first sweep, so short runs still export them)
+                now = time.monotonic()
+                if self._dev_mem_t is None or now - self._dev_mem_t >= 1.0:
+                    self._dev_mem_t = now
+                    try:
+                        device_memory_stats()
+                    except Exception:
+                        pass
+            if not shadow:       # else at the tick's end: the emit adds to it
+                GOODPUT.refresh_gauge()
+            # degradation control loop: the gauge sweep doubles as the poll
+            # cadence. A router-owned controller is polled by the router
+            # only, so N replicas sharing it don't multiply the hysteresis
+            # clock by N.
+            if (self.degrade is not None
+                    and self.degrade.owner in (None, self)):
+                self.degrade.poll()
+            # SLO burn-rate sweep rides the same cadence and the same
+            # ownership protocol (a Router-claimed tracker is polled by the
+            # router only)
+            if self.slo is not None and self.slo.owner in (None, self):
+                self.slo.poll()
+            # in the shadow the tick's seconds are not known: push the
+            # sums of whole ticks, which trail by the tick in flight
+            self._push_roofline(self._roofline_sums if shadow
+                                else self._phase_acc)
 
     def _kv_block_bytes(self) -> int:
         """HBM bytes one pool block holds across all layers (K and V,
@@ -2190,9 +2225,13 @@ class LLMEngine:
         t0 = time.monotonic()
         self._tick_phase = {}
         self._tick_no += 1
+        self._gauge_shadowed = False
         with _span("serving.step", tick=self._tick_no):
             try:
                 return self._step_impl()
+            except BaseException:
+                self._gauge_force = True     # whatever state it left
+                raise
             finally:
                 total = time.monotonic() - t0
                 with _span("serving.bookkeeping"):
@@ -2229,8 +2268,12 @@ class LLMEngine:
         if self.async_depth:
             _TICK_HIDDEN.observe(self._hidden_acc)
             self._hidden_acc = 0.0
+        self._roofline_sums = {p: tuple(row) for p, row in acc.items()}
         force, self._gauge_force = self._gauge_force, False
-        self._refresh_gauges(force=force)
+        if force or not self._gauge_shadowed:
+            self._refresh_gauges(force=force)
+        else:
+            GOODPUT.refresh_gauge()
 
     def _step_impl(self):
         """Exception-atomicity shim around :meth:`_step_inner` for the
@@ -2549,6 +2592,10 @@ class LLMEngine:
                 self.top_ps, bool(self.groups),
                 lora=self._lora_arg(d_aidx, 1), bias=d_bias)
             was_active = run_mask.copy()
+            # the program is queued: the copy of its tokens is asked for
+            # now, and the host sweeps its gauges while the device works
+            nxt.copy_to_host_async()
+            self._sweep_in_shadow(run_mask)
             with _span("serving.fetch", cat="device_wait"):
                 nxt = np.asarray(nxt)         # the one per-tick host fetch
         t2 = time.perf_counter()

@@ -110,7 +110,7 @@ class ModelExecutor:
         # program is handed: the weights as they were when it was built
         self._model = _FlatModel(model)
         self.top_k = top_k
-        self.rng = jax.random.PRNGKey(seed)
+        self.rng = jax.random.PRNGKey(seed)     # the setter: no pair held
         self.cp = int(cp)
         self.mesh = None
         # kv_dtype="int8": int8 block pools + parallel per-(position,
@@ -207,9 +207,31 @@ class ModelExecutor:
             functools.partial(_prefix_cow_update, cp_axis="cp"),
             (cs, R, R), cs), donate_argnums=(0,))
 
+    @property
+    def rng(self):
+        """The engine key: what the next :meth:`next_key` splits."""
+        return self._rng
+
+    @rng.setter
+    def rng(self, key):
+        # another key (the async window's rewind, a test): a pair split
+        # ahead from the old one is not its split
+        self._rng, self._split_ahead = key, None
+
     def next_key(self):
-        self.rng, sub = _SPLIT_JIT(self.rng)
+        """The chained split: ``rng, sub = split(rng)``. The pair is the
+        one :meth:`split_ahead` dispatched, where it did."""
+        pair = self._split_ahead or _SPLIT_JIT(self._rng)
+        self._rng, sub = pair
+        self._split_ahead = None
         return sub
+
+    def split_ahead(self):
+        """Dispatch the next :meth:`next_key`'s split now: called with a
+        program just queued, so the split's dispatch costs the host time
+        it would spend waiting, not time the device then idles."""
+        if self._split_ahead is None:
+            self._split_ahead = _SPLIT_JIT(self._rng)
 
     def _no_cp_lora(self, lora):
         if lora is not None and self.cp > 1:
@@ -306,15 +328,16 @@ class ModelExecutor:
                     jnp.asarray(cols), jnp.asarray(vals), sub,
                     jnp.asarray(temps), jnp.asarray(top_ps),
                     None if bias is None else jnp.asarray(bias))
-                return nxt, logp
-            # the staging arrays go in as the numpy arrays they are: the
-            # call uploads them together, where a ``jnp.asarray`` each is
-            # a dispatch each, with the device idle meanwhile
-            nxt, logp, self.cache = _TICK_JIT(
-                self._model, last_tok, self.cache, run_mask, rows, cols,
-                vals, sub, temps, top_ps, self.top_k, need_logp, lora=lora,
-                logit_bias=bias)
-            return nxt, logp
+            else:
+                # the staging arrays go in as the numpy arrays they are:
+                # the call uploads them together, where a ``jnp.asarray``
+                # each is a dispatch each, with the device idle meanwhile
+                nxt, logp, self.cache = _TICK_JIT(
+                    self._model, last_tok, self.cache, run_mask, rows,
+                    cols, vals, sub, temps, top_ps, self.top_k, need_logp,
+                    lora=lora, logit_bias=bias)
+        self.split_ahead()       # the tick is queued: the next key's split
+        return nxt, logp
 
     def decode_tick_async(self, tokens, active, stop, gen, max_gen,
                           temps, top_ps, eos_id):
@@ -367,10 +390,12 @@ class ModelExecutor:
         """Per-row temperature/top-k/top-p sampling, dispatched and not
         waited for: -> the rows' tokens, on the device. ``bias`` ([rows,
         V], 0 / -1e30) is the grammar-mask addend."""
-        return _SAMPLE_ROWS_JIT(
+        sampled = _SAMPLE_ROWS_JIT(
             logits.astype(jnp.float32), self.next_key(), jnp.asarray(temps),
             jnp.asarray(top_ps), self.top_k,
             bias=(None if bias is None else jnp.asarray(bias)))
+        self.split_ahead()       # queued behind the rows' forward
+        return sampled
 
     def fetch_sampled(self, sampled):
         """The host's one wait of a prefill entry: each of
