@@ -55,7 +55,14 @@ class PagedKVCache:
     holds ``passes * N_blocks`` rows, pass ``u``'s copy of block ``b`` at
     row ``u * N_blocks + b`` (``_pass_tables``). Block ids, tables and the
     host's managers stay per-block: one block is one span of tokens in
-    every cache layer."""
+    every cache layer.
+
+    ``states`` is empty for a model whose every layer keeps K/V. A model
+    with recurrent layers (``layer_kinds(cfg)``: the gated delta rule of
+    ``models/olmo_hybrid.py``) holds K/V pools for its full layers only,
+    and for each linear layer a pair ``(S [slots, H, d_k, d_v] float32,
+    conv [slots, K - 1, channels])``: the state a slot's tokens have left
+    there, beside the K/V they left in the pools."""
     k_pools: list   # [L] of [passes * N_blocks, block_size, H_kv, D]
     v_pools: list
     block_tables: jnp.ndarray  # [B, max_blocks] int32 (pad = n_blocks)
@@ -63,6 +70,7 @@ class PagedKVCache:
     k_scales: tuple = ()       # [L] of [passes * N_blocks, block_size, H_kv] f32
     v_scales: tuple = ()
     passes: int = 1
+    states: tuple = ()         # [linear layers] of (S, conv), a row a slot
 
     @property
     def block_size(self):
@@ -115,19 +123,90 @@ class PagedKVCache:
                  kv_dtype=None):
         """The cache of the model ``cfg`` describes: its layers, its
         passes over them, its K/V heads."""
-        return PagedKVCache.init(
-            cfg.num_hidden_layers, num_blocks, block_size,
-            cfg.num_key_value_heads,
+        kinds = layer_kinds(cfg)
+        n_kv = (cfg.num_hidden_layers if kinds is None
+                else kinds.count(FULL_LAYER))
+        cache = PagedKVCache.init(
+            n_kv, num_blocks, block_size,
+            kv_pool_heads(cfg.num_key_value_heads),
             cfg.hidden_size // cfg.num_attention_heads, batch,
             max_blocks_per_seq, cfg.dtype, kv_dtype=kv_dtype,
             passes=cache_passes(cfg))
+        if kinds is not None:
+            cache.states = init_states(cfg, batch,
+                                       kinds.count(LINEAR_LAYER))
+        return cache
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
     lambda c: ((c.k_pools, c.v_pools, c.block_tables, c.lens,
-                c.k_scales, c.v_scales), c.passes),
-    lambda passes, ch: PagedKVCache(*ch, passes))
+                c.k_scales, c.v_scales, c.states), c.passes),
+    lambda passes, ch: PagedKVCache(*ch[:6], passes, ch[6]))
+
+
+def kv_pool_heads(num_kv_heads: int) -> int:
+    """K/V heads a pool row holds for a model of ``num_kv_heads``: that
+    many, or, where they pass the sublane tile of 8 without filling it (30
+    heads), the next multiple (32), the heads past the model's own zero.
+    The paged kernels copy ``[block, H_kv, D]`` slabs of the pool where it
+    lies, and Mosaic copies whole tiles of the last two dims
+    (``decode_slab_is_tiled``): off the tiling a model is served by the
+    gathers, which build a row's whole table width every call. The forwards
+    pad a layer's heads to its pool's (``_pool_heads``), so a cache built
+    by hand at the model's own head count is served as before."""
+    if num_kv_heads <= 8 or num_kv_heads % 8 == 0:
+        return num_kv_heads
+    return -(-num_kv_heads // 8) * 8
+
+
+def _pool_heads(x, heads: int):
+    """[..., H, D] -> [..., ``heads``, D]: the heads a pool row has past
+    the model's own are zero (their scores are never read)."""
+    if x.shape[-2] == heads:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[-2] = (0, heads - x.shape[-2])
+    return jnp.pad(x, pad)
+
+
+LINEAR_LAYER, FULL_LAYER = "linear_attention", "full_attention"
+
+
+def layer_kinds(cfg):
+    """A model's layers by kind, from what its configuration says of them
+    (``layer_types``, one name a layer), or None where every layer keeps
+    K/V: the one-kind model every forward here served before."""
+    kinds = getattr(cfg, "layer_types", None)
+    if not kinds or LINEAR_LAYER not in kinds[:cfg.num_hidden_layers]:
+        return None
+    return tuple(kinds[:cfg.num_hidden_layers])
+
+
+def state_shapes(cfg):
+    """(recurrent state, conv state) of one linear layer and one slot:
+    ``S`` a head in ``R^{d_k x d_v}``, and the last ``K - 1`` inputs of
+    the depthwise convolution over the q, k and v channels."""
+    h, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    return ((h, dk, dv), (cfg.linear_conv_kernel_dim - 1, h * (2 * dk + dv)))
+
+
+def init_states(cfg, rows, layers):
+    """``rows`` zeroed states (a slot's, or a snapshot pool's entries) for
+    each of ``layers`` linear layers: float32 ``S``, the conv inputs in
+    the model's dtype."""
+    s_shape, c_shape = state_shapes(cfg)
+    return tuple((jnp.zeros((rows,) + s_shape, jnp.float32),
+                  jnp.zeros((rows,) + c_shape, cfg.dtype))
+                 for _ in range(layers))
+
+
+def state_bytes(states) -> int:
+    """HBM bytes ONE row (a slot, a snapshot) holds over all linear
+    layers, as the arrays are logically sized."""
+    return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+               for pair in states for a in pair)
 
 
 def cache_passes(cfg) -> int:
@@ -354,14 +433,23 @@ class PrefixMatch:
     a private copy of ``src_block`` and prefills from token ``hit``
     inside it. ``len()`` is the number of fully-shared blocks so the
     scheduler's block-denominated reservation math stays
-    manager-agnostic; truthiness is any token hit at all."""
+    manager-agnostic; truthiness is any token hit at all.
 
-    __slots__ = ("blocks", "token_count", "cow")
+    ``snapshot`` is ``(token depth, entry)`` of the deepest recurrent-state
+    snapshot on the matched path, None where there is none (always, for a
+    model whose every layer keeps K/V); ``offered`` the tokens the K/V
+    match alone found, which ``state_hit`` keeps when it cuts the match
+    down to that snapshot."""
 
-    def __init__(self, blocks, token_count, cow=None):
+    __slots__ = ("blocks", "token_count", "cow", "snapshot", "offered")
+
+    def __init__(self, blocks, token_count, cow=None, snapshot=None,
+                 offered=None):
         self.blocks = blocks
         self.token_count = token_count
         self.cow = cow
+        self.snapshot = snapshot
+        self.offered = token_count if offered is None else offered
 
     def __len__(self):
         return len(self.blocks)
@@ -382,7 +470,7 @@ class _RadixNode:
     hold its KV. Spans start block-aligned; only a childless tail may be
     partial (len(tokens) % block_size != 0)."""
 
-    __slots__ = ("tokens", "blocks", "children", "parent", "touch")
+    __slots__ = ("tokens", "blocks", "children", "parent", "touch", "snaps")
 
     def __init__(self, tokens, blocks, parent):
         self.tokens = tokens          # np.int32 span
@@ -390,6 +478,9 @@ class _RadixNode:
         self.children = []            # children start block-aligned
         self.parent = parent
         self.touch = 0
+        # recurrent-state snapshots this span owns: {tokens into the span
+        # (block-aligned, > 0): snapshot entry}; None until one is taken
+        self.snaps = None
 
 
 class _PendingCopy:
@@ -451,6 +542,142 @@ class RadixPrefixBlockManager(RefBlockManager):
         self.cache_stats = {"hit_blocks": 0, "evictions": 0,
                             "lookup_blocks": 0, "token_hits": 0,
                             "partial_hits": 0, "lookup_tokens": 0}
+        self.snap_capacity = 0       # > 0 after enable_snapshots
+
+    # ---- recurrent-state snapshots (a model with linear layers): K/V
+    # blocks of a matched prefix say nothing of the state at that depth,
+    # so a hit is worth only as far as a snapshot exists. A fixed pool of
+    # ``capacity`` entries (the device arrays are the executor's); an entry
+    # is owned by a trie position (a node, tokens into it), leaves with
+    # the blocks under it, and the least recently restored goes first
+    # when the pool is full.
+    def enable_snapshots(self, capacity: int):
+        self.snap_capacity = int(capacity)
+        self._lru_leaf = self._lru_leaf_keeping_snapshots
+        self._snap_free = list(range(self.snap_capacity - 1, -1, -1))
+        self._snap_home: dict[int, tuple] = {}   # entry -> (node, offset)
+        self._snap_touch: dict[int, int] = {}
+        self._snap_pending: set[int] = set()     # reserved, not yet owned
+        # snap_offered_tokens: prompt tokens the K/V match alone offered
+        # the admissions; beside ``token_hits`` (what was adopted: tokens
+        # whose state came from a snapshot) the price of the rule of a hit
+        self.cache_stats.update(snap_taken=0, snap_restored=0,
+                                snap_evicted=0, snap_dropped=0,
+                                snap_offered_tokens=0)
+        self.ledger.set_snapshots(0, self.snap_capacity)
+
+    def snapshots_held(self) -> int:
+        """Entries a trie position owns or an admission has reserved."""
+        return self.snap_capacity - len(self._snap_free) \
+            if self.snap_capacity else 0
+
+    def snapshot_audit(self) -> dict:
+        """The pool as the manager holds it, for the ledger's identity and
+        the quiescence check: entries ``owned`` by a trie position,
+        ``reserved`` by an admission and ``free``, of ``capacity``, and
+        the ``misplaced`` ones, whose trie position does not hold them."""
+        if not self.snap_capacity:
+            return {"owned": 0, "reserved": [], "free": 0, "capacity": 0,
+                    "misplaced": []}
+        return {"owned": len(self._snap_home),
+                "reserved": sorted(self._snap_pending),
+                "free": len(self._snap_free), "capacity": self.snap_capacity,
+                "misplaced": [idx for idx, (node, off)
+                              in self._snap_home.items()
+                              if (node.snaps or {}).get(off) != idx
+                              or off > len(node.tokens)]}
+
+    def _snap_changed(self):
+        self.cache_epoch += 1
+        self.ledger.set_snapshots(self.snapshots_held(), self.snap_capacity)
+
+    def _forget_snapshot(self, idx: int, stat: str):
+        node, off = self._snap_home.pop(idx)
+        del node.snaps[off]
+        self._snap_touch.pop(idx, None)
+        self._snap_free.append(idx)
+        self.cache_stats[stat] += 1
+        self._snap_changed()
+
+    def _drop_snaps_past(self, node, n_tokens: int):
+        """The snapshots of ``node`` deeper than ``n_tokens`` into it go
+        with the blocks that held their prefix."""
+        for off in [o for o in (node.snaps or ()) if o > n_tokens]:
+            self._forget_snapshot(node.snaps[off], "snap_dropped")
+
+    def state_hit(self, match: PrefixMatch) -> PrefixMatch:
+        """THE RULE OF A HIT for a model with recurrent layers: the K/V
+        match cut down to its deepest snapshot (block-aligned by
+        construction, so no copy-on-write tail); nothing where the path
+        has none."""
+        depth = match.snapshot[0] if match.snapshot else 0
+        return PrefixMatch(match.blocks[:depth // self.block_size], depth,
+                           None, match.snapshot, match.token_count)
+
+    def restored_snapshot(self, idx: int):
+        """An admission restored entry ``idx``: it is the most recently
+        used."""
+        self._touch += 1
+        self._snap_touch[idx] = self._touch
+        self.cache_stats["snap_restored"] += 1
+
+    def reserve_snapshot(self):
+        """An entry for a snapshot about to be taken, or None (no pool):
+        a free one, else the least recently restored one's, which leaves
+        its trie position now. -> (entry, whether one was evicted)."""
+        if not self.snap_capacity:
+            return None
+        evicted = False
+        if not self._snap_free:
+            if not self._snap_home:
+                return None              # every entry reserved, none owned
+            self._forget_snapshot(
+                min(self._snap_home, key=lambda i: self._snap_touch[i]),
+                "snap_evicted")
+            evicted = True
+        idx = self._snap_free.pop()
+        self._snap_pending.add(idx)
+        self._snap_changed()
+        return idx, evicted
+
+    def release_snapshot(self, idx: int):
+        """A reserved entry whose snapshot was never taken (its request
+        left before reaching the depth)."""
+        self._snap_pending.discard(idx)
+        self._snap_free.append(idx)
+        self._snap_changed()
+
+    def attach_snapshot(self, tokens, depth: int, idx: int, adapter=None):
+        """Entry ``idx`` (reserved) now holds the state after
+        ``tokens[:depth]``: hand it to the trie position at that depth.
+        False (and the entry is free again) where the path no longer
+        reaches that far or the position has a snapshot already."""
+        toks = np.asarray(tokens, np.int32).reshape(-1)[:depth]
+        node, d = self._root_for(adapter), 0
+        while d < depth:
+            best, bl = self._best_child(node, toks[d:])
+            if best is None or bl == 0:
+                break
+            if d + bl == depth:
+                off = depth - d
+                if best.snaps is None:
+                    best.snaps = {}
+                if off in best.snaps:
+                    break
+                self._snap_pending.discard(idx)
+                best.snaps[off] = idx
+                self._snap_home[idx] = (best, off)
+                self._touch += 1
+                self._snap_touch[idx] = self._touch
+                self.cache_stats["snap_taken"] += 1
+                self._snap_changed()
+                return True
+            if bl < len(best.tokens):
+                break
+            node, d = best, d + bl
+        self.cache_stats["snap_dropped"] += 1
+        self.release_snapshot(idx)
+        return False
 
     # ---- capacity: parked trie blocks are reclaimable, so count as free
     @property
@@ -464,13 +691,9 @@ class RadixPrefixBlockManager(RefBlockManager):
             return self._evict_one()
         raise MemoryError("paged cache out of blocks")
 
-    def _evict_one(self) -> int:
-        """Reclaim ONE parked block: the tail block of the least-recently
-        touched childless leaf whose tail is unreferenced. Because
-        adoption always takes the full matched path and release frees a
-        table all at once, a parked block's whole suffix (deeper blocks
-        of its node + every descendant) is parked too — so such a leaf
-        always exists while ``_parked`` is non-empty."""
+    def _lru_leaf(self):
+        """The least-recently touched childless leaf whose tail block is
+        unreferenced, None if there is none."""
         victim = None
         stack = [ch for root in self._roots.values()
                  for ch in root.children]
@@ -481,6 +704,37 @@ class RadixPrefixBlockManager(RefBlockManager):
             elif node.blocks and node.blocks[-1] in self._parked:
                 if victim is None or node.touch < victim.touch:
                     victim = node
+        return victim
+
+    def _lru_leaf_keeping_snapshots(self):
+        """``_lru_leaf`` for a manager with a snapshot pool (bound in its
+        place by ``enable_snapshots``, so a manager without one runs the
+        scan it always ran): a span that owns a state snapshot was seen
+        twice at least, so the oldest of those (``kept``) goes only when no
+        other span can."""
+        victim = kept = None
+        stack = [ch for root in self._roots.values()
+                 for ch in root.children]
+        while stack:
+            node = stack.pop()
+            if node.children:
+                stack.extend(node.children)
+            elif node.blocks and node.blocks[-1] in self._parked:
+                if node.snaps:
+                    if kept is None or node.touch < kept.touch:
+                        kept = node
+                elif victim is None or node.touch < victim.touch:
+                    victim = node
+        return victim or kept
+
+    def _evict_one(self) -> int:
+        """Reclaim ONE parked block: the tail block of the least-recently
+        touched childless leaf whose tail is unreferenced. Because
+        adoption always takes the full matched path and release frees a
+        table all at once, a parked block's whole suffix (deeper blocks
+        of its node + every descendant) is parked too — so such a leaf
+        always exists while ``_parked`` is non-empty."""
+        victim = self._lru_leaf()
         if victim is None:       # unreachable by the suffix invariant
             raise MemoryError("paged cache out of blocks")
         from paddle_tpu.utils.faults import fault_point
@@ -494,6 +748,8 @@ class RadixPrefixBlockManager(RefBlockManager):
         del self._in_trie[blk]
         victim.tokens = victim.tokens[:len(victim.blocks)
                                       * self.block_size]
+        if victim.snaps:
+            self._drop_snaps_past(victim, len(victim.tokens))
         if not victim.blocks and victim.parent is not None:
             victim.parent.children.remove(victim)
         self.cache_stats["evictions"] += 1
@@ -555,12 +811,16 @@ class RadixPrefixBlockManager(RefBlockManager):
         self.cache_stats["lookup_tokens"] += max(cap, 0)
         self._touch += 1
         node, depth = self._root_for(adapter), 0
-        blocks, cow = [], None
+        blocks, cow, snap = [], None, None
         while depth < cap:
             best, bl = self._best_child(node, toks[depth:cap])
             if best is None or bl == 0:
                 break
             best.touch = self._touch
+            if best.snaps:
+                off = max((o for o in best.snaps if o <= bl), default=0)
+                if off:
+                    snap = (depth + off, best.snaps[off])
             if bl == len(best.tokens) and bl % bs == 0:
                 blocks.extend(best.blocks)
                 depth += bl
@@ -575,7 +835,7 @@ class RadixPrefixBlockManager(RefBlockManager):
                 cow = (best.blocks[n_full], hit)
             depth += bl
             break
-        return PrefixMatch(blocks, depth, cow)
+        return PrefixMatch(blocks, depth, cow, snap)
 
     # --------------------------------------------------------- adoption
     def adopt_prefix(self, seq_id, match) -> list:
@@ -764,6 +1024,12 @@ class RadixPrefixBlockManager(RefBlockManager):
         upper.children.append(node)
         for b in upper.blocks:
             self._in_trie[b] = upper
+        if node.snaps:
+            snaps, node.snaps, upper.snaps = node.snaps, {}, {}
+            for off, idx in snaps.items():
+                home = (upper, off) if off <= sp else (node, off - sp)
+                home[0].snaps[home[1]] = idx
+                self._snap_home[idx] = home
         return upper
 
 
@@ -892,12 +1158,58 @@ def _residual(x, branch, lyr, norm_name):
     return x + (branch if norm is None else norm(branch))
 
 
+def _pre_norm(x, lyr, norm_name):
+    """The layer's norm of a branch's input, where it has one (an Olmo
+    block norms each branch's output alone)."""
+    norm = getattr(lyr, norm_name, None)
+    return x if norm is None else norm(x)
+
+
+def _qk_norm(att, q, k):
+    """q and k through the attention module's norms over the whole
+    projection, before RoPE, where it has them."""
+    if getattr(att, "q_norm", None) is None:
+        return q, k
+    return att.q_norm(q), att.k_norm(k)
+
+
 def _mlp_residual(x, lyr):
-    return _residual(x, _mlp_out(lyr, lyr.post_attention_layernorm(x)), lyr,
-                     "post_attention_layernorm_2")
+    return _residual(
+        x, _mlp_out(lyr, _pre_norm(x, lyr, "post_attention_layernorm")),
+        lyr, "post_attention_layernorm_2")
 
 
-def _run_stack(model, cache, x, tables, layer):
+def _linear_residual(x, lyr, state, lens, rows=None, fresh=None):
+    """A linear layer's body, shared by the three paged forwards: the
+    mixer from each row's state, its branch norm and add, the MLP.
+    ``state`` is the layer's ``(S, conv)`` over all slots; ``rows`` [A]
+    the slots the rows of ``x`` belong to (None: row i is slot i), a
+    sentinel >= slots a dead row whose state goes nowhere; ``fresh`` [A]
+    marks rows that start from the zero state (a prompt's first token:
+    a slot's state is zeroed by the program that starts its prompt, not
+    by the host), None: every row goes on from its slot's state. -> (x,
+    the layer's state)."""
+    s_all, c_all = state
+    if rows is None:
+        s_in, c_in = s_all, c_all
+    else:
+        at = jnp.minimum(rows, s_all.shape[0] - 1)
+        s_in, c_in = s_all[at], c_all[at]
+    if fresh is not None:
+        s_in = jnp.where(fresh[:, None, None, None], 0.0, s_in)
+        c_in = jnp.where(fresh[:, None, None], 0, c_in).astype(c_all.dtype)
+    with jax.named_scope("attention"):
+        y, s_out, c_out = lyr.linear_attn.mix(x, s_in, c_in, lens)
+        x = _residual(x, y, lyr, "input_layernorm_2")
+    if rows is None:
+        state = (s_out, c_out)
+    else:
+        state = (s_all.at[rows].set(s_out, mode="drop"),
+                 c_all.at[rows].set(c_out, mode="drop"))
+    return _mlp_residual(x, lyr), state
+
+
+def _run_stack(model, cache, x, tables, layer, linear=None):
     """The decoder stack over ``x``, shared by the three paged forwards:
     every layer once and then the final norm; for a looped model
     (``cache.passes`` > 1) that whole pass ``passes`` times under one
@@ -905,41 +1217,62 @@ def _run_stack(model, cache, x, tables, layer):
     one before and writing pool rows of its own (``_pass_tables``), so the
     program holds one body a layer however many passes there are.
 
-    ``layer(x, li, lyr, pools, tables) -> (x, pools)`` is the caller's
-    layer body; ``pools`` is the layer's (k_pool, v_pool, k_scale,
-    v_scale), the scales None for a bf16 cache. Returns (the normed x,
-    k_pools, v_pools, k_scales, v_scales)."""
+    ``layer(x, ci, lyr, pools, tables) -> (x, pools)`` is the caller's
+    body of a layer that keeps K/V, ``ci`` its index among those and
+    ``pools`` its (k_pool, v_pool, k_scale, v_scale), the scales None for a
+    bf16 cache. ``linear(x, lyr, state) -> (x, state)`` is its body of a
+    layer that carries a recurrent state (``layer_kinds``), ``state`` that
+    layer's entry of ``cache.states``. Returns (the normed x, the cache's
+    fields as the stack left them, for ``dataclasses.replace``)."""
     bb = _backbone(model)
     if cache.passes != cache_passes(model.cfg):
         raise ValueError(
             f"the cache holds {cache.passes} pass(es) a layer, the model "
             f"runs {cache_passes(model.cfg)}: build it with "
             "PagedKVCache.init_for(model.cfg, ...)")
+    kinds = layer_kinds(model.cfg)
+    if kinds is not None and (
+            len(cache.states) != kinds.count(LINEAR_LAYER)
+            or len(cache.k_pools) != kinds.count(FULL_LAYER)):
+        raise ValueError(
+            f"the cache holds {len(cache.k_pools)} K/V pool(s) and "
+            f"{len(cache.states)} recurrent state(s), the model's layers "
+            f"are {kinds}: build it with PagedKVCache.init_for(model.cfg, "
+            "...)")
 
-    def one_pass(x, pools, tables):
+    def one_pass(x, pools, states, tables):
         k, v, ks, vs = (list(p) for p in pools)
+        states = list(states)
+        ci = si = 0
         for li, lyr in enumerate(bb.layers):
-            x, (k[li], v[li], ks[li], vs[li]) = layer(
-                x, li, lyr, (k[li], v[li], ks[li], vs[li]), tables)
-        return bb.norm(x), (k, v, ks, vs)
+            if kinds is not None and kinds[li] == LINEAR_LAYER:
+                x, states[si] = linear(x, lyr, states[si])
+                si += 1
+                continue
+            x, (k[ci], v[ci], ks[ci], vs[ci]) = layer(
+                x, ci, lyr, (k[ci], v[ci], ks[ci], vs[ci]), tables)
+            ci += 1
+        return bb.norm(x), (k, v, ks, vs), tuple(states)
 
     # a bf16 cache has no scale pools: a None a layer stands in for them
-    no_scales = [None] * len(bb.layers)
+    no_scales = [None] * len(cache.k_pools)
     pools = (cache.k_pools, cache.v_pools, list(cache.k_scales) or no_scales,
              list(cache.v_scales) or no_scales)
     if cache.passes == 1:
-        x, pools = one_pass(x, pools, tables)
+        x, pools, states = one_pass(x, pools, cache.states, tables)
     else:
         def body(u, carry):
             with jax.named_scope("ut_step"):
                 return one_pass(*carry, _pass_tables(
                     tables, u, cache.num_blocks, cache.passes))
-        x, pools = jax.lax.fori_loop(
-            0, cache.passes, body, (x, tuple(list(p) for p in pools)))
+        x, pools, states = jax.lax.fori_loop(
+            0, cache.passes, body,
+            (x, tuple(list(p) for p in pools), cache.states))
     k, v, ks, vs = pools
     if not cache.k_scales:
         ks = vs = ()
-    return x, k, v, tuple(ks), tuple(vs)
+    return x, dict(k_pools=k, v_pools=v, k_scales=tuple(ks),
+                   v_scales=tuple(vs), states=states)
 
 
 def is_moe_model(model) -> bool:
@@ -1031,7 +1364,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     rows = cache.pool_rows
 
     def layer(x, li, lyr, pools, rtables):
-        h = lyr.input_layernorm(x)
+        h = _pre_norm(x, lyr, "input_layernorm")
         with jax.named_scope("attention"):
             att = lyr.self_attn
             qkv = _wo(h, att.qkv_proj)
@@ -1041,6 +1374,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
                 qkv = qkv + att.qkv_bias
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q, k = _qk_norm(att, q, k)
             q = A.apply_rope(q.reshape(b, s, nh, hd), cos, sin)
             k = A.apply_rope(k.reshape(b, s, nkv, hd), cos, sin)
             v = v.reshape(b, s, nkv, hd)
@@ -1050,8 +1384,10 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
             out = A.scaled_dot_product_attention(
                 q, k, v, is_causal=True, kv_lens=prompt_lens,
                 window=getattr(cfg, "sliding_window", None))
-            pools = _scatter_kv(pools, k, v, _scatter_prefill, rtables,
-                                prompt_lens, rows, bs)
+            hp = pools[0].shape[2]
+            pools = _scatter_kv(pools, _pool_heads(k, hp), _pool_heads(v, hp),
+                                _scatter_prefill, rtables, prompt_lens, rows,
+                                bs)
             attn_out = out.reshape(b, s, nh * hd)
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
@@ -1059,15 +1395,18 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
             x = _residual(x, proj, lyr, "input_layernorm_2")
         return _mlp_residual(x, lyr), pools
 
-    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
-        model, cache, x, rtables, layer)
+    def linear(x, lyr, state):
+        # every row is a prompt's start: from the zero state into its slot
+        return _linear_residual(x, lyr, state, prompt_lens, slot_ids,
+                                jnp.ones((b,), bool))
+
+    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
     logits = _model_logits(model, x)
     last = jnp.take_along_axis(
         logits, jnp.maximum(prompt_lens - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)[:, 0]
-    new_cache = replace(cache, k_pools=k_pools, v_pools=v_pools,
-                        block_tables=new_tables, lens=new_lens,
-                        k_scales=k_scales, v_scales=v_scales)
+    new_cache = replace(cache, block_tables=new_tables, lens=new_lens,
+                        **fields)
     return last, new_cache
 
 
@@ -1089,7 +1428,7 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
     rows = cache.pool_rows
 
     def layer(x, li, lyr, pools, rtables):
-        h = lyr.input_layernorm(x)
+        h = _pre_norm(x, lyr, "input_layernorm")
         with jax.named_scope("attention"):
             att = lyr.self_attn
             qkv = _wo(h, att.qkv_proj)
@@ -1099,12 +1438,15 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
                 qkv = qkv + att.qkv_bias
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q, k = _qk_norm(att, q, k)
             q = _apply_rope_rows(q.reshape(b, 1, nh, hd), cos, sin)
             k = _apply_rope_rows(k.reshape(b, 1, nkv, hd), cos, sin)
             v = v.reshape(b, 1, nkv, hd)
+            hp = pools[0].shape[2]
+            q = _pool_heads(q, hp * (nh // nkv))
             pools = k_pool, v_pool, ks, vs = _scatter_kv(
-                pools, k, v, _scatter_decode, rtables, cache.lens, active,
-                rows, bs)
+                pools, _pool_heads(k, hp), _pool_heads(v, hp),
+                _scatter_decode, rtables, cache.lens, active, rows, bs)
             # sliding-window configs: the pool retains all tokens (blocks
             # below the window could be recycled — not done yet) but decode
             # attends only the last `window` positions, matching prefill
@@ -1124,19 +1466,21 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
                     window=window, k_scale=ks, v_scale=vs, partials=True)
                 o_p, m_p, l_p = psum_merge_partials(o_p, m_p, l_p, cp_axis)
                 out = finalize_partials(o_p, l_p, q.dtype)
-            attn_out = out.reshape(b, 1, nh * hd)
+            attn_out = out[..., :nh, :].reshape(b, 1, nh * hd)
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
             x = _residual(x, proj, lyr, "input_layernorm_2")
         return _mlp_residual(x, lyr), pools
 
-    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
-        model, cache, x, rtables, layer)
+    def linear(x, lyr, state):
+        # one token a slot, each from its slot's state, in place; a slot
+        # that does not run (length 0) keeps its state
+        return _linear_residual(x, lyr, state, active.astype(jnp.int32))
+
+    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
     logits = _model_logits(model, x)[:, 0]
-    return logits, replace(cache, k_pools=k_pools, v_pools=v_pools,
-                           lens=new_lens, k_scales=k_scales,
-                           v_scales=v_scales)
+    return logits, replace(cache, lens=new_lens, **fields)
 
 
 def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
@@ -1257,8 +1601,11 @@ def clear_jit_caches():
         _async_tick_jit().clear_cache()
     for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _PREFILL_CHUNK_JIT,
               _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT, _PREFIX_COW_JIT,
-              _paged_chunk_call, *_EXTRA_CLEAR):
+              _STATE_TAKE_JIT, _STATE_RESTORE_JIT, _paged_chunk_call,
+              *_EXTRA_CLEAR):
         f.clear_cache()
+    from paddle_tpu.ops.pallas import gated_delta
+    gated_delta.clear_caches()
 
 
 def _copy_partial_blocks(pools, copy_src, copy_dst):
@@ -1341,6 +1688,25 @@ def _prefix_cow_update(cache: PagedKVCache, copy_src, copy_dst,
 
 
 _PREFIX_COW_JIT = jax.jit(_prefix_cow_update, donate_argnums=(0,))
+
+
+def _state_take(snaps, states, slot, idx):
+    """Snapshot pool entry ``idx`` <- slot ``slot``'s state, every linear
+    layer's (``snaps`` is laid out as ``cache.states``, a row an entry)."""
+    return tuple(tuple(pool.at[idx].set(live[slot])
+                       for pool, live in zip(pair, state))
+                 for pair, state in zip(snaps, states))
+
+
+def _state_restore(cache: PagedKVCache, snaps, slot, idx):
+    """Slot ``slot``'s state <- snapshot pool entry ``idx``."""
+    return replace(cache, states=tuple(
+        tuple(live.at[slot].set(pool[idx]) for pool, live in zip(pair, state))
+        for pair, state in zip(snaps, cache.states)))
+
+
+_STATE_TAKE_JIT = jax.jit(_state_take, donate_argnums=(0,))
+_STATE_RESTORE_JIT = jax.jit(_state_restore, donate_argnums=(0,))
 
 
 def _beam_select(running_lp, seqs, fin_seqs, fin_scores, logp, i,
@@ -1645,7 +2011,7 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
     rows = cache.pool_rows
 
     def layer(x, li, lyr, pools, rtables):
-        h = lyr.input_layernorm(x)
+        h = _pre_norm(x, lyr, "input_layernorm")
         with jax.named_scope("attention"):
             att = lyr.self_attn
             qkv = _wo(h, att.qkv_proj)
@@ -1655,13 +2021,17 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
                 qkv = qkv + att.qkv_bias
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q, k = _qk_norm(att, q, k)
             q = rope(q.reshape(a, c, nh, hd))
             k = rope(k.reshape(a, c, nkv, hd))
             v = v.reshape(a, c, nkv, hd)
+            hp = pools[0].shape[2]
+            q = _pool_heads(q, hp * (nh // nkv))
             # scatter the chunk FIRST so the gathered view holds prefix+chunk
             pools = k_pool, v_pool, ks, vs = _scatter_kv(
-                pools, k, v, _scatter_decode_chunk, rtables, offsets,
-                chunk_lens, rows, bs)
+                pools, _pool_heads(k, hp), _pool_heads(v, hp),
+                _scatter_decode_chunk, rtables, offsets, chunk_lens, rows,
+                bs)
             # ragged pool-direct attention: the kernel reads only each row's
             # live blocks (the XLA fallback reconstructs the old full
             # gather + dense-mask view, bit-compatible)
@@ -1674,19 +2044,23 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
                     q, k_pool, v_pool, rtables, offsets, chunk_lens,
                     window=window, k_scale=ks, v_scale=vs, partials=True)
                 out = _cp_merge_chunk(o_p, m_p, l_p, cp_axis, q.dtype)
-            attn_out = out.reshape(a, c, nh * hd)
+            attn_out = out[..., :nh, :].reshape(a, c, nh * hd)
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
             x = _residual(x, proj, lyr, "input_layernorm_2")
         return _mlp_residual(x, lyr), pools
 
-    x, k_pools, v_pools, k_scales, v_scales = _run_stack(
-        model, cache, x, rtables, layer)
+    def linear(x, lyr, state):
+        # a chunk goes on from the state its slot's earlier chunks (or a
+        # restored snapshot) left; a chunk at offset 0 starts a prompt
+        return _linear_residual(x, lyr, state, chunk_lens, slot_ids,
+                                offsets == 0)
+
+    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
     logits = _model_logits(model, x)
-    new_cache = replace(cache, k_pools=k_pools, v_pools=v_pools,
-                        block_tables=new_tables, lens=new_lens,
-                        k_scales=k_scales, v_scales=v_scales)
+    new_cache = replace(cache, block_tables=new_tables, lens=new_lens,
+                        **fields)
     if full_logits:
         return logits, new_cache
     last = jnp.take_along_axis(

@@ -127,6 +127,10 @@ class MemLedger:
         self.peak_states = dict.fromkeys(self.STATES, 0)   # per-publish max
         self.bytes_per_token = 0.0
         self.peak_bytes_per_token = 0.0
+        # a sixth kind of memory beside the five block states: entries of
+        # the recurrent-state snapshot pool a trie position owns or an
+        # admission has reserved, of its fixed capacity (0: no such pool)
+        self._snapshots = (0, 0)
         _LEDGERS.add(self)
 
     @property
@@ -212,6 +216,18 @@ class MemLedger:
             return
         self._reserved = max(0, int(n))
 
+    def set_snapshots(self, held: int, capacity: int):
+        """Mirror of the radix manager's snapshot pool: entries held (owned
+        by a trie position, or reserved) of its capacity."""
+        if not self._enabled:
+            return
+        self._snapshots = (int(held), int(capacity))
+
+    @property
+    def snapshots(self) -> tuple:
+        """(held, capacity) of the recurrent-state snapshot pool."""
+        return self._snapshots
+
     # ---------------------------------------------------------- reads
     def _classify_locked(self) -> dict:
         """The five-state breakdown from the transition mirrors.
@@ -263,7 +279,9 @@ class MemLedger:
             return "disabled (PT_MEM_LEDGER=0)"
         c = self.counts()
         body = " ".join(f"{s}={c[s]}" for s in self.STATES)
-        return f"{body} (of {self.num_blocks})"
+        held, cap = self._snapshots
+        snaps = f" state_snapshots={held}/{cap}" if cap else ""
+        return f"{body} (of {self.num_blocks}){snaps}"
 
     def snapshot(self) -> dict:
         """JSON-safe pool document (/memory, flight dumps)."""
@@ -278,6 +296,8 @@ class MemLedger:
                 "num_blocks": self.num_blocks,
                 "block_size": self.block_size,
                 "states": c, "reserved_promised": self._reserved,
+                "state_snapshots": {"held": self._snapshots[0],
+                                    "capacity": self._snapshots[1]},
                 "fragmentation": round(self.fragmentation(), 6),
                 "bytes_per_token": round(self.bytes_per_token, 3),
                 "stalls": stalls, "top_holders": top}
@@ -416,6 +436,18 @@ class MemLedger:
         if sum(counts.values()) != self.num_blocks:
             diffs.append(f"sum(states) = {sum(counts.values())} != "
                          f"num_blocks = {self.num_blocks}")
+        if getattr(mgr, "snap_capacity", 0):
+            # the sixth kind: every entry is free, reserved, or owned by
+            # exactly one trie position that still has blocks under it
+            held, a = self.snapshots[0], mgr.snapshot_audit()
+            if (a["owned"] + len(a["reserved"]) != held
+                    or held + a["free"] != a["capacity"]):
+                diffs.append(
+                    f"state snapshots: {a['owned']} owned + "
+                    f"{len(a['reserved'])} reserved + {a['free']} free, "
+                    f"ledger holds {held} of {a['capacity']}")
+            diffs.extend(f"state snapshot {idx}: its trie position does not "
+                         "hold it" for idx in a["misplaced"])
         return {"ok": not diffs, "diffs": diffs[:20], "counts": counts,
                 "walk": walk}
 

@@ -118,6 +118,15 @@ class ModelGeometry:
     # (o, m, l) triple per layer); billed as extra bytes so
     # serving_mbu{decode} stays honest about the per-step gather cost.
     cp: int = 1
+    # layers that carry a recurrent state and keep no K/V (the gated delta
+    # rule of models/olmo_hybrid.py): ``linear_layers`` of ``num_layers``,
+    # each with ``linear_heads`` states of linear_key_dim x linear_value_dim
+    # float32 and the last ``linear_conv - 1`` inputs of its convolution
+    linear_layers: int = 0
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 0
 
     @classmethod
     def from_config(cls, cfg, dtype_bytes: int = 2) -> "ModelGeometry":
@@ -132,11 +141,20 @@ class ModelGeometry:
         # a looped model applies its layers ``total_ut_steps`` times a
         # token: its weights stream that often and every pass keeps K/V
         passes = int(getattr(cfg, "total_ut_steps", 1))
+        kinds = tuple(getattr(cfg, "layer_types", None)
+                      or ())[:int(cfg.num_hidden_layers)]
+        linear = {}
+        if "linear_attention" in kinds:
+            linear = dict(linear_layers=kinds.count("linear_attention"),
+                          linear_heads=int(cfg.linear_num_key_heads),
+                          linear_key_dim=int(cfg.linear_key_head_dim),
+                          linear_value_dim=int(cfg.linear_value_head_dim),
+                          linear_conv=int(cfg.linear_conv_kernel_dim))
         return cls(num_layers=int(cfg.num_hidden_layers) * passes, hidden=h,
                    intermediate=inter, vocab=int(cfg.vocab_size), heads=nh,
                    kv_heads=int(getattr(cfg, "num_key_value_heads", nh)),
                    head_dim=h // nh, dtype_bytes=int(dtype_bytes),
-                   num_experts=experts, experts_per_tok=per_tok)
+                   num_experts=experts, experts_per_tok=per_tok, **linear)
 
     # ---- derived counts -------------------------------------------------
     @property
@@ -144,6 +162,26 @@ class ModelGeometry:
         """Fused qkv + output projection."""
         return (self.hidden * (self.heads + 2 * self.kv_heads)
                 * self.head_dim + self.heads * self.head_dim * self.hidden)
+
+    @property
+    def linear_params_per_layer(self) -> int:
+        """A linear layer's mixer: q, k, v, the gate z, beta and the decay,
+        the depthwise convolution, the output projection."""
+        h, dk, dv = self.linear_heads, self.linear_key_dim, \
+            self.linear_value_dim
+        chans = h * (2 * dk + dv)
+        return (self.hidden * (chans + h * dv + 2 * h)
+                + self.linear_conv * chans + h * dv * self.hidden)
+
+    @property
+    def mixer_params(self) -> int:
+        """Every layer's token mixer: attention where the layer keeps
+        K/V, the linear mixer where it carries a state."""
+        attn = ((self.num_layers - self.linear_layers)
+                * self.attn_params_per_layer)
+        if not self.linear_layers:
+            return attn
+        return attn + self.linear_layers * self.linear_params_per_layer
 
     @property
     def mlp_params_per_expert(self) -> int:
@@ -155,8 +193,8 @@ class ModelGeometry:
         """Weight parameters ONE token's forward multiplies against:
         attention + experts_per_tok MLPs (all of the dense MLP) + head."""
         e = self.experts_per_tok if self.num_experts else 1
-        return (self.num_layers * (self.attn_params_per_layer
-                                   + e * self.mlp_params_per_expert)
+        return (self.mixer_params
+                + self.num_layers * e * self.mlp_params_per_expert
                 + self.hidden * self.vocab)
 
     @property
@@ -164,8 +202,8 @@ class ModelGeometry:
         """Weight parameters a batched forward streams from HBM: every
         expert is resident (the batch routes across all of them)."""
         e = self.num_experts if self.num_experts else 1
-        return (self.num_layers * (self.attn_params_per_layer
-                                   + e * self.mlp_params_per_expert)
+        return (self.mixer_params
+                + self.num_layers * e * self.mlp_params_per_expert
                 + self.hidden * self.vocab)
 
 
@@ -182,7 +220,19 @@ def kv_bytes_per_position(geom: ModelGeometry) -> float:
     stores head_dim codes plus a per-(position, kv-head) scale."""
     per_head = (geom.head_dim * (geom.kv_dtype_bytes or geom.dtype_bytes)
                 + geom.kv_scale_bytes)
-    return float(geom.num_layers * 2 * geom.kv_heads * per_head)
+    return float((geom.num_layers - geom.linear_layers) * 2 * geom.kv_heads
+                 * per_head)
+
+
+def state_bytes_per_slot(geom: ModelGeometry) -> float:
+    """Bytes of recurrent state ONE sequence holds over all linear
+    layers (float32 ``S``, the conv inputs in the weights' dtype): what a
+    decode token reads and writes whatever its context, and a prefill
+    call once a row. 0 for a model whose every layer keeps K/V."""
+    h, dk, dv = geom.linear_heads, geom.linear_key_dim, geom.linear_value_dim
+    conv = max(geom.linear_conv - 1, 0) * h * (2 * dk + dv)
+    return float(geom.linear_layers * (h * dk * dv * 4
+                                       + conv * geom.dtype_bytes))
 
 
 def phase_flops(geom: ModelGeometry, tokens: float,
@@ -196,7 +246,13 @@ def phase_flops(geom: ModelGeometry, tokens: float,
     (whole table per token) with the same argument."""
     matmul = 2.0 * geom.activated_params * tokens
     attn = 4.0 * geom.heads * geom.head_dim * kv_read_positions
-    return matmul + attn
+    if not geom.linear_layers:
+        return matmul + attn
+    # the delta rule of the linear layers: k^T S, the rank-one write and
+    # S^T q, 2 flops a multiply-add, a head and token
+    rule = (6.0 * geom.linear_layers * geom.linear_heads
+            * geom.linear_key_dim * geom.linear_value_dim * tokens)
+    return matmul + attn + rule
 
 
 def phase_bytes(geom: ModelGeometry, *, tokens: float, weight_passes: float,
@@ -266,6 +322,10 @@ def record_serving_throughput(phase: str, *, seconds: float, tokens: float,
     fl = phase_flops(geom, tokens, kv_read_positions)
     by = phase_bytes(geom, tokens=tokens, weight_passes=weight_passes,
                      kv_read_positions=kv_read_positions)
+    if phase == "decode" and geom.linear_layers:
+        # a decode token reads and writes its sequence's recurrent state
+        # (a prefill call does once a row: its weight pass dwarfs that)
+        by += 2.0 * state_bytes_per_slot(geom) * tokens
     ai = arith_intensity(fl, by)
     mfu_v = fl / seconds / peak_flops if peak_flops else 0.0
     mbu_v = by / seconds / peak_hbm_bps if peak_hbm_bps else 0.0

@@ -48,6 +48,7 @@ import numpy as np
 from paddle_tpu.models.paged import (_beam_finalize, _BEAM_SELECT_JIT,
                                      cache_passes,
                                      greedy_accept_length, is_moe_model,
+                                     layer_kinds, state_bytes,
                                      stochastic_accept_row)
 from paddle_tpu.observability import span as _span
 from paddle_tpu.observability.flight import FLIGHT
@@ -57,6 +58,7 @@ from paddle_tpu.observability.roofline import (ModelGeometry,
                                                record_serving_throughput,
                                                resolve_serving_peaks)
 from paddle_tpu.serving.executor import ModelExecutor, _SAMPLE_ROWS_JIT  # noqa: F401  (re-exported)
+from paddle_tpu.serving.executor import STATEFUL_MODEL as _STATEFUL
 from paddle_tpu.serving.kv import KVManager, cache_block_bytes
 from paddle_tpu.serving.scheduler import Scheduler
 from paddle_tpu.serving.cp import (_CP_AXIS, _CP_GATHER_S,
@@ -73,7 +75,8 @@ from paddle_tpu.serving.telemetry import (_ACTIVE_SLOTS, _ASYNC_DEPTH,
                                           _SPEC_DRAFT_REUSE,
                                           _SPEC_FALLBACKS,
                                           _SPEC_PROPOSED, _SPEC_RATE,
-                                          _SPEC_TOKENS, _TENANT_FINISHED,
+                                          _SPEC_TOKENS, _STATE_BYTES,
+                                          _TENANT_FINISHED,
                                           _TENANT_REJECTED, _TENANT_TOK_LAT,
                                           _TENANT_TOKENS, _TENANT_TTFT,
                                           _TICK, _TICK_BREAKDOWN,
@@ -92,6 +95,10 @@ from paddle_tpu.utils.profiler import device_memory_stats
 # this serves from (v5e: 197 TFLOP/s over 819 GB/s is 240 FLOP a byte, a
 # token-row of a bf16 matmul 1 FLOP a weight byte), to the power of two above
 _RIDGE_TOKENS = 256
+
+_HYBRID_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
+                   "serving/transfer.py ships K/V blocks, not the recurrent "
+                   "state that goes with them")
 
 _LOOPED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "its payload holds one row a block and layer, a looped "
@@ -114,7 +121,7 @@ class LLMEngine:
                  max_queue_len=None, clock=None, draft_model=None,
                  spec_k=4, spec_adaptive=True, prefill_only=False,
                  adapter_store=None, degrade=None, slo=None, kv_dtype=None,
-                 cp=1, async_depth=0):
+                 cp=1, async_depth=0, num_state_snapshots=0):
         # the model itself goes to the executor, which flattens it once:
         # the engine serves the weights it was built with
         cfg = self.cfg = model.cfg
@@ -240,8 +247,11 @@ class LLMEngine:
         # a looped model (its stack run ``total_ut_steps`` times a token,
         # K/V of every pass kept): what does not compose with it yet
         self.ut_steps = cache_passes(cfg)
+        self._looped = (f"a looped model ({self.ut_steps} passes over "
+                        f"{cfg.num_hidden_layers} layers)")
         if self.ut_steps > 1:
-            self._refuse_looped(
+            self._refuse(
+                self._looped,
                 self.cp > 1 and "context parallelism (cp > 1): the pools' "
                 "rows are laid out pass by pass, not shard by shard",
                 adapter_store is not None and "multi-LoRA (adapter_store): "
@@ -253,8 +263,33 @@ class LLMEngine:
                 "early_exit_threshold < 1: a token's passes would vary, "
                 "and the scheduler counts one fixed cost a slot and tick")
 
+        # a model with recurrent layers (``layer_types`` names them): each
+        # slot owns a state beside its K/V (zeroed by the program that
+        # starts its prompt, rebuilt by the replay after a preemption), and
+        # a prefix hit is worth only as far as a snapshot of that state
+        # exists; ``num_state_snapshots`` is the capacity of their pool,
+        # one constructor size as ``num_blocks`` is one
+        self.stateful = layer_kinds(cfg) is not None
+        self.num_state_snapshots = (int(num_state_snapshots)
+                                    if self.stateful else 0)
+        if self.stateful:
+            self._refuse(
+                _STATEFUL,
+                draft_model is not None and "a draft model: a rejected "
+                "token's write to the recurrent state cannot be rolled back",
+                self.cp > 1 and "context parallelism (cp > 1): the state "
+                "is a slot's, not a shard's",
+                adapter_store is not None and "multi-LoRA (adapter_store): "
+                "its adapters are written for attention's projections",
+                self.async_depth > 0 and "async_depth > 0: the pipelined "
+                "tick has not been run over a recurrent state",
+                kv_dtype is not None and "a quantized K/V pool (kv_dtype): "
+                "not calibrated beside a float32 state")
+
         # ---- the three extracted layers ----
         self.kv = KVManager(num_blocks, block_size)
+        if self.stateful:
+            self.kv.keep_state(self.num_state_snapshots)
         self._block_bytes = None     # per-block HBM bytes, lazily computed
         self._dev_mem_t = None       # last device_memory_stats refresh
         self.sched = Scheduler(max_queue_len=max_queue_len, clock=clock)
@@ -263,7 +298,8 @@ class LLMEngine:
             block_size=block_size, max_blocks_per_seq=self.max_blocks_per_seq,
             top_k=top_k, seed=seed, draft_model=draft_model,
             spec_k=self.spec_k, max_seq_len=self.max_seq_len,
-            kv_dtype=kv_dtype, cp=self.cp)
+            kv_dtype=kv_dtype, cp=self.cp,
+            num_state_snapshots=self.num_state_snapshots)
 
         # host mirrors (vectorised bookkeeping — no per-token python loops)
         self.slot_req = np.full(num_slots, -1, np.int64)   # req_id or -1
@@ -335,7 +371,10 @@ class LLMEngine:
                       # pool, over every cache layer (a (pass, layer) pair
                       # of a looped model), scale pools included
                       "cache_bytes_per_token":
-                          cache_block_bytes(self.cache) // block_size}
+                          cache_block_bytes(self.cache) // block_size,
+                      # likewise: bytes of recurrent state one slot holds
+                      # over every linear layer (0: every layer keeps K/V)
+                      "state_bytes_per_slot": state_bytes(self.cache.states)}
         self._adm_counter = 0                # admission recency, per slot
         self.adm_order = np.zeros(num_slots, np.int64)
 
@@ -498,15 +537,13 @@ class LLMEngine:
     def _has_deadlines(self, value):
         self.sched.has_deadlines = value
 
-    def _refuse_looped(self, *reasons):
+    def _refuse(self, model: str, *reasons):
         """Raise for the first of ``reasons`` that is a message: the one
-        place a looped model is told what it cannot be served with."""
+        place a model of a kind (``model``: ``self._looped``, ``_STATEFUL``)
+        is told what it cannot be served with."""
         for why in reasons:
             if why:
-                raise NotImplementedError(
-                    f"a looped model ({self.ut_steps} passes over "
-                    f"{self.cfg.num_hidden_layers} layers) is not "
-                    f"served with {why}")
+                raise NotImplementedError(f"{model} is not served with {why}")
 
     # ------------------------------------------------------------- intake
     def add_request(self, req: Request) -> int:
@@ -529,7 +566,12 @@ class LLMEngine:
         if req.num_beams < 1:
             raise ValueError("num_beams must be >= 1")
         if req.num_beams > 1:
-            self._refuse_looped(
+            self._refuse(
+                _STATEFUL,
+                self.stateful and "beam search (num_beams > 1): a fork "
+                "would have to copy a slot's recurrent state")
+            self._refuse(
+                self._looped,
                 self.ut_steps > 1 and "beam search (num_beams > 1): no "
                 "test has forked a looped model's blocks")
             if req.num_beams > self.num_slots:
@@ -701,6 +743,7 @@ class LLMEngine:
                 return True
         if req_id in self.prefilling:
             slot, _ = self.prefilling.pop(req_id)
+            self._drop_snapshot_plan(self.requests[req_id])
             self.mgr.free(req_id)
             self.slot_req[slot] = -1
             self._release_adapter(req_id)
@@ -882,6 +925,43 @@ class LLMEngine:
     # ---------------------------------------------------------- admission
     def _admit(self):
         return self.sched.select_admissions(self)
+
+    def _admit_state(self, req, slot: int, match) -> bool:
+        """The host's state work of one admission of a model with
+        recurrent layers (the ``serving.state`` span, under
+        ``serving.admit``). ``match`` is what ``KVManager.match`` offered:
+        the K/V match cut down to its deepest snapshot. That snapshot is
+        restored into the slot (a device copy, ordered before the slot's
+        first chunk by its data). THE POLICY OF TAKING: where the K/V match
+        reached past the snapshot, this prefix is being seen a second time,
+        so the state at the end of the K/V match (block-aligned) is
+        snapshotted when this request's prefill reaches it: an entry is
+        reserved now, the least recently restored one leaving if the pool
+        is full. A prompt nobody asks again never takes an entry. -> whether
+        a snapshot is planned (the prompt then goes through the chunk
+        program, which can stop at that depth)."""
+        restored = match.token_count if match else 0
+        offered = match.offered if match is not None else 0
+        self.mgr.cache_stats["snap_offered_tokens"] += offered
+        with _span("serving.state", rid=req.req_id, matched=offered,
+                   restored=restored) as sp:
+            if restored:
+                self.exe.restore_state(slot, match.snapshot[1])
+                self.mgr.restored_snapshot(match.snapshot[1])
+            at = offered // self.block_size * self.block_size
+            got = self.mgr.reserve_snapshot() if at > restored else None
+            if got is not None:
+                req._snapshot_plan = (at, got[0])
+            sp.set(taken=int(got is not None),
+                   evicted=int(bool(got and got[1])))
+        return got is not None
+
+    def _drop_snapshot_plan(self, req):
+        """A request leaves its slot before its prefill reached the depth
+        it was to snapshot: the reserved entry is free again."""
+        plan, req._snapshot_plan = req._snapshot_plan, None
+        if plan is not None:
+            self.mgr.release_snapshot(plan[1])
 
     def _live_blocks(self, rid: int) -> int:
         return self.kv.live_blocks(rid)
@@ -1203,6 +1283,11 @@ class LLMEngine:
             req = self.requests[rid]
             p = self._pr(req)
             chunk = p[consumed: consumed + budget]
+            plan = req._snapshot_plan
+            if plan is not None and consumed < plan[0] < consumed + len(chunk):
+                # the chunk stops at the depth to snapshot: the slot's
+                # state after the call is the state at that depth
+                chunk = chunk[:plan[0] - consumed]
             t = self._allocate_or_preempt(rid, consumed + len(chunk),
                                           protect=staged)
             if t is None:
@@ -1243,6 +1328,14 @@ class LLMEngine:
         for rid, (chunk, consumed, slot, _, _, sampling), tok in zip(
                 rids, live, first):
             req = self.requests[rid]
+            plan = req._snapshot_plan
+            if plan is not None and consumed + len(chunk) == plan[0]:
+                # queued behind the chunk's call: the entry holds the state
+                # at this depth, and the trie position there owns it
+                self.exe.take_state(slot, plan[1])
+                req._snapshot_plan = None
+                self.mgr.attach_snapshot(self._pr(req), plan[0], plan[1],
+                                         adapter=req.adapter_id)
             if sampling is None:
                 self.prefilling[rid] = (slot, consumed + len(chunk))
                 continue
@@ -1870,7 +1963,8 @@ class LLMEngine:
         requests — only ACTIVE greedy slots are extractable (the router
         extracts after the final prefill chunk activates the slot)."""
         self._drain_async("boundary")
-        self._refuse_looped(self.ut_steps > 1 and _LOOPED_HANDOFF)
+        self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
+        self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -1967,7 +2061,8 @@ class LLMEngine:
                 "engine is draining — finishing in-flight requests, "
                 "admitting nothing new")
         req = payload.req
-        self._refuse_looped(self.ut_steps > 1 and _LOOPED_HANDOFF)
+        self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
+        self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2151,6 +2246,11 @@ class LLMEngine:
             _KV_UTIL.set(used / self.mgr.num_blocks if self.mgr.num_blocks
                          else 0.0)
             self.kv.push_prefix_metrics()
+            if self.stateful:
+                per = self.stats["state_bytes_per_slot"]
+                _STATE_BYTES.set(per * self.num_slots, kind="slots")
+                _STATE_BYTES.set(per * self.mgr.snapshots_held(),
+                                 kind="snapshots")
             # context parallelism (ISSUE 18): axis size + per-shard block
             # occupancy under the contiguous split. The gauge family stays
             # silent at cp=1 (no shard labels registered) so single-device
@@ -2586,7 +2686,8 @@ class LLMEngine:
         # (what is left of slots x table width)
         with self._tick_timer("sample", "serving.decode", slots=n_run,
                               kv_blocks=ctx // self.block_size,
-                              **self.exe.span_args):
+                              **self.exe.span_args,
+                              **self.exe.state_slots(n_run)):
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),

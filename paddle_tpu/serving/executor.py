@@ -21,8 +21,10 @@ from paddle_tpu.models.decoding import KVCache, _sample_rows
 from paddle_tpu.models.paged import (PagedKVCache, _BEAM_GROUP_UPDATE_JIT,
                                      _PREFILL_CHUNK_JIT, _PREFILL_JIT,
                                      _PREFIX_COW_JIT, _REWIND_LENS_JIT,
+                                     _STATE_RESTORE_JIT, _STATE_TAKE_JIT,
                                      _TICK_JIT, _VERIFY_CHUNK_JIT,
                                      _async_tick_jit, _prefix_cow_update,
+                                     init_states,
                                      llama_decode_tick,
                                      llama_prefill_chunk_paged,
                                      llama_prefill_paged,
@@ -81,6 +83,10 @@ def _token_rows(ids, lens) -> dict:
     return {"rows": int(np.size(ids)), "useful": int(np.sum(lens))}
 
 
+# how a refusal names a model with recurrent layers (``LLMEngine._refuse``)
+STATEFUL_MODEL = "a model with recurrent (linear-attention) layers"
+
+
 def _chunk_kv_blocks(lens, offs, block_size) -> int:
     """Pool blocks a cache layer the chunk kernel walks in one call: every
     live row's blocks up to the end of its chunk, from the host's lengths
@@ -104,7 +110,8 @@ class ModelExecutor:
 
     def __init__(self, model, *, num_slots, num_blocks, block_size,
                  max_blocks_per_seq, top_k=None, seed=0, draft_model=None,
-                 spec_k=4, max_seq_len=None, kv_dtype=None, cp=1):
+                 spec_k=4, max_seq_len=None, kv_dtype=None, cp=1,
+                 num_state_snapshots=0):
         cfg = model.cfg
         # the one copy of the model the executor keeps, and what every
         # program is handed: the weights as they were when it was built
@@ -123,6 +130,17 @@ class ModelExecutor:
         # passes over the stack a token, and the K/V layers it keeps
         self.span_args = {"ut_steps": self.cache.passes,
                         "cache_layers": self.cache.cache_layers}
+        # a model with recurrent layers: its state a slot is in the cache
+        # (``cache.states``), donated through the programs with the pools;
+        # the snapshot pool is beside it, laid out the same, a row an entry,
+        # and touched by the two copy programs alone
+        self.state_layers = len(self.cache.states)
+        self.snaps = ()
+        if self.state_layers:
+            self.span_args["state_layers"] = self.state_layers
+            if num_state_snapshots:
+                self.snaps = init_states(cfg, int(num_state_snapshots),
+                                         self.state_layers)
         if self.cp > 1:
             self._init_cp(num_blocks)
         self.draft_model = draft_model
@@ -248,7 +266,8 @@ class ModelExecutor:
         applies the batched multi-LoRA correction per row. The cache is
         donated, as in the chunk program: a tick's several calls in
         flight hold one pool, not one more for each."""
-        with _span("exe.prefill", **_token_rows(ids, lens), **self.span_args):
+        with _span("exe.prefill", **_token_rows(ids, lens),
+                   **self._ctx_tokens(lens, 0), **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._cp_prefill(
@@ -266,7 +285,7 @@ class ModelExecutor:
         with _span("exe.prefill_chunk", **_token_rows(ids, lens),
                    kv_blocks=_chunk_kv_blocks(lens, offs,
                                               self.cache.block_size),
-                   **self.span_args):
+                   **self._ctx_tokens(lens, offs), **self.span_args):
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._cp_prefill_chunk(
@@ -280,9 +299,41 @@ class ModelExecutor:
                 jnp.asarray(rows), lora=lora)
             return logits
 
+    def _ctx_tokens(self, lens, offs) -> dict:
+        """``ctx_tokens`` of a prefill call for a model with recurrent
+        layers: the sum over its live rows of ``offset + len``, from the
+        host's lengths (with ``useful``, each token's context for a count
+        of the call's FLOPs). Nothing, and no work, for any other model."""
+        if not self.state_layers:
+            return {}
+        lens = np.asarray(lens)
+        return {"ctx_tokens": int(np.sum((np.asarray(offs) + lens)[lens > 0]))}
+
+    def state_slots(self, n_run: int) -> dict:
+        """``state_slots`` of a decode tick's spans: the running slots
+        whose recurrent state the tick reads and writes. Nothing for a
+        model whose every layer keeps K/V."""
+        return {"state_slots": n_run} if self.state_layers else {}
+
+    def take_state(self, slot: int, idx: int):
+        """Snapshot entry ``idx`` <- the state slot ``slot`` holds once the
+        programs queued so far have run."""
+        self.snaps = _STATE_TAKE_JIT(self.snaps, self.cache.states,
+                                     np.int32(slot), np.int32(idx))
+
+    def restore_state(self, slot: int, idx: int):
+        """Slot ``slot``'s state <- snapshot entry ``idx``."""
+        self.cache = _STATE_RESTORE_JIT(self.cache, self.snaps,
+                                        np.int32(slot), np.int32(idx))
+
     def verify_chunk(self, ids, clens, offs, slot_ids, rows, lora=None):
         """Target forward over each slot's proposal window (spec decode);
         shares the chunked-prefill program shape."""
+        if self.state_layers:
+            raise NotImplementedError(
+                f"{STATEFUL_MODEL} is not served with verify_chunk: a "
+                "rejected token's write to the recurrent state cannot be "
+                "rolled back")
         if self.cp > 1:
             self._no_cp_lora(lora)
             logits, self.cache = self._cp_verify_chunk(
@@ -313,8 +364,9 @@ class ModelExecutor:
         logp [num_slots, vocab] or None per ``need_logp``). ``lora`` is
         the per-slot multi-LoRA pytree; ``bias`` a [num_slots, V]
         grammar-mask logit bias applied before sampling."""
-        with _span("exe.decode_tick", slots=int(np.sum(run_mask)),
-                   **self.span_args):
+        n_run = int(np.sum(run_mask))
+        with _span("exe.decode_tick", slots=n_run, **self.span_args,
+                   **self.state_slots(n_run)):
             sub = self.next_key()
             if self.cp > 1:
                 self._no_cp_lora(lora)

@@ -17,7 +17,8 @@ from paddle_tpu.serving.telemetry import (_PREFIX_EVICTIONS,
                                           _PREFIX_HIT_RATE, _PREFIX_HITS,
                                           _PREFIX_PARTIAL_HITS,
                                           _PREFIX_TOKEN_HIT_RATE,
-                                          _PREFIX_TOKEN_HITS)
+                                          _PREFIX_TOKEN_HITS,
+                                          _STATE_SNAPSHOTS)
 
 
 def cache_block_bytes(cache) -> int:
@@ -46,6 +47,7 @@ class KVManager:
         # The radix trie matches token spans and reuses partial blocks
         # copy-on-write.
         self.mgr = RadixPrefixBlockManager(num_blocks, block_size)
+        self.stateful = False        # until ``keep_state``
         # the block manager owns the per-pool memory ledger (its own
         # mutation choke points notify it); this layer mirrors the
         # reservation count into it and exposes the forensic wrappers
@@ -80,6 +82,21 @@ class KVManager:
 
     def free(self, rid: int):
         self.mgr.free(rid)
+
+    def keep_state(self, snapshots: int):
+        """The model carries recurrent state: two kinds of cache in one
+        manager, a snapshot pool of ``snapshots`` entries (it may be 0)
+        beside the blocks. A K/V match is from now on worth only as far
+        as a snapshot of the state exists (``match``)."""
+        self.stateful = True
+        self.mgr.enable_snapshots(snapshots)
+
+    def match(self, tokens, adapter=None):
+        """The prefix an admission may adopt: the radix trie's K/V match,
+        cut down to its deepest state snapshot where the model carries
+        recurrent state (``RadixPrefixBlockManager.state_hit``)."""
+        m = self.mgr.match_prefix(tokens, adapter=adapter)
+        return self.mgr.state_hit(m) if self.stateful else m
 
     # ------------------------------------------------------------- ledger
     def live_blocks(self, rid: int) -> int:
@@ -151,6 +168,9 @@ class KVManager:
             assert not self.resv and not self.need, (
                 f"ledger leak: resv={self.resv} need={self.need}")
             assert not self.mgr.tables, f"table leak: {list(self.mgr.tables)}"
+            if self.stateful:
+                held = self.mgr.snapshot_audit()["reserved"]
+                assert not held, f"state-snapshot reservation leak: {held}"
         except AssertionError as e:
             FLIGHT.record("serving.quiescence_violation",
                           **self.ledger.flight_fields())
@@ -175,6 +195,9 @@ class KVManager:
         _PREFIX_EVICTIONS.inc(delta("evictions"))
         _PREFIX_TOKEN_HITS.inc(delta("token_hits"))
         _PREFIX_PARTIAL_HITS.inc(delta("partial_hits"))
+        if self.stateful:
+            for event in ("taken", "restored", "evicted", "dropped"):
+                _STATE_SNAPSHOTS.inc(delta("snap_" + event), event=event)
         self._prefix_pushed = dict(stats)
         _PREFIX_HIT_RATE.set(stats.get("hit_blocks", 0)
                              / max(stats.get("lookup_blocks", 0), 1))

@@ -199,7 +199,7 @@ class Scheduler:
         if (memo is not None and epoch is not None
                 and memo[0] == epoch and memo[1] == len(p)):
             return memo[2]
-        m = kv.mgr.match_prefix(p, adapter=req.adapter_id)
+        m = kv.match(p, adapter=req.adapter_id)
         if epoch is not None:
             req._match_memo = (epoch, len(p), m)
         return m
@@ -387,7 +387,11 @@ class Scheduler:
                 slot = int(free_slots.pop(0))
                 if cached:
                     kv.mgr.adopt_prefix(req.req_id, cached)
-                if cached or len(p) > eng.max_prompt_len:
+                # a model with recurrent layers: the slot's state comes
+                # from the snapshot the match was cut to, and a prefix seen
+                # a second time plans a snapshot of its own
+                planned = eng.stateful and eng._admit_state(req, slot, cached)
+                if cached or planned or len(p) > eng.max_prompt_len:
                     # chunk-prefill path from offset ct: claims the slot
                     # INACTIVE; blocks allocate chunk-by-chunk against
                     # the reservation. (Cached short prompts ride it too —
@@ -463,6 +467,7 @@ class Scheduler:
         rid = max(cand, key=lambda r: eng.adm_order[eng.prefilling[r][0]])
         slot, consumed = eng.prefilling.pop(rid)
         req = self.requests[rid]
+        eng._drop_snapshot_plan(req)
         if eng.prefix_caching and consumed:
             # the chunks already scattered are finished device work —
             # commit them so the replay prefill re-matches instead of

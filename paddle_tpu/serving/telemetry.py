@@ -119,6 +119,17 @@ _SPEC_DRAFT_REUSE = METRICS.counter(
 # prefix cache: cumulative adopt/evict counts exported from the block
 # manager's cache_stats (deltas pushed each gauge refresh), plus the
 # lifetime hit rate (blocks adopted / blocks prefill would have written)
+_STATE_SNAPSHOTS = METRICS.counter(
+    "serving_state_snapshots_total",
+    "recurrent-state snapshots of a model with linear layers, by event: "
+    "taken (a trie position now owns one), restored (an admission began "
+    "from one), evicted (the least recently restored made room), dropped "
+    "(its blocks left the trie, or its position was gone or taken)",
+    labelnames=("event",))
+_STATE_BYTES = METRICS.gauge(
+    "serving_state_bytes",
+    "HBM bytes of recurrent state: the slots' (every slot, live or not) "
+    "and the snapshot pool's entries in use", labelnames=("kind",))
 _PREFIX_HITS = METRICS.counter(
     "serving_prefix_hit_blocks_total",
     "prompt blocks adopted from the prefix cache instead of prefilled")

@@ -91,6 +91,9 @@ class Request:
     # 11): the spec-decode draft seed uses it to skip re-embedding the
     # adopted prefix when the draft cache still holds those tokens
     _adopted: int = 0
+    # a model with recurrent layers: (token depth, snapshot entry) of the
+    # state snapshot this request's prefill is to take on its way
+    _snapshot_plan: tuple = None
     # request tracker (ISSUE 9): trace_id is minted at first submit while
     # tracking is enabled (None = untracked, every tracker call no-ops);
     # trace_summary is the finished timeline summary, same dict /requests

@@ -25,7 +25,8 @@ PALLAS_DIR = Path(pt.__file__).parent / "ops" / "pallas"
 KERNEL_NAMES = {
     "paged_decode_attention", "paged_chunk_attention", "flash_attention_fwd",
     "flash_attention_dq", "flash_attention_dkv", "rms_norm_fwd", "fused_rope",
-    "grouped_matmul", "grouped_matmul_dx", "grouped_matmul_dw"}
+    "grouped_matmul", "grouped_matmul_dx", "grouped_matmul_dw",
+    "gated_delta_chunk"}
 
 
 @pytest.fixture(scope="module")
@@ -412,7 +413,7 @@ def _pallas_calls():
 
 def test_every_pallas_call_site_passes_a_name():
     sites = dict(_pallas_calls())
-    assert len(sites) == 9
+    assert len(sites) == 10
     names = []
     for where, call in sites.items():
         kw = {k.arg: k.value for k in call.keywords}
@@ -422,7 +423,7 @@ def test_every_pallas_call_site_passes_a_name():
             names.append(v.value)
         else:                    # the one site two kernels reach: its
             assert isinstance(v, ast.Name), where    # callers name it
-    assert len(set(names)) == len(names) == 8
+    assert len(set(names)) == len(names) == 9
     assert set(names) <= KERNEL_NAMES
 
 

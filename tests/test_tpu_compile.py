@@ -301,6 +301,83 @@ def test_chunk_program_lowers_the_kernel_once(one_chip, monkeypatch, family):
     assert paged_attention._trace_events.count("chunk:pallas") == sites
 
 
+# (id, rows, tokens, heads, d_k, d_v): Olmo-Hybrid-7B's linear layers at the
+# cell's chunk of 1024 tokens and a short ragged call
+DELTA_CHUNK = [("olmo7b_1x1024", 1, 1024, 30, 96, 192),
+               ("olmo7b_2x200", 2, 200, 30, 96, 192)]
+
+
+@pytest.mark.parametrize("case", DELTA_CHUNK, ids=lambda c: c[0])
+def test_gated_delta_chunk_compiles(one_chip, case):
+    from paddle_tpu.ops.pallas import gated_delta
+    _, b, t, h, dk, dv = case
+    fn = lambda *a: gated_delta.gated_delta_chunk_pallas(*a, interpret=False)
+    compiled = _compile(
+        fn, one_chip, ((b, t, h, dk), bf16), ((b, t, h, dk), bf16),
+        ((b, t, h, dv), bf16), ((b, t, h), f32), ((b, t, h), f32),
+        ((b, h, dk, dv), f32), ((b,), i32))
+    assert 'kernel_name = "gated_delta_chunk"' in compiled.as_text() \
+        or "gated_delta_chunk" in compiled.as_text()
+
+
+def test_gated_delta_step_updates_the_states_where_they_lie(one_chip):
+    """The step is XLA's own form (no kernel): compiled for the chip at the
+    cell's shape with the state donated, it holds no second copy of the
+    slots' states."""
+    from paddle_tpu.ops.pallas import gated_delta
+    n, h, dk, dv = 8, 30, 96, 192
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((n, h, dk), bf16), ((n, h, dk), bf16), ((n, h, dv), bf16),
+        ((n, h), f32), ((n, h), f32), ((n, h, dk, dv), f32),
+        ((n,), jnp.bool_))]
+    compiled = jax.jit(gated_delta.gated_delta_step,
+                       donate_argnums=(5,)).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    state = n * h * dk * dv * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state // 2
+
+
+def test_hybrid_chunk_program_lowers_each_kernel_once(monkeypatch):
+    """A period of the hybrid: three linear layers and a full one call the
+    delta-rule kernel and the chunk-attention kernel through one jitted
+    function each, so the lowered chunk program holds one Mosaic call of
+    either; the tick program's only kernel is the decode attention (the
+    rule's step is XLA's own form)."""
+    from paddle_tpu.models import paged
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                               OlmoHybridForCausalLM)
+    from paddle_tpu.ops.pallas import gated_delta
+    cfg = OlmoHybridConfig.tiny(
+        hidden_size=256, num_attention_heads=2, num_key_value_heads=2,
+        intermediate_size=512, vocab_size=128, linear_num_key_heads=2,
+        linear_num_value_heads=2, linear_key_head_dim=96,
+        linear_value_head_dim=192, dtype=bf16)
+    model = jax.eval_shape(lambda: OlmoHybridForCausalLM(cfg))
+    cache = jax.eval_shape(lambda: paged.PagedKVCache.init_for(
+        cfg, 32, 16, 4, 8))
+    S = jax.ShapeDtypeStruct
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged.clear_jit_caches()
+    del paged_attention._trace_events[:]
+    try:
+        chunk = jax.jit(paged.llama_prefill_chunk_paged).trace(
+            model, S((1, 128), i32), S((1,), i32), S((1,), i32), cache,
+            S((1,), i32), S((1, 8), i32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        tick = jax.jit(paged.llama_decode_step_paged).trace(
+            model, S((4,), i32), cache, S((4,), jnp.bool_)).lower(
+                lowering_platforms=("tpu",)).as_text()
+    finally:
+        paged.clear_jit_caches()     # traced under a patched backend
+    kernels = re.findall(r'kernel_name = "([^"]+)"', chunk)
+    assert sorted(kernels) == ["gated_delta_chunk", "paged_chunk_attention"]
+    assert len(re.findall(r"call @_gated_delta_chunk_call", chunk)) == 3
+    kernels = re.findall(r'kernel_name = "([^"]+)"', tick)
+    assert set(kernels) == {"paged_decode_attention"}
+    assert paged_attention._trace_events.count("gated_delta_chunk:pallas") \
+        == 3
+
+
 # (id, B, S, H, H_kv, window, with backward)
 FLASH = [
     ("fwd_4x2048x16", 4, 2048, 16, 16, None, False),
