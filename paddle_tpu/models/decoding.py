@@ -190,24 +190,40 @@ def _sample_rows(logits, rng, temps, top_ps, top_k=None, bias=None):
     GenerationConfig). ``temps``/``top_ps``: [B] traced — temperature 0
     means greedy FOR THAT ROW; top_p 1.0 disables the nucleus cut.
     ``top_k`` stays global/static. ``bias`` ([B, V] additive, 0 / -1e30)
-    is the grammar-constraint mask (ISSUE 14): added BEFORE the
-    temperature scale and the greedy argmax, so both the stochastic and
-    the greedy row paths can only pick mask-legal tokens."""
+    is the grammar-constraint mask (ISSUE 14): added BEFORE either
+    branch, so both the stochastic and the greedy rows can only pick
+    mask-legal tokens.
+
+    One ``lax.cond`` on what the call can see of its rows: where no
+    temperature is above 0 the call IS ``argmax(logits + bias)`` and
+    nothing else runs; the scale, the full-vocabulary sort, softmax,
+    cumulative sum and the Gumbel draw run only when some row samples
+    (its greedy rows still take the argmax). ``rng`` is the caller's
+    whichever branch runs, so the host's key sequence does not depend
+    on the branch. A caller with rows that do not run (a freed slot
+    keeps its last temperature) passes 0 for them."""
     if bias is not None:
         logits = logits + bias
-    safe_t = jnp.where(temps > 0, temps, 1.0)[:, None]
-    scaled = logits / safe_t
-    if top_k is not None and top_k > 0:
-        kth = jnp.sort(scaled, axis=-1)[..., -top_k][..., None]
-        scaled = jnp.where(scaled < kth, -1e30, scaled)
-    sorted_logits = jnp.sort(scaled, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.sum(cum < top_ps[:, None], axis=-1, keepdims=True)
-    cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx, axis=-1)
-    scaled = jnp.where(scaled < cutoff, -1e30, scaled)
-    sampled = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(temps > 0, sampled, jnp.argmax(logits, axis=-1))
+
+    def greedy():
+        return jnp.argmax(logits, axis=-1)
+
+    def stochastic():
+        safe_t = jnp.where(temps > 0, temps, 1.0)[:, None]
+        scaled = logits / safe_t
+        if top_k is not None and top_k > 0:
+            kth = jnp.sort(scaled, axis=-1)[..., -top_k][..., None]
+            scaled = jnp.where(scaled < kth, -1e30, scaled)
+        sorted_logits = jnp.sort(scaled, axis=-1)[..., ::-1]
+        probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.sum(cum < top_ps[:, None], axis=-1, keepdims=True)
+        cutoff = jnp.take_along_axis(sorted_logits, cutoff_idx, axis=-1)
+        scaled = jnp.where(scaled < cutoff, -1e30, scaled)
+        sampled = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(temps > 0, sampled, greedy())
+
+    return lax.cond(jnp.any(temps > 0), stochastic, greedy)
 
 
 def generate(model, input_ids, max_new_tokens=32, temperature=0.0, top_k=None,

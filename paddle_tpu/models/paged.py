@@ -1494,9 +1494,14 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
     the [B] sampled-token fetch the engine needs for streaming/EOS.
 
     ``temps``/``top_ps``: [B] traced per-slot sampling params (each
-    request its own; 0 temperature = greedy for that row). ``top_k`` is
-    static/global. ``want_logp`` (static): also return the [B, vocab]
-    log-probs for beam selection, LEFT ON DEVICE. When False
+    request its own; 0 temperature = greedy for that row). The sampler
+    is handed the temperatures of the rows that RUN (0 for a row that
+    is not ``active``: its token is discarded below, and a freed slot
+    keeps its last request's temperature on the host), so a tick whose
+    running rows are all greedy takes ``_sample_rows``' argmax branch:
+    no sort, softmax, cumulative sum or draw over the vocabulary.
+    ``top_k`` is static/global. ``want_logp`` (static): also return the
+    [B, vocab] log-probs for beam selection, LEFT ON DEVICE. When False
     (greedy-only ticks) logp is () so no [B, vocab] f32 buffer is ever
     materialised."""
     from paddle_tpu.models.decoding import _sample_rows
@@ -1508,8 +1513,9 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
     logp = (jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
             if want_logp else ())
     with jax.named_scope("sampler"):
-        nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
-                           top_k, logit_bias)
+        nxt = _sample_rows(logits.astype(jnp.float32), rng,
+                           jnp.where(active, temps, 0), top_ps, top_k,
+                           logit_bias)
     nxt = jnp.where(active, nxt.astype(jnp.int32), tokens)
     return nxt, logp, cache
 
@@ -1538,15 +1544,17 @@ def llama_decode_tick_async(model, tokens, cache: PagedKVCache, active,
     needs them, so this program stays a pure decode-cruise fast path.
     Returns (nxt, ran, stop', gen', cache) — ``ran`` is the mask of
     rows that actually computed this tick, which is exactly the rows
-    the synchronous loop would have run."""
+    the synchronous loop would have run, and the rows whose
+    temperatures the sampler sees (0 for the others, as in the
+    synchronous tick)."""
     from paddle_tpu.models.decoding import _sample_rows
     _note_trace("tick:async")
     ran = active & ~stop
     logits, cache = llama_decode_step_paged(model, tokens, cache, ran,
                                             None)
     with jax.named_scope("sampler"):
-        nxt = _sample_rows(logits.astype(jnp.float32), rng, temps, top_ps,
-                           top_k, None)
+        nxt = _sample_rows(logits.astype(jnp.float32), rng,
+                           jnp.where(ran, temps, 0), top_ps, top_k, None)
     nxt = jnp.where(ran, nxt.astype(jnp.int32), tokens)
     new_gen = gen + ran.astype(gen.dtype)
     stopped = ran & ((nxt == eos_id) | (new_gen >= max_gen))

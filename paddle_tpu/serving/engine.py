@@ -70,7 +70,8 @@ from paddle_tpu.serving.telemetry import (_ACTIVE_SLOTS, _ASYNC_DEPTH,
                                           _GRAMMAR_SPEC_REJECTS,
                                           _GRAMMAR_TOKENS, _KV_IN_USE,
                                           _KV_UTIL, _QUEUE_DEPTH,
-                                          _REJECTED, _SNAPSHOTS,
+                                          _REJECTED, _SAMPLER_CALLS,
+                                          _SNAPSHOTS,
                                           _SPEC_ACCEPTED,
                                           _SPEC_DRAFT_REUSE,
                                           _SPEC_FALLBACKS,
@@ -360,6 +361,8 @@ class LLMEngine:
         self.prefill_rows = min(num_slots,
                                 max(1, _RIDGE_TOKENS // max_prompt_len))
         self._prefill_sent = [0, 0]    # this tick's [live rows, calls]
+        # and, for each of its calls that chose a first token: was it greedy
+        self._prefill_greedy = []
         # host-vs-device split of decode ticks (admission ticks excluded):
         # stats["host_s"] is scheduling/bookkeeping, stats["device_s"] the
         # jitted tick incl. the [num_slots] token fetch
@@ -988,6 +991,15 @@ class LLMEngine:
                 else req.temperature,
                 self.default_top_p if req.top_p is None else req.top_p)
 
+    @staticmethod
+    def _count_sampler(temps) -> bool:
+        """Count one sampler call by the branch ``_sample_rows`` takes
+        for it: ``temps`` are the temperatures of the rows that run the
+        call. -> whether it is the greedy one."""
+        greedy = not (temps > 0).any()
+        _SAMPLER_CALLS.inc(path="greedy" if greedy else "stochastic")
+        return greedy
+
     def _send_prefill_rows(self, live, chunked: bool):
         """Send the rows that carry a prompt this tick to one of the two
         prefill programs, ``prefill_rows`` of them a call, and sample the
@@ -1033,6 +1045,7 @@ class LLMEngine:
                             self._ctx_causal(lens, offs))
             logits.append(out)
             if sampled:
+                self._prefill_greedy.append(self._count_sampler(row_temps))
                 toks.append((g0, self.exe.sample_rows(
                     out, row_temps, row_tps,
                     bias=self._grammar_bias_rows(sampled, R))))
@@ -2478,8 +2491,12 @@ class LLMEngine:
         eos = -1 if self.eos_token_id is None else int(self.eos_token_id)
         rng_before = self.exe.rng
         t0 = time.perf_counter()
+        # the host's count: a row the device stopped and the host has not
+        # seen yet still counts with its temperature (the program masks it)
+        greedy = self._count_sampler(self.temps[act])
         with self._tick_timer("sample", "serving.decode",
-                              slots=int(act.sum()), **self.exe.span_args):
+                              slots=int(act.sum()), greedy=greedy,
+                              **self.exe.span_args):
             nxt, ran, stop, gen = self.exe.decode_tick_async(
                 dev["tokens"], act, dev["stop"], dev["gen"],
                 dev["max_gen"], self.temps, self.top_ps, eos)
@@ -2614,12 +2631,14 @@ class LLMEngine:
                 rids += [r for r in self.prefilling if r not in chunked]
                 sp.set(admitted=len(rids), queued=len(self.queue), rids=rids)
         with self._tick_timer("prefill", "serving.prefill") as sp:
-            self._prefill_sent = [0, 0]
+            self._prefill_sent, self._prefill_greedy = [0, 0], []
             if admits or beam_admits:
                 emitted += self._prefill(admits, beam_admits)
             emitted += self._prefill_chunks()
             sp.set(live_rows=self._prefill_sent[0],
                    calls=self._prefill_sent[1])
+            if self._prefill_greedy:
+                sp.set(greedy=all(self._prefill_greedy))
         if self.prefill_only:
             # prefill-role replica: newly activated slots carry their
             # first token; the router extracts them — never decode here
@@ -2675,6 +2694,7 @@ class LLMEngine:
         # roofline: one weight pass over the batch; every running slot
         # reads its whole block-rounded context and writes one position
         n_run = int(run_mask.sum())
+        greedy = self._count_sampler(self.temps[run_mask])
         ctx = self._ctx_blocks(run_mask)
         self._acc_phase("decode", n_run, 1, ctx)
         t1 = time.perf_counter()
@@ -2685,6 +2705,7 @@ class LLMEngine:
         # kv_blocks: the pool blocks the decode kernel walks this tick
         # (what is left of slots x table width)
         with self._tick_timer("sample", "serving.decode", slots=n_run,
+                              greedy=greedy,
                               kv_blocks=ctx // self.block_size,
                               **self.exe.span_args,
                               **self.exe.state_slots(n_run)):

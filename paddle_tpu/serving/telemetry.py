@@ -35,6 +35,14 @@ _TOKENS = METRICS.counter(
 _FINISHED = METRICS.counter(
     "serving_finished_total", "requests finished, by finish_reason",
     labelnames=("reason",))
+# which branch of ``decoding._sample_rows`` a call took, by the rule the
+# program applies (does any row that runs it sample?), counted on the host:
+# one count a decode tick and one a prefill call that samples
+_SAMPLER_CALLS = METRICS.counter(
+    "serving_sampler_calls_total",
+    "sampler calls by the branch taken: greedy (argmax alone) or "
+    "stochastic (sort, nucleus cut and draw over the vocabulary)",
+    labelnames=("path",))
 _QUEUE_DEPTH = METRICS.gauge(
     "serving_queue_depth", "requests waiting for admission")
 _ACTIVE_SLOTS = METRICS.gauge(

@@ -429,12 +429,13 @@ def _loops(jaxpr_text):
 # PR that brought looped models (the tiny Mistral below, jax 0.9.0). A PR
 # that means to change one of these programs replaces its digest. (PR 29
 # rewrote the chunk KERNEL: the CPU trace takes the gather and never sees
-# it, so ``chunk.*`` stand as they were, like the other six.)
+# it, so ``chunk.*`` stand as they were, like the other six. PR 38 put
+# the sampler's stochastic path under a ``cond``: both ``tick.*`` moved.)
 ONE_PASS_DIGESTS = {
     "prefill.None": "406290326ee86fc9", "chunk.None": "9c3bbc292fc90c5a",
-    "tick.None": "1dc3168d7830d6fe", "cow.None": "cfd0dfa73db95ea3",
+    "tick.None": "fb17388c44e1f2e6", "cow.None": "cfd0dfa73db95ea3",
     "prefill.int8": "e4f98f2e5629e2ba", "chunk.int8": "02d726380f37d7bf",
-    "tick.int8": "6b51a153cf2053d5", "cow.int8": "2ee99e5c66973264",
+    "tick.int8": "7a7d0bdc88375bf0", "cow.int8": "2ee99e5c66973264",
 }
 
 

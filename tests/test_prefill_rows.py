@@ -173,12 +173,12 @@ def test_a_burst_of_admissions_is_sent_a_row_a_call(model, store, kind):
     assert all(len(t) == 5 for t in got)
     _check_kind(model, kind, prompts, got, reqs, 5)
     (tick, calls), = ticks
-    assert tick == {"live_rows": 4, "calls": 4}
+    assert tick == {"live_rows": 4, "calls": 4, "greedy": kind != "sampled"}
     assert [c["name"] for c in calls] == ["exe.prefill"] * 4
     assert [c["args"]["rows"] for c in calls] == [CAP] * 4
     assert [c["args"]["useful"] for c in calls] == [9, 200, 33, 120]
     (tick, calls), = ref_ticks
-    assert tick == {"live_rows": 4, "calls": 1}
+    assert tick == {"live_rows": 4, "calls": 1, "greedy": kind != "sampled"}
     assert calls[0]["args"]["rows"] == 4 * CAP
 
 
@@ -198,14 +198,16 @@ def test_rows_chunking_together_are_sent_a_row_a_call(model, store, kind):
     assert all(len(t) == 4 for t in got)
     _check_kind(model, kind, prompts, got, reqs, 4)
     ticks = [t for t in ticks if t[1][0]["name"] == "exe.prefill_chunk"]
-    assert ticks[0][0] == {"live_rows": 4, "calls": 4}
+    greedy = kind != "sampled"       # of the calls that chose a first token
+    assert ticks[0][0] == {"live_rows": 4, "calls": 4, "greedy": greedy}
     # the cached prompt's 40 tokens are hits, its partial block copied
     assert [c["args"]["useful"] for c in ticks[0][1]] == [256, 5, 30, 70]
-    assert ticks[1][0] == {"live_rows": 1, "calls": 1}      # the long one's
+    assert ticks[1][0] == {"live_rows": 1, "calls": 1,      # the long one's
+                           "greedy": greedy}
     assert ticks[1][1][0]["args"] == {**ticks[1][1][0]["args"],
                                       "rows": CAP, "useful": 44}
     ref = [t for t in ref_ticks if t[1][0]["name"] == "exe.prefill_chunk"]
-    assert ref[0][0] == {"live_rows": 4, "calls": 1}
+    assert ref[0][0] == {"live_rows": 4, "calls": 1, "greedy": greedy}
 
 
 def test_a_beam_rides_the_admission_calls_as_one_more_row(model):
@@ -222,7 +224,7 @@ def test_a_beam_rides_the_admission_calls_as_one_more_row(model):
     r1 = eng.add_request(Request(p1, max_new_tokens=5))
     ticks = _traced(eng)
     eng.assert_quiescent()
-    assert ticks[0][0] == {"live_rows": 3, "calls": 3}
+    assert ticks[0][0] == {"live_rows": 3, "calls": 3, "greedy": True}
     assert [c["args"]["useful"] for c in ticks[0][1]] == [11, 150, 37]
     out = {r: list(eng.requests[r].tokens) for r in (r0, r1, rb)}
     assert out[rb] == [int(t) for t in np.asarray(ref_seq)[len(pb):]]
@@ -329,7 +331,8 @@ def test_pad_rows_counts_the_dead_rows_of_the_calls_sent(model):
             eng.add_request(Request(p, max_new_tokens=1))
         base = GOODPUT.waste_by_why().get("pad_rows", 0)
         (tick, calls), = _traced(eng)
-        assert tick == {"live_rows": 3, "calls": -(-3 // rows)}
+        assert tick == {"live_rows": 3, "calls": -(-3 // rows),
+                        "greedy": True}
         assert all(c["args"]["rows"] == rows * CAP for c in calls)
         assert sum(c["args"]["useful"] for c in calls) == 90
         assert GOODPUT.waste_by_why().get("pad_rows", 0) - base == wasted

@@ -378,6 +378,148 @@ def test_hybrid_chunk_program_lowers_each_kernel_once(monkeypatch):
         == 3
 
 
+# ---------------------------------------------------------------------------
+# The decode tick whole, sampler included (ISSUE 38): compiled for the chip,
+# the sampler's full-vocabulary sort (with its softmax, cumulative sum and
+# draw) stays under ONE conditional, in the branch a greedy tick does not
+# take. A compiler that flattened the conditional into a select, or hoisted
+# the sort out of it, would put the sort back in every tick.
+def _computations(hlo):
+    """An HLO module's text -> {computation name: its body}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = "ENTRY" if line.startswith("ENTRY") else m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return {k: "\n".join(v) for k, v in comps.items()}
+
+
+def _reached(comps, root):
+    """The computations ``root`` calls, however deep, and itself."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [n for n in re.findall(r"%([\w.\-]+)", comps[c])
+                     if n in comps]
+    return seen
+
+
+def _assert_sort_under_the_samplers_conditional(hlo):
+    comps = _computations(hlo)
+    conds = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}"
+                       r".*/sampler/cond", hlo)
+    assert len(conds) == 1, conds
+    greedy, stochastic = (_reached(comps, b.strip().lstrip("%"))
+                          for b in conds[0].split(","))
+    sorts = {c for c, body in comps.items() if " sort(" in body}
+    assert sorts and sorts <= stochastic - greedy, sorts
+
+
+def _tiny_served(family):
+    """(abstract model, abstract cache of 8 slots) of a served family on
+    the slab tiling."""
+    from paddle_tpu.models import paged
+    kw = dict(hidden_size=256, num_attention_heads=2, num_key_value_heads=2,
+              intermediate_size=512, vocab_size=4096, dtype=bf16)
+    if family == "llama":
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        cfg, cls = LlamaConfig.tiny(num_hidden_layers=2, **kw), \
+            LlamaForCausalLM
+    elif family == "ouro":
+        from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+        cfg, cls = OuroConfig.tiny(num_hidden_layers=2, total_ut_steps=4,
+                                   **kw), OuroForCausalLM
+    else:
+        from paddle_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                   OlmoHybridForCausalLM)
+        cfg, cls = OlmoHybridConfig.tiny(
+            linear_num_key_heads=2, linear_num_value_heads=2,
+            linear_key_head_dim=96, linear_value_head_dim=192, **kw), \
+            OlmoHybridForCausalLM
+    return (jax.eval_shape(lambda: cls(cfg)),
+            jax.eval_shape(lambda: paged.PagedKVCache.init_for(
+                cfg, 32, 16, 8, 8)))
+
+
+def _placed(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
+        tree)
+
+
+def _tick_args(model, cache, sharding):
+    """``llama_decode_tick``'s array arguments for 8 slots, as shapes, every
+    leaf with ``sharding``."""
+    S = jax.ShapeDtypeStruct
+    return _placed((model, S((8,), i32), cache, S((8,), jnp.bool_),
+                    S((8,), i32), S((8,), i32), S((8,), i32),
+                    S((2,), jnp.uint32), S((8,), f32), S((8,), f32)),
+                   sharding)
+
+
+def _compiled_for_the_chip(monkeypatch, jitted, *args):
+    """The program's text, compiled with the dispatchers on their TPU side."""
+    from paddle_tpu.models import paged
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged.clear_jit_caches()
+    try:
+        return jitted.lower(*args).compile().as_text()
+    finally:
+        paged.clear_jit_caches()     # traced under a patched backend
+
+
+@pytest.mark.parametrize("family", ["llama", "ouro", "hybrid"])
+def test_decode_tick_holds_its_sort_under_a_conditional(one_chip,
+                                                        monkeypatch, family):
+    from paddle_tpu.models import paged
+    tick = jax.jit(paged.llama_decode_tick, static_argnums=(10, 11),
+                   donate_argnums=(2,))
+    text = _compiled_for_the_chip(
+        monkeypatch, tick, *_tick_args(*_tiny_served(family), one_chip),
+        None, False)
+    assert "paged_decode_attention" in text
+    _assert_sort_under_the_samplers_conditional(text)
+
+
+def test_cp_decode_tick_holds_its_sort_under_a_conditional(v5e_2x2,
+                                                           monkeypatch):
+    """The cp tick as the executor builds it: the tick under a ``shard_map``
+    over the four chips, pools sharded on their blocks, everything else
+    (the temperatures, so the predicate) replicated."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.models import paged
+    model, cache = _tiny_served("llama")
+    mesh = Mesh(np.array(v5e_2x2), ("cp",))
+    R, pool = P(), P("cp")
+    cs = paged.PagedKVCache(pool, pool, R, R, pool, pool)
+
+    def tick(model, tokens, cache, active, rows, cols, vals, rng, temps,
+             top_ps):
+        return paged.llama_decode_tick(
+            model, tokens, cache, active, rows, cols, vals, rng, temps,
+            top_ps, None, False, None, None, cp_axis="cp")
+
+    fn = jax.jit(jax.shard_map(
+        tick, mesh=mesh, check_vma=False,
+        in_specs=(R, R, cs, R, R, R, R, R, R, R), out_specs=(R, R, cs)),
+        donate_argnums=(2,))
+    args = list(_tick_args(model, cache, NamedSharding(mesh, R)))
+    sharded = NamedSharding(mesh, pool)
+    args[2] = paged.PagedKVCache(
+        _placed(cache.k_pools, sharded), _placed(cache.v_pools, sharded),
+        args[2].block_tables, args[2].lens,
+        _placed(cache.k_scales, sharded), _placed(cache.v_scales, sharded),
+        cache.passes)
+    _assert_sort_under_the_samplers_conditional(
+        _compiled_for_the_chip(monkeypatch, fn, *args))
+
+
 # (id, B, S, H, H_kv, window, with backward)
 FLASH = [
     ("fwd_4x2048x16", 4, 2048, 16, 16, None, False),
@@ -461,9 +603,7 @@ def test_fused_rope_compiles(one_chip):
 # backend check is patched here (in the test only) and one step of each
 # such path is compiled for the described 2x2.
 def _abstract(tree, mesh):
-    rep = mesh.replicated()
-    return jax.tree_util.tree_map(
-        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=rep), tree)
+    return _placed(tree, mesh.replicated())
 
 
 def _kernels_in(jitted, *args):
