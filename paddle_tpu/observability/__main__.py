@@ -18,6 +18,7 @@ from paddle_tpu.observability.metrics import METRICS
 # every module that registers instruments at import time (a test_lint
 # rule asserts every METRICS.counter/gauge/histogram caller is listed)
 _INSTRUMENT_MODULES = (
+    "paddle_tpu.observability.tracing",
     "paddle_tpu.observability.flops",
     "paddle_tpu.observability.roofline",
     "paddle_tpu.observability.compile",

@@ -36,6 +36,13 @@ the device. :func:`instant` emits zero-duration "i" events — fault
 injections use it so a chaos run's timeline shows exactly where each
 fault landed.
 
+The collector's pauses are on the same timeline: one ``gc.callbacks``
+entry, installed when this module is imported, marks every collection as
+a span ``host.gc`` (``generation``, ``collected``) under whatever span is
+open on the thread the collection interrupts, recorded iff spans record;
+and always adds the pause, from its own two clock reads, to the counter
+``python_gc_seconds_total{generation}``.
+
 Cross-thread/cross-replica stitching (ISSUE 9): :meth:`Tracer.flow`
 emits Chrome-trace flow events — ``ph`` "s" (start) / "t" (step) /
 "f" (end) sharing an ``id`` draw as one connected arrow across
@@ -49,12 +56,15 @@ real OS thread.
 from __future__ import annotations
 
 import functools
+import gc
 import itertools
 import json
 import os
 import sys
 import threading
 import time
+
+from paddle_tpu.observability.metrics import METRICS
 
 __all__ = ["TRACER", "Tracer", "span", "instant", "export_chrome_trace"]
 
@@ -174,7 +184,9 @@ class Tracer:
     def __init__(self, max_events: int = 200_000):
         self.max_events = max_events
         self._events: list[dict] = []
-        self._lock = threading.Lock()
+        # re-entrant: a collection that starts while this thread holds the
+        # lock (``export`` allocates under it) ends in ``_emit``
+        self._lock = threading.RLock()
         self._enabled = False
         self._pid = os.getpid()
         self._tracks: dict = {}      # label -> synthetic tid (survives clear)
@@ -310,3 +322,31 @@ def instant(name: str, **args):
 
 def export_chrome_trace(path: str = None) -> str:
     return TRACER.export_chrome_trace(path)
+
+
+# ------------------------------------------------------------ the collector
+_GC_SECONDS = METRICS.counter(
+    "python_gc_seconds_total",
+    "seconds the thread that tripped it stood in Python's cyclic garbage "
+    "collector, by generation collected (a tail of gaps between tokens "
+    "that follows this counter is the collector's)",
+    labelnames=("generation",))
+_GC_BY_GEN = tuple(_GC_SECONDS.labels(generation=g) for g in range(3))
+_GC_OPEN = []                      # (the pass in hand's span, its start)
+
+
+def _on_gc(phase: str, info: dict):
+    """The ``gc.callbacks`` entry. Collections do not nest and a pass
+    ends on the thread it began on, so one slot holds the pass in hand."""
+    t = time.monotonic_ns()
+    if phase == "start":
+        _GC_OPEN[:] = [(span("host.gc",
+                             generation=info["generation"]).begin(t), t)]
+    elif _GC_OPEN:
+        sp, t0 = _GC_OPEN.pop()
+        _GC_BY_GEN[info["generation"]].inc((t - t0) * 1e-9)
+        sp.set(collected=info["collected"])
+        sp.end(t)
+
+
+gc.callbacks.append(_on_gc)

@@ -550,6 +550,14 @@ class LLMEngine:
 
     # ------------------------------------------------------------- intake
     def add_request(self, req: Request) -> int:
+        """Check and queue ``req`` -> its id. The span ``serving.submit``
+        is the program's share of what a caller does between two ticks."""
+        with _span("serving.submit") as sp:
+            rid = self._submit(req)
+            sp.set(rid=rid)
+            return rid
+
+    def _submit(self, req: Request) -> int:
         self.sched.check_backpressure(self.stats)
         # ladder L4: explicit backpressure on NEW sessions. Requests a
         # Router already accepted (_preadmitted) pass — rejecting them
@@ -2503,8 +2511,9 @@ class LLMEngine:
         self.stats["device_s"] += time.perf_counter() - t0
         dev["tokens"], dev["stop"], dev["gen"] = nxt, stop, gen
         self._async_rewound = False
-        self._async_win.append(
-            {"nxt": nxt, "ran": ran, "rng_before": rng_before})
+        self._async_win.append({"nxt": nxt, "ran": ran,
+                                "rng_before": rng_before,
+                                "seq": self.exe.model_seq})
         self.stats["ticks"] += 1
         emitted = []
         if len(self._async_win) > self.async_depth:
@@ -2529,7 +2538,8 @@ class LLMEngine:
         never ran that tick, so it never consumed that key."""
         e = self._async_win.pop(0)
         t0 = time.monotonic_ns()
-        fetch = _span("serving.fetch", cat="device_wait").begin(t0)
+        fetch = _span("serving.fetch", cat="device_wait",
+                      seq=e["seq"]).begin(t0)
         nxt = np.asarray(e["nxt"])
         ran = np.asarray(e["ran"])
         t1 = time.monotonic_ns()
@@ -2639,69 +2649,76 @@ class LLMEngine:
                    calls=self._prefill_sent[1])
             if self._prefill_greedy:
                 sp.set(greedy=all(self._prefill_greedy))
-        if self.prefill_only:
-            # prefill-role replica: newly activated slots carry their
-            # first token; the router extracts them — never decode here
-            return emitted
-        if not self.active.any():
-            return emitted
-        # speculative draft-and-verify for eligible slots; the plain
-        # one-token tick then covers only what speculation did not handle
-        # (beam slots, final-token slots, fallback after an injected
-        # verify fault).
-        spec_handled = np.zeros(self.num_slots, bool)
-        if (self.draft_model is not None
-                and (self.degrade is None or self.degrade.spec_enabled())):
-            elig = (self.active & ~self.is_beam
-                    & (self.max_gen - self.gen >= 2))
-            if elig.any():
-                spec_handled, spec_emitted = self._spec_tick(elig)
-                emitted += spec_emitted
-        run_mask = self.active & ~spec_handled
-        if not run_mask.any():
-            # every active slot advanced speculatively: the whole point —
-            # this tick paid ONE target forward for k+1 positions per slot
-            return emitted
-        t0 = time.perf_counter()
-        if self._is_moe:
-            # chaos: a dead expert shard fails the token all_to_all. Fires
-            # BEFORE table growth and the donating tick jit, so an injected
-            # exception aborts the tick with the cache, tables, and
-            # table_len untouched — cancel/free reclaims every block and
-            # assert_quiescent stays clean (exception-atomic).
-            fault_point("serving.moe_dispatch", engine=self,
-                        slots=np.nonzero(run_mask)[0])
-        if self.exe.cache.k_scales:
-            # chaos: quantize-on-write about to run inside the tick jit
-            # (int8 pools only). Fires BEFORE table growth and the
-            # donating tick, so an injected exception aborts with pools,
-            # scale pools, tables, and the ledger untouched — no leaked
-            # blocks, no stale scales (exception-atomic).
-            fault_point("serving.kv_quant", engine=self,
-                        slots=np.nonzero(run_mask)[0])
-        if self.cp > 1:
-            # chaos: the decode tick is about to run the cross-shard
-            # partial gather (psum merge over cp). Fires BEFORE table
-            # growth and the donating tick jit, so an injected exception
-            # aborts the tick with the cache, tables, table_len, and the
-            # ledger untouched — no leaked blocks, assert_quiescent and
-            # reconcile stay clean (exception-atomic).
-            fault_point("serving.cp_gather", engine=self,
-                        slots=np.nonzero(run_mask)[0])
-        rows, cols, vals = self._grow_tables(run_mask & ~self.is_beam)
-        # growth may have preempted slots — recompute the mask after it
-        run_mask = self.active & ~spec_handled
-        # roofline: one weight pass over the batch; every running slot
-        # reads its whole block-rounded context and writes one position
-        n_run = int(run_mask.sum())
-        greedy = self._count_sampler(self.temps[run_mask])
-        ctx = self._ctx_blocks(run_mask)
-        self._acc_phase("decode", n_run, 1, ctx)
-        t1 = time.perf_counter()
-        d_aidx = np.where(run_mask, self.slot_aidx, -1)
-        d_bias = self._grammar_bias_rows(
-            [(int(s), int(s)) for s in np.nonzero(run_mask)[0]],
-            self.num_slots)
+        # what lies between the prefill calls and the decode dispatch: the
+        # tick's inputs are built here, with nothing in flight
+        with _span("serving.stage") as stage:
+            preempted = self.stats["preemptions"]
+            if self.prefill_only:
+                # prefill-role replica: newly activated slots carry their
+                # first token; the router extracts them — never decode here
+                return emitted
+            if not self.active.any():
+                return emitted
+            # speculative draft-and-verify for eligible slots; the plain
+            # one-token tick then covers only what speculation did not handle
+            # (beam slots, final-token slots, fallback after an injected
+            # verify fault).
+            spec_handled = np.zeros(self.num_slots, bool)
+            if (self.draft_model is not None
+                    and (self.degrade is None or self.degrade.spec_enabled())):
+                elig = (self.active & ~self.is_beam
+                        & (self.max_gen - self.gen >= 2))
+                if elig.any():
+                    spec_handled, spec_emitted = self._spec_tick(elig)
+                    emitted += spec_emitted
+            run_mask = self.active & ~spec_handled
+            if not run_mask.any():
+                # every active slot advanced speculatively: the whole point —
+                # this tick paid ONE target forward for k+1 positions per slot
+                return emitted
+            t0 = time.perf_counter()
+            if self._is_moe:
+                # chaos: a dead expert shard fails the token all_to_all. Fires
+                # BEFORE table growth and the donating tick jit, so an injected
+                # exception aborts the tick with the cache, tables, and
+                # table_len untouched — cancel/free reclaims every block and
+                # assert_quiescent stays clean (exception-atomic).
+                fault_point("serving.moe_dispatch", engine=self,
+                            slots=np.nonzero(run_mask)[0])
+            if self.exe.cache.k_scales:
+                # chaos: quantize-on-write about to run inside the tick jit
+                # (int8 pools only). Fires BEFORE table growth and the
+                # donating tick, so an injected exception aborts with pools,
+                # scale pools, tables, and the ledger untouched — no leaked
+                # blocks, no stale scales (exception-atomic).
+                fault_point("serving.kv_quant", engine=self,
+                            slots=np.nonzero(run_mask)[0])
+            if self.cp > 1:
+                # chaos: the decode tick is about to run the cross-shard
+                # partial gather (psum merge over cp). Fires BEFORE table
+                # growth and the donating tick jit, so an injected exception
+                # aborts the tick with the cache, tables, table_len, and the
+                # ledger untouched — no leaked blocks, assert_quiescent and
+                # reconcile stay clean (exception-atomic).
+                fault_point("serving.cp_gather", engine=self,
+                            slots=np.nonzero(run_mask)[0])
+            rows, cols, vals = self._grow_tables(run_mask & ~self.is_beam)
+            # growth may have preempted slots — recompute the mask after it
+            run_mask = self.active & ~spec_handled
+            # roofline: one weight pass over the batch; every running slot
+            # reads its whole block-rounded context and writes one position
+            n_run = int(run_mask.sum())
+            greedy = self._count_sampler(self.temps[run_mask])
+            ctx = self._ctx_blocks(run_mask)
+            self._acc_phase("decode", n_run, 1, ctx)
+            t1 = time.perf_counter()
+            d_aidx = np.where(run_mask, self.slot_aidx, -1)
+            d_bias = self._grammar_bias_rows(
+                [(int(s), int(s)) for s in np.nonzero(run_mask)[0]],
+                self.num_slots)
+            if stage.recording:
+                stage.set(grown=int((rows < self.num_slots).sum()),
+                          preempted=self.stats["preemptions"] - preempted)
         # kv_blocks: the pool blocks the decode kernel walks this tick
         # (what is left of slots x table width)
         with self._tick_timer("sample", "serving.decode", slots=n_run,
@@ -2718,7 +2735,8 @@ class LLMEngine:
             # now, and the host sweeps its gauges while the device works
             nxt.copy_to_host_async()
             self._sweep_in_shadow(run_mask)
-            with _span("serving.fetch", cat="device_wait"):
+            with _span("serving.fetch", cat="device_wait",
+                       seq=self.exe.model_seq):
                 nxt = np.asarray(nxt)         # the one per-tick host fetch
         t2 = time.perf_counter()
         if self.cp > 1:

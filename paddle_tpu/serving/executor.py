@@ -116,6 +116,11 @@ class ModelExecutor:
         # the one copy of the model the executor keeps, and what every
         # program is handed: the weights as they were when it was built
         self._model = _FlatModel(model)
+        # the running count of programs dispatched (``exe.dispatch``'s
+        # ``seq``), and the count at the newest one that was not the key's
+        # split: the ``seq`` a ``device_wait`` span names when it waits for
+        # the program just sent
+        self.seq = self.model_seq = 0
         self.top_k = top_k
         self.rng = jax.random.PRNGKey(seed)     # the setter: no pair held
         self.cp = int(cp)
@@ -225,6 +230,19 @@ class ModelExecutor:
             functools.partial(_prefix_cow_update, cp_axis="cp"),
             (cs, R, R), cs), donate_argnums=(0,))
 
+    def _dispatch(self, program: str, jitted, *args, **kw):
+        """Every jitted call the executor makes: the span ``exe.dispatch``
+        (``cat="dispatch"``) around the call and nothing else, so its
+        duration is the flatten, the upload and the enqueue; ``seq`` counts
+        the programs. With the ``seq`` on the ``device_wait`` spans a reader
+        knows from the host's clock when nothing was in flight."""
+        self.seq += 1
+        if program != "split":
+            self.model_seq = self.seq
+        with _span("exe.dispatch", cat="dispatch", program=program,
+                   seq=self.seq):
+            return jitted(*args, **kw)
+
     @property
     def rng(self):
         """The engine key: what the next :meth:`next_key` splits."""
@@ -239,7 +257,8 @@ class ModelExecutor:
     def next_key(self):
         """The chained split: ``rng, sub = split(rng)``. The pair is the
         one :meth:`split_ahead` dispatched, where it did."""
-        pair = self._split_ahead or _SPLIT_JIT(self._rng)
+        pair = self._split_ahead or self._dispatch("split", _SPLIT_JIT,
+                                                   self._rng)
         self._rng, sub = pair
         self._split_ahead = None
         return sub
@@ -249,7 +268,8 @@ class ModelExecutor:
         program just queued, so the split's dispatch costs the host time
         it would spend waiting, not time the device then idles."""
         if self._split_ahead is None:
-            self._split_ahead = _SPLIT_JIT(self._rng)
+            self._split_ahead = self._dispatch("split", _SPLIT_JIT,
+                                               self._rng)
 
     def _no_cp_lora(self, lora):
         if lora is not None and self.cp > 1:
@@ -268,15 +288,19 @@ class ModelExecutor:
         flight hold one pool, not one more for each."""
         with _span("exe.prefill", **_token_rows(ids, lens),
                    **self._ctx_tokens(lens, 0), **self.span_args):
+            # the uploads stay outside the dispatch edge: the device waits
+            # for them, and they are this entry's self time
+            ids, lens = jnp.asarray(ids), jnp.asarray(lens)
+            slots, rows = jnp.asarray(slots), jnp.asarray(rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
-                logits, self.cache = self._cp_prefill(
-                    self._model, jnp.asarray(ids), jnp.asarray(lens),
-                    self.cache, jnp.asarray(slots), jnp.asarray(rows))
+                logits, self.cache = self._dispatch(
+                    "prefill", self._cp_prefill, self._model, ids, lens,
+                    self.cache, slots, rows)
                 return logits
-            logits, self.cache = _PREFILL_JIT(
-                self._model, jnp.asarray(ids), jnp.asarray(lens),
-                self.cache, jnp.asarray(slots), jnp.asarray(rows), lora=lora)
+            logits, self.cache = self._dispatch(
+                "prefill", _PREFILL_JIT, self._model, ids, lens, self.cache,
+                slots, rows, lora=lora)
             return logits
 
     def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
@@ -286,17 +310,18 @@ class ModelExecutor:
                    kv_blocks=_chunk_kv_blocks(lens, offs,
                                               self.cache.block_size),
                    **self._ctx_tokens(lens, offs), **self.span_args):
+            ids, lens, offs = (jnp.asarray(ids), jnp.asarray(lens),
+                               jnp.asarray(offs))
+            slots, rows = jnp.asarray(slots), jnp.asarray(rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
-                logits, self.cache = self._cp_prefill_chunk(
-                    self._model, jnp.asarray(ids), jnp.asarray(lens),
-                    jnp.asarray(offs), self.cache, jnp.asarray(slots),
-                    jnp.asarray(rows))
+                logits, self.cache = self._dispatch(
+                    "chunk", self._cp_prefill_chunk, self._model, ids, lens,
+                    offs, self.cache, slots, rows)
                 return logits
-            logits, self.cache = _PREFILL_CHUNK_JIT(
-                self._model, jnp.asarray(ids), jnp.asarray(lens),
-                jnp.asarray(offs), self.cache, jnp.asarray(slots),
-                jnp.asarray(rows), lora=lora)
+            logits, self.cache = self._dispatch(
+                "chunk", _PREFILL_CHUNK_JIT, self._model, ids, lens, offs,
+                self.cache, slots, rows, lora=lora)
             return logits
 
     def _ctx_tokens(self, lens, offs) -> dict:
@@ -318,13 +343,15 @@ class ModelExecutor:
     def take_state(self, slot: int, idx: int):
         """Snapshot entry ``idx`` <- the state slot ``slot`` holds once the
         programs queued so far have run."""
-        self.snaps = _STATE_TAKE_JIT(self.snaps, self.cache.states,
-                                     np.int32(slot), np.int32(idx))
+        self.snaps = self._dispatch(
+            "state_take", _STATE_TAKE_JIT, self.snaps, self.cache.states,
+            np.int32(slot), np.int32(idx))
 
     def restore_state(self, slot: int, idx: int):
         """Slot ``slot``'s state <- snapshot entry ``idx``."""
-        self.cache = _STATE_RESTORE_JIT(self.cache, self.snaps,
-                                        np.int32(slot), np.int32(idx))
+        self.cache = self._dispatch(
+            "state_restore", _STATE_RESTORE_JIT, self.cache, self.snaps,
+            np.int32(slot), np.int32(idx))
 
     def verify_chunk(self, ids, clens, offs, slot_ids, rows, lora=None):
         """Target forward over each slot's proposal window (spec decode);
@@ -334,27 +361,25 @@ class ModelExecutor:
                 f"{STATEFUL_MODEL} is not served with verify_chunk: a "
                 "rejected token's write to the recurrent state cannot be "
                 "rolled back")
+        ids, clens, offs = (jnp.asarray(ids), jnp.asarray(clens),
+                            jnp.asarray(offs))
+        slot_ids, rows = jnp.asarray(slot_ids), jnp.asarray(rows)
         if self.cp > 1:
             self._no_cp_lora(lora)
-            logits, self.cache = self._cp_verify_chunk(
-                self._model, jnp.asarray(ids), jnp.asarray(clens),
-                jnp.asarray(offs), self.cache, jnp.asarray(slot_ids),
-                jnp.asarray(rows))
+            logits, self.cache = self._dispatch(
+                "verify", self._cp_verify_chunk, self._model, ids, clens,
+                offs, self.cache, slot_ids, rows)
             return logits
-        logits, self.cache = _VERIFY_CHUNK_JIT(
-            self._model, jnp.asarray(ids), jnp.asarray(clens),
-            jnp.asarray(offs), self.cache, jnp.asarray(slot_ids),
-            jnp.asarray(rows), lora=lora)
+        logits, self.cache = self._dispatch(
+            "verify", _VERIFY_CHUNK_JIT, self._model, ids, clens, offs,
+            self.cache, slot_ids, rows, lora=lora)
         return logits
 
     def rewind_lens(self, slots, lens):
         """Length-pointer-only rewind after a partial spec accept."""
-        if self.cp > 1:
-            self.cache = self._cp_rewind(self.cache, jnp.asarray(slots),
-                                         jnp.asarray(lens))
-            return
-        self.cache = _REWIND_LENS_JIT(self.cache, jnp.asarray(slots),
-                                      jnp.asarray(lens))
+        self.cache = self._dispatch(
+            "rewind", self._cp_rewind if self.cp > 1 else _REWIND_LENS_JIT,
+            self.cache, jnp.asarray(slots), jnp.asarray(lens))
 
     # ------------------------------------------------------------- decode
     def decode_tick(self, last_tok, run_mask, rows, cols, vals, temps,
@@ -374,7 +399,8 @@ class ModelExecutor:
                     raise NotImplementedError(
                         "beam search (want_logp) under cp > 1 is not "
                         "supported")
-                nxt, logp, self.cache = self._cp_tick(
+                nxt, logp, self.cache = self._dispatch(
+                    "tick", self._cp_tick,
                     self._model, jnp.asarray(last_tok), self.cache,
                     jnp.asarray(run_mask), jnp.asarray(rows),
                     jnp.asarray(cols), jnp.asarray(vals), sub,
@@ -384,7 +410,8 @@ class ModelExecutor:
                 # the staging arrays go in as the numpy arrays they are:
                 # the call uploads them together, where a ``jnp.asarray``
                 # each is a dispatch each, with the device idle meanwhile
-                nxt, logp, self.cache = _TICK_JIT(
+                nxt, logp, self.cache = self._dispatch(
+                    "tick", _TICK_JIT,
                     self._model, last_tok, self.cache, run_mask, rows,
                     cols, vals, sub, temps, top_ps, self.top_k, need_logp,
                     lora=lora, logit_bias=bias)
@@ -404,7 +431,8 @@ class ModelExecutor:
         with _span("exe.decode_tick", slots=int(np.sum(active)),
                    **self.span_args):
             sub = self.next_key()
-            nxt, ran, stop, gen, self.cache = _async_tick_jit()(
+            nxt, ran, stop, gen, self.cache = self._dispatch(
+                "tick", _async_tick_jit(),
                 self._model, tokens, self.cache, jnp.asarray(active), stop,
                 gen, max_gen, sub, jnp.asarray(temps), jnp.asarray(top_ps),
                 jnp.int32(eos_id), self.top_k)
@@ -423,8 +451,8 @@ class ModelExecutor:
             dst = np.full(width, nb, np.int32)
             for j, (s, d) in enumerate(chunk):
                 src[j], dst[j] = s, d
-            self.cache = cow(self.cache, jnp.asarray(src),
-                             jnp.asarray(dst))
+            self.cache = self._dispatch("cow", cow, self.cache,
+                                        jnp.asarray(src), jnp.asarray(dst))
 
     def beam_group_update(self, slots, rows, lens_val, copy_src, copy_dst):
         """Install forked beam tables + partial-block copy-on-write."""
@@ -432,7 +460,8 @@ class ModelExecutor:
             raise NotImplementedError(
                 "beam search under context parallelism (cp > 1) is not "
                 "supported yet")
-        self.cache = _BEAM_GROUP_UPDATE_JIT(
+        self.cache = self._dispatch(
+            "beam", _BEAM_GROUP_UPDATE_JIT,
             self.cache, jnp.asarray(slots, jnp.int32), jnp.asarray(rows),
             jnp.asarray(lens_val, jnp.int32), jnp.asarray(copy_src),
             jnp.asarray(copy_dst))
@@ -442,7 +471,8 @@ class ModelExecutor:
         """Per-row temperature/top-k/top-p sampling, dispatched and not
         waited for: -> the rows' tokens, on the device. ``bias`` ([rows,
         V], 0 / -1e30) is the grammar-mask addend."""
-        sampled = _SAMPLE_ROWS_JIT(
+        sampled = self._dispatch(
+            "sample", _SAMPLE_ROWS_JIT,
             logits.astype(jnp.float32), self.next_key(), jnp.asarray(temps),
             jnp.asarray(top_ps), self.top_k,
             bias=(None if bias is None else jnp.asarray(bias)))
@@ -452,7 +482,7 @@ class ModelExecutor:
     def fetch_sampled(self, sampled):
         """The host's one wait of a prefill entry: each of
         :meth:`sample_rows`' results, as numpy."""
-        with _span("exe.sample", cat="device_wait",
+        with _span("exe.sample", cat="device_wait", seq=self.model_seq,
                    rows=sum(t.shape[0] for t in sampled)):
             return [np.asarray(t) for t in sampled]
 
@@ -460,7 +490,8 @@ class ModelExecutor:
     def draft_rows(self, ids, rp, cl):
         """One draft-model forward over per-row chunks of the dense
         draft cache (speculative proposal feeds)."""
-        logits, self._draft_cache = _FWD_ROWS_JIT(
+        logits, self._draft_cache = self._dispatch(
+            "draft", _FWD_ROWS_JIT,
             self.draft_model, jnp.asarray(ids), self._draft_cache,
             jnp.asarray(rp, jnp.int32), None, jnp.asarray(cl, jnp.int32))
         return logits

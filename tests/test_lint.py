@@ -182,6 +182,7 @@ def test_threads_carry_pt_name_prefix():
 _INSTRUMENT_PREFIXES = (
     "serving_", "router_", "train_", "io_", "ckpt_", "moe_", "compile_",
     "collective_", "elastic_", "faults_", "steptimer_", "device_",
+    "python_",      # the interpreter itself: the collector's pauses
 )
 _INSTRUMENT_ALLOWLIST = {
     # e.g. "paddle_tpu/some/module.py": "registers dynamic names",

@@ -6,7 +6,9 @@ at the executor's entries, and a name on every Pallas kernel.
 All CPU, none timing-sensitive: times are only compared with each other.
 """
 import ast
+import gc
 import glob
+import json
 import re
 import time
 from pathlib import Path
@@ -18,8 +20,9 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.observability import GOODPUT, TRACER, span
-from paddle_tpu.serving import LLMEngine, Request
+from paddle_tpu.observability import GOODPUT, METRICS, TRACER, span
+from paddle_tpu.serving import LLMEngine, Request, engine as engine_mod
+from paddle_tpu.serving import executor as executor_mod
 
 PALLAS_DIR = Path(pt.__file__).parent / "ops" / "pallas"
 KERNEL_NAMES = {
@@ -61,8 +64,11 @@ def _run(eng):
     return ticks
 
 
-def _spans():
-    return [e for e in TRACER.export()["traceEvents"] if e["ph"] == "X"]
+def _spans(collector=False):
+    """The buffer's spans. A collection may start anywhere, so a test
+    that counts spans leaves the collector's (``host.gc``) out."""
+    return [e for e in TRACER.export()["traceEvents"] if e["ph"] == "X"
+            and (collector or e["name"] != "host.gc")]
 
 
 def _descendants(evs, root):
@@ -114,9 +120,13 @@ def test_span_off_records_nothing_and_reads_no_clock(monkeypatch):
     real = time.monotonic_ns
     monkeypatch.setattr(time, "monotonic_ns",
                         lambda: reads.append(1) or real())
-    with span("ghost", n=1) as sp:
-        assert not sp.recording
-        sp.set(more=2)
+    gc.disable()                 # the collector's two reads are its own
+    try:
+        with span("ghost", n=1) as sp:
+            assert not sp.recording
+            sp.set(more=2)
+    finally:
+        gc.enable()
     assert reads == [] and TRACER.export()["traceEvents"] == []
 
 
@@ -164,6 +174,76 @@ def test_decorated_span_nests_under_the_callers_span():
     caller = next(e for e in evs if e["name"] == "caller")
     callees = [e for e in evs if e["name"] == "callee"]
     assert [e["parent"] for e in callees] == [caller["id"], None]
+
+
+# ------------------------------------------------ the collector's pauses
+
+def _gc_seconds(generation=2):
+    return METRICS.get("python_gc_seconds_total").value(
+        generation=str(generation))
+
+
+@pytest.mark.parametrize("recording", [True, False])
+def test_a_collection_is_a_host_gc_span_and_always_moves_the_counter(
+        recording):
+    """Recording: ``host.gc`` under the span that was open, with the
+    generation and what was collected. Everything off: nothing in the
+    buffer. Either way the pause is in ``python_gc_seconds_total``."""
+    before = _gc_seconds()
+    if recording:
+        TRACER.enable()
+    with span("outer"):
+        cycle = []
+        cycle.append(cycle)
+        del cycle
+        gc.collect()
+    TRACER.disable()
+    assert _gc_seconds() > before
+    if not recording:
+        assert TRACER.export()["traceEvents"] == []
+        return
+    evs = _spans(collector=True)
+    outer = next(e for e in evs if e["name"] == "outer")
+    full = [e for e in evs if e["name"] == "host.gc"
+            and e["args"]["generation"] == 2]
+    assert len(full) == 1 and full[0]["parent"] == outer["id"]
+    assert full[0]["args"]["collected"] >= 1
+    assert outer["ts"] <= full[0]["ts"]
+    assert full[0]["ts"] + full[0]["dur"] <= outer["ts"] + outer["dur"]
+    # the counter holds the span's own two clock reads
+    assert _gc_seconds() - before >= full[0]["dur"] * 1e-6 - 1e-9
+
+
+def test_export_under_a_collection_does_not_deadlock():
+    """``export`` allocates with the buffer's lock held; a collection
+    that starts there ends in ``_emit`` on the same thread."""
+    TRACER.enable()
+    for _ in range(200):
+        with span("filler"):
+            pass
+    real = TRACER._lock
+    calls = []
+
+    class Collecting:
+        def __enter__(self):
+            if not real.acquire(timeout=5):      # not re-entrant: no span
+                raise RuntimeError("the buffer's lock is held")
+            if not calls:
+                calls.append(1)
+                gc.collect()     # a pass while this thread holds the lock
+            return self
+
+        def __exit__(self, *exc):
+            real.release()
+            return False
+
+    TRACER._lock = Collecting()
+    try:
+        names = [e["name"] for e in TRACER.export()["traceEvents"]]
+    finally:
+        TRACER._lock = real
+        TRACER.disable()
+    assert names.count("filler") == 200 and "host.gc" in names
 
 
 # ------------------------------------------------------ the serving tick
@@ -228,6 +308,241 @@ def test_every_tick_is_one_step_span_with_its_children_inside(model):
     total = sum(len(r.tokens) for r in eng.requests.values())
     first = 3                     # each request's first token comes from
     assert sum(e["args"]["tokens"] for e in emits) == total - first  # prefill
+
+
+# ----------------------------------------- the dispatch edge and the waits
+
+# the executor's module-level programs and the ``program`` each is sent as
+PROGRAMS = {"_TICK_JIT": "tick", "_PREFILL_JIT": "prefill",
+            "_PREFILL_CHUNK_JIT": "chunk", "_SAMPLE_ROWS_JIT": "sample",
+            "_SPLIT_JIT": "split", "_PREFIX_COW_JIT": "cow",
+            "_STATE_TAKE_JIT": "state_take",
+            "_STATE_RESTORE_JIT": "state_restore"}
+
+
+def _count_jitted_calls(monkeypatch):
+    """Wrap every program the executor module holds -> {program: calls}."""
+    calls = {}
+    for attr, program in PROGRAMS.items():
+        def counted(*a, _fn=getattr(executor_mod, attr), _p=program, **kw):
+            calls[_p] = calls.get(_p, 0) + 1
+            return _fn(*a, **kw)
+        monkeypatch.setattr(executor_mod, attr, counted)
+    return calls
+
+
+def _hybrid_engine():
+    from chipbench.builders import olmo_hybrid as builder
+    cfg = json.loads((Path(pt.__file__).parents[1] / "chipbench" / "tests"
+                      / "cells" / "configs"
+                      / "tiny-olmo-hybrid.json").read_text())
+    return LLMEngine(builder.build(cfg, 3).eval(), num_slots=4, block_size=4,
+                     max_prompt_len=16, max_seq_len=128, num_blocks=64,
+                     num_state_snapshots=4)
+
+
+@pytest.mark.parametrize("family", ["llama", "hybrid"])
+def test_every_jitted_call_is_one_dispatch_span(model, monkeypatch, family):
+    """One ``exe.dispatch`` a jitted call, named by its ``program``;
+    ``seq`` rises by one from call to call, whatever the program."""
+    if family == "llama":
+        eng, rounds = _engine(model), 2      # a second round: radix hits,
+        prompts = [np.random.RandomState(1).randint(0, 64, (n,))  # one cow
+                   for n in (5, 19, 3)]
+        prompts.append(np.concatenate([prompts[1][:9], [7, 7]]))
+        want = {"tick", "prefill", "chunk", "sample", "split", "cow"}
+    else:
+        eng, rounds = _hybrid_engine(), 3    # K/V, a snapshot, a restore
+        doc = np.random.default_rng(7).integers(1, 256, 32, dtype=np.int32)
+        prompts = [np.concatenate([doc, [9, 8, 7, 6, 5]])]
+        want = {"tick", "chunk", "sample", "split", "state_take",
+                "state_restore"}
+    calls = _count_jitted_calls(monkeypatch)
+    first = eng.exe.seq
+    TRACER.enable()
+    for _ in range(rounds):
+        for p in prompts:
+            eng.add_request(Request(p, max_new_tokens=4))
+        _run(eng)
+    TRACER.disable()
+    sent = sorted((e for e in _spans() if e["name"] == "exe.dispatch"),
+                  key=lambda e: e["ts"])
+    assert all(e["cat"] == "dispatch" for e in sent)
+    by_program = {}
+    for e in sent:
+        by_program[e["args"]["program"]] = by_program.get(
+            e["args"]["program"], 0) + 1
+    assert by_program == calls and set(calls) >= want
+    assert [e["args"]["seq"] for e in sent] == list(
+        range(first + 1, first + 1 + len(sent)))
+    assert eng.exe.seq == first + len(sent)
+    # the span is around the call and nothing else: no span below it, and
+    # a prefill entry's uploads are the entry's own time, before the edge
+    assert not {e["parent"] for e in _spans()} & {e["id"] for e in sent}
+    by_id = {e["id"]: e for e in _spans()}
+    entries = {"tick": "exe.decode_tick", "prefill": "exe.prefill",
+               "chunk": "exe.prefill_chunk"}
+    for e in sent:
+        if e["args"]["program"] in entries:
+            assert by_id[e["parent"]]["name"] == entries[e["args"]["program"]]
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_every_device_wait_names_the_program_it_waited_for(model, depth):
+    """``seq`` on a ``device_wait`` span is a dispatched program's. In the
+    synchronous loop it is the newest one that is not the key's split, so
+    nothing is in flight when the wait ends; at ``async_depth`` 2 the
+    cruising ticks wait for an older one."""
+    # blocks of 16: the pipeline cruises only while no slot's table grows
+    eng = _engine(model, async_depth=depth, block_size=16 if depth else 4)
+    _traffic(eng)
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    evs = _spans()
+    sent = {e["args"]["seq"]: e for e in evs if e["name"] == "exe.dispatch"}
+    waits = [e for e in evs if e["cat"] == "device_wait"]
+    assert len(waits) > 8
+    behind = 0
+    for w in waits:
+        d = sent[w["args"]["seq"]]
+        assert d["args"]["program"] == (
+            "sample" if w["name"] == "exe.sample" else "tick")
+        assert d["ts"] + d["dur"] <= w["ts"]
+        newest = max(s for s, e in sent.items() if e["ts"] < w["ts"]
+                     and e["args"]["program"] != "split")
+        assert w["args"]["seq"] <= newest
+        behind += w["args"]["seq"] < newest
+    assert (behind == 0) if depth == 0 else (behind >= 3)
+
+
+def _calls_outside(fn_node, covered):
+    """Names of the calls in ``fn_node`` that stand in no ``with`` block
+    whose context ``covered`` accepts (the context expressions themselves
+    left out)."""
+    out = []
+
+    def walk(node, inside):
+        if isinstance(node, ast.With):
+            here = inside or any(covered(i.context_expr)
+                                 for i in node.items)
+            for item in node.items:
+                if not covered(item.context_expr):
+                    walk(item.context_expr, inside)
+            for child in node.body:
+                walk(child, here)
+            return
+        if isinstance(node, ast.Call) and not inside:
+            f = node.func
+            out.append(f.attr if isinstance(f, ast.Attribute) else f.id)
+        for child in ast.iter_child_nodes(node):
+            walk(child, inside)
+
+    for stmt in fn_node.body:
+        walk(stmt, False)
+    return out
+
+
+def _function(module, name):
+    tree = ast.parse(Path(module.__file__).read_text())
+    (fn,) = [n for n in ast.walk(tree)
+             if isinstance(n, ast.FunctionDef) and n.name == name]
+    return fn
+
+
+def _direct_jit_calls(source):
+    """``name:line`` of every call of a ``_*_JIT`` or ``_cp_*`` name."""
+    found = []
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.Call):
+            f = node.func
+            name = (f.id if isinstance(f, ast.Name)
+                    else f.attr if isinstance(f, ast.Attribute) else "")
+            if re.fullmatch(r"_\w+_JIT|_cp_\w+", name):
+                found.append(f"{name}:{node.lineno}")
+    return found
+
+
+def test_no_jitted_call_of_the_executor_stands_outside_the_helper():
+    """Every ``_*_JIT(...)`` and ``self._cp_*(...)`` of
+    ``serving/executor.py`` is called by ``_dispatch`` alone, which calls
+    what it is handed."""
+    assert _direct_jit_calls(Path(executor_mod.__file__).read_text()) == []
+    assert "jitted" in [
+        n.func.id for n in ast.walk(_function(executor_mod, "_dispatch"))
+        if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)]
+    # the rule sees both ways of writing such a call, and no other
+    assert _direct_jit_calls(
+        "x = _TICK_JIT(m)\ny = self._cp_tick(m)\nself._no_cp_lora(l)\n"
+        "self._dispatch('tick', _TICK_JIT, m)") == ["_TICK_JIT:1",
+                                                    "_cp_tick:2"]
+
+
+def test_stage_closes_the_decode_ticks_tree(model):
+    """``serving.stage`` once a tick, between ``serving.prefill`` and
+    ``serving.decode``; with it a decode tick's children follow one
+    another in ``_step_inner``'s order and leave none of its calls
+    uncovered but the ones listed here."""
+    eng = _engine(model)
+    _traffic(eng)
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    evs = _spans()
+    decoded = 0
+    for st in (e for e in evs if e["name"] == "serving.step"):
+        kids = sorted((e for e in evs if e["parent"] == st["id"]),
+                      key=lambda e: e["ts"])
+        names = [e["name"] for e in kids]
+        assert names.count("serving.stage") == 1
+        if "serving.decode" not in names:
+            continue
+        decoded += 1
+        assert names == ["serving.expire", "serving.admit",
+                         "serving.prefill", "serving.stage",
+                         "serving.decode", "serving.emit",
+                         "serving.bookkeeping"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        stage = kids[3]
+        assert set(stage["args"]) == {"grown", "preempted"}
+        assert 0 <= stage["args"]["grown"] <= eng.num_slots
+    assert decoded > 5
+    grown = sum(e["args"].get("grown", 0) for e in evs
+                if e["name"] == "serving.stage")
+    assert grown > 0             # 6 tokens a request cross a block of 4
+
+    def spanned(ctx):
+        return (isinstance(ctx, ast.Call) and (
+            getattr(ctx.func, "id", None) == "_span"
+            or getattr(ctx.func, "attr", None) == "_tick_timer"))
+    outside = _calls_outside(_function(engine_mod, "_step_inner"), spanned)
+    assert sorted(set(outside)) == sorted({
+        # chaos hooks, no-ops without a rule installed
+        "fault_point",
+        # the pipelined loop's own branch (its spans: ``_async_step``)
+        "_async_block_reason", "_async_step", "_drain_async",
+        # beam groups: no cell runs one
+        "list", "_beam_advance", "values", "asarray",
+        # the accounting's clock reads and the cp histogram
+        "perf_counter", "observe",
+        # which slots ran, for the emit loop
+        "nonzero"})
+
+
+def test_submit_span_carries_the_requests_id(model):
+    eng = _engine(model)
+    TRACER.enable()
+    rids = [eng.add_request(Request(np.arange(1, n), max_new_tokens=2))
+            for n in (4, 6, 9)]
+    with pytest.raises(ValueError):
+        eng.add_request(Request(np.arange(1, 4), max_new_tokens=0))
+    TRACER.disable()
+    subs = [e for e in _spans() if e["name"] == "serving.submit"]
+    assert [e["args"]["rid"] for e in subs[:3]] == rids
+    assert "args" not in subs[3]             # refused: no id
+    assert all(e["parent"] is None for e in subs)
+    _run(eng)
 
 
 def test_decode_span_counts_the_pool_blocks_the_kernel_walks(model):
