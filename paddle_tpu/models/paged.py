@@ -1572,11 +1572,120 @@ moe_decode_step_paged = llama_decode_step_paged
 moe_decode_tick = llama_decode_tick
 
 
-# module-level jit wrappers: their compile caches persist across
-# paged_generate calls (a per-call jax.jit would recompile every request)
-_PREFILL_JIT = jax.jit(llama_prefill_paged, donate_argnums=(3,))
+# ------------------------------------------- host staging, as one array
+class Staging:
+    """The small host arrays of one call of a forward program, handed over
+    as ONE int32 vector: a numpy argument of a jitted call is a transfer of
+    its own (~0.1 ms of the call on the chip, whatever its size, with the
+    device idle behind a synchronous tick), so the executor packs a call's
+    arrays and the program's first lines take the vector apart. The layout
+    is written once, here: ``Staging(name=(dtype, shape), ...)`` in the
+    order of :meth:`pack`'s arguments and :meth:`unpack`'s results, dtypes
+    ``int32``, ``bool`` (an int32 0 / 1 in the vector) and ``float32`` (its
+    bits). Hashed and compared by its fields: a static argument of the
+    programs, so engines of one shape share a trace."""
+
+    __slots__ = ("fields", "size", "_hash")
+    DTYPES = ("int32", "bool", "float32")
+
+    def __init__(self, **fields):
+        out, lo = [], 0
+        for name, (dtype, shape) in fields.items():
+            if dtype not in self.DTYPES:
+                raise TypeError(f"staging field {name!r}: dtype {dtype!r} "
+                                f"is none of {self.DTYPES}")
+            shape = tuple(int(d) for d in shape)
+            hi = lo + int(np.prod(shape, dtype=np.int64))
+            out.append((name, dtype, shape, lo, hi))
+            lo = hi
+        self.fields, self.size = tuple(out), lo
+        self._hash = hash(self.fields)
+
+    def __hash__(self):
+        return self._hash
+
+    def __eq__(self, other):
+        return isinstance(other, Staging) and self.fields == other.fields
+
+    def pack(self, *arrays) -> np.ndarray:
+        """-> a FRESH vector holding ``arrays``, each cast to its field's
+        dtype: never a view of what it was handed, which a caller may
+        change while the transfer is pending."""
+        if len(arrays) != len(self.fields):
+            raise TypeError(f"staging takes {len(self.fields)} arrays "
+                            f"({', '.join(f[0] for f in self.fields)}), "
+                            f"got {len(arrays)}")
+        vec = np.empty(self.size, np.int32)
+        for (name, dtype, shape, lo, hi), a in zip(self.fields, arrays):
+            a = np.ascontiguousarray(a, dtype)
+            if a.shape != shape:
+                raise ValueError(f"staging field {name!r}: shape {a.shape}, "
+                                 f"laid out as {shape}")
+            vec[lo:hi] = (a.view(np.int32) if dtype == "float32"
+                          else a).reshape(-1)
+        return vec
+
+    def unpack(self, vec) -> tuple:
+        """Inside a traced program: the arrays :meth:`pack` was handed, in
+        its order, dtypes and shapes (static slices of ``vec``)."""
+        out = []
+        for _, dtype, shape, lo, hi in self.fields:
+            x = jax.lax.slice(vec, (lo,), (hi,)).reshape(shape)
+            if dtype == "float32":
+                x = jax.lax.bitcast_convert_type(x, jnp.float32)
+            elif dtype == "bool":
+                x = x != 0
+            out.append(x)
+        return tuple(out)
+
+
+def tick_staging(num_slots: int) -> Staging:
+    """What :func:`tick_staged` is sent: ``llama_decode_tick``'s seven
+    ``[num_slots]`` arrays."""
+    i, f = ("int32", (num_slots,)), ("float32", (num_slots,))
+    return Staging(tokens=i, active=("bool", (num_slots,)), upd_rows=i,
+                   upd_cols=i, upd_vals=i, temps=f, top_ps=f)
+
+
+@functools.cache
+def prefill_staging(rows: int, width: int, max_blocks: int,
+                    chunked: bool) -> Staging:
+    """What a prefill program is sent for ``rows`` rows of ``width`` tokens:
+    :func:`prefill_staged`'s four arrays, and the rows' ``offsets`` for
+    :func:`prefill_chunk_staged` (``chunked``)."""
+    r = ("int32", (rows,))
+    return Staging(input_ids=("int32", (rows, width)), lens=r,
+                   **({"offsets": r} if chunked else {}), slot_ids=r,
+                   table_rows=("int32", (rows, max_blocks)))
+
+
+def prefill_staged(model, staged, cache: PagedKVCache, layout: Staging,
+                   lora=None, cp_axis=None):
+    """:func:`llama_prefill_paged` with its host arrays as one vector."""
+    ids, lens, slots, rows = layout.unpack(staged)
+    return llama_prefill_paged(model, ids, lens, cache, slots, rows, lora,
+                               cp_axis)
+
+
+def tick_staged(model, staged, cache: PagedKVCache, rng, layout: Staging,
+                top_k=None, want_logp=False, lora=None, logit_bias=None,
+                cp_axis=None):
+    """:func:`llama_decode_tick` with its host arrays as one vector."""
+    tokens, active, rows, cols, vals, temps, top_ps = layout.unpack(staged)
+    return llama_decode_tick(model, tokens, cache, active, rows, cols, vals,
+                             rng, temps, top_ps, top_k, want_logp, lora,
+                             logit_bias, cp_axis)
+
+
+# module-level jit wrappers: their compile caches persist across calls (a
+# per-call jax.jit would recompile every request). The serving executor's
+# three forwards take their host arrays staged; the generators below hand
+# the array-signature bodies device arrays of their own.
+_PREFILL_JIT = jax.jit(prefill_staged, static_argnums=(3,),
+                       donate_argnums=(2,))
+_GENERATE_PREFILL_JIT = jax.jit(llama_prefill_paged, donate_argnums=(3,))
 _DECODE_JIT = jax.jit(llama_decode_step_paged)
-_TICK_JIT = jax.jit(llama_decode_tick, static_argnums=(10, 11),
+_TICK_JIT = jax.jit(tick_staged, static_argnums=(4, 5, 6),
                     donate_argnums=(2,))
 # The async tick donates the cache only on accelerator backends: PJRT's
 # CPU client executes a computation inline on the dispatching thread
@@ -1607,10 +1716,10 @@ def clear_jit_caches():
     every layer of a program) goes with the programs that hold it."""
     if _async_tick_jit.cache_info().currsize:   # built: backend exists
         _async_tick_jit().clear_cache()
-    for f in (_PREFILL_JIT, _DECODE_JIT, _TICK_JIT, _PREFILL_CHUNK_JIT,
-              _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT, _PREFIX_COW_JIT,
-              _STATE_TAKE_JIT, _STATE_RESTORE_JIT, _paged_chunk_call,
-              *_EXTRA_CLEAR):
+    for f in (_PREFILL_JIT, _GENERATE_PREFILL_JIT, _DECODE_JIT, _TICK_JIT,
+              _PREFILL_CHUNK_JIT, _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT,
+              _PREFIX_COW_JIT, _STATE_TAKE_JIT, _STATE_RESTORE_JIT,
+              _paged_chunk_call, *_EXTRA_CLEAR):
         f.clear_cache()
     from paddle_tpu.ops.pallas import gated_delta
     gated_delta.clear_caches()
@@ -1808,7 +1917,7 @@ def paged_beam_search(model, prompt, max_new_tokens=32, num_beams=4,
         t = mgr.tables[j]
         rows[j, :len(t)] = t
 
-    logits, cache = _PREFILL_JIT(
+    logits, cache = _GENERATE_PREFILL_JIT(
         model, jnp.asarray(prompt[None, :]), jnp.asarray([s], jnp.int32),
         cache, jnp.asarray([0], jnp.int32),
         jnp.asarray(rows[:1]))
@@ -1901,7 +2010,7 @@ def paged_generate(model, input_ids, prompt_lens, max_new_tokens=32,
     cache = PagedKVCache.init_for(cfg, num_blocks, block_size, b, max_blocks)
     cache.block_tables = mgr.table_array(range(b), max_blocks)
 
-    prefill = _PREFILL_JIT
+    prefill = _GENERATE_PREFILL_JIT
     step = _DECODE_JIT
 
     logits, cache = prefill(model, jnp.asarray(input_ids),
@@ -2095,8 +2204,17 @@ def _scatter_decode_chunk(pool, vals, tables, offsets, chunk_lens, nb, bs):
     return flat.reshape(pool.shape)
 
 
-_PREFILL_CHUNK_JIT = jax.jit(llama_prefill_chunk_paged,
-                             donate_argnums=(4,))
+def prefill_chunk_staged(model, staged, cache: PagedKVCache,
+                         layout: Staging, lora=None, cp_axis=None):
+    """:func:`llama_prefill_chunk_paged` with its host arrays as one
+    vector."""
+    ids, lens, offs, slots, rows = layout.unpack(staged)
+    return llama_prefill_chunk_paged(model, ids, lens, offs, cache, slots,
+                                     rows, lora=lora, cp_axis=cp_axis)
+
+
+_PREFILL_CHUNK_JIT = jax.jit(prefill_chunk_staged, static_argnums=(3,),
+                             donate_argnums=(2,))
 
 
 # ------------------------------------------------ speculative helpers

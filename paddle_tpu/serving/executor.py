@@ -8,6 +8,13 @@ staging arrays and get logits/tokens back; all cache donation happens
 inside this class, so an exception raised BEFORE a call here leaves
 ``self.cache`` intact (the exception-atomicity contract the chaos
 sites rely on).
+
+The three forwards every tick runs (``tick``, ``prefill``, ``chunk``) are
+handed their staging arrays as ONE fresh int32 vector
+(``models.paged.Staging``: packed here, before the donating call, taken
+apart by the program's first lines), because every numpy argument of a
+jitted call is a transfer of its own with the device idle behind it.
+``exe.dispatch`` counts a call's host arrays as ``uploads``.
 """
 from __future__ import annotations
 
@@ -25,11 +32,10 @@ from paddle_tpu.models.paged import (PagedKVCache, _BEAM_GROUP_UPDATE_JIT,
                                      _TICK_JIT, _VERIFY_CHUNK_JIT,
                                      _async_tick_jit, _prefix_cow_update,
                                      init_states,
-                                     llama_decode_tick,
-                                     llama_prefill_chunk_paged,
-                                     llama_prefill_paged,
                                      llama_verify_chunk_paged,
-                                     spec_rewind_lens)
+                                     prefill_chunk_staged, prefill_staged,
+                                     prefill_staging, spec_rewind_lens,
+                                     tick_staged, tick_staging)
 from paddle_tpu.models.speculative import _FWD_ROWS_JIT
 from paddle_tpu.observability import span as _span
 
@@ -122,6 +128,7 @@ class ModelExecutor:
         # the program just sent
         self.seq = self.model_seq = 0
         self.top_k = top_k
+        self._tick_staging = tick_staging(num_slots)
         self.rng = jax.random.PRNGKey(seed)     # the setter: no pair held
         self.cp = int(cp)
         self.mesh = None
@@ -201,31 +208,32 @@ class ModelExecutor:
                              in_specs=in_specs, out_specs=out_specs,
                              check_vma=False)
 
-        self._cp_prefill = jax.jit(smap(
-            functools.partial(llama_prefill_paged, cp_axis="cp"),
-            (R, R, R, cs, R, R), (R, cs)), donate_argnums=(3,))
-        self._cp_prefill_chunk = jax.jit(smap(
-            functools.partial(llama_prefill_chunk_paged, cp_axis="cp"),
-            (R, R, R, R, cs, R, R), (R, cs)), donate_argnums=(4,))
+        def staged_twin(program):
+            """(model, staged, cache, layout) -> (logits, cache)"""
+            def twin(model, staged, cache, layout):
+                return smap(
+                    functools.partial(program, layout=layout, cp_axis="cp"),
+                    (R, R, cs), (R, cs))(model, staged, cache)
+            return jax.jit(twin, static_argnums=(3,), donate_argnums=(2,))
+
+        self._cp_prefill = staged_twin(prefill_staged)
+        self._cp_prefill_chunk = staged_twin(prefill_chunk_staged)
         self._cp_verify_chunk = jax.jit(smap(
             functools.partial(llama_verify_chunk_paged, cp_axis="cp"),
             (R, R, R, R, cs, R, R), (R, cs)), donate_argnums=(4,))
         self._cp_rewind = jax.jit(smap(
             spec_rewind_lens, (cs, R, R), cs), donate_argnums=(0,))
-        top_k = self.top_k
+        top_k, layout = self.top_k, self._tick_staging
 
         # top_k / want_logp are STATIC in the tick; bake them (beams — the
         # only want_logp consumer — are refused under cp by the engine) so
         # shard_map sees purely positional array args
-        def _tick(model, tokens, cache, active, rows, cols, vals, rng,
-                  temps, top_ps, bias):
-            return llama_decode_tick(
-                model, tokens, cache, active, rows, cols, vals, rng,
-                temps, top_ps, top_k, False, None, bias, cp_axis="cp")
+        def _tick(model, staged, cache, rng, bias):
+            return tick_staged(model, staged, cache, rng, layout, top_k,
+                               False, None, bias, cp_axis="cp")
 
         self._cp_tick = jax.jit(smap(
-            _tick, (R, R, cs, R, R, R, R, R, R, R, R), (R, R, cs)),
-            donate_argnums=(2,))
+            _tick, (R, R, cs, R, R), (R, R, cs)), donate_argnums=(2,))
         self._cp_cow = jax.jit(smap(
             functools.partial(_prefix_cow_update, cp_axis="cp"),
             (cs, R, R), cs), donate_argnums=(0,))
@@ -234,13 +242,19 @@ class ModelExecutor:
         """Every jitted call the executor makes: the span ``exe.dispatch``
         (``cat="dispatch"``) around the call and nothing else, so its
         duration is the flatten, the upload and the enqueue; ``seq`` counts
-        the programs. With the ``seq`` on the ``device_wait`` spans a reader
-        knows from the host's clock when nothing was in flight."""
+        the programs, ``uploads`` the host (numpy) arrays among the call's
+        arguments, each a transfer the call makes before it returns. With
+        the ``seq`` on the ``device_wait`` spans a reader knows from the
+        host's clock when nothing was in flight."""
         self.seq += 1
         if program != "split":
             self.model_seq = self.seq
         with _span("exe.dispatch", cat="dispatch", program=program,
-                   seq=self.seq):
+                   seq=self.seq) as edge:
+            if edge.recording:
+                edge.set(uploads=sum(
+                    isinstance(a, (np.ndarray, np.generic))
+                    for a in (*args, *kw.values())))
             return jitted(*args, **kw)
 
     @property
@@ -288,19 +302,18 @@ class ModelExecutor:
         flight hold one pool, not one more for each."""
         with _span("exe.prefill", **_token_rows(ids, lens),
                    **self._ctx_tokens(lens, 0), **self.span_args):
-            # the uploads stay outside the dispatch edge: the device waits
-            # for them, and they are this entry's self time
-            ids, lens = jnp.asarray(ids), jnp.asarray(lens)
-            slots, rows = jnp.asarray(slots), jnp.asarray(rows)
+            layout = prefill_staging(*np.shape(ids), np.shape(rows)[1],
+                                     False)
+            staged = layout.pack(ids, lens, slots, rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._dispatch(
-                    "prefill", self._cp_prefill, self._model, ids, lens,
-                    self.cache, slots, rows)
+                    "prefill", self._cp_prefill, self._model, staged,
+                    self.cache, layout)
                 return logits
             logits, self.cache = self._dispatch(
-                "prefill", _PREFILL_JIT, self._model, ids, lens, self.cache,
-                slots, rows, lora=lora)
+                "prefill", _PREFILL_JIT, self._model, staged, self.cache,
+                layout, lora=lora)
             return logits
 
     def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
@@ -310,18 +323,18 @@ class ModelExecutor:
                    kv_blocks=_chunk_kv_blocks(lens, offs,
                                               self.cache.block_size),
                    **self._ctx_tokens(lens, offs), **self.span_args):
-            ids, lens, offs = (jnp.asarray(ids), jnp.asarray(lens),
-                               jnp.asarray(offs))
-            slots, rows = jnp.asarray(slots), jnp.asarray(rows)
+            layout = prefill_staging(*np.shape(ids), np.shape(rows)[1],
+                                     True)
+            staged = layout.pack(ids, lens, offs, slots, rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache = self._dispatch(
-                    "chunk", self._cp_prefill_chunk, self._model, ids, lens,
-                    offs, self.cache, slots, rows)
+                    "chunk", self._cp_prefill_chunk, self._model, staged,
+                    self.cache, layout)
                 return logits
             logits, self.cache = self._dispatch(
-                "chunk", _PREFILL_CHUNK_JIT, self._model, ids, lens, offs,
-                self.cache, slots, rows, lora=lora)
+                "chunk", _PREFILL_CHUNK_JIT, self._model, staged,
+                self.cache, layout, lora=lora)
             return logits
 
     def _ctx_tokens(self, lens, offs) -> dict:
@@ -393,6 +406,11 @@ class ModelExecutor:
         with _span("exe.decode_tick", slots=n_run, **self.span_args,
                    **self.state_slots(n_run)):
             sub = self.next_key()
+            # one fresh vector, one transfer, made by the call: a numpy
+            # argument each is a transfer each, and a ``jnp.asarray`` each
+            # a dispatch each, with the device idle meanwhile
+            staged = self._tick_staging.pack(last_tok, run_mask, rows, cols,
+                                             vals, temps, top_ps)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 if need_logp:
@@ -400,20 +418,12 @@ class ModelExecutor:
                         "beam search (want_logp) under cp > 1 is not "
                         "supported")
                 nxt, logp, self.cache = self._dispatch(
-                    "tick", self._cp_tick,
-                    self._model, jnp.asarray(last_tok), self.cache,
-                    jnp.asarray(run_mask), jnp.asarray(rows),
-                    jnp.asarray(cols), jnp.asarray(vals), sub,
-                    jnp.asarray(temps), jnp.asarray(top_ps),
-                    None if bias is None else jnp.asarray(bias))
+                    "tick", self._cp_tick, self._model, staged, self.cache,
+                    sub, bias)
             else:
-                # the staging arrays go in as the numpy arrays they are:
-                # the call uploads them together, where a ``jnp.asarray``
-                # each is a dispatch each, with the device idle meanwhile
                 nxt, logp, self.cache = self._dispatch(
-                    "tick", _TICK_JIT,
-                    self._model, last_tok, self.cache, run_mask, rows,
-                    cols, vals, sub, temps, top_ps, self.top_k, need_logp,
+                    "tick", _TICK_JIT, self._model, staged, self.cache, sub,
+                    self._tick_staging, self.top_k, need_logp,
                     lora=lora, logit_bias=bias)
         self.split_ahead()       # the tick is queued: the next key's split
         return nxt, logp

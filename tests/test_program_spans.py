@@ -376,8 +376,8 @@ def test_every_jitted_call_is_one_dispatch_span(model, monkeypatch, family):
     assert [e["args"]["seq"] for e in sent] == list(
         range(first + 1, first + 1 + len(sent)))
     assert eng.exe.seq == first + len(sent)
-    # the span is around the call and nothing else: no span below it, and
-    # a prefill entry's uploads are the entry's own time, before the edge
+    # the span is around the call and nothing else: no span below it. A
+    # forward's one host array, the staged vector, goes up inside the edge
     assert not {e["parent"] for e in _spans()} & {e["id"] for e in sent}
     by_id = {e["id"]: e for e in _spans()}
     entries = {"tick": "exe.decode_tick", "prefill": "exe.prefill",
@@ -385,6 +385,7 @@ def test_every_jitted_call_is_one_dispatch_span(model, monkeypatch, family):
     for e in sent:
         if e["args"]["program"] in entries:
             assert by_id[e["parent"]]["name"] == entries[e["args"]["program"]]
+            assert e["args"]["uploads"] == 1
 
 
 @pytest.mark.parametrize("depth", [0, 2])
@@ -476,6 +477,32 @@ def test_no_jitted_call_of_the_executor_stands_outside_the_helper():
         "x = _TICK_JIT(m)\ny = self._cp_tick(m)\nself._no_cp_lora(l)\n"
         "self._dispatch('tick', _TICK_JIT, m)") == ["_TICK_JIT:1",
                                                     "_cp_tick:2"]
+
+
+def _asarray_calls(fn_node):
+    """Lines of ``fn_node``'s calls of ``jnp.asarray`` / ``jax.device_put``:
+    each a dispatch of its own, made before the edge opens."""
+    return [n.lineno for n in ast.walk(fn_node) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute)
+            and n.func.attr in ("asarray", "device_put")
+            and isinstance(n.func.value, ast.Name)
+            and n.func.value.id in ("jnp", "jax")]
+
+
+@pytest.mark.parametrize("entry", ["decode_tick", "prefill", "prefill_chunk"])
+def test_a_forward_entry_uploads_nothing_before_its_edge(entry):
+    """The three forwards every tick runs hand their staging arrays to
+    ``_dispatch`` packed (``Staging.pack``, numpy alone): no upload of one
+    stands in the entry, outside the edge with the device idle."""
+    fn = _function(executor_mod, entry)
+    assert _asarray_calls(fn) == []
+    assert "pack" in [n.func.attr for n in ast.walk(fn)
+                      if isinstance(n, ast.Call)
+                      and isinstance(n.func, ast.Attribute)]
+    # the rule sees an upload where there is one
+    assert _asarray_calls(_function(executor_mod, "verify_chunk")) != []
+    assert _asarray_calls(ast.parse(
+        "def f(x):\n    a = np.asarray(x)\n    return jnp.asarray(a)")) == [3]
 
 
 def test_stage_closes_the_decode_ticks_tree(model):

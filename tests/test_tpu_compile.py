@@ -486,6 +486,26 @@ def test_decode_tick_holds_its_sort_under_a_conditional(one_chip,
     _assert_sort_under_the_samplers_conditional(text)
 
 
+@pytest.mark.parametrize("family", ["llama", "ouro", "hybrid"])
+def test_staged_tick_is_the_same_program_for_the_chip(one_chip, monkeypatch,
+                                                      family):
+    """The tick as the executor sends it, its seven host arrays one int32
+    vector taken apart by static slices: the kernel and the sampler's
+    conditional as in the body alone, and no more than the vector, the
+    key and the cache's leaves come in."""
+    from paddle_tpu.models import paged
+    model, cache = _tiny_served(family)
+    layout = paged.tick_staging(8)
+    S = jax.ShapeDtypeStruct
+    args = _placed((model, S((layout.size,), i32), cache,
+                    S((2,), jnp.uint32)), one_chip)
+    text = _compiled_for_the_chip(monkeypatch, paged._TICK_JIT, *args,
+                                  layout, None, False)
+    assert "paged_decode_attention" in text
+    _assert_sort_under_the_samplers_conditional(text)
+    assert f"s32[{layout.size}]" in text
+
+
 def test_cp_decode_tick_holds_its_sort_under_a_conditional(v5e_2x2,
                                                            monkeypatch):
     """The cp tick as the executor builds it: the tick under a ``shard_map``
