@@ -6,6 +6,7 @@ cut in depth only, with random weights made from ``--seed``.
 
     python chip_smoke.py              # one TPU chip; anything else fails
     python chip_smoke.py --chips 4    # ONLY the cross-chip legs (run by hand)
+    timeout 240 python chip_smoke.py --latent   # ONLY the latent leg (by hand)
     python chip_smoke.py --tiny       # CPU rehearsal of the control flow
 
 One process, no children, no network, no git. Every phase fails the run on
@@ -451,6 +452,108 @@ def ring_kv(tiny, seed, devs):
 
 
 # -------------------------------------------------------------------- main
+def latent(tiny, seed):
+    """The shape that hung a chip in PR 41: a ``[1, 2048]`` prefill of a
+    Kimi-K2 model at its published widths, the dense layer and two expert
+    layers, through the latent chunk kernel, once as the tree serves it
+    (``held_forward`` takes its passes under a ``lax.while_loop``) and once
+    with the second size under a ``lax.cond``, two conditionals in one
+    program. With the chunk kernel asking for 48 MiB of scoped VMEM the
+    second form never returned; at the compiler's default both run and
+    agree. A hung device cannot be timed out from inside: run this leg
+    under ``timeout``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.models import paged
+    from paddle_tpu.models.kimi_k2 import KimiK2Config, KimiK2ForCausalLM
+
+    t = 64 if tiny else 2048
+    if tiny:
+        cfg = KimiK2Config.tiny(num_hidden_layers=3, n_routed_experts=64,
+                                held_experts=(0, 1), dtype=jnp.bfloat16)
+    else:
+        cfg = KimiK2Config(num_hidden_layers=3, vocab_size=20480,
+                           held_experts=tuple(range(12)),
+                           dtype=jnp.bfloat16)
+    pt.seed(seed)
+    model = KimiK2ForCausalLM(cfg).eval()
+    blocks, width = t // 16 * 2, t // 16 * 2
+    ids = np.zeros((1, t), np.int32)
+    ids[0, :t // 2] = np.arange(t // 2) % 200 + 1
+    rows = np.full((1, width), blocks, np.int32)
+    rows[0, :t // 32] = np.arange(t // 32)
+
+    def prefill():
+        # a function of its own a form: a second trace of one function
+        # would be answered from the first's
+        return jax.jit(lambda model, cache: paged.llama_prefill_paged(
+            model, jnp.asarray(ids), jnp.array([t // 2]), cache,
+            jnp.array([0]), jnp.asarray(rows))[0])
+
+    def under_a_conditional(xt, vals, idx, held, n_exp, gate_up, down,
+                            live=None):
+        """``held_forward`` with its common size or every pair under a
+        ``lax.cond``: the form that hung."""
+        n_tok, k = idx.shape
+        n_held, n = gate_up.shape[0], n_tok * k
+        lut = np.full((n_exp,), n_held, np.int32)
+        lut[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
+        local = jnp.asarray(lut)[idx]
+        if live is not None:
+            local = jnp.where(live[:, None], local, n_held)
+        flat, gate = local.reshape(n), vals.reshape(n)
+        order = jnp.argsort(flat, stable=True)
+        counts = jnp.sum(flat[:, None] == jnp.arange(n_held)[None, :],
+                         axis=0, dtype=jnp.int32)
+        pairs = jnp.sum(counts)
+
+        def one(rows_):
+            sel = order[:rows_]
+            sizes = jnp.minimum(counts, jnp.maximum(
+                rows_ - (jnp.cumsum(counts) - counts), 0))
+            ys = moe.grouped_mlp_apply(xt[sel // k], gate_up, down, sizes)
+            ys = jnp.where((jnp.arange(rows_) < pairs)[:, None],
+                           ys.astype(jnp.float32), 0.0) * gate[sel][:, None]
+            return jnp.zeros((n_tok, xt.shape[1]), jnp.float32).at[
+                sel // k].add(ys, mode="drop")
+
+        common = max(128, moe.held_rows(n, n_held, n_exp) // 2)
+        y = jax.lax.cond(pairs <= common, lambda: one(common),
+                         lambda: one(n))
+        return y, pairs, jnp.sum((counts > 0).astype(jnp.int32))
+
+    out = {}
+    for form in ("while_loop", "cond"):
+        held_forward = moe.held_forward
+        if form == "cond":
+            moe.held_forward = under_a_conditional
+        try:
+            cache = paged.PagedKVCache.init_for(cfg, blocks, 16, 4, width)
+            fn = prefill().lower(model, cache).compile()
+            text = fn.as_text()
+            conds = text.count(" conditional(")
+            say(latent=form, step="compiled", conditionals=conds,
+                raised_scoped_regions=text.count('"scoped_memory_configs":[{'))
+            # on the chip the second form must hold its two conditionals
+            # (the CPU's compiler turns small ones into selects)
+            check(tiny or conds == (2 if form == "cond" else 0),
+                  "conditionals in the", form, "form:", conds)
+            t0 = time.perf_counter()
+            out[form] = np.asarray(fn(model, cache), np.float32)
+            say(latent=form, step="ran",
+                first_call_s=round(time.perf_counter() - t0, 4),
+                finite=bool(np.isfinite(out[form]).all()))
+        finally:
+            moe.held_forward = held_forward
+        check(np.isfinite(out[form]).all(), "latent prefill not finite", form)
+    gap = float(np.abs(out["cond"] - out["while_loop"]).max())
+    say(latent="both", widest_logit_difference=gap)
+    check(gap < 0.05, "the two forms of held_forward differ by", gap)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
@@ -459,6 +562,9 @@ def main():
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run only the cross-chip legs")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--latent", action="store_true",
+                    help="run only the latent (MLA) leg: the program that "
+                         "hung a chip in PR 41; run it under timeout")
     args = ap.parse_args()
 
     import jax
@@ -490,7 +596,9 @@ def main():
         compile_cache_dir=cache_dir, cache_entries_before=before,
         cache_warm=before > 0, memory=mem(dev))
 
-    if args.chips == 1:
+    if args.latent:
+        latent(args.tiny, args.seed)
+    elif args.chips == 1:
         check_block_until_ready(args.tiny)
         serve(args.tiny, args.seed, dev)
         train(args.tiny, args.seed, dev)

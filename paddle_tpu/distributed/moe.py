@@ -36,6 +36,19 @@ runs its local experts over ``sum(counts)`` rows instead of
 dense capacity-padded dispatch/compute path bit-for-bit (read at trace
 time; re-trace after flipping).
 
+**Router kinds and held experts** (``MoELayer(router=..., held=...)``).
+``router="softmax"`` is every family above. ``router="sigmoid_bias"`` is
+the DeepSeek-V3 / Kimi-K2 gate (``noaux_tc``): scores are sigmoids, the
+top-k is chosen by score PLUS a per-expert selection bias, the combine
+weights come from the UNBIASED scores, renormalised and times
+``routed_scale``. ``held`` names the experts (global ids) whose weights
+this layer holds, one chip's share of a wide expert-parallel deployment:
+the layer routes over all ``num_experts``, computes the part of the routed
+sum its own experts give and leaves the rest out (``held_forward``: the
+shares of all chips add up to the uncut layer). No token is dropped
+whatever the load; nothing stands in for the absent chips or their
+exchange.
+
 The gate also reports a **drop rate** (fraction of routing choices that
 overflowed capacity) so saturation is observable (the reference exposes
 drop behaviour through its gate counters).
@@ -44,6 +57,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.core.dtypes import get_default_dtype
@@ -242,21 +256,141 @@ def grouped_forward(xt, route, gate_up, down, num_tokens: int):
     return yt.at[route["tok"]].add(y_sorted * wgt[:, None], mode="drop")
 
 
+ROUTERS = ("softmax", "sigmoid_bias")
+
+# pairs (token, chosen expert) under which a held layer gathers every pair
+# of a call: below it the rows of a call are few and one shape serves
+_HELD_ROWS_FLOOR = 1024
+
+
+def sigmoid_bias_gate(logits, bias, k, renormalize=True, scale=1.0):
+    """The ``noaux_tc`` gate: ``s = sigmoid(logits)`` in float32, the k
+    experts of largest ``s + bias``, their weights ``s`` (NOT ``s + bias``)
+    over their sum (+1e-20) times ``scale`` -> ([T, k] weights, [T, k]
+    expert ids). No groups: ``n_group`` 1, ``topk_group`` 1."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    vals = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        vals = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-20)
+    return vals * scale, idx
+
+
+def held_rows(pairs: int, held: int, experts: int) -> int:
+    """Rows one pass of :func:`held_forward` gathers for a call of
+    ``pairs`` (token, expert) pairs: all of them for a small call or a
+    layer that holds every expert, else four times the share a uniform
+    router sends to ``held`` of ``experts``, in whole 128-row tiles."""
+    if pairs <= _HELD_ROWS_FLOOR or held >= experts:
+        return pairs
+    return min(pairs, max(_HELD_ROWS_FLOOR,
+                          -(-4 * pairs * held // experts // 128) * 128))
+
+
+def held_forward(xt, gate_vals, gate_idx, held, num_experts, gate_up, down,
+                 live=None):
+    """The routed sum over the experts this layer holds. xt [T, H];
+    gate_vals / gate_idx [T, k] weights and GLOBAL expert ids over all
+    ``num_experts``; ``held`` the global ids of the ``gate_up`` / ``down``
+    stacks' rows (None: every expert, in order); ``live`` [T] bool, False
+    a padding token that is routed nowhere (None: every token counts) ->
+    (y [T, H] float32, routed_pairs, experts_hit): the pairs routed to a
+    held expert and the held experts that got at least one.
+
+    Pairs are sorted by held expert, those of absent experts last, and go
+    through the grouped products ``rows`` at a time (:func:`held_rows`, a
+    static bound): one pass in the common case, as many as the held pairs
+    need under a ``lax.while_loop`` otherwise (none for a call that routes
+    nothing here), so nothing is dropped whatever the load. (Not a
+    ``lax.cond`` over two sizes: its second branch would hold every pair's
+    rows at once, 0.9 GB of temporaries in a 2,048-token chunk call of
+    Kimi-K2 whether or not it is taken.)"""
+    t, k = gate_idx.shape
+    n_held = gate_up.shape[0]
+    if held is None:
+        local = gate_idx
+    else:
+        lut = np.full((num_experts,), n_held, np.int32)
+        lut[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
+        local = jnp.asarray(lut)[gate_idx]
+    if live is not None:
+        local = jnp.where(live[:, None], local, n_held)
+    n = t * k
+    flat_local = local.reshape(n)          # pair p: token p // k
+    flat_gate = gate_vals.reshape(n)
+    order = jnp.argsort(flat_local, stable=True)
+    # a compare and a sum, not a scatter of every pair into a dozen bins
+    counts = jnp.sum(flat_local[:, None] == jnp.arange(n_held)[None, :],
+                     axis=0, dtype=jnp.int32)
+    pairs = jnp.sum(counts)
+    ends = jnp.cumsum(counts)
+    rows = held_rows(n, n_held, num_experts)
+    # whole passes: a pair index of ``n`` (token ``t``) stands for no pair
+    order = jnp.pad(order, (0, -n % rows), constant_values=n)
+
+    def one_pass(i, y):
+        """Sorted pairs [i * rows, (i + 1) * rows): each expert's part of
+        the window is its group."""
+        lo = i * rows
+        sel = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        tok = sel // k
+        sizes = jnp.clip(jnp.minimum(ends, lo + rows)
+                         - jnp.maximum(ends - counts, lo), 0)
+        ys = grouped_mlp_apply(xt[tok], gate_up, down, sizes)
+        # rows past the held pairs hold whatever the kernel left there
+        ys = jnp.where((lo + jnp.arange(rows) < pairs)[:, None],
+                       ys.astype(jnp.float32), 0.0) * flat_gate[sel][:, None]
+        return y.at[tok].add(ys, mode="drop")
+
+    y = jnp.zeros((t, xt.shape[1]), jnp.float32)
+    if rows >= n:
+        y = one_pass(0, y)
+    else:
+        _, y = jax.lax.while_loop(
+            lambda c: c[0] * rows < pairs,
+            lambda c: (c[0] + 1, one_pass(c[0], c[1])), (jnp.int32(0), y))
+    return y, pairs, jnp.sum((counts > 0).astype(jnp.int32))
+
+
 class MoELayer(Module):
     """Drop-in MLP replacement (ref MoELayer). Sort-based routing
     everywhere; under a mesh with ep > 1 the forward is a shard_map whose
     ``lax.all_to_all`` over the ep axis is the reference's ``c_alltoall``.
     The aux loss is returned for the trainer to add; the last drop rate is
-    exposed via ``return_metrics=True``."""
+    exposed via ``return_metrics=True``.
+
+    ``router`` picks the gate (``ROUTERS``): ``"softmax"`` (softmax, top-k,
+    optionally renormalised: Mixtral, Qwen2-MoE, ``moe_llm``) or
+    ``"sigmoid_bias"`` (:func:`sigmoid_bias_gate`, with the selection bias
+    ``gate_bias`` and ``routed_scale``). ``held``: the global ids of the
+    experts whose weights this layer holds, in the order of the
+    ``experts`` stack (``len(held)`` experts, not ``num_experts``); None
+    holds them all. A layer with ``held`` or the sigmoid gate routes over
+    all ``num_experts`` and computes its own experts' part
+    (:func:`held_forward`), dropless, single-shard; its
+    ``return_metrics`` also carry ``routed_pairs`` and ``experts_hit``."""
 
     def __init__(self, hidden, intermediate, num_experts, k=2,
-                 capacity_factor=1.25, dtype=None, norm_topk_prob=True):
+                 capacity_factor=1.25, dtype=None, norm_topk_prob=True,
+                 router="softmax", routed_scale=1.0, held=None):
         super().__init__()
         dtype = dtype or get_default_dtype()
+        if router not in ROUTERS:
+            raise ValueError(f"router {router!r} is none of {ROUTERS}")
+        if held is not None:
+            held = tuple(int(e) for e in held)
+            if len(set(held)) != len(held) or not all(
+                    0 <= e < num_experts for e in held):
+                raise ValueError(f"held {held} are not distinct experts of "
+                                 f"{num_experts}")
         self.gate_w = I.Normal(0.0, 0.02)((hidden, num_experts), jnp.float32)
-        self.experts = ExpertMLP(num_experts, hidden, intermediate, dtype)
+        if router == "sigmoid_bias":
+            self.gate_bias = jnp.zeros((num_experts,), jnp.float32)
+        self.experts = ExpertMLP(num_experts if held is None else len(held),
+                                 hidden, intermediate, dtype)
         self.num_experts, self.k, self.capacity_factor = num_experts, k, capacity_factor
         self.norm_topk_prob = norm_topk_prob
+        self.router, self.routed_scale, self.held = router, routed_scale, held
 
     def _capacity(self, tokens: int) -> int:
         if self.capacity_factor is None:
@@ -267,10 +401,23 @@ class MoELayer(Module):
                   + 0.999)
         return max(cap, 4)
 
-    def __call__(self, x, return_aux=True, return_metrics=False):
+    def __call__(self, x, return_aux=True, return_metrics=False, live=None):
+        """``live`` [B, S] bool (a layer with held experts or the sigmoid
+        gate only): False marks a padding token, which is routed to no
+        expert and counted nowhere."""
         from paddle_tpu.distributed.mesh import current_mesh
         mesh = current_mesh()
         ep = mesh.size("ep") if mesh is not None else 1
+        if self.router != "softmax" or self.held is not None:
+            if ep > 1:
+                raise NotImplementedError(
+                    "a layer with held experts or the sigmoid gate is one "
+                    "chip's share: it is not built for a mesh with ep > 1")
+            y, pairs, hit = self._forward_held(x, live)
+            if return_metrics:
+                return y, 0.0, {"drop_rate": 0.0, "routed_pairs": pairs,
+                                "experts_hit": hit}
+            return (y, 0.0) if return_aux else y
         if ep > 1:
             y, aux, drop = self._forward_ep(x, mesh, ep)
         else:
@@ -297,6 +444,27 @@ class MoELayer(Module):
             y_e = expert_mlp_apply(x_e, gate_up, down)
             yt = sparse_combine(y_e, route, dest, t)
         return yt.reshape(b, s, h), aux, drop
+
+    # -- one chip's share of the experts, or the sigmoid gate ---------------
+    def _forward_held(self, x, live=None):
+        b, s, h = x.shape
+        xt = x.reshape(b * s, h)
+        # the gate in float32 as published: on the TPU a float32 product
+        # at the default precision is a bfloat16 one
+        logits = jnp.dot(xt.astype(jnp.float32), self.gate_w,
+                         precision=jax.lax.Precision.HIGHEST)
+        if self.router == "sigmoid_bias":
+            vals, idx = sigmoid_bias_gate(logits, self.gate_bias, self.k,
+                                          self.norm_topk_prob,
+                                          self.routed_scale)
+        else:
+            vals, idx, _ = _gate_probs(logits, self.k, self.norm_topk_prob)
+            vals = vals * self.routed_scale
+        gate_up, down = _expert_arrays(self.experts, x.dtype)
+        y, pairs, hit = held_forward(
+            xt, vals, idx, self.held, self.num_experts, gate_up, down,
+            None if live is None else live.reshape(b * s))
+        return y.astype(x.dtype).reshape(b, s, h), pairs, hit
 
     # -- expert-parallel path: shard_map + all_to_all over the ep axis ------
     def _forward_ep(self, x, mesh, ep):

@@ -30,6 +30,9 @@ import numpy as np
 
 from paddle_tpu.observability.memledger import MemLedger
 from paddle_tpu.ops import attention as A
+from paddle_tpu.ops.pallas.latent_attention import (
+    latent_row_width, paged_latent_chunk_attention,
+    paged_latent_decode_attention)
 from paddle_tpu.ops.pallas.paged_attention import (_note_trace,
                                                    _paged_chunk_call,
                                                    paged_chunk_attention,
@@ -62,9 +65,19 @@ class PagedKVCache:
     ``models/olmo_hybrid.py``) holds K/V pools for its full layers only,
     and for each linear layer a pair ``(S [slots, H, d_k, d_v] float32,
     conv [slots, K - 1, channels])``: the state a slot's tokens have left
-    there, beside the K/V they left in the pools."""
+    there, beside the K/V they left in the pools.
+
+    A model whose layers keep LATENT rows (multi-head latent attention,
+    ``models/kimi_k2.py``; ``layer_kinds`` names them ``latent_attention``)
+    holds ONE pool a layer, not a K pool and a V pool: ``k_pools[i]`` is
+    ``[N_blocks, block_size, W]``, a position's row the normalised latent
+    and the one rotated key all heads share, side by side
+    (``latent_row_width``: whole 128-lane rows, the lanes past the two
+    zero), and ``v_pools`` is empty. Block ids, tables, the trie,
+    copy-on-write and the scheduler are per block as for K/V, so a prefix
+    hit adopts latent blocks as it adopts K/V blocks."""
     k_pools: list   # [L] of [passes * N_blocks, block_size, H_kv, D]
-    v_pools: list
+    v_pools: list   # (latent layers: k_pools [N_blocks, block_size, W], no v)
     block_tables: jnp.ndarray  # [B, max_blocks] int32 (pad = n_blocks)
     lens: jnp.ndarray          # [B] int32 — tokens currently in cache
     k_scales: tuple = ()       # [L] of [passes * N_blocks, block_size, H_kv] f32
@@ -124,6 +137,18 @@ class PagedKVCache:
         """The cache of the model ``cfg`` describes: its layers, its
         passes over them, its K/V heads."""
         kinds = layer_kinds(cfg)
+        if kinds is not None and LATENT_LAYER in kinds:
+            if set(kinds) != {LATENT_LAYER} or kv_dtype is not None \
+                    or cache_passes(cfg) != 1:
+                raise NotImplementedError(
+                    "latent layers beside layers of another kind, under "
+                    "several passes, or in a quantized pool are not built")
+            width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+            return PagedKVCache(
+                [jnp.zeros((num_blocks, block_size, width), cfg.dtype)
+                 for _ in kinds], [],
+                jnp.full((batch, max_blocks_per_seq), num_blocks, jnp.int32),
+                jnp.zeros((batch,), jnp.int32))
         n_kv = (cfg.num_hidden_layers if kinds is None
                 else kinds.count(FULL_LAYER))
         cache = PagedKVCache.init(
@@ -171,14 +196,19 @@ def _pool_heads(x, heads: int):
 
 
 LINEAR_LAYER, FULL_LAYER = "linear_attention", "full_attention"
+LATENT_LAYER = "latent_attention"
 
 
 def layer_kinds(cfg):
     """A model's layers by kind, from what its configuration says of them
     (``layer_types``, one name a layer), or None where every layer keeps
-    K/V: the one-kind model every forward here served before."""
+    K/V: the one-kind model every forward here served before. The kinds:
+    ``full_attention`` (K/V pools), ``linear_attention`` (a recurrent state
+    a slot, no K/V: ``PagedKVCache.states``) and ``latent_attention`` (one
+    pool of latent rows a layer, read by all heads)."""
     kinds = getattr(cfg, "layer_types", None)
-    if not kinds or LINEAR_LAYER not in kinds[:cfg.num_hidden_layers]:
+    if not kinds or not {LINEAR_LAYER, LATENT_LAYER} & set(
+            kinds[:cfg.num_hidden_layers]):
         return None
     return tuple(kinds[:cfg.num_hidden_layers])
 
@@ -1209,7 +1239,41 @@ def _linear_residual(x, lyr, state, lens, rows=None, fresh=None):
     return _mlp_residual(x, lyr), state
 
 
-def _run_stack(model, cache, x, tables, layer, linear=None):
+def _latent_residual(x, lyr, pool, positions, live, scatter, attend):
+    """A latent layer's body, shared by the three paged forwards: the
+    absorbed form of ``models/kimi_k2.py`` over the layer's pool of latent
+    rows. ``positions`` [B, S] of the rows of ``x``, ``live`` [B, S] which of
+    them carry a token (a padding row is routed to no expert);
+    ``scatter(pool, rows [B, S, W]) -> pool`` writes the new positions'
+    rows where the caller's tables put them; ``attend(q [B, S, H, W],
+    pool) -> [B, S, H, rank]`` is the caller's attention over the pool as
+    it then stands (a decode tick's one-token kernel, a prefill's causal
+    chunk kernel). -> (x, pool, counts): ``counts`` what the layer's MLP
+    says it routed (``KimiK2MoE``), None for a dense one."""
+    h = _pre_norm(x, lyr, "input_layernorm")
+    with jax.named_scope("attention"):
+        att = lyr.self_attn
+        rope = att.rope(positions)
+        pool = scatter(pool, att.cache_rows(h, *rope))
+        q = att.absorbed_queries(h, *rope)
+        x = x + att.output(attend(q, pool))
+    h = _pre_norm(x, lyr, "post_attention_layernorm")
+    with jax.named_scope("mlp"):
+        out = lyr.mlp(h, live) if lyr.sparse else lyr.mlp(h)
+    y, counts = out if isinstance(out, tuple) else (out, None)
+    return x + y, pool, counts
+
+
+def _rope_scaling(cfg):
+    """The ``rope_scaling`` of the rotation the K/V layers share. A latent
+    layer rotates its own rope dims itself (``self_attn.rope``: YaRN blends
+    each pair, which no (base, divisor) says)."""
+    if LATENT_LAYER in (layer_kinds(cfg) or ()):
+        return None
+    return getattr(cfg, "rope_scaling", None)
+
+
+def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
     """The decoder stack over ``x``, shared by the three paged forwards:
     every layer once and then the final norm; for a looped model
     (``cache.passes`` > 1) that whole pass ``passes`` times under one
@@ -1222,8 +1286,12 @@ def _run_stack(model, cache, x, tables, layer, linear=None):
     ``pools`` its (k_pool, v_pool, k_scale, v_scale), the scales None for a
     bf16 cache. ``linear(x, lyr, state) -> (x, state)`` is its body of a
     layer that carries a recurrent state (``layer_kinds``), ``state`` that
-    layer's entry of ``cache.states``. Returns (the normed x, the cache's
-    fields as the stack left them, for ``dataclasses.replace``)."""
+    layer's entry of ``cache.states``. ``latent(x, ci, lyr, pool) -> (x,
+    pool, counts)`` is its body of a layer that keeps latent rows, ``pool``
+    that layer's one pool (``k_pools[ci]``). Returns (the normed x, the
+    cache's fields as the stack left them, for ``dataclasses.replace``, and
+    the latent layers' ``counts`` summed: what the call's expert layers say
+    they routed, ``KimiK2MoE``; None where no layer counts)."""
     bb = _backbone(model)
     if cache.passes != cache_passes(model.cfg):
         raise ValueError(
@@ -1233,12 +1301,14 @@ def _run_stack(model, cache, x, tables, layer, linear=None):
     kinds = layer_kinds(model.cfg)
     if kinds is not None and (
             len(cache.states) != kinds.count(LINEAR_LAYER)
-            or len(cache.k_pools) != kinds.count(FULL_LAYER)):
+            or len(cache.k_pools) != len(kinds) - kinds.count(LINEAR_LAYER)):
         raise ValueError(
             f"the cache holds {len(cache.k_pools)} K/V pool(s) and "
             f"{len(cache.states)} recurrent state(s), the model's layers "
             f"are {kinds}: build it with PagedKVCache.init_for(model.cfg, "
             "...)")
+
+    routed = []     # latent layers' counts (they run one pass: no carry)
 
     def one_pass(x, pools, states, tables):
         k, v, ks, vs = (list(p) for p in pools)
@@ -1248,6 +1318,12 @@ def _run_stack(model, cache, x, tables, layer, linear=None):
             if kinds is not None and kinds[li] == LINEAR_LAYER:
                 x, states[si] = linear(x, lyr, states[si])
                 si += 1
+                continue
+            if kinds is not None and kinds[li] == LATENT_LAYER:
+                x, k[ci], counts = latent(x, ci, lyr, k[ci])
+                if counts is not None:
+                    routed.append(counts)
+                ci += 1
                 continue
             x, (k[ci], v[ci], ks[ci], vs[ci]) = layer(
                 x, ci, lyr, (k[ci], v[ci], ks[ci], vs[ci]), tables)
@@ -1271,8 +1347,18 @@ def _run_stack(model, cache, x, tables, layer, linear=None):
     k, v, ks, vs = pools
     if not cache.k_scales:
         ks = vs = ()
-    return x, dict(k_pools=k, v_pools=v, k_scales=tuple(ks),
-                   v_scales=tuple(vs), states=states)
+    return (x, dict(k_pools=k, v_pools=v, k_scales=tuple(ks),
+                    v_scales=tuple(vs), states=states),
+            sum(routed[1:], routed[0]) if routed else None)
+
+
+def _note_routed(routed, counts):
+    """Hand a call's routing counts to the caller that asked for them:
+    ``routed`` is the list a staged program passed to its forward (None:
+    nobody asked), ``counts`` what ``_run_stack`` summed (None: no layer
+    counts)."""
+    if routed is not None and counts is not None:
+        routed.append(counts)
 
 
 def is_moe_model(model) -> bool:
@@ -1314,8 +1400,12 @@ def _lora_delta(x, lora, kind, li):
 
 def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
                         slot_ids=None, table_rows=None, lora=None,
-                        cp_axis=None):
+                        cp_axis=None, routed=None):
     """Prefill padded ragged prompts [B, S]; returns (last_logits, cache).
+    ``routed``, here and in the other two forwards: a list that is handed
+    what the call's expert layers routed (int32 [2]: the pairs sent to
+    experts held here, the held experts hit; ``models/kimi_k2.py``), where
+    the model counts that.
 
     Attention runs the padded-varlen path (kv_lens) — the fused kernel on
     TPU; K/V of every valid position is scattered into the block pool.
@@ -1352,7 +1442,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     rtables = _cp_local_tables(tables, cp_axis, cache.num_blocks)
     x = jnp.take(_backbone(model).embed_tokens, input_ids, axis=0)
     d = cfg.hidden_size // cfg.num_attention_heads
-    scaling = getattr(cfg, "rope_scaling", None)
+    scaling = _rope_scaling(cfg)
     cos, sin = A.rope_cos_sin(
         s, d, base=cfg.rope_theta, scaling=scaling,
         max_position_embeddings=getattr(cfg, "max_position_embeddings",
@@ -1400,7 +1490,22 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
         return _linear_residual(x, lyr, state, prompt_lens, slot_ids,
                                 jnp.ones((b,), bool))
 
-    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
+    def latent(x, ci, lyr, pool):
+        # a whole prompt is a chunk at offset 0 over the rows it has just
+        # written: one attention path for every prefill
+        att = lyr.self_attn
+        return _latent_residual(
+            x, lyr, pool, jnp.broadcast_to(jnp.arange(s), (b, s)),
+            jnp.arange(s)[None, :] < prompt_lens[:, None],
+            lambda pool, vals: _scatter_prefill(pool, vals, rtables,
+                                                prompt_lens, rows, bs),
+            lambda q, pool: paged_latent_chunk_attention(
+                q, pool, rtables, jnp.zeros((b,), jnp.int32), prompt_lens,
+                v_width=att.rank, scale=att.scale))
+
+    x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
+                                   latent)
+    _note_routed(routed, counts)
     logits = _model_logits(model, x)
     last = jnp.take_along_axis(
         logits, jnp.maximum(prompt_lens - 1, 0)[:, None, None].astype(jnp.int32),
@@ -1411,7 +1516,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
 
 
 def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
-                            lora=None, cp_axis=None):
+                            lora=None, cp_axis=None, routed=None):
     """One decode token per sequence. tokens: [B] int32; active: [B] bool
     (finished rows neither write KV nor advance). Returns (logits, cache)."""
     cfg = model.cfg
@@ -1420,7 +1525,7 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
     x = jnp.take(_backbone(model).embed_tokens, tokens[:, None], axis=0)  # [B,1,E]
     d = cfg.hidden_size // cfg.num_attention_heads
     cos, sin = _rope_rows(cache.lens, d, cfg.rope_theta,
-                          getattr(cfg, "rope_scaling", None),
+                          _rope_scaling(cfg),
                           getattr(cfg, "max_position_embeddings", None))
     window = getattr(cfg, "sliding_window", None)
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
@@ -1478,7 +1583,19 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
         # that does not run (length 0) keeps its state
         return _linear_residual(x, lyr, state, active.astype(jnp.int32))
 
-    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
+    def latent(x, ci, lyr, pool):
+        att = lyr.self_attn
+        return _latent_residual(
+            x, lyr, pool, cache.lens[:, None], active[:, None],
+            lambda pool, vals: _scatter_decode(pool, vals, rtables,
+                                               cache.lens, active, rows, bs),
+            lambda q, pool: paged_latent_decode_attention(
+                q[:, 0], pool, rtables, new_lens, v_width=att.rank,
+                scale=att.scale)[:, None])
+
+    x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
+                                   latent)
+    _note_routed(routed, counts)
     logits = _model_logits(model, x)[:, 0]
     return logits, replace(cache, lens=new_lens, **fields)
 
@@ -1486,7 +1603,7 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
 def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
                       upd_rows, upd_cols, upd_vals, rng, temps, top_ps,
                       top_k=None, want_logp=False, lora=None,
-                      logit_bias=None, cp_axis=None):
+                      logit_bias=None, cp_axis=None, routed=None):
     """ONE fused serving tick: apply incremental block-table updates
     (``tables[upd_rows[i], upd_cols[i]] = upd_vals[i]``, sentinel rows
     dropped — no host-side table rebuild/re-upload), run the decode step,
@@ -1509,7 +1626,8 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
                                                            mode="drop")
     cache = replace(cache, block_tables=tables)
     logits, cache = llama_decode_step_paged(model, tokens, cache, active,
-                                            lora, cp_axis=cp_axis)
+                                            lora, cp_axis=cp_axis,
+                                            routed=routed)
     logp = (jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
             if want_logp else ())
     with jax.named_scope("sampler"):
@@ -1661,20 +1779,31 @@ def prefill_staging(rows: int, width: int, max_blocks: int,
 
 def prefill_staged(model, staged, cache: PagedKVCache, layout: Staging,
                    lora=None, cp_axis=None):
-    """:func:`llama_prefill_paged` with its host arrays as one vector."""
+    """:func:`llama_prefill_paged` with its host arrays as one vector ->
+    (last logits, cache, routed): ``routed`` the call's counts for a model
+    with held experts (int32 [2]), None for any other."""
     ids, lens, slots, rows = layout.unpack(staged)
-    return llama_prefill_paged(model, ids, lens, cache, slots, rows, lora,
-                               cp_axis)
+    routed = []
+    logits, cache = llama_prefill_paged(model, ids, lens, cache, slots, rows,
+                                        lora, cp_axis, routed)
+    return logits, cache, (routed[0] if routed else None)
 
 
 def tick_staged(model, staged, cache: PagedKVCache, rng, layout: Staging,
                 top_k=None, want_logp=False, lora=None, logit_bias=None,
                 cp_axis=None):
-    """:func:`llama_decode_tick` with its host arrays as one vector."""
+    """:func:`llama_decode_tick` with its host arrays as one vector. For a
+    model with held experts the tick's two counts ride behind the slots'
+    tokens (``nxt`` is ``[num_slots + 2]``): they come back in the fetch
+    the tick makes anyway."""
     tokens, active, rows, cols, vals, temps, top_ps = layout.unpack(staged)
-    return llama_decode_tick(model, tokens, cache, active, rows, cols, vals,
-                             rng, temps, top_ps, top_k, want_logp, lora,
-                             logit_bias, cp_axis)
+    routed = []
+    nxt, logp, cache = llama_decode_tick(
+        model, tokens, cache, active, rows, cols, vals, rng, temps, top_ps,
+        top_k, want_logp, lora, logit_bias, cp_axis, routed)
+    if routed:
+        nxt = jnp.concatenate([nxt, routed[0].astype(nxt.dtype)])
+    return nxt, logp, cache
 
 
 # module-level jit wrappers: their compile caches persist across calls (a
@@ -2062,7 +2191,8 @@ def paged_generate(model, input_ids, prompt_lens, max_new_tokens=32,
 
 def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
                               cache: PagedKVCache, slot_ids, table_rows,
-                              full_logits=False, lora=None, cp_axis=None):
+                              full_logits=False, lora=None, cp_axis=None,
+                              routed=None):
     """CONTINUE a prefill: write chunk tokens at positions
     ``offsets[a] .. offsets[a]+chunk_lens[a]-1`` of their slots and attend
     each chunk query over the slot's WHOLE pool prefix (gather-based) —
@@ -2110,8 +2240,7 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
     d = cfg.hidden_size // cfg.num_attention_heads
     positions = offsets[:, None] + jnp.arange(c, dtype=jnp.int32)  # [A, C]
     base, pos_div = A.resolve_rope_scaling(
-        cfg.rope_theta, d, getattr(cfg, "rope_scaling", None),
-        allow_dynamic=False,
+        cfg.rope_theta, d, _rope_scaling(cfg), allow_dynamic=False,
         max_position_embeddings=getattr(cfg, "max_position_embeddings",
                                         None))
     inv = 1.0 / (jnp.asarray(base, jnp.float32)
@@ -2174,7 +2303,22 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
         return _linear_residual(x, lyr, state, chunk_lens, slot_ids,
                                 offsets == 0)
 
-    x, fields = _run_stack(model, cache, x, rtables, layer, linear)
+    def latent(x, ci, lyr, pool):
+        # the absorbed form over the pool prefix, whatever the offset: a
+        # context of L cached rows is neither gathered nor expanded
+        att = lyr.self_attn
+        return _latent_residual(
+            x, lyr, pool, positions,
+            jnp.arange(c)[None, :] < chunk_lens[:, None],
+            lambda pool, vals: _scatter_decode_chunk(
+                pool, vals, rtables, offsets, chunk_lens, rows, bs),
+            lambda q, pool: paged_latent_chunk_attention(
+                q, pool, rtables, offsets, chunk_lens, v_width=att.rank,
+                scale=att.scale))
+
+    x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
+                                   latent)
+    _note_routed(routed, counts)
     logits = _model_logits(model, x)
     new_cache = replace(cache, block_tables=new_tables, lens=new_lens,
                         **fields)
@@ -2207,10 +2351,13 @@ def _scatter_decode_chunk(pool, vals, tables, offsets, chunk_lens, nb, bs):
 def prefill_chunk_staged(model, staged, cache: PagedKVCache,
                          layout: Staging, lora=None, cp_axis=None):
     """:func:`llama_prefill_chunk_paged` with its host arrays as one
-    vector."""
+    vector -> (last logits, cache, routed), as :func:`prefill_staged`."""
     ids, lens, offs, slots, rows = layout.unpack(staged)
-    return llama_prefill_chunk_paged(model, ids, lens, offs, cache, slots,
-                                     rows, lora=lora, cp_axis=cp_axis)
+    routed = []
+    logits, cache = llama_prefill_chunk_paged(
+        model, ids, lens, offs, cache, slots, rows, lora=lora,
+        cp_axis=cp_axis, routed=routed)
+    return logits, cache, (routed[0] if routed else None)
 
 
 _PREFILL_CHUNK_JIT = jax.jit(prefill_chunk_staged, static_argnums=(3,),

@@ -43,6 +43,7 @@ serving batch sizes.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from dataclasses import dataclass, asdict
@@ -54,6 +55,8 @@ from paddle_tpu.observability.flops import (PEAK_BF16, chip_peak,
 __all__ = ["PEAK_HBM_BPS", "chip_peak_hbm_bw", "resolve_serving_peaks",
            "ModelGeometry", "weight_bytes", "kv_bytes_per_position",
            "phase_flops", "phase_bytes", "arith_intensity",
+           "latent_read_bytes", "grouped_products_flops",
+           "grouped_products_bytes",
            "roofline_verdict", "record_serving_throughput",
            "serving_roofline_report", "reset_serving_roofline"]
 
@@ -127,13 +130,32 @@ class ModelGeometry:
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 0
+    # latent attention (MLA, models/kimi_k2.py): every layer caches ONE
+    # row of latent_rank + latent_rope values a position, read by all
+    # heads; q through a rank of q_rank, heads of nope_dim + latent_rope
+    # (keys) and v_dim (values) expanded from the latent
+    latent_rank: int = 0
+    latent_rope: int = 0
+    q_rank: int = 0
+    nope_dim: int = 0
+    v_dim: int = 0
+    # expert layers in full: ``dense_layers`` leading layers keep a dense
+    # MLP of ``dense_intermediate``; every expert layer adds
+    # ``shared_experts`` experts all tokens take; ``held_experts`` of the
+    # ``num_experts`` routed ones are resident here (0: all of them), one
+    # chip's share of a wide expert-parallel deployment
+    dense_layers: int = 0
+    dense_intermediate: int = 0
+    shared_experts: int = 0
+    held_experts: int = 0
 
     @classmethod
     def from_config(cls, cfg, dtype_bytes: int = 2) -> "ModelGeometry":
         h = int(cfg.hidden_size)
         nh = int(cfg.num_attention_heads)
         experts = int(getattr(cfg, "num_experts", 0)
-                      or getattr(cfg, "num_local_experts", 0) or 0)
+                      or getattr(cfg, "num_local_experts", 0)
+                      or getattr(cfg, "n_routed_experts", 0) or 0)
         per_tok = int(getattr(cfg, "num_experts_per_tok", 0)
                       or getattr(cfg, "experts_per_tok", 0) or 0)
         inter = int(getattr(cfg, "moe_intermediate_size", 0)
@@ -150,6 +172,17 @@ class ModelGeometry:
                           linear_key_dim=int(cfg.linear_key_head_dim),
                           linear_value_dim=int(cfg.linear_value_head_dim),
                           linear_conv=int(cfg.linear_conv_kernel_dim))
+        if "latent_attention" in kinds:
+            linear = dict(
+                latent_rank=int(cfg.kv_lora_rank),
+                latent_rope=int(cfg.qk_rope_head_dim),
+                q_rank=int(cfg.q_lora_rank),
+                nope_dim=int(cfg.qk_nope_head_dim),
+                v_dim=int(cfg.v_head_dim),
+                dense_layers=int(cfg.first_k_dense_replace),
+                dense_intermediate=int(cfg.intermediate_size),
+                shared_experts=int(cfg.n_shared_experts),
+                held_experts=len(getattr(cfg, "held_experts", None) or ()))
         return cls(num_layers=int(cfg.num_hidden_layers) * passes, hidden=h,
                    intermediate=inter, vocab=int(cfg.vocab_size), heads=nh,
                    kv_heads=int(getattr(cfg, "num_key_value_heads", nh)),
@@ -159,7 +192,15 @@ class ModelGeometry:
     # ---- derived counts -------------------------------------------------
     @property
     def attn_params_per_layer(self) -> int:
-        """Fused qkv + output projection."""
+        """Fused qkv + output projection; for latent attention the two
+        down-projections, the two expansions and the output projection."""
+        if self.latent_rank:
+            qk = self.nope_dim + self.latent_rope
+            return (self.hidden * (self.q_rank + self.latent_rank
+                                   + self.latent_rope)
+                    + self.heads * (self.q_rank * qk + self.latent_rank
+                                    * (self.nope_dim + self.v_dim)
+                                    + self.v_dim * self.hidden))
         return (self.hidden * (self.heads + 2 * self.kv_heads)
                 * self.head_dim + self.heads * self.head_dim * self.hidden)
 
@@ -188,22 +229,32 @@ class ModelGeometry:
         """gate + up + down projections of one (dense or expert) MLP."""
         return 3 * self.hidden * self.intermediate
 
+    def _mlp_params(self, routed) -> float:
+        """Every layer's MLP with ``routed`` routed experts an expert
+        layer: the leading dense layers, and the shared experts beside."""
+        return ((self.num_layers - self.dense_layers)
+                * (routed + self.shared_experts) * self.mlp_params_per_expert
+                + self.dense_layers * 3 * self.hidden
+                * self.dense_intermediate)
+
     @property
     def activated_params(self) -> int:
         """Weight parameters ONE token's forward multiplies against:
-        attention + experts_per_tok MLPs (all of the dense MLP) + head."""
+        attention + experts_per_tok MLPs (all of the dense MLP) + head.
+        With ``held_experts`` the share of its experts_per_tok a uniform
+        router sends here (a fraction of an expert a token)."""
         e = self.experts_per_tok if self.num_experts else 1
-        return (self.mixer_params
-                + self.num_layers * e * self.mlp_params_per_expert
+        if self.held_experts:
+            e = e * self.held_experts / self.num_experts
+        return (self.mixer_params + self._mlp_params(e)
                 + self.hidden * self.vocab)
 
     @property
     def resident_params(self) -> int:
         """Weight parameters a batched forward streams from HBM: every
         expert is resident (the batch routes across all of them)."""
-        e = self.num_experts if self.num_experts else 1
-        return (self.mixer_params
-                + self.num_layers * e * self.mlp_params_per_expert
+        e = (self.held_experts or self.num_experts) if self.num_experts else 1
+        return (self.mixer_params + self._mlp_params(e)
                 + self.hidden * self.vocab)
 
 
@@ -218,10 +269,35 @@ def kv_bytes_per_position(geom: ModelGeometry) -> float:
     """K + V bytes of ONE cached position across all layers; GQA head
     grouping makes this kv_heads/heads of the MHA figure. An int8 pool
     stores head_dim codes plus a per-(position, kv-head) scale."""
+    if geom.latent_rank:
+        return latent_read_bytes(geom, 1.0)
     per_head = (geom.head_dim * (geom.kv_dtype_bytes or geom.dtype_bytes)
                 + geom.kv_scale_bytes)
     return float((geom.num_layers - geom.linear_layers) * 2 * geom.kv_heads
                  * per_head)
+
+
+def latent_read_bytes(geom: ModelGeometry, positions: float) -> float:
+    """Bytes a latent decode read moves for ``positions`` cached positions
+    over all layers: the model's ``latent_rank + latent_rope`` values a row,
+    ONCE for all heads and for both uses (key and value), whatever the pool
+    pads a row to."""
+    return float(positions * geom.num_layers
+                 * (geom.latent_rank + geom.latent_rope) * geom.dtype_bytes)
+
+
+def grouped_products_flops(geom: ModelGeometry, routed_pairs: float) -> float:
+    """FLOPs of the grouped products over ``routed_pairs`` (token, expert)
+    pairs: gate, up and down of one expert a pair, 2 a multiply-add."""
+    return 2.0 * routed_pairs * geom.mlp_params_per_expert
+
+
+def grouped_products_bytes(geom: ModelGeometry, experts_hit: float) -> float:
+    """Weight bytes the grouped products stream for ``experts_hit`` experts
+    that got at least one token (summed over layers): an expert's three
+    matrices once each."""
+    return (experts_hit * geom.mlp_params_per_expert
+            * (geom.weight_dtype_bytes or geom.dtype_bytes))
 
 
 def state_bytes_per_slot(geom: ModelGeometry) -> float:
@@ -246,6 +322,11 @@ def phase_flops(geom: ModelGeometry, tokens: float,
     (whole table per token) with the same argument."""
     matmul = 2.0 * geom.activated_params * tokens
     attn = 4.0 * geom.heads * geom.head_dim * kv_read_positions
+    if geom.latent_rank:
+        # the absorbed form: a head's query against the whole row, its
+        # probabilities against the row's latent part, in every layer
+        attn = (2.0 * geom.num_layers * geom.heads * kv_read_positions
+                * (2 * geom.latent_rank + geom.latent_rope))
     if not geom.linear_layers:
         return matmul + attn
     # the delta rule of the linear layers: k^T S, the rank-one write and
@@ -307,6 +388,13 @@ _REPORTS: dict = {}
 _REPORTS_LOCK = threading.Lock()
 
 
+@functools.lru_cache(maxsize=None)
+def _geometry_fields(geom: ModelGeometry) -> dict:
+    """``asdict(geom)``, taken once a geometry: every tick's gauge sweep
+    reports it, and a field the class gains is then no cost a tick."""
+    return asdict(geom)
+
+
 def record_serving_throughput(phase: str, *, seconds: float, tokens: float,
                               weight_passes: float, kv_read_positions: float,
                               geom: ModelGeometry, peak_flops: float = 0.0,
@@ -337,7 +425,7 @@ def record_serving_throughput(phase: str, *, seconds: float, tokens: float,
         "flops_per_sec": fl / seconds, "bytes_per_sec": by / seconds,
         "arith_intensity": ai, "mfu": mfu_v, "mbu": mbu_v,
         "bound": roofline_verdict(ai, peak_flops, peak_hbm_bps),
-        "geometry": asdict(geom),
+        "geometry": dict(_geometry_fields(geom)),
     }
     _MFU.set(mfu_v, phase=phase)
     _MBU.set(mbu_v, phase=phase)

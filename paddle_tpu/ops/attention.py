@@ -9,9 +9,11 @@ fp32 softmax that XLA fuses into the surrounding computation.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 _NEG_INF = -2.3819763e38  # most-negative bf16-representable; avoids nan from -inf - -inf
@@ -186,6 +188,44 @@ def resolve_rope_scaling(base, head_dim, scaling, seq_len=None,
             base = base * alpha ** (head_dim / (head_dim - 2))
         return base, 1.0
     raise ValueError(f"unknown rope_scaling type {kind!r}")
+
+
+def yarn_inv_freq(head_dim, base, scaling):
+    """YaRN's per-pair inverse frequencies, [head_dim / 2] float32
+    (``{"type": "yarn", "factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow"}``, the published DeepSeek / Kimi form): the
+    interpolated frequency ``f / factor`` and the plain ``f`` blended by a
+    linear ramp over the pair index between the two correction dims
+    (``yarn_find_correction_range``; ``yarn_linear_ramp_mask`` with its
+    ``+0.001`` where the two bounds meet). A per-pair blend, so it is not
+    a ``(base, divisor)`` of :func:`resolve_rope_scaling`."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    inv = base ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    # ramp 0 keeps the plain frequency (extrapolation), 1 interpolates
+    return jnp.asarray(inv / factor * ramp + inv * (1.0 - ramp), jnp.float32)
+
+
+def yarn_mscale(scaling, key="mscale_all_dim") -> float:
+    """``0.1 * scaling[key] * ln(factor) + 1`` (1 at factor <= 1): YaRN's
+    attention temperature. Latent attention multiplies its softmax scale by
+    the square of the ``mscale_all_dim`` one."""
+    factor = float(scaling["factor"])
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * float(scaling.get(key, 1.0)) * math.log(factor) + 1.0
 
 
 def rope_cos_sin(seq_len, head_dim, base=10000.0, dtype=jnp.float32, position_ids=None,

@@ -103,8 +103,10 @@ def _plan(m, e, group_sizes, bm):
     tile_ends = jnp.cumsum(padded // bm)
     total = tile_ends[-1]
     w = -(-m // bm) + e
-    w_ids = jnp.minimum(jnp.arange(w, dtype=jnp.int32), total - 1)
-    gid = jnp.searchsorted(tile_ends, w_ids, side="right").astype(jnp.int32)
+    w_ids = jnp.minimum(jnp.arange(w, dtype=jnp.int32),
+                        jnp.maximum(total - 1, 0))
+    gid = jnp.minimum(jnp.searchsorted(tile_ends, w_ids, side="right"),
+                      e - 1).astype(jnp.int32)      # e: no live tile at all
     ends = jnp.cumsum(sizes)
     shift = (jnp.cumsum(padded) - padded) - (ends - sizes)
     row_gid = jnp.searchsorted(ends, jnp.arange(m, dtype=jnp.int32),
@@ -147,16 +149,20 @@ def _pallas_fwd(lhs, rhs, group_sizes, block_m, block_n, interpret,
     gid, total, dest, w = _plan(m, e, group_sizes, bm)
     xp = jnp.zeros((w * bm, k), lhs.dtype).at[dest].set(lhs)
 
+    def last_live(wi, tot_ref):
+        # no live tile at all (every group empty): block 0, never written
+        return jnp.minimum(wi, jnp.maximum(tot_ref[0] - 1, 0))
+
     def xmap(ni, wi, gid_ref, tot_ref):
         del ni, gid_ref
-        return jnp.minimum(wi, tot_ref[0] - 1), 0
+        return last_live(wi, tot_ref), 0
 
     def wmap(ni, wi, gid_ref, tot_ref):
-        return gid_ref[jnp.minimum(wi, tot_ref[0] - 1)], 0, ni
+        return gid_ref[last_live(wi, tot_ref)], 0, ni
 
     def omap(ni, wi, gid_ref, tot_ref):
         del gid_ref
-        return jnp.minimum(wi, tot_ref[0] - 1), ni
+        return last_live(wi, tot_ref), ni
 
     yp = pl.pallas_call(
         _fwd_kernel,

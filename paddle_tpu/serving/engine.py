@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.models.paged import (_beam_finalize, _BEAM_SELECT_JIT,
+from paddle_tpu.models.paged import (LATENT_LAYER, LINEAR_LAYER,
+                                     _beam_finalize, _BEAM_SELECT_JIT,
                                      cache_passes,
                                      greedy_accept_length, is_moe_model,
                                      layer_kinds, state_bytes,
@@ -58,6 +59,7 @@ from paddle_tpu.observability.roofline import (ModelGeometry,
                                                record_serving_throughput,
                                                resolve_serving_peaks)
 from paddle_tpu.serving.executor import ModelExecutor, _SAMPLE_ROWS_JIT  # noqa: F401  (re-exported)
+from paddle_tpu.serving.executor import LATENT_MODEL as _LATENT
 from paddle_tpu.serving.executor import STATEFUL_MODEL as _STATEFUL
 from paddle_tpu.serving.kv import KVManager, cache_block_bytes
 from paddle_tpu.serving.scheduler import Scheduler
@@ -100,6 +102,10 @@ _RIDGE_TOKENS = 256
 _HYBRID_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "serving/transfer.py ships K/V blocks, not the recurrent "
                    "state that goes with them")
+
+_LATENT_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
+                   "serving/transfer.py ships a K block and a V block a "
+                   "layer, a latent pool holds one array")
 
 _LOOPED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "its payload holds one row a block and layer, a looped "
@@ -270,7 +276,8 @@ class LLMEngine:
         # a prefix hit is worth only as far as a snapshot of that state
         # exists; ``num_state_snapshots`` is the capacity of their pool,
         # one constructor size as ``num_blocks`` is one
-        self.stateful = layer_kinds(cfg) is not None
+        kinds = layer_kinds(cfg) or ()
+        self.stateful = LINEAR_LAYER in kinds
         self.num_state_snapshots = (int(num_state_snapshots)
                                     if self.stateful else 0)
         if self.stateful:
@@ -286,6 +293,24 @@ class LLMEngine:
                 "tick has not been run over a recurrent state",
                 kv_dtype is not None and "a quantized K/V pool (kv_dtype): "
                 "not calibrated beside a float32 state")
+
+        # a model whose layers keep latent rows (multi-head latent
+        # attention): one pool a layer, read by all heads; block ids, the
+        # trie, copy-on-write and the scheduler are per block as for K/V
+        self.latent = LATENT_LAYER in kinds
+        if self.latent:
+            self._refuse(
+                _LATENT,
+                self.cp > 1 and "context parallelism (cp > 1): the latent "
+                "kernels emit no partials to merge across shards",
+                kv_dtype is not None and "a quantized K/V pool (kv_dtype): "
+                "no scales are kept for a latent row",
+                draft_model is not None and "a draft model: no test has "
+                "rewound a latent pool past a rejected proposal",
+                adapter_store is not None and "multi-LoRA (adapter_store): "
+                "its adapters are written for a fused qkv projection",
+                self.async_depth > 0 and "async_depth > 0: the pipelined "
+                "tick has not been run over a latent pool")
 
         # ---- the three extracted layers ----
         self.kv = KVManager(num_blocks, block_size)
@@ -581,6 +606,10 @@ class LLMEngine:
                 _STATEFUL,
                 self.stateful and "beam search (num_beams > 1): a fork "
                 "would have to copy a slot's recurrent state")
+            self._refuse(
+                _LATENT,
+                self.latent and "beam search (num_beams > 1): no test has "
+                "forked a latent pool's blocks")
             self._refuse(
                 self._looped,
                 self.ut_steps > 1 and "beam search (num_beams > 1): no "
@@ -1986,6 +2015,7 @@ class LLMEngine:
         self._drain_async("boundary")
         self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
         self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
+        self._refuse(_LATENT, self.latent and _LATENT_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2084,6 +2114,7 @@ class LLMEngine:
         req = payload.req
         self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
         self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
+        self._refuse(_LATENT, self.latent and _LATENT_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2725,7 +2756,7 @@ class LLMEngine:
                               greedy=greedy,
                               kv_blocks=ctx // self.block_size,
                               **self.exe.span_args,
-                              **self.exe.state_slots(n_run)):
+                              **self.exe.state_slots(n_run)) as stage:
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),
@@ -2738,6 +2769,11 @@ class LLMEngine:
             with _span("serving.fetch", cat="device_wait",
                        seq=self.exe.model_seq):
                 nxt = np.asarray(nxt)         # the one per-tick host fetch
+            if self.exe.routes and stage.recording:
+                # the tick's two counts rode behind its tokens; the
+                # prefill calls queued before it have run too
+                stage.set(routed_pairs=int(nxt[-2]), experts_hit=int(nxt[-1]))
+                self.exe.take_routed()
         t2 = time.perf_counter()
         if self.cp > 1:
             _CP_GATHER_S.observe(t2 - t1)

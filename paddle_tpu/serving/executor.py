@@ -25,13 +25,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models.decoding import KVCache, _sample_rows
-from paddle_tpu.models.paged import (PagedKVCache, _BEAM_GROUP_UPDATE_JIT,
+from paddle_tpu.models.paged import (LATENT_LAYER, PagedKVCache,
+                                     _BEAM_GROUP_UPDATE_JIT,
                                      _PREFILL_CHUNK_JIT, _PREFILL_JIT,
                                      _PREFIX_COW_JIT, _REWIND_LENS_JIT,
                                      _STATE_RESTORE_JIT, _STATE_TAKE_JIT,
                                      _TICK_JIT, _VERIFY_CHUNK_JIT,
                                      _async_tick_jit, _prefix_cow_update,
-                                     init_states,
+                                     init_states, is_moe_model, layer_kinds,
                                      llama_verify_chunk_paged,
                                      prefill_chunk_staged, prefill_staged,
                                      prefill_staging, spec_rewind_lens,
@@ -91,6 +92,8 @@ def _token_rows(ids, lens) -> dict:
 
 # how a refusal names a model with recurrent layers (``LLMEngine._refuse``)
 STATEFUL_MODEL = "a model with recurrent (linear-attention) layers"
+# and one whose layers keep latent rows (multi-head latent attention)
+LATENT_MODEL = "a model with latent-attention (MLA) layers"
 
 
 def _chunk_kv_blocks(lens, offs, block_size) -> int:
@@ -147,6 +150,13 @@ class ModelExecutor:
         # the snapshot pool is beside it, laid out the same, a row an entry,
         # and touched by the two copy programs alone
         self.state_layers = len(self.cache.states)
+        # a model whose expert layers say what they routed (``models/
+        # kimi_k2.py``): a program's two counts come back with what the
+        # host fetches anyway; the prefill calls' wait here, (program, seq,
+        # device counts), for the next wait (``take_routed``)
+        self.latent = LATENT_LAYER in (layer_kinds(cfg) or ())
+        self.routes = self.latent and is_moe_model(model)
+        self._routed = []
         self.snaps = ()
         if self.state_layers:
             self.span_args["state_layers"] = self.state_layers
@@ -209,11 +219,12 @@ class ModelExecutor:
                              check_vma=False)
 
         def staged_twin(program):
-            """(model, staged, cache, layout) -> (logits, cache)"""
+            """(model, staged, cache, layout) -> (logits, cache, None): no
+            model that counts its routing is served under cp"""
             def twin(model, staged, cache, layout):
-                return smap(
-                    functools.partial(program, layout=layout, cp_axis="cp"),
-                    (R, R, cs), (R, cs))(model, staged, cache)
+                return (*smap(
+                    lambda *a: program(*a, layout=layout, cp_axis="cp")[:2],
+                    (R, R, cs), (R, cs))(model, staged, cache), None)
             return jax.jit(twin, static_argnums=(3,), donate_argnums=(2,))
 
         self._cp_prefill = staged_twin(prefill_staged)
@@ -307,13 +318,15 @@ class ModelExecutor:
             staged = layout.pack(ids, lens, slots, rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
-                logits, self.cache = self._dispatch(
+                logits, self.cache, _ = self._dispatch(
                     "prefill", self._cp_prefill, self._model, staged,
                     self.cache, layout)
                 return logits
-            logits, self.cache = self._dispatch(
+            logits, self.cache, routed = self._dispatch(
                 "prefill", _PREFILL_JIT, self._model, staged, self.cache,
                 layout, lora=lora)
+            if routed is not None:
+                self._routed.append(("prefill", self.seq, routed))
             return logits
 
     def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
@@ -328,21 +341,38 @@ class ModelExecutor:
             staged = layout.pack(ids, lens, offs, slots, rows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
-                logits, self.cache = self._dispatch(
+                logits, self.cache, _ = self._dispatch(
                     "chunk", self._cp_prefill_chunk, self._model, staged,
                     self.cache, layout)
                 return logits
-            logits, self.cache = self._dispatch(
+            logits, self.cache, routed = self._dispatch(
                 "chunk", _PREFILL_CHUNK_JIT, self._model, staged,
                 self.cache, layout, lora=lora)
+            if routed is not None:
+                self._routed.append(("chunk", self.seq, routed))
             return logits
 
+    def take_routed(self):
+        """What the prefill calls since the last wait routed, one span
+        ``exe.routed`` a call (``program``, the call's ``seq``,
+        ``routed_pairs``, ``experts_hit``): a prefill call's own span
+        closes when it is queued, before the device has counted. Called
+        where the host has just waited for a later program, so the counts
+        are there to read; read only while spans record."""
+        for program, seq, counts in self._routed:
+            with _span("exe.routed", program=program, seq=seq) as sp:
+                if sp.recording:
+                    pairs, hit = np.asarray(counts).tolist()
+                    sp.set(routed_pairs=pairs, experts_hit=hit)
+        self._routed.clear()
+
     def _ctx_tokens(self, lens, offs) -> dict:
-        """``ctx_tokens`` of a prefill call for a model with recurrent
-        layers: the sum over its live rows of ``offset + len``, from the
-        host's lengths (with ``useful``, each token's context for a count
-        of the call's FLOPs). Nothing, and no work, for any other model."""
-        if not self.state_layers:
+        """``ctx_tokens`` of a prefill call for a model with recurrent or
+        latent layers: the sum over its live rows of ``offset + len``, from
+        the host's lengths (with ``useful``, each token's context for a
+        count of the call's FLOPs). Nothing, and no work, for any other
+        model."""
+        if not (self.state_layers or self.latent):
             return {}
         lens = np.asarray(lens)
         return {"ctx_tokens": int(np.sum((np.asarray(offs) + lens)[lens > 0]))}
@@ -374,6 +404,10 @@ class ModelExecutor:
                 f"{STATEFUL_MODEL} is not served with verify_chunk: a "
                 "rejected token's write to the recurrent state cannot be "
                 "rolled back")
+        if self.latent:
+            raise NotImplementedError(
+                f"{LATENT_MODEL} is not served with verify_chunk: no test "
+                "has rewound a latent pool past a rejected proposal")
         ids, clens, offs = (jnp.asarray(ids), jnp.asarray(clens),
                             jnp.asarray(offs))
         slot_ids, rows = jnp.asarray(slot_ids), jnp.asarray(rows)
@@ -494,7 +528,10 @@ class ModelExecutor:
         :meth:`sample_rows`' results, as numpy."""
         with _span("exe.sample", cat="device_wait", seq=self.model_seq,
                    rows=sum(t.shape[0] for t in sampled)):
-            return [np.asarray(t) for t in sampled]
+            got = [np.asarray(t) for t in sampled]
+        if self._routed:
+            self.take_routed()
+        return got
 
     # -------------------------------------------------------------- draft
     def draft_rows(self, ids, rp, cl):

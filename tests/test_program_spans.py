@@ -29,7 +29,8 @@ KERNEL_NAMES = {
     "paged_decode_attention", "paged_chunk_attention", "flash_attention_fwd",
     "flash_attention_dq", "flash_attention_dkv", "rms_norm_fwd", "fused_rope",
     "grouped_matmul", "grouped_matmul_dx", "grouped_matmul_dw",
-    "gated_delta_chunk"}
+    "gated_delta_chunk", "paged_latent_decode_attention",
+    "paged_latent_chunk_attention"}
 
 
 @pytest.fixture(scope="module")
@@ -755,7 +756,7 @@ def _pallas_calls():
 
 def test_every_pallas_call_site_passes_a_name():
     sites = dict(_pallas_calls())
-    assert len(sites) == 10
+    assert len(sites) == 12
     names = []
     for where, call in sites.items():
         kw = {k.arg: k.value for k in call.keywords}
@@ -765,7 +766,7 @@ def test_every_pallas_call_site_passes_a_name():
             names.append(v.value)
         else:                    # the one site two kernels reach: its
             assert isinstance(v, ast.Name), where    # callers name it
-    assert len(set(names)) == len(names) == 9
+    assert len(set(names)) == len(names) == 11
     assert set(names) <= KERNEL_NAMES
 
 

@@ -179,13 +179,14 @@ def _hand_over_arrays(monkeypatch):
     def prefill(model, staged, cache, layout, lora=None):
         calls["prefill"] += 1
         ids, lens, slots, rows = _host_unpack(layout, staged)
-        return _BODY_PREFILL(model, ids, lens, cache, slots, rows, lora=lora)
+        return (*_BODY_PREFILL(model, ids, lens, cache, slots, rows,
+                               lora=lora), None)      # no counts of routing
 
     def chunk(model, staged, cache, layout, lora=None):
         calls["chunk"] += 1
         ids, lens, offs, slots, rows = _host_unpack(layout, staged)
-        return _BODY_CHUNK(model, ids, lens, offs, cache, slots, rows,
-                           lora=lora)
+        return (*_BODY_CHUNK(model, ids, lens, offs, cache, slots, rows,
+                             lora=lora), None)
 
     monkeypatch.setattr(executor_mod, "_TICK_JIT", tick)
     monkeypatch.setattr(executor_mod, "_PREFILL_JIT", prefill)

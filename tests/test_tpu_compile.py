@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from paddle_tpu.ops.pallas import latent_attention
 from paddle_tpu.ops.pallas.norms import rms_norm
 from paddle_tpu.ops.pallas import paged_attention
 from paddle_tpu.ops.pallas.paged_attention import (
@@ -584,6 +585,12 @@ def test_flash_attention_kv_lens_compiles(one_chip, case):
 GROUPED = [
     ("e8_2048x5504_fwd_bwd", 4096, 8, 2048, 5504, True),
     ("e64_2048x1024_256rows", 256, 64, 2048, 1024, False),
+    # kimik2.serve.longshared: 12 held experts of 7168 x 2048, a 32-slot
+    # tick's 256 pairs and a 2,048-token chunk's common branch
+    ("kimi_gate_up_tick", 256, 12, 7168, 4096, False),
+    ("kimi_down_tick", 256, 12, 2048, 7168, False),
+    ("kimi_gate_up_chunk", 2048, 12, 7168, 4096, False),
+    ("kimi_down_chunk_all_pairs", 16384, 12, 2048, 7168, False),
 ]
 
 
@@ -596,6 +603,95 @@ def test_grouped_matmul_compiles(one_chip, case):
         fn = jax.grad(lambda x, w, g: gmm(x, w, g).astype(f32).sum(),
                       argnums=(0, 1))
     _compile(fn, one_chip, ((m, k), bf16), ((e, k, n), bf16), ((e,), i32))
+
+
+# ---- latent attention (MLA) at Kimi-K2's sizes: 64 heads over rows of
+# 512 + 64 -> 640 values, the first 512 the value; the cell's pool
+# (id, pool blocks, table width)
+LATENT = [("cell_pool20480_table1056", 20480, 1056), ("table32", 512, 32)]
+
+
+@pytest.mark.parametrize("case", LATENT, ids=[c[0] for c in LATENT])
+def test_latent_decode_attention_compiles(one_chip, case):
+    _, n, width = case
+    fn = functools.partial(
+        latent_attention.paged_latent_decode_attention_pallas, v_width=512,
+        scale=0.13, interpret=False)
+    compiled = _compile(fn, one_chip, ((32, 64, 640), bf16),
+                        ((n, 16, 640), bf16), ((32, width), i32),
+                        ((32,), i32))
+    _assert_pool_read_in_place(compiled, n * 16 * 640)
+    assert "paged_latent_decode_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case", LATENT, ids=[c[0] for c in LATENT])
+def test_latent_chunk_attention_compiles(one_chip, case):
+    _, n, width = case
+    fn = functools.partial(
+        latent_attention.paged_latent_chunk_attention_pallas, v_width=512,
+        scale=0.13, interpret=False)
+    compiled = _compile(fn, one_chip, ((1, 2048, 64, 640), bf16),
+                        ((n, 16, 640), bf16), ((1, width), i32), ((1,), i32),
+                        ((1,), i32))
+    _assert_pool_read_in_place(compiled, n * 16 * 640)
+    assert "paged_latent_chunk_attention" in compiled.as_text()
+
+
+def test_latent_pool_off_the_tiling_takes_the_gather(one_chip, monkeypatch):
+    """A block of 8 bf16 rows is half a sublane tile: the dispatcher sends
+    it to the gather, and the program holds no Mosaic call."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = functools.partial(latent_attention.paged_latent_decode_attention,
+                           v_width=512, scale=0.13)
+    args = [jax.ShapeDtypeStruct(s_, d, sharding=one_chip) for s_, d in (
+        ((8, 64, 640), bf16), ((64, 8, 640), bf16), ((8, 16), i32),
+        ((8,), i32))]
+    assert "tpu_custom_call" not in jax.jit(fn).lower(*args).compile() \
+        .as_text()
+
+
+def _kimi_served():
+    """Kimi-K2's head sizes and ranks at a narrow hidden size, 12 of 384
+    experts held: the programs' shapes, with leaves instead of weights."""
+    from paddle_tpu.models import paged
+    from paddle_tpu.models.kimi_k2 import KimiK2Config, KimiK2ForCausalLM
+    cfg = KimiK2Config(
+        vocab_size=4096, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256, num_hidden_layers=2,
+        num_attention_heads=64, num_key_value_heads=64,
+        held_experts=tuple(range(12)), dtype=bf16)
+    return (jax.eval_shape(lambda: KimiK2ForCausalLM(cfg)),
+            jax.eval_shape(lambda: paged.PagedKVCache.init_for(
+                cfg, 256, 16, 8, 64)))
+
+
+def test_kimi_tick_and_chunk_programs_compile_with_their_kernels(
+        one_chip, monkeypatch):
+    """The staged tick and chunk programs of a latent model with held
+    experts: the latent kernels and the grouped products in them, the
+    tick's two counts behind its tokens, the chunk's beside its cache."""
+    from paddle_tpu.models import paged
+    model, cache = _kimi_served()
+    S = jax.ShapeDtypeStruct
+    layout = paged.tick_staging(8)
+    args = _placed((model, S((layout.size,), i32), cache,
+                    S((2,), jnp.uint32)), one_chip)
+    text = _compiled_for_the_chip(monkeypatch, paged._TICK_JIT, *args,
+                                  layout, None, False)
+    assert "paged_latent_decode_attention" in text
+    assert "grouped_matmul" in text and "s32[10]" in text
+    assert '"scoped_memory_configs":[{' not in text
+    layout = paged.prefill_staging(1, 2048, 64, True)
+    args = _placed((model, S((layout.size,), i32), cache), one_chip)
+    text = _compiled_for_the_chip(monkeypatch, paged._PREFILL_CHUNK_JIT,
+                                  *args, layout)
+    assert "paged_latent_chunk_attention" in text
+    assert "grouped_matmul" in text and "while" in text
+    # no kernel of the program asks for another scoped-VMEM size than the
+    # compiler's: one that does makes XLA give every operation a region of
+    # its own, and with two conditionals beside the latent chunk kernel's
+    # 48 MiB the chip never returned (PERF.md section 6, PR 41)
+    assert '"scoped_memory_configs":[{' not in text
 
 
 # rows x hidden: chip_smoke.py's own (decode tick, chunk rows, reference
