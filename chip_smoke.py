@@ -460,8 +460,9 @@ def latent(tiny, seed):
     with the second size under a ``lax.cond``, two conditionals in one
     program. With the chunk kernel asking for 48 MiB of scoped VMEM the
     second form never returned; at the compiler's default both run and
-    agree. A hung device cannot be timed out from inside: run this leg
-    under ``timeout``."""
+    agree. Since PR 42 that kernel is the EXPANDED one (a prefill call's);
+    its timings alone at the cell's shapes follow. A hung device cannot be
+    timed out from inside: run this leg under ``timeout``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -552,6 +553,56 @@ def latent(tiny, seed):
     gap = float(np.abs(out["cond"] - out["while_loop"]).max())
     say(latent="both", widest_logit_difference=gap)
     check(gap < 0.05, "the two forms of held_forward differ by", gap)
+    latent_chunk_kernel_alone(tiny, att=model.layers[0].self_attn)
+
+
+def latent_chunk_kernel_alone(tiny, att):
+    """The expanded chunk kernel by itself at the shapes of
+    ``kimik2.serve.longshared`` (a ``[1, 2048]`` chunk of 64 heads over a
+    pool ``[20480, 16, 640]`` behind a table 1,056 wide): ms a call, full
+    and tail chunks at three offsets, and the kernel against its gather
+    twin at a table the twin can hold."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import latent_attention as L
+
+    c, n, width = (64, 64, 32) if tiny else (2048, 20480, 1056)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    h, dt = att.num_heads, att.o_proj.dtype
+    w_kvb = att.kv_b()
+    q_nope = jax.random.normal(keys[0], (1, c, h, att.nope)).astype(dt)
+    q_rope = jax.random.normal(keys[1], (1, c, h, att.rope_dim)).astype(dt)
+    pool = jax.random.normal(keys[2], (n, 16, att.row_width)).astype(dt)
+    pool = pool.at[:, :, att.rank + att.rope_dim:].set(0)
+    tables = jnp.asarray(
+        np.random.default_rng(0).permutation(n)[None, :width], jnp.int32)
+    fn = jax.jit(functools.partial(L.paged_latent_chunk_attention,
+                                   scale=att.scale))
+
+    def call(off, live):
+        return fn(q_nope, q_rope, w_kvb, pool, tables,
+                  jnp.array([off], jnp.int32), jnp.array([live], jnp.int32))
+
+    small = (q_nope[:, :256], q_rope[:, :256], w_kvb, pool, tables[:, :32],
+             jnp.array([200], jnp.int32), jnp.array([min(c, 251)], jnp.int32))
+    twin = L.paged_latent_chunk_attention_xla(*small, scale=att.scale)
+    got = L.paged_latent_chunk_attention(*small, scale=att.scale)
+    gap = float(jnp.abs(got.astype(jnp.float32)
+                        - twin.astype(jnp.float32)).max())
+    say(latent_chunk_kernel="against its gather twin", widest=gap)
+    check(gap < 0.05, "the expanded kernel and its twin differ by", gap)
+    for off in (0, 128, 384) if tiny else (0, 6000, 14000):
+        for live in (c, max(1, c * 100 // 2048)):
+            call(off, live).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = call(off, live)
+            out.block_until_ready()
+            say(latent_chunk_kernel="alone", offset=off, live_rows=live,
+                ms_a_call=round((time.perf_counter() - t0) / 5 * 1e3, 3))
 
 
 def main():

@@ -25,13 +25,17 @@ rotating halves of those dims as they lie (the published code first
 de-interleaves them: a fixed permutation of columns, which seeded random
 weights cannot tell apart).
 
-Two forms of the same mathematics. :meth:`KimiK2Attention.__call__` is the
-EXPANDED one (per-head K of ``qk_nope + qk_rope`` and V of ``v_head_dim``
-from the latent), what a plain forward over a whole sequence runs. The
-paged forwards (``models/paged.py: _latent_residual``) run the ABSORBED one
-over the latent cache: ``q~_h = [q_nope_h W^K_h^T | q_rope_h]`` scored
-against the cache rows themselves, ``o_h = (sum_s p c_kv(s)) W^V_h``: 64
-query heads to one cache head, nothing expanded.
+Two forms of the same mathematics, chosen by the call site. The EXPANDED
+one (per-head K of ``qk_nope + qk_rope`` and V of ``v_head_dim`` from the
+latent; :meth:`KimiK2Attention.expanded`) is what scores many queries
+against a key: :meth:`KimiK2Attention.__call__` over a whole sequence, and
+the paged PREFILL forwards (``models/paged.py: _latent_residual``), whose
+kernel expands each block of cache rows in VMEM. The ABSORBED one
+(:meth:`KimiK2Attention.absorbed`: ``q~_h = [q_nope_h W^K_h^T | q_rope_h]``
+scored against the cache rows themselves, ``o_h = (sum_s p c_kv(s))
+W^V_h``: 64 query heads to one cache head, nothing expanded) is the paged
+DECODE TICK's, one query a row: it spends 3.4 times the score FLOPs and no
+expansion, which pays under ~170 queries a key.
 
 **One chip's share.** ``held_experts`` names the routed experts (global
 ids) this model holds in each expert layer; the router keeps its published
@@ -209,8 +213,8 @@ class KimiK2Attention(Module):
         return (self.kv_a_layernorm(kv[..., :self.rank]),
                 _rotate(kv[..., self.rank:], cos, sin))
 
-    def _kv_b(self):
-        """W_kvb as [rank, H, nope + v]."""
+    def kv_b(self):
+        """W_kvb as [rank, H, nope + v]: a head's columns ``[k_nope | v]``."""
         w = self.kv_b_proj
         if hasattr(w, "dequantize"):
             w = w.dequantize(self.o_proj.dtype)
@@ -223,32 +227,40 @@ class KimiK2Attention(Module):
         return jnp.pad(jnp.concatenate([c_kv, k_r], axis=-1),
                        ((0, 0), (0, 0), (0, pad)))
 
-    def absorbed_queries(self, u, cos, sin):
-        """q~ = [q_nope W^K^T | q_rope | 0], [B, S, H, W]: a head's query
-        against the cache rows themselves."""
-        q_nope, q_rope = self.queries(u, cos, sin)
-        w_k = self._kv_b()[..., :self.nope]               # [rank, H, nope]
-        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_k.astype(u.dtype))
-        pad = self.row_width - self.rank - self.rope_dim
-        return jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
-                       ((0, 0), (0, 0), (0, 0), (0, pad)))
-
-    def output(self, o_lat):
-        """o_lat [B, S, H, rank] (probabilities times latents) -> the
-        branch's output [B, S, hidden]: W^V a head, then ``o_proj``."""
-        b, s = o_lat.shape[:2]
-        w_v = self._kv_b()[..., self.nope:]               # [rank, H, v]
-        o = jnp.einsum("bshc,chd->bshd", o_lat, w_v.astype(o_lat.dtype))
+    def project(self, o):
+        """o [B, S, H, v] -> the branch's output [B, S, hidden]."""
+        b, s = o.shape[:2]
         return wo_matmul(o.reshape(b, s, self.num_heads * self.v_dim),
                          self.o_proj)
 
+    def absorbed(self, u, cos, sin, attend):
+        """The absorbed form: ``attend(q~ [B, S, H, W]) -> [B, S, H, rank]``
+        (probabilities times latents) is the caller's attention of
+        ``q~ = [q_nope W^K^T | q_rope | 0]`` over the cache rows themselves;
+        W^V a head, then ``o_proj``."""
+        q_nope, q_rope = self.queries(u, cos, sin)
+        w_k = self.kv_b()[..., :self.nope]                # [rank, H, nope]
+        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_k.astype(u.dtype))
+        pad = self.row_width - self.rank - self.rope_dim
+        o_lat = attend(jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                               ((0, 0), (0, 0), (0, 0), (0, pad))))
+        w_v = self.kv_b()[..., self.nope:]                # [rank, H, v]
+        return self.project(jnp.einsum("bshc,chd->bshd", o_lat,
+                                       w_v.astype(o_lat.dtype)))
+
+    def expanded(self, u, cos, sin, attend):
+        """The expanded form: ``attend(q_nope, q_rope, W_kvb) -> [B, S, H,
+        v]`` is the caller's attention of the per-head queries over the K
+        and V it expands from the latents; then ``o_proj``."""
+        return self.project(attend(*self.queries(u, cos, sin), self.kv_b()))
+
     def __call__(self, u, cos, sin):
         """The expanded form, causal over the whole of ``u`` [B, S, E]."""
-        b, s, _ = u.shape
+        s = u.shape[1]
         q_nope, q_rope = self.queries(u, cos, sin)
         c_kv, k_r = self.latent(u, cos, sin)
         kv = jnp.einsum("bsc,chd->bshd", c_kv,
-                        self._kv_b().astype(c_kv.dtype))
+                        self.kv_b().astype(c_kv.dtype))
         k_nope, v = kv[..., :self.nope], kv[..., self.nope:]
         scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
                              preferred_element_type=jnp.float32)
@@ -256,9 +268,8 @@ class KimiK2Attention(Module):
                                preferred_element_type=jnp.float32))
         keep = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
         p = jax.nn.softmax(jnp.where(keep, scores * self.scale, -1e30), -1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
-        return wo_matmul(o.reshape(b, s, self.num_heads * self.v_dim),
-                         self.o_proj)
+        return self.project(jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype),
+                                       v))
 
 
 class KimiK2MoE(Module):

@@ -31,7 +31,7 @@ import numpy as np
 from paddle_tpu.observability.memledger import MemLedger
 from paddle_tpu.ops import attention as A
 from paddle_tpu.ops.pallas.latent_attention import (
-    latent_row_width, paged_latent_chunk_attention,
+    _latent_chunk_call, latent_row_width, paged_latent_chunk_attention,
     paged_latent_decode_attention)
 from paddle_tpu.ops.pallas.paged_attention import (_note_trace,
                                                    _paged_chunk_call,
@@ -1240,28 +1240,41 @@ def _linear_residual(x, lyr, state, lens, rows=None, fresh=None):
 
 
 def _latent_residual(x, lyr, pool, positions, live, scatter, attend):
-    """A latent layer's body, shared by the three paged forwards: the
-    absorbed form of ``models/kimi_k2.py`` over the layer's pool of latent
-    rows. ``positions`` [B, S] of the rows of ``x``, ``live`` [B, S] which of
-    them carry a token (a padding row is routed to no expert);
+    """A latent layer's body, shared by the three paged forwards: what
+    ``models/kimi_k2.py`` writes to and reads from the layer's pool of
+    latent rows. ``positions`` [B, S] of the rows of ``x``, ``live`` [B, S]
+    which of them carry a token (a padding row is routed to no expert);
     ``scatter(pool, rows [B, S, W]) -> pool`` writes the new positions'
-    rows where the caller's tables put them; ``attend(q [B, S, H, W],
-    pool) -> [B, S, H, rank]`` is the caller's attention over the pool as
-    it then stands (a decode tick's one-token kernel, a prefill's causal
-    chunk kernel). -> (x, pool, counts): ``counts`` what the layer's MLP
-    says it routed (``KimiK2MoE``), None for a dense one."""
+    rows where the caller's tables put them; ``attend(att, h, rope, pool)
+    -> [B, S, hidden]`` is the caller's attention branch over the pool as
+    it then stands, in the form its call site asks: a decode tick scores
+    one query a row ABSORBED (``att.absorbed`` around the one-token
+    kernel), a prefill call a chunk EXPANDED (``_latent_chunk_attend``).
+    -> (x, pool, counts): ``counts`` what the layer's MLP says it routed
+    (``KimiK2MoE``), None for a dense one."""
     h = _pre_norm(x, lyr, "input_layernorm")
     with jax.named_scope("attention"):
         att = lyr.self_attn
         rope = att.rope(positions)
         pool = scatter(pool, att.cache_rows(h, *rope))
-        q = att.absorbed_queries(h, *rope)
-        x = x + att.output(attend(q, pool))
+        x = x + attend(att, h, rope, pool)
     h = _pre_norm(x, lyr, "post_attention_layernorm")
     with jax.named_scope("mlp"):
         out = lyr.mlp(h, live) if lyr.sparse else lyr.mlp(h)
     y, counts = out if isinstance(out, tuple) else (out, None)
     return x + y, pool, counts
+
+
+def _latent_chunk_attend(tables, offsets, chunk_lens):
+    """``_latent_residual``'s ``attend`` of a prefill call: the chunk's
+    rows, at ``offsets`` and ``chunk_lens`` long, scored in the expanded
+    form against the pool prefix (the kernel expands each block of latent
+    rows to a head's K and V in VMEM: no K or V in HBM, no context
+    gathered)."""
+    return lambda att, h, rope, pool: att.expanded(
+        h, *rope, lambda q_nope, q_rope, w_kvb: paged_latent_chunk_attention(
+            q_nope, q_rope, w_kvb, pool, tables, offsets, chunk_lens,
+            scale=att.scale))
 
 
 def _rope_scaling(cfg):
@@ -1493,15 +1506,13 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     def latent(x, ci, lyr, pool):
         # a whole prompt is a chunk at offset 0 over the rows it has just
         # written: one attention path for every prefill
-        att = lyr.self_attn
         return _latent_residual(
             x, lyr, pool, jnp.broadcast_to(jnp.arange(s), (b, s)),
             jnp.arange(s)[None, :] < prompt_lens[:, None],
             lambda pool, vals: _scatter_prefill(pool, vals, rtables,
                                                 prompt_lens, rows, bs),
-            lambda q, pool: paged_latent_chunk_attention(
-                q, pool, rtables, jnp.zeros((b,), jnp.int32), prompt_lens,
-                v_width=att.rank, scale=att.scale))
+            _latent_chunk_attend(rtables, jnp.zeros((b,), jnp.int32),
+                                 prompt_lens))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
                                    latent)
@@ -1584,14 +1595,15 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
         return _linear_residual(x, lyr, state, active.astype(jnp.int32))
 
     def latent(x, ci, lyr, pool):
-        att = lyr.self_attn
+        # one query a row: the absorbed form, nothing expanded
         return _latent_residual(
             x, lyr, pool, cache.lens[:, None], active[:, None],
             lambda pool, vals: _scatter_decode(pool, vals, rtables,
                                                cache.lens, active, rows, bs),
-            lambda q, pool: paged_latent_decode_attention(
-                q[:, 0], pool, rtables, new_lens, v_width=att.rank,
-                scale=att.scale)[:, None])
+            lambda att, h, rope, pool: att.absorbed(
+                h, *rope, lambda q: paged_latent_decode_attention(
+                    q[:, 0], pool, rtables, new_lens, v_width=att.rank,
+                    scale=att.scale)[:, None]))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
                                    latent)
@@ -1841,14 +1853,14 @@ def clear_jit_caches():
     context changes under the same call signature — flipping
     ``PT_GROUPED_GEMM``, patching a dispatcher's rule in a test, or
     entering/leaving a mesh re-routes layers, but the jit caches key on
-    shapes only. The chunk kernel's own ``jit`` (one traced call for
+    shapes only. The chunk kernels' own ``jit`` (one traced call for
     every layer of a program) goes with the programs that hold it."""
     if _async_tick_jit.cache_info().currsize:   # built: backend exists
         _async_tick_jit().clear_cache()
     for f in (_PREFILL_JIT, _GENERATE_PREFILL_JIT, _DECODE_JIT, _TICK_JIT,
               _PREFILL_CHUNK_JIT, _VERIFY_CHUNK_JIT, _REWIND_LENS_JIT,
               _PREFIX_COW_JIT, _STATE_TAKE_JIT, _STATE_RESTORE_JIT,
-              _paged_chunk_call, *_EXTRA_CLEAR):
+              _paged_chunk_call, _latent_chunk_call, *_EXTRA_CLEAR):
         f.clear_cache()
     from paddle_tpu.ops.pallas import gated_delta
     gated_delta.clear_caches()
@@ -2304,17 +2316,12 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
                                 offsets == 0)
 
     def latent(x, ci, lyr, pool):
-        # the absorbed form over the pool prefix, whatever the offset: a
-        # context of L cached rows is neither gathered nor expanded
-        att = lyr.self_attn
         return _latent_residual(
             x, lyr, pool, positions,
             jnp.arange(c)[None, :] < chunk_lens[:, None],
             lambda pool, vals: _scatter_decode_chunk(
                 pool, vals, rtables, offsets, chunk_lens, rows, bs),
-            lambda q, pool: paged_latent_chunk_attention(
-                q, pool, rtables, offsets, chunk_lens, v_width=att.rank,
-                scale=att.scale))
+            _latent_chunk_attend(rtables, offsets, chunk_lens))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
                                    latent)

@@ -324,7 +324,9 @@ def phase_flops(geom: ModelGeometry, tokens: float,
     attn = 4.0 * geom.heads * geom.head_dim * kv_read_positions
     if geom.latent_rank:
         # the absorbed form: a head's query against the whole row, its
-        # probabilities against the row's latent part, in every layer
+        # probabilities against the row's latent part, in every layer (the
+        # tick's form; a prefill call runs the expanded one since PR 42,
+        # 3.4 times fewer: this gauge does not know the phase)
         attn = (2.0 * geom.num_layers * geom.heads * kv_read_positions
                 * (2 * geom.latent_rank + geom.latent_rope))
     if not geom.linear_layers:

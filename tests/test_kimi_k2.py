@@ -1,10 +1,10 @@
 """Kimi-K2 on the normal serving path (ISSUE 41): latent attention over a
 paged latent cache against the plain reference
-(``chipbench/reference_kimi_k2.py``: logits, not tokens), the absorbed form
-against the expanded one, the sigmoid gate with its selection bias, one
-chip's share of the experts against the uncut layer, prefix hits and
-copy-on-write on latent blocks, what the family is refused, and what its
-spans carry.
+(``chipbench/reference_kimi_k2.py``: logits, not tokens), the expanded
+prefill and the absorbed tick against the plain forward, the sigmoid gate
+with its selection bias, one chip's share of the experts against the uncut
+layer, prefix hits and copy-on-write on latent blocks, what the family is
+refused, and what its spans carry.
 
 Sizes: a dense layer and two expert layers, hidden 64, the published
 ratios (rope half of nope, a latent twice a head), 16 experts of which 4 a
@@ -121,22 +121,81 @@ def test_latent_decode_kernel_is_its_gather_twin():
     assert float(jnp.abs(got[1]).max()) == 0.0      # a row of length 0
 
 
-@pytest.mark.parametrize("q_tile", [None, 128])
-def test_latent_chunk_kernel_is_its_gather_twin(q_tile):
-    rng, n, bs, w, pool, perm = _pool_case(1)
-    offs, cl = np.array([16, 0, 100], np.int32), np.array([21, 0, 24],
-                                                          np.int32)
-    tables = _tables(offs + cl, perm, n, bs)
-    q = jnp.asarray(rng.normal(size=(3, 24, 8, w)), jnp.bfloat16)
-    kw = dict(v_width=128, scale=0.1)
-    got = L.paged_latent_chunk_attention_pallas(
-        q, pool, tables, offs, cl, q_tile=q_tile, interpret=True, **kw)
-    want = L.paged_latent_chunk_attention_xla(q, pool, tables, offs, cl, **kw)
+# the expanded chunk kernel's cases: (offsets, chunk_lens, chunk, the
+# kernel's forced sizes, KB a slot of its row buffer: 16 is a compute block
+# of 32 rows); the pool's row is [c_kv 128 | k_r 64 | 0], 4 heads
+CHUNK_CASES = {
+    "offset_0_full_chunk": ([0], [24], 24, {}, 512),
+    "offset_off_block_and_tile": (
+        [37], [300], 300, dict(q_tile=256, sub_tile=128), 16),
+    "tail_of_3_at_a_long_offset": ([170], [3], 24, {}, 16),
+    "unequal_rows_a_dead_one_between": (
+        [16, 0, 100], [21, 0, 24], 24, {}, 16),
+    "forced_small_q_tile_two_heads_a_step": (
+        [5, 130], [140, 9], 140, dict(q_tile=128, heads=2), 40),
+}
+
+
+def _chunk_case(offs, cl, c, seed=1):
+    rng, n, bs, w, pool, perm = _pool_case(seed)
+    pool = pool.at[:, :, 192:].set(0)
+    offs, cl = np.array(offs, np.int32), np.array(cl, np.int32)
+    tables = _tables(offs + cl, perm, n, bs, width=24)
+    q_nope = jnp.asarray(rng.normal(size=(len(offs), c, 4, 128)),
+                         jnp.bfloat16)
+    q_rope = jnp.asarray(rng.normal(size=(len(offs), c, 4, 64)),
+                         jnp.bfloat16)
+    w_kvb = jnp.asarray(rng.normal(size=(128, 4, 256)) * 0.1, jnp.bfloat16)
+    return (q_nope, q_rope, w_kvb, pool, tables, offs, cl)
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_latent_chunk_kernel_is_its_gather_twin(case, monkeypatch):
+    offs, cl, c, sizes, buffer_kb = CHUNK_CASES[case]
+    args = _chunk_case(offs, cl, c)
+    monkeypatch.setattr(L, "_BUFFER_BYTES", buffer_kb * 1024)
+    paged.clear_jit_caches()          # the kernel's own jit among them
+    try:
+        got = L.paged_latent_chunk_attention_pallas(
+            *args, scale=0.1, interpret=True, **sizes)
+    finally:
+        paged.clear_jit_caches()
+    want = L.paged_latent_chunk_attention_xla(*args, scale=0.1)
+    assert got.shape == (len(offs), c, 4, 128)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=8e-3)
     # a dead row and a live row's positions past its length emit zeros
-    assert float(jnp.abs(got[1]).max()) == 0.0
-    assert float(jnp.abs(got[0, 21:]).max()) == 0.0
+    for row, n in enumerate(cl):
+        assert float(jnp.abs(got[row, n:]).max(initial=0.0)) == 0.0
+        assert n == 0 or float(jnp.abs(got[row, :n]).max()) > 0.0
+
+
+def test_the_expanded_kernel_is_the_plain_expanded_forward():
+    """``KimiK2Attention.__call__`` over a whole row (K and V of every
+    position expanded by XLA, no cache) against the kernel over the row's
+    latent cache, chunk by chunk: every live position's branch output."""
+    from paddle_tpu.models.kimi_k2 import KimiK2Attention
+    rng = np.random.default_rng(8)
+    att = KimiK2Attention(KimiK2Config.tiny())
+    s, bs, n = 45, 8, 12
+    u = jnp.asarray(rng.normal(size=(1, s, 64)), jnp.float32)
+    want = np.asarray(att(u, *att.rope(jnp.arange(s)[None])))
+    tables = rng.permutation(n)[None, :8].astype(np.int32)
+    pool = jnp.zeros((n, bs, att.row_width), jnp.float32)
+    for off, ln, c in ((0, 13, 16), (13, 32, 32)):
+        pos = off + jnp.arange(c)[None]
+        rope = att.rope(pos)
+        h = jnp.zeros((1, c, 64)).at[:, :ln].set(u[:, off:off + ln])
+        rows = att.cache_rows(h, *rope)[0, :ln]
+        at = np.arange(off, off + ln)
+        pool = pool.at[tables[0, at // bs], at % bs].set(rows)
+        got = att.expanded(
+            h, *rope, lambda q_nope, q_rope, w_kvb:
+            L.paged_latent_chunk_attention_pallas(
+                q_nope, q_rope, w_kvb, pool, tables, [off], [ln],
+                scale=att.scale, interpret=True))
+        np.testing.assert_allclose(np.asarray(got)[0, :ln],
+                                   want[0, off:off + ln], atol=2e-5)
 
 
 def test_the_slab_rule_names_what_mosaic_copies():
@@ -174,8 +233,9 @@ def test_prefill_then_decode_is_the_reference_at_every_step(model):
         jnp.array([0, 2]), jnp.asarray(rows))
     for _ in range(8):
         want = reference(seq)
-        # float32 both: the absorbed form over the cache against the
-        # expanded one over the row
+        # float32 both: the prefill expanded from the cache's rows, then
+        # each tick absorbed over them, against the expanded form over the
+        # whole row
         np.testing.assert_allclose(np.asarray(logits)[0], want[-1], atol=5e-5)
         seq.append(int(np.argmax(want[-1])))
         tables = cache.block_tables.at[0, :8].set(jnp.arange(8) + 3)
@@ -190,11 +250,13 @@ def test_prefill_then_decode_is_the_reference_at_every_step(model):
             bin(int(m)).count("1") for m in masks[:, -1])
 
 
-def test_the_absorbed_form_is_the_expanded_form(model):
-    """A chunk at an offset through the latent cache (absorbed) and the
-    model's plain forward (expanded, no cache): every position's logits."""
+def test_expanded_prefill_then_absorbed_ticks_are_the_plain_forward(model):
+    """The one place the two forms meet: chunks at an offset through the
+    latent cache (EXPANDED over the rows as cached), then decode ticks over
+    the same cache (ABSORBED), against the model's plain forward (expanded,
+    no cache): every position's logits, then every step's."""
     rng = np.random.default_rng(6)
-    seq = rng.integers(1, 256, 29, dtype=np.int32)
+    seq = list(rng.integers(1, 256, 29, dtype=np.int32))
     want = np.asarray(model(jnp.asarray(seq)[None])[0])
     cache = fresh_cache(model, slots=1)
     rows = np.full((1, 32), 32, np.int32)
@@ -208,6 +270,16 @@ def test_the_absorbed_form_is_the_expanded_form(model):
             jnp.array([0]), jnp.asarray(rows), full_logits=True)
         got.append(np.asarray(logits)[0, :n])
     np.testing.assert_allclose(np.concatenate(got), want, atol=5e-5)
+    cache = paged.replace(
+        cache, block_tables=cache.block_tables.at[0, 8:10].set(
+            jnp.arange(2) + 8))
+    for _ in range(3):
+        seq.append(int(np.argmax(want[-1])))
+        logits, cache = paged.llama_decode_step_paged(
+            model, jnp.array([seq[-1]]), cache, jnp.array([True]))
+        want = np.asarray(model(jnp.asarray(seq)[None])[0])
+        np.testing.assert_allclose(np.asarray(logits)[0], want[-1],
+                                   atol=5e-5)
 
 
 # ------------------------------------------------------ the serving engine

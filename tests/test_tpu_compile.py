@@ -463,15 +463,20 @@ def _tick_args(model, cache, sharding):
                    sharding)
 
 
-def _compiled_for_the_chip(monkeypatch, jitted, *args):
-    """The program's text, compiled with the dispatchers on their TPU side."""
+def _program_for_the_chip(monkeypatch, jitted, *args):
+    """The program, compiled with the dispatchers on their TPU side."""
     from paddle_tpu.models import paged
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     paged.clear_jit_caches()
     try:
-        return jitted.lower(*args).compile().as_text()
+        return jitted.lower(*args).compile()
     finally:
         paged.clear_jit_caches()     # traced under a patched backend
+
+
+def _compiled_for_the_chip(monkeypatch, jitted, *args):
+    """That program's text."""
+    return _program_for_the_chip(monkeypatch, jitted, *args).as_text()
 
 
 @pytest.mark.parametrize("family", ["llama", "ouro", "hybrid"])
@@ -626,15 +631,22 @@ def test_latent_decode_attention_compiles(one_chip, case):
 
 @pytest.mark.parametrize("case", LATENT, ids=[c[0] for c in LATENT])
 def test_latent_chunk_attention_compiles(one_chip, case):
+    """The expanded chunk kernel at the cell's chunk: per-head queries,
+    W_kvb as the model keeps it, the pool read where it lies, and no scoped
+    VMEM asked beyond the compiler's own."""
     _, n, width = case
     fn = functools.partial(
-        latent_attention.paged_latent_chunk_attention_pallas, v_width=512,
-        scale=0.13, interpret=False)
-    compiled = _compile(fn, one_chip, ((1, 2048, 64, 640), bf16),
+        latent_attention.paged_latent_chunk_attention_pallas, scale=0.13,
+        interpret=False)
+    compiled = _compile(fn, one_chip, ((1, 2048, 64, 128), bf16),
+                        ((1, 2048, 64, 64), bf16), ((512, 64, 256), bf16),
                         ((n, 16, 640), bf16), ((1, width), i32), ((1,), i32),
                         ((1,), i32))
-    _assert_pool_read_in_place(compiled, n * 16 * 640)
-    assert "paged_latent_chunk_attention" in compiled.as_text()
+    _assert_pool_read_in_place(       # q_rope, q_nope and the output, q
+        compiled, n * 16 * 640, but=[2048 * 64 * d for d in (64, 128, 256)])
+    text = compiled.as_text()
+    assert "paged_latent_chunk_attention" in text
+    assert '"scoped_memory_configs":[{' not in text
 
 
 def test_latent_pool_off_the_tiling_takes_the_gather(one_chip, monkeypatch):
@@ -683,9 +695,15 @@ def test_kimi_tick_and_chunk_programs_compile_with_their_kernels(
     assert '"scoped_memory_configs":[{' not in text
     layout = paged.prefill_staging(1, 2048, 64, True)
     args = _placed((model, S((layout.size,), i32), cache), one_chip)
-    text = _compiled_for_the_chip(monkeypatch, paged._PREFILL_CHUNK_JIT,
+    chunk = _program_for_the_chip(monkeypatch, paged._PREFILL_CHUNK_JIT,
                                   *args, layout)
+    text = chunk.as_text()
     assert "paged_latent_chunk_attention" in text
+    # the expanded form keeps no K or V in HBM and forms no absorbed
+    # query: this program's temporaries are 68.6 MiB by the compiler's
+    # count, where the absorbed form's q~ [2048, 64, 640] and latent
+    # outputs [2048, 64, 512] made them 345.9 (PERF.md section 6, PR 42)
+    assert chunk.memory_analysis().temp_size_in_bytes < 96 << 20
     assert "grouped_matmul" in text and "while" in text
     # no kernel of the program asks for another scoped-VMEM size than the
     # compiler's: one that does makes XLA give every operation a region of
