@@ -279,6 +279,8 @@ class KimiK2MoE(Module):
     that got at least one (``routed_pairs``, ``experts_hit`` on the
     serving spans)."""
 
+    counts_routed = True      # ``models.paged.counts_routed``
+
     def __init__(self, cfg: KimiK2Config):
         super().__init__()
         self.moe = MoELayer(

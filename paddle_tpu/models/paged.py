@@ -20,6 +20,7 @@ lengths (rounded up per block) — NOT batch × max_len as in the static
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from dataclasses import dataclass, replace
@@ -75,7 +76,20 @@ class PagedKVCache:
     (``latent_row_width``: whole 128-lane rows, the lanes past the two
     zero), and ``v_pools`` is empty. Block ids, tables, the trie,
     copy-on-write and the scheduler are per block as for K/V, so a prefix
-    hit adopts latent blocks as it adopts K/V blocks."""
+    hit adopts latent blocks as it adopts K/V blocks.
+
+    A model with WINDOW layers beside full ones (``models/trinity.py``;
+    ``window_space_layers``) keeps TWO BLOCK SPACES in one cache: the pools
+    of the full layers hold ``N_blocks`` blocks and are addressed by
+    ``block_tables``; the pools of the window layers (``window_layers``,
+    static: their indices among the K/V layers) hold ``window_blocks``
+    blocks, of a numbering of their own, and are addressed by
+    ``window_tables``. Both tables are indexed by ``position //
+    block_size``; a window layer reads only a row's last ``window``
+    positions, so the host frees the window space's blocks below them and a
+    row holds O(window) blocks there, O(length) in the full space. A model
+    of one kind has no second space: ``window_tables`` None,
+    ``window_layers`` ()."""
     k_pools: list   # [L] of [passes * N_blocks, block_size, H_kv, D]
     v_pools: list   # (latent layers: k_pools [N_blocks, block_size, W], no v)
     block_tables: jnp.ndarray  # [B, max_blocks] int32 (pad = n_blocks)
@@ -84,6 +98,8 @@ class PagedKVCache:
     v_scales: tuple = ()
     passes: int = 1
     states: tuple = ()         # [linear layers] of (S, conv), a row a slot
+    window_tables: jnp.ndarray | None = None   # [B, max_blocks], pad = N_w
+    window_layers: tuple = ()  # K/V layers whose pools are the window space
 
     @property
     def block_size(self):
@@ -91,8 +107,17 @@ class PagedKVCache:
 
     @property
     def pool_rows(self):
-        """Rows of one pool: every pass's copy of every block."""
-        return self.k_pools[0].shape[0]
+        """Rows of one pool (of the full space, where there are two):
+        every pass's copy of every block."""
+        full = next(i for i in range(len(self.k_pools))
+                    if i not in self.window_layers)
+        return self.k_pools[full].shape[0]
+
+    @property
+    def window_blocks(self):
+        """Blocks of the window space (0: the cache has one space)."""
+        return (self.k_pools[self.window_layers[0]].shape[0]
+                if self.window_layers else 0)
 
     @property
     def num_blocks(self):
@@ -133,9 +158,12 @@ class PagedKVCache:
 
     @staticmethod
     def init_for(cfg, num_blocks, block_size, batch, max_blocks_per_seq,
-                 kv_dtype=None):
+                 kv_dtype=None, window_blocks=None):
         """The cache of the model ``cfg`` describes: its layers, its
-        passes over them, its K/V heads."""
+        passes over them, its K/V heads; for a model with window layers
+        beside full ones its two block spaces, ``num_blocks`` of the full
+        and ``window_blocks`` of the window space. A model of one kind
+        builds what it always built."""
         kinds = layer_kinds(cfg)
         if kinds is not None and LATENT_LAYER in kinds:
             if set(kinds) != {LATENT_LAYER} or kv_dtype is not None \
@@ -150,24 +178,40 @@ class PagedKVCache:
                 jnp.full((batch, max_blocks_per_seq), num_blocks, jnp.int32),
                 jnp.zeros((batch,), jnp.int32))
         n_kv = (cfg.num_hidden_layers if kinds is None
-                else kinds.count(FULL_LAYER))
+                else len(kinds) - kinds.count(LINEAR_LAYER))
         cache = PagedKVCache.init(
             n_kv, num_blocks, block_size,
-            kv_pool_heads(cfg.num_key_value_heads),
-            cfg.hidden_size // cfg.num_attention_heads, batch,
+            kv_pool_heads(cfg.num_key_value_heads), head_dim(cfg), batch,
             max_blocks_per_seq, cfg.dtype, kv_dtype=kv_dtype,
             passes=cache_passes(cfg))
-        if kinds is not None:
+        if kinds is not None and LINEAR_LAYER in kinds:
             cache.states = init_states(cfg, batch,
                                        kinds.count(LINEAR_LAYER))
+        window = window_space_layers(cfg)
+        if window:
+            if kv_dtype is not None or cache.passes != 1 or cache.states \
+                    or not window_blocks:
+                raise NotImplementedError(
+                    "two block spaces (window layers beside full ones) in "
+                    "a quantized pool, under several passes, beside "
+                    "recurrent layers or without window_blocks are not "
+                    "built")
+            shape = (int(window_blocks),) + cache.k_pools[0].shape[1:]
+            for ci in window:
+                cache.k_pools[ci] = jnp.zeros(shape, cfg.dtype)
+                cache.v_pools[ci] = jnp.zeros(shape, cfg.dtype)
+            cache.window_tables = jnp.full((batch, max_blocks_per_seq),
+                                           int(window_blocks), jnp.int32)
+            cache.window_layers = window
         return cache
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
     lambda c: ((c.k_pools, c.v_pools, c.block_tables, c.lens,
-                c.k_scales, c.v_scales, c.states), c.passes),
-    lambda passes, ch: PagedKVCache(*ch[:6], passes, ch[6]))
+                c.k_scales, c.v_scales, c.states, c.window_tables),
+               (c.passes, c.window_layers)),
+    lambda aux, ch: PagedKVCache(*ch[:6], aux[0], ch[6], ch[7], aux[1]))
 
 
 def kv_pool_heads(num_kv_heads: int) -> int:
@@ -197,20 +241,58 @@ def _pool_heads(x, heads: int):
 
 LINEAR_LAYER, FULL_LAYER = "linear_attention", "full_attention"
 LATENT_LAYER = "latent_attention"
+WINDOW_LAYER = "sliding_attention"
 
 
 def layer_kinds(cfg):
     """A model's layers by kind, from what its configuration says of them
     (``layer_types``, one name a layer), or None where every layer keeps
-    K/V: the one-kind model every forward here served before. The kinds:
-    ``full_attention`` (K/V pools), ``linear_attention`` (a recurrent state
-    a slot, no K/V: ``PagedKVCache.states``) and ``latent_attention`` (one
-    pool of latent rows a layer, read by all heads)."""
+    K/V alike: the one-kind model every forward here served before. The
+    kinds: ``full_attention`` (K/V pools), ``sliding_attention`` (K/V pools
+    of which a query reads the last ``cfg.sliding_window`` positions: the
+    window is the layer's, not the model's), ``linear_attention`` (a
+    recurrent state a slot, no K/V: ``PagedKVCache.states``) and
+    ``latent_attention`` (one pool of latent rows a layer, read by all
+    heads)."""
     kinds = getattr(cfg, "layer_types", None)
-    if not kinds or not {LINEAR_LAYER, LATENT_LAYER} & set(
+    if not kinds or not {LINEAR_LAYER, LATENT_LAYER, WINDOW_LAYER} & set(
             kinds[:cfg.num_hidden_layers]):
         return None
     return tuple(kinds[:cfg.num_hidden_layers])
+
+
+def kv_windows(cfg) -> tuple:
+    """The window of each layer that keeps K/V, in the order of the cache's
+    pools: how many of a row's last positions a query there reads, None
+    for all of them. The one answer to "is this layer windowed": a model
+    that names its layers' kinds gives its ``sliding_attention`` layers
+    ``cfg.sliding_window`` and its ``full_attention`` layers None; a model
+    that does not (Mistral v0.1's shape) gives every layer
+    ``cfg.sliding_window``."""
+    window = getattr(cfg, "sliding_window", None)
+    kinds = layer_kinds(cfg)
+    if kinds is None:
+        return (window,) * cfg.num_hidden_layers
+    return tuple(window if k == WINDOW_LAYER else None for k in kinds
+                 if k in (FULL_LAYER, WINDOW_LAYER))
+
+
+def window_space_layers(cfg) -> tuple:
+    """The K/V layers (their indices among the cache's pools) whose blocks
+    live in a space of their own: the window layers of a model that has
+    full layers too. () for a model of one kind, whose one space is what
+    it always was, windowed or not."""
+    windows = kv_windows(cfg)
+    if all(w is None for w in windows) or None not in windows:
+        return ()
+    return tuple(i for i, w in enumerate(windows) if w is not None)
+
+
+def head_dim(cfg) -> int:
+    """A head's width: the configuration's own ``head_dim`` where it
+    states one (it need not be hidden / heads), else hidden / heads."""
+    return (getattr(cfg, "head_dim", None)
+            or cfg.hidden_size // cfg.num_attention_heads)
 
 
 def state_shapes(cfg):
@@ -1063,6 +1145,46 @@ class RadixPrefixBlockManager(RefBlockManager):
         return upper
 
 
+class TwoSpaceBlockManager(RadixPrefixBlockManager):
+    """The manager of the FULL space of a cache with two block spaces
+    (``PagedKVCache.window_layers``), with the window space's beside it as
+    ``window``: a plain :class:`BlockManager` over that space's own block
+    numbers. A sequence's two tables are indexed alike (by ``position //
+    block_size``) and grow together: ``allocate`` takes the same table
+    positions in both spaces or in neither, ``free`` returns both. What a
+    window layer no longer reads is freed in the window space alone
+    (``window.free_prefix``, leaving ``None`` holes there), so a sequence
+    holds O(window) blocks in it and O(length) here.
+
+    Two managers and not one manager of two free lists: everything the
+    engine, the scheduler and the ledger do to the full space (reservations,
+    tables, the memory ledger) stays one manager's, exactly the one a model
+    of one kind has, and the window space needs only what
+    :class:`BlockManager` already is (holes, resumed prefix scans, a ledger
+    of its own). Prefix adoption and forks would have to pair blocks across
+    the spaces; the engine refuses them for such a model."""
+
+    def __init__(self, num_blocks: int, block_size: int, window_blocks: int):
+        super().__init__(num_blocks, block_size)
+        self.window = BlockManager(window_blocks, block_size)
+
+    def allocate(self, seq_id: int, n_tokens: int):
+        short = (self.blocks_needed(n_tokens)
+                 - len(self.window.tables.get(seq_id, ()))
+                 - self.window.free_blocks)
+        if short > 0:
+            raise MemoryError(
+                f"paged cache out of window-space blocks: {short} short "
+                f"(of {self.window.num_blocks})")
+        table = super().allocate(seq_id, n_tokens)   # raises: nothing taken
+        self.window.allocate(seq_id, n_tokens)
+        return table
+
+    def free(self, seq_id: int):
+        super().free(seq_id)
+        self.window.free(seq_id)
+
+
 def _rope_rows(positions, head_dim, base, scaling=None, max_pos=None):
     """cos/sin for PER-ROW positions: [B] -> [B, 1, 1, D/2] (ragged decode:
     every sequence sits at a different position). Shares the scaling math
@@ -1203,10 +1325,61 @@ def _qk_norm(att, q, k):
     return att.q_norm(q), att.k_norm(k)
 
 
+def _rotated(att, rope, t):
+    """q or k through the caller's rotation ``rope``, unless the layer
+    carries no positional encoding (``use_rope`` False: a NoPE layer)."""
+    return rope(t) if getattr(att, "use_rope", True) else t
+
+
+def _gated(att, h, attn_out):
+    """The heads' output times ``sigmoid(h W_g)``, elementwise, where the
+    attention module has an output gate (``gate_proj``)."""
+    gate = getattr(att, "gate_proj", None)
+    if gate is None:
+        return attn_out
+    return attn_out * jax.nn.sigmoid(
+        _wo(h, gate).astype(jnp.float32)).astype(attn_out.dtype)
+
+
+def _embed(model, ids):
+    """The tokens' embedding rows, times the backbone's input scale where
+    it has one (``embed_scale``: muP's sqrt(hidden))."""
+    bb = _backbone(model)
+    x = jnp.take(bb.embed_tokens, ids, axis=0)
+    scale = getattr(bb, "embed_scale", None)
+    return x * jnp.asarray(scale, x.dtype) if scale else x
+
+
+def _kind_scope(cache, window):
+    """The named scope of one paged kernel call in a model with two block
+    spaces, so that a device trace tells its window layers' calls
+    (``attention.window``) from its full layers' (``attention.full``);
+    nothing for a model of one kind."""
+    if not cache.window_layers:
+        return contextlib.nullcontext()
+    return jax.named_scope("attention.window" if window is not None
+                           else "attention.full")
+
+
 def _mlp_residual(x, lyr):
     return _residual(
         x, _mlp_out(lyr, _pre_norm(x, lyr, "post_attention_layernorm")),
         lyr, "post_attention_layernorm_2")
+
+
+def _mlp_counted(x, lyr, live):
+    """A K/V layer's MLP branch -> (x, counts): ``_mlp_residual`` and None
+    for a layer whose MLP counts nothing; for an expert layer that says
+    what it routed (``counts_routed``: its ``mlp(h, live)`` gives ``(y,
+    counts)``, ``live()`` [B, S] False a padding token routed nowhere: a
+    thunk, so that a program whose layers count nothing traces nothing for
+    it) the same add with its counts."""
+    if not counts_routed(lyr):
+        return _mlp_residual(x, lyr), None
+    h = _pre_norm(x, lyr, "post_attention_layernorm")
+    with jax.named_scope("mlp"):
+        y, counts = lyr.mlp(h, live())
+    return _residual(x, y, lyr, "post_attention_layernorm_2"), counts
 
 
 def _linear_residual(x, lyr, state, lens, rows=None, fresh=None):
@@ -1286,7 +1459,8 @@ def _rope_scaling(cfg):
     return getattr(cfg, "rope_scaling", None)
 
 
-def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
+def _run_stack(model, cache, x, tables, layer, linear=None, latent=None,
+               wtables=None):
     """The decoder stack over ``x``, shared by the three paged forwards:
     every layer once and then the final norm; for a looped model
     (``cache.passes`` > 1) that whole pass ``passes`` times under one
@@ -1294,17 +1468,19 @@ def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
     one before and writing pool rows of its own (``_pass_tables``), so the
     program holds one body a layer however many passes there are.
 
-    ``layer(x, ci, lyr, pools, tables) -> (x, pools)`` is the caller's
-    body of a layer that keeps K/V, ``ci`` its index among those and
+    ``layer(x, ci, lyr, pools, tables) -> (x, pools, counts)`` is the
+    caller's body of a layer that keeps K/V, ``ci`` its index among those,
     ``pools`` its (k_pool, v_pool, k_scale, v_scale), the scales None for a
-    bf16 cache. ``linear(x, lyr, state) -> (x, state)`` is its body of a
+    bf16 cache, ``tables`` those of its block space (``wtables`` for a
+    layer of ``cache.window_layers``) and ``counts`` what its MLP says it
+    routed (None: nothing). ``linear(x, lyr, state) -> (x, state)`` is its body of a
     layer that carries a recurrent state (``layer_kinds``), ``state`` that
     layer's entry of ``cache.states``. ``latent(x, ci, lyr, pool) -> (x,
     pool, counts)`` is its body of a layer that keeps latent rows, ``pool``
     that layer's one pool (``k_pools[ci]``). Returns (the normed x, the
     cache's fields as the stack left them, for ``dataclasses.replace``, and
-    the latent layers' ``counts`` summed: what the call's expert layers say
-    they routed, ``KimiK2MoE``; None where no layer counts)."""
+    the layers' ``counts`` summed: what the call's expert layers say they
+    routed, ``KimiK2MoE`` / ``TrinityMoE``; None where no layer counts)."""
     bb = _backbone(model)
     if cache.passes != cache_passes(model.cfg):
         raise ValueError(
@@ -1321,7 +1497,11 @@ def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
             f"are {kinds}: build it with PagedKVCache.init_for(model.cfg, "
             "...)")
 
-    routed = []     # latent layers' counts (they run one pass: no carry)
+    routed = []     # layers' counts (a model that counts runs one pass)
+    if cache.passes > 1 and any(map(counts_routed, bb.layers)):
+        raise NotImplementedError(
+            "a looped model whose expert layers count what they route: the "
+            "counts cannot leave the loop over the passes")
 
     def one_pass(x, pools, states, tables):
         k, v, ks, vs = (list(p) for p in pools)
@@ -1338,8 +1518,11 @@ def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
                     routed.append(counts)
                 ci += 1
                 continue
-            x, (k[ci], v[ci], ks[ci], vs[ci]) = layer(
-                x, ci, lyr, (k[ci], v[ci], ks[ci], vs[ci]), tables)
+            x, (k[ci], v[ci], ks[ci], vs[ci]), counts = layer(
+                x, ci, lyr, (k[ci], v[ci], ks[ci], vs[ci]),
+                wtables if ci in cache.window_layers else tables)
+            if counts is not None:
+                routed.append(counts)
             ci += 1
         return bb.norm(x), (k, v, ks, vs), tuple(states)
 
@@ -1365,6 +1548,21 @@ def _run_stack(model, cache, x, tables, layer, linear=None, latent=None):
             sum(routed[1:], routed[0]) if routed else None)
 
 
+def _last_logits(model, cache, x, lens):
+    """The head's logits at each row's last live position, [A, V]. A cache
+    with two block spaces takes the row first and the head after (its
+    model's vocabulary is 200k rows: the head over every position of a
+    2,048-token chunk would cost more than the chunk's layers); every
+    other model's programs compute what they always computed."""
+    at = lambda: jnp.maximum(lens - 1, 0)[:, None, None].astype(  # noqa: E731
+        jnp.int32)
+    if cache.window_layers:
+        return _model_logits(model,
+                             jnp.take_along_axis(x, at(), axis=1))[:, 0]
+    logits = _model_logits(model, x)
+    return jnp.take_along_axis(logits, at(), axis=1)[:, 0]
+
+
 def _note_routed(routed, counts):
     """Hand a call's routing counts to the caller that asked for them:
     ``routed`` is the list a staged program passed to its forward (None:
@@ -1372,6 +1570,12 @@ def _note_routed(routed, counts):
     counts)."""
     if routed is not None and counts is not None:
         routed.append(counts)
+
+
+def counts_routed(lyr) -> bool:
+    """Whether a layer's MLP is an expert block that hands out what it
+    routed (``KimiK2MoE``, ``TrinityMoE``: the class says so)."""
+    return getattr(getattr(lyr, "mlp", None), "counts_routed", False)
 
 
 def is_moe_model(model) -> bool:
@@ -1413,7 +1617,7 @@ def _lora_delta(x, lora, kind, li):
 
 def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
                         slot_ids=None, table_rows=None, lora=None,
-                        cp_axis=None, routed=None):
+                        cp_axis=None, routed=None, window_rows=None):
     """Prefill padded ragged prompts [B, S]; returns (last_logits, cache).
     ``routed``, here and in the other two forwards: a list that is handed
     what the call's expert layers routed (int32 [2]: the pairs sent to
@@ -1430,7 +1634,9 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     installed on device) while every other slot's pools/tables/lens stay
     untouched — so prefill of admitted requests interleaves with decode of
     in-flight ones. Padding rows use slot_id >= num_slots (scatter-drop)
-    and prompt_len 0."""
+    and prompt_len 0. ``window_rows`` [A, max_blocks], here and in the
+    chunk forward: the rows' tables in the window space, for a cache that
+    has one."""
     cfg = model.cfg
     if getattr(cfg, "fp8", False):
         raise NotImplementedError(
@@ -1440,6 +1646,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
     b, s = input_ids.shape
     bs = cache.block_size
     prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
+    wtables = new_wtables = cache.window_tables
     if slot_ids is None:
         tables = cache.block_tables          # row i == slot i (legacy)
         new_lens = prompt_lens
@@ -1449,13 +1656,19 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
         tables = jnp.asarray(table_rows, jnp.int32)   # [A, max_blocks]
         new_tables = cache.block_tables.at[slot_ids].set(tables, mode="drop")
         new_lens = cache.lens.at[slot_ids].set(prompt_lens, mode="drop")
+        if cache.window_layers:
+            wtables = jnp.asarray(window_rows, jnp.int32)
+            new_wtables = cache.window_tables.at[slot_ids].set(wtables,
+                                                               mode="drop")
     # cp: tables stay GLOBAL on device; only the pool scatters see the
     # LOCAL view (non-owned writes drop). In-prompt attention is dense
     # over the local pre-quant k/v — replicated compute, no merge needed.
     rtables = _cp_local_tables(tables, cp_axis, cache.num_blocks)
-    x = jnp.take(_backbone(model).embed_tokens, input_ids, axis=0)
-    d = cfg.hidden_size // cfg.num_attention_heads
+    x = _embed(model, input_ids)
+    d = head_dim(cfg)
     scaling = _rope_scaling(cfg)
+    windows = kv_windows(cfg)
+    live = lambda: jnp.arange(s)[None, :] < prompt_lens[:, None]  # noqa: E731
     cos, sin = A.rope_cos_sin(
         s, d, base=cfg.rope_theta, scaling=scaling,
         max_position_embeddings=getattr(cfg, "max_position_embeddings",
@@ -1464,7 +1677,7 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
         cur_len=(prompt_lens if (scaling or {}).get("type") == "dynamic"
                  else None),
         allow_dynamic=False)
-    rows = cache.pool_rows
+    rope = lambda t: A.apply_rope(t, cos, sin)  # noqa: E731
 
     def layer(x, li, lyr, pools, rtables):
         h = _pre_norm(x, lyr, "input_layernorm")
@@ -1478,25 +1691,26 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
             q, k = _qk_norm(att, q, k)
-            q = A.apply_rope(q.reshape(b, s, nh, hd), cos, sin)
-            k = A.apply_rope(k.reshape(b, s, nkv, hd), cos, sin)
+            q = _rotated(att, rope, q.reshape(b, s, nh, hd))
+            k = _rotated(att, rope, k.reshape(b, s, nkv, hd))
             v = v.reshape(b, s, nkv, hd)
             # the prompt's own attention is dense over the LOCAL pre-
             # quantization k/v — only the pool writes quantize, so prefill
             # quality is exactly the decode dequantization error, never worse
             out = A.scaled_dot_product_attention(
                 q, k, v, is_causal=True, kv_lens=prompt_lens,
-                window=getattr(cfg, "sliding_window", None))
+                window=windows[li])
             hp = pools[0].shape[2]
             pools = _scatter_kv(pools, _pool_heads(k, hp), _pool_heads(v, hp),
-                                _scatter_prefill, rtables, prompt_lens, rows,
-                                bs)
-            attn_out = out.reshape(b, s, nh * hd)
+                                _scatter_prefill, rtables, prompt_lens,
+                                pools[0].shape[0], bs)
+            attn_out = _gated(att, h, out.reshape(b, s, nh * hd))
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
             x = _residual(x, proj, lyr, "input_layernorm_2")
-        return _mlp_residual(x, lyr), pools
+        x, counts = _mlp_counted(x, lyr, live)
+        return x, pools, counts
 
     def linear(x, lyr, state):
         # every row is a prompt's start: from the zero state into its slot
@@ -1507,22 +1721,19 @@ def llama_prefill_paged(model, input_ids, prompt_lens, cache: PagedKVCache,
         # a whole prompt is a chunk at offset 0 over the rows it has just
         # written: one attention path for every prefill
         return _latent_residual(
-            x, lyr, pool, jnp.broadcast_to(jnp.arange(s), (b, s)),
-            jnp.arange(s)[None, :] < prompt_lens[:, None],
+            x, lyr, pool, jnp.broadcast_to(jnp.arange(s), (b, s)), live(),
             lambda pool, vals: _scatter_prefill(pool, vals, rtables,
-                                                prompt_lens, rows, bs),
+                                                prompt_lens,
+                                                cache.pool_rows, bs),
             _latent_chunk_attend(rtables, jnp.zeros((b,), jnp.int32),
                                  prompt_lens))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
-                                   latent)
+                                   latent, wtables)
     _note_routed(routed, counts)
-    logits = _model_logits(model, x)
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(prompt_lens - 1, 0)[:, None, None].astype(jnp.int32),
-        axis=1)[:, 0]
+    last = _last_logits(model, cache, x, prompt_lens)
     new_cache = replace(cache, block_tables=new_tables, lens=new_lens,
-                        **fields)
+                        window_tables=new_wtables, **fields)
     return last, new_cache
 
 
@@ -1533,15 +1744,14 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
     cfg = model.cfg
     b = tokens.shape[0]
     nb, bs = cache.num_blocks, cache.block_size
-    x = jnp.take(_backbone(model).embed_tokens, tokens[:, None], axis=0)  # [B,1,E]
-    d = cfg.hidden_size // cfg.num_attention_heads
-    cos, sin = _rope_rows(cache.lens, d, cfg.rope_theta,
+    x = _embed(model, tokens[:, None])                        # [B, 1, E]
+    cos, sin = _rope_rows(cache.lens, head_dim(cfg), cfg.rope_theta,
                           _rope_scaling(cfg),
                           getattr(cfg, "max_position_embeddings", None))
-    window = getattr(cfg, "sliding_window", None)
+    rope = lambda t: _apply_rope_rows(t, cos, sin)  # noqa: E731
+    windows = kv_windows(cfg)
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
     rtables = _cp_local_tables(cache.block_tables, cp_axis, nb)
-    rows = cache.pool_rows
 
     def layer(x, li, lyr, pools, rtables):
         h = _pre_norm(x, lyr, "input_layernorm")
@@ -1555,22 +1765,27 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
             q, k = _qk_norm(att, q, k)
-            q = _apply_rope_rows(q.reshape(b, 1, nh, hd), cos, sin)
-            k = _apply_rope_rows(k.reshape(b, 1, nkv, hd), cos, sin)
+            q = _rotated(att, rope, q.reshape(b, 1, nh, hd))
+            k = _rotated(att, rope, k.reshape(b, 1, nkv, hd))
             v = v.reshape(b, 1, nkv, hd)
             hp = pools[0].shape[2]
             q = _pool_heads(q, hp * (nh // nkv))
             pools = k_pool, v_pool, ks, vs = _scatter_kv(
                 pools, _pool_heads(k, hp), _pool_heads(v, hp),
-                _scatter_decode, rtables, cache.lens, active, rows, bs)
-            # sliding-window configs: the pool retains all tokens (blocks
-            # below the window could be recycled — not done yet) but decode
-            # attends only the last `window` positions, matching prefill
+                _scatter_decode, rtables, cache.lens, active,
+                pools[0].shape[0], bs)
+            # a window layer attends the row's last ``window`` positions
+            # alone, as its prefill did; the host frees the blocks below
+            # them (``LLMEngine._recycle_window``), so a stale table entry
+            # there may name a block another row holds by now: the kernel
+            # never reads one
+            window = windows[li]
             if cp_axis is None:
-                out = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                             rtables, new_lens,
-                                             window=window, k_scale=ks,
-                                             v_scale=vs)
+                with _kind_scope(cache, window):
+                    out = paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                                 rtables, new_lens,
+                                                 window=window, k_scale=ks,
+                                                 v_scale=vs)
             else:
                 # per-shard partials over the locally-owned blocks + ONE
                 # psum-style merge: O(heads*dim) cross-shard bytes per step,
@@ -1582,12 +1797,14 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
                     window=window, k_scale=ks, v_scale=vs, partials=True)
                 o_p, m_p, l_p = psum_merge_partials(o_p, m_p, l_p, cp_axis)
                 out = finalize_partials(o_p, l_p, q.dtype)
-            attn_out = out[..., :nh, :].reshape(b, 1, nh * hd)
+            attn_out = _gated(att, h,
+                              out[..., :nh, :].reshape(b, 1, nh * hd))
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
             x = _residual(x, proj, lyr, "input_layernorm_2")
-        return _mlp_residual(x, lyr), pools
+        x, counts = _mlp_counted(x, lyr, lambda: active[:, None])
+        return x, pools, counts
 
     def linear(x, lyr, state):
         # one token a slot, each from its slot's state, in place; a slot
@@ -1599,14 +1816,15 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
         return _latent_residual(
             x, lyr, pool, cache.lens[:, None], active[:, None],
             lambda pool, vals: _scatter_decode(pool, vals, rtables,
-                                               cache.lens, active, rows, bs),
+                                               cache.lens, active,
+                                               cache.pool_rows, bs),
             lambda att, h, rope, pool: att.absorbed(
                 h, *rope, lambda q: paged_latent_decode_attention(
                     q[:, 0], pool, rtables, new_lens, v_width=att.rank,
                     scale=att.scale)[:, None]))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
-                                   latent)
+                                   latent, cache.window_tables)
     _note_routed(routed, counts)
     logits = _model_logits(model, x)[:, 0]
     return logits, replace(cache, lens=new_lens, **fields)
@@ -1615,10 +1833,13 @@ def llama_decode_step_paged(model, tokens, cache: PagedKVCache, active,
 def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
                       upd_rows, upd_cols, upd_vals, rng, temps, top_ps,
                       top_k=None, want_logp=False, lora=None,
-                      logit_bias=None, cp_axis=None, routed=None):
+                      logit_bias=None, cp_axis=None, routed=None,
+                      upd_wvals=None):
     """ONE fused serving tick: apply incremental block-table updates
     (``tables[upd_rows[i], upd_cols[i]] = upd_vals[i]``, sentinel rows
-    dropped — no host-side table rebuild/re-upload), run the decode step,
+    dropped — no host-side table rebuild/re-upload; a cache with a window
+    space grows that table at the same rows and columns by ``upd_wvals``:
+    both are indexed by position), run the decode step,
     and sample the next token ON DEVICE. The only per-tick host traffic is
     the [B] sampled-token fetch the engine needs for streaming/EOS.
 
@@ -1637,6 +1858,9 @@ def llama_decode_tick(model, tokens, cache: PagedKVCache, active,
     tables = cache.block_tables.at[upd_rows, upd_cols].set(upd_vals,
                                                            mode="drop")
     cache = replace(cache, block_tables=tables)
+    if cache.window_layers:
+        cache = replace(cache, window_tables=cache.window_tables.at[
+            upd_rows, upd_cols].set(upd_wvals, mode="drop"))
     logits, cache = llama_decode_step_paged(model, tokens, cache, active,
                                             lora, cp_axis=cp_axis,
                                             routed=routed)
@@ -1769,24 +1993,27 @@ class Staging:
         return tuple(out)
 
 
-def tick_staging(num_slots: int) -> Staging:
+def tick_staging(num_slots: int, two_spaces: bool = False) -> Staging:
     """What :func:`tick_staged` is sent: ``llama_decode_tick``'s seven
-    ``[num_slots]`` arrays."""
+    ``[num_slots]`` arrays, and for a cache with ``two_spaces`` the window
+    table's new entries (``upd_wvals``) behind them."""
     i, f = ("int32", (num_slots,)), ("float32", (num_slots,))
     return Staging(tokens=i, active=("bool", (num_slots,)), upd_rows=i,
-                   upd_cols=i, upd_vals=i, temps=f, top_ps=f)
+                   upd_cols=i, upd_vals=i, temps=f, top_ps=f,
+                   **({"upd_wvals": i} if two_spaces else {}))
 
 
 @functools.cache
 def prefill_staging(rows: int, width: int, max_blocks: int,
-                    chunked: bool) -> Staging:
+                    chunked: bool, two_spaces: bool = False) -> Staging:
     """What a prefill program is sent for ``rows`` rows of ``width`` tokens:
-    :func:`prefill_staged`'s four arrays, and the rows' ``offsets`` for
-    :func:`prefill_chunk_staged` (``chunked``)."""
-    r = ("int32", (rows,))
+    :func:`prefill_staged`'s four arrays, the rows' ``offsets`` for
+    :func:`prefill_chunk_staged` (``chunked``), and for a cache with
+    ``two_spaces`` the rows' window-space tables behind them."""
+    r, t = ("int32", (rows,)), ("int32", (rows, max_blocks))
     return Staging(input_ids=("int32", (rows, width)), lens=r,
                    **({"offsets": r} if chunked else {}), slot_ids=r,
-                   table_rows=("int32", (rows, max_blocks)))
+                   table_rows=t, **({"window_rows": t} if two_spaces else {}))
 
 
 def prefill_staged(model, staged, cache: PagedKVCache, layout: Staging,
@@ -1794,10 +2021,10 @@ def prefill_staged(model, staged, cache: PagedKVCache, layout: Staging,
     """:func:`llama_prefill_paged` with its host arrays as one vector ->
     (last logits, cache, routed): ``routed`` the call's counts for a model
     with held experts (int32 [2]), None for any other."""
-    ids, lens, slots, rows = layout.unpack(staged)
+    ids, lens, slots, rows, *wrows = layout.unpack(staged)
     routed = []
     logits, cache = llama_prefill_paged(model, ids, lens, cache, slots, rows,
-                                        lora, cp_axis, routed)
+                                        lora, cp_axis, routed, *wrows)
     return logits, cache, (routed[0] if routed else None)
 
 
@@ -1808,11 +2035,12 @@ def tick_staged(model, staged, cache: PagedKVCache, rng, layout: Staging,
     model with held experts the tick's two counts ride behind the slots'
     tokens (``nxt`` is ``[num_slots + 2]``): they come back in the fetch
     the tick makes anyway."""
-    tokens, active, rows, cols, vals, temps, top_ps = layout.unpack(staged)
+    (tokens, active, rows, cols, vals, temps, top_ps,
+     *wvals) = layout.unpack(staged)
     routed = []
     nxt, logp, cache = llama_decode_tick(
         model, tokens, cache, active, rows, cols, vals, rng, temps, top_ps,
-        top_k, want_logp, lora, logit_bias, cp_axis, routed)
+        top_k, want_logp, lora, logit_bias, cp_axis, routed, *wvals)
     if routed:
         nxt = jnp.concatenate([nxt, routed[0].astype(nxt.dtype)])
     return nxt, logp, cache
@@ -2204,7 +2432,7 @@ def paged_generate(model, input_ids, prompt_lens, max_new_tokens=32,
 def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
                               cache: PagedKVCache, slot_ids, table_rows,
                               full_logits=False, lora=None, cp_axis=None,
-                              routed=None):
+                              routed=None, window_rows=None):
     """CONTINUE a prefill: write chunk tokens at positions
     ``offsets[a] .. offsets[a]+chunk_lens[a]-1`` of their slots and attend
     each chunk query over the slot's WHOLE pool prefix (gather-based) —
@@ -2241,15 +2469,21 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
     new_tables = cache.block_tables.at[slot_ids].set(tables, mode="drop")
     new_lens = cache.lens.at[slot_ids].set(offsets + chunk_lens,
                                            mode="drop")
-    window = getattr(cfg, "sliding_window", None)
+    wtables = new_wtables = cache.window_tables
+    if cache.window_layers:
+        wtables = jnp.asarray(window_rows, jnp.int32)
+        new_wtables = cache.window_tables.at[slot_ids].set(wtables,
+                                                           mode="drop")
+    windows = kv_windows(cfg)
     # cp (ring-attention chunked prefill): quantize-on-write scatters land
     # each chunk's K/V in the owning shard via the LOCAL table view; the
     # pool read below computes per-shard partials over owned blocks only
     # and merges them across cp (ring rotation / Ulysses all_to_all)
     rtables = _cp_local_tables(tables, cp_axis, cache.num_blocks)
 
-    x = jnp.take(_backbone(model).embed_tokens, input_ids, axis=0)
-    d = cfg.hidden_size // cfg.num_attention_heads
+    x = _embed(model, input_ids)
+    d = head_dim(cfg)
+    live = lambda: jnp.arange(c)[None, :] < chunk_lens[:, None]  # noqa: E731
     positions = offsets[:, None] + jnp.arange(c, dtype=jnp.int32)  # [A, C]
     base, pos_div = A.resolve_rope_scaling(
         cfg.rope_theta, d, _rope_scaling(cfg), allow_dynamic=False,
@@ -2266,8 +2500,6 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
         return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
                                axis=-1).astype(t.dtype)
 
-    rows = cache.pool_rows
-
     def layer(x, li, lyr, pools, rtables):
         h = _pre_norm(x, lyr, "input_layernorm")
         with jax.named_scope("attention"):
@@ -2280,34 +2512,39 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
             nh, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
             q, k = _qk_norm(att, q, k)
-            q = rope(q.reshape(a, c, nh, hd))
-            k = rope(k.reshape(a, c, nkv, hd))
+            q = _rotated(att, rope, q.reshape(a, c, nh, hd))
+            k = _rotated(att, rope, k.reshape(a, c, nkv, hd))
             v = v.reshape(a, c, nkv, hd)
             hp = pools[0].shape[2]
             q = _pool_heads(q, hp * (nh // nkv))
             # scatter the chunk FIRST so the gathered view holds prefix+chunk
             pools = k_pool, v_pool, ks, vs = _scatter_kv(
                 pools, _pool_heads(k, hp), _pool_heads(v, hp),
-                _scatter_decode_chunk, rtables, offsets, chunk_lens, rows,
-                bs)
+                _scatter_decode_chunk, rtables, offsets, chunk_lens,
+                pools[0].shape[0], bs)
             # ragged pool-direct attention: the kernel reads only each row's
             # live blocks (the XLA fallback reconstructs the old full
-            # gather + dense-mask view, bit-compatible)
+            # gather + dense-mask view, bit-compatible); of a window layer,
+            # those from the first query's window on
+            window = windows[li]
             if cp_axis is None:
-                out = paged_chunk_attention(q, k_pool, v_pool, rtables,
-                                            offsets, chunk_lens, window=window,
-                                            k_scale=ks, v_scale=vs)
+                with _kind_scope(cache, window):
+                    out = paged_chunk_attention(
+                        q, k_pool, v_pool, rtables, offsets, chunk_lens,
+                        window=window, k_scale=ks, v_scale=vs)
             else:
                 o_p, m_p, l_p = paged_chunk_attention(
                     q, k_pool, v_pool, rtables, offsets, chunk_lens,
                     window=window, k_scale=ks, v_scale=vs, partials=True)
                 out = _cp_merge_chunk(o_p, m_p, l_p, cp_axis, q.dtype)
-            attn_out = out[..., :nh, :].reshape(a, c, nh * hd)
+            attn_out = _gated(att, h,
+                              out[..., :nh, :].reshape(a, c, nh * hd))
             proj = _wo(attn_out, att.o_proj)
             if lora is not None:
                 proj = proj + _lora_delta(attn_out, lora, "o", li)
             x = _residual(x, proj, lyr, "input_layernorm_2")
-        return _mlp_residual(x, lyr), pools
+        x, counts = _mlp_counted(x, lyr, live)
+        return x, pools, counts
 
     def linear(x, lyr, state):
         # a chunk goes on from the state its slot's earlier chunks (or a
@@ -2317,24 +2554,20 @@ def llama_prefill_chunk_paged(model, input_ids, chunk_lens, offsets,
 
     def latent(x, ci, lyr, pool):
         return _latent_residual(
-            x, lyr, pool, positions,
-            jnp.arange(c)[None, :] < chunk_lens[:, None],
+            x, lyr, pool, positions, live(),
             lambda pool, vals: _scatter_decode_chunk(
-                pool, vals, rtables, offsets, chunk_lens, rows, bs),
+                pool, vals, rtables, offsets, chunk_lens, cache.pool_rows,
+                bs),
             _latent_chunk_attend(rtables, offsets, chunk_lens))
 
     x, fields, counts = _run_stack(model, cache, x, rtables, layer, linear,
-                                   latent)
+                                   latent, wtables)
     _note_routed(routed, counts)
-    logits = _model_logits(model, x)
     new_cache = replace(cache, block_tables=new_tables, lens=new_lens,
-                        **fields)
+                        window_tables=new_wtables, **fields)
     if full_logits:
-        return logits, new_cache
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(chunk_lens - 1, 0)[:, None, None].astype(
-            jnp.int32), axis=1)[:, 0]
-    return last, new_cache
+        return _model_logits(model, x), new_cache
+    return _last_logits(model, cache, x, chunk_lens), new_cache
 
 
 def _scatter_decode_chunk(pool, vals, tables, offsets, chunk_lens, nb, bs):
@@ -2359,11 +2592,12 @@ def prefill_chunk_staged(model, staged, cache: PagedKVCache,
                          layout: Staging, lora=None, cp_axis=None):
     """:func:`llama_prefill_chunk_paged` with its host arrays as one
     vector -> (last logits, cache, routed), as :func:`prefill_staged`."""
-    ids, lens, offs, slots, rows = layout.unpack(staged)
+    ids, lens, offs, slots, rows, *wrows = layout.unpack(staged)
     routed = []
     logits, cache = llama_prefill_chunk_paged(
         model, ids, lens, offs, cache, slots, rows, lora=lora,
-        cp_axis=cp_axis, routed=routed)
+        cp_axis=cp_axis, routed=routed,
+        window_rows=wrows[0] if wrows else None)
     return logits, cache, (routed[0] if routed else None)
 
 

@@ -49,8 +49,9 @@ from paddle_tpu.models.paged import (LATENT_LAYER, LINEAR_LAYER,
                                      _beam_finalize, _BEAM_SELECT_JIT,
                                      cache_passes,
                                      greedy_accept_length, is_moe_model,
-                                     layer_kinds, state_bytes,
-                                     stochastic_accept_row)
+                                     kv_windows, layer_kinds, state_bytes,
+                                     stochastic_accept_row,
+                                     window_space_layers)
 from paddle_tpu.observability import span as _span
 from paddle_tpu.observability.flight import FLIGHT
 from paddle_tpu.observability.goodput import GOODPUT
@@ -60,6 +61,7 @@ from paddle_tpu.observability.roofline import (ModelGeometry,
                                                resolve_serving_peaks)
 from paddle_tpu.serving.executor import ModelExecutor, _SAMPLE_ROWS_JIT  # noqa: F401  (re-exported)
 from paddle_tpu.serving.executor import LATENT_MODEL as _LATENT
+from paddle_tpu.serving.executor import MIXED_MODEL as _MIXED
 from paddle_tpu.serving.executor import STATEFUL_MODEL as _STATEFUL
 from paddle_tpu.serving.kv import KVManager, cache_block_bytes
 from paddle_tpu.serving.scheduler import Scheduler
@@ -85,7 +87,8 @@ from paddle_tpu.serving.telemetry import (_ACTIVE_SLOTS, _ASYNC_DEPTH,
                                           _TICK, _TICK_BREAKDOWN,
                                           _TICK_HIDDEN, _TIMEOUTS,
                                           _TOK_LAT, _TOKENS,
-                                          _TTFT, tenant_label)
+                                          _TTFT, _WINDOW_KV_IN_USE,
+                                          _WINDOW_RECYCLED, tenant_label)
 from paddle_tpu.serving.transfer import (KVPayload, _GATHER_BLOCKS_JIT,
                                          _INSTALL_BLOCKS_JIT)
 from paddle_tpu.serving.types import (EngineDrainingError, OverloadError,
@@ -106,6 +109,10 @@ _HYBRID_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
 _LATENT_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "serving/transfer.py ships a K block and a V block a "
                    "layer, a latent pool holds one array")
+
+_MIXED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
+                  "serving/transfer.py ships the blocks of one table, a "
+                  "sequence here has one in each of two block spaces")
 
 _LOOPED_HANDOFF = ("the KV handoff (extract_sequence / install_sequence): "
                    "its payload holds one row a block and layer, a looped "
@@ -128,7 +135,8 @@ class LLMEngine:
                  max_queue_len=None, clock=None, draft_model=None,
                  spec_k=4, spec_adaptive=True, prefill_only=False,
                  adapter_store=None, degrade=None, slo=None, kv_dtype=None,
-                 cp=1, async_depth=0, num_state_snapshots=0):
+                 cp=1, async_depth=0, num_state_snapshots=0,
+                 num_window_blocks=None):
         # the model itself goes to the executor, which flattens it once:
         # the engine serves the weights it was built with
         cfg = self.cfg = model.cfg
@@ -157,6 +165,41 @@ class LLMEngine:
         self.async_depth = async_depth
         self.num_slots = num_slots
         self.block_size = block_size
+        # the window of the model's window layers (None: it has none; a
+        # layer's window is its kind's, ``models.paged.kv_windows``): the
+        # paged kernels read a row's last ``window`` positions there, so
+        # the blocks below them are recycled, and a row holds O(window)
+        # blocks in those layers. ``mixed``: window layers beside full
+        # ones, whose blocks live in a space of their own (a second table
+        # a row, a second manager: ``kv.window``) of ``num_window_blocks``
+        # blocks; the full space is ``num_blocks`` and everything that was
+        # one space's stays its. A model whose every layer is windowed
+        # (Mistral v0.1's shape) has one space, recycled as it always was.
+        self.window = next((w for w in kv_windows(cfg) if w is not None),
+                           None)
+        self.mixed = bool(window_space_layers(cfg))
+        if self.mixed:
+            # what is not built over two spaces, each by what it would take
+            self._refuse(
+                _MIXED,
+                prefix_caching and "prefix caching (pass prefix_caching="
+                "False): a hit needs the window layers' rows of the last "
+                f"{self.window} positions kept under the trie's node, and "
+                "recycling frees them",
+                draft_model is not None and "a draft model: the verify "
+                "program is handed one space's tables, and a rejected "
+                "proposal may lie in a block the window space has recycled",
+                cp > 1 and "context parallelism (cp > 1): the window space's "
+                "blocks are not laid out shard by shard",
+                adapter_store is not None and "multi-LoRA (adapter_store): "
+                "its adapters are written for the full layers' attention "
+                "alone",
+                async_depth > 0 and "async_depth > 0: the pipelined tick "
+                "grows no tables, and the window space's recycle every tick",
+                kv_dtype is not None and "a quantized K/V pool (kv_dtype): "
+                "no scale pools are built for the window space",
+                preemption and "preemption=True: the replay of a preempted "
+                "row over two block spaces is not tested")
         # graceful degradation (ISSUE 16): an optional shared
         # DegradationController — consulted by the spec gate, the
         # chunked-prefill budget, admission shedding, and the session
@@ -196,11 +239,6 @@ class LLMEngine:
         self.top_k = top_k
         self.temps = np.zeros(num_slots, np.float32)
         self.top_ps = np.ones(num_slots, np.float32)
-        # sliding-window models: blocks entirely below cur - window are
-        # never attended again (the paged kernel KEEPS only positions
-        # >= lens - window, masking everything below) — recycle them,
-        # bounding live blocks per sequence by O(window), not O(length)
-        self.window = getattr(cfg, "sliding_window", None)
         self._dyn_rope = (getattr(cfg, "rope_scaling", None)
                           or {}).get("type") == "dynamic"
         # prefix caching is sound only when a block's KV is a function of
@@ -232,7 +270,7 @@ class LLMEngine:
         if draft_model is not None:
             if self.spec_k < 1:
                 raise ValueError("spec_k must be >= 1")
-            if self.window is not None or \
+            if (self.window is not None and not self.mixed) or \
                     getattr(draft_model.cfg, "sliding_window", None):
                 raise NotImplementedError(
                     "speculative decoding needs full (un-windowed) caches "
@@ -313,7 +351,17 @@ class LLMEngine:
                 "tick has not been run over a latent pool")
 
         # ---- the three extracted layers ----
-        self.kv = KVManager(num_blocks, block_size)
+        # two spaces: the window space holds, for every slot at once, what
+        # a window layer reads plus one chunk (a row's most: a chunk at
+        # offset o keeps the rows from o - window on until it has run)
+        self._window_row_blocks = ((self.window + max_prompt_len)
+                                   // block_size + 2) if self.mixed else 0
+        if self.mixed and num_window_blocks is None:
+            num_window_blocks = num_slots * self._window_row_blocks
+        self.kv = KVManager(num_blocks, block_size,
+                            int(num_window_blocks) if self.mixed else 0)
+        # the space whose blocks below the window are recycled
+        self._wspace = self.kv.window if self.mixed else self.kv.mgr
         if self.stateful:
             self.kv.keep_state(self.num_state_snapshots)
         self._block_bytes = None     # per-block HBM bytes, lazily computed
@@ -325,7 +373,8 @@ class LLMEngine:
             top_k=top_k, seed=seed, draft_model=draft_model,
             spec_k=self.spec_k, max_seq_len=self.max_seq_len,
             kv_dtype=kv_dtype, cp=self.cp,
-            num_state_snapshots=self.num_state_snapshots)
+            num_state_snapshots=self.num_state_snapshots,
+            window_blocks=num_window_blocks if self.mixed else None)
 
         # host mirrors (vectorised bookkeeping — no per-token python loops)
         self.slot_req = np.full(num_slots, -1, np.int64)   # req_id or -1
@@ -614,6 +663,11 @@ class LLMEngine:
                 self._looped,
                 self.ut_steps > 1 and "beam search (num_beams > 1): no "
                 "test has forked a looped model's blocks")
+            self._refuse(
+                _MIXED,
+                self.mixed and "beam search (num_beams > 1): a fork would "
+                "have to share blocks in two spaces, and a recycled window "
+                "block may be one a forked child still reads")
             if req.num_beams > self.num_slots:
                 raise ValueError(f"num_beams {req.num_beams} exceeds "
                                  f"num_slots={self.num_slots}")
@@ -639,7 +693,10 @@ class LLMEngine:
                              f"max_prompt_len={self.max_prompt_len} "
                              "(chunked prefill does not combine with "
                              "beam search)")
-        if len(req.prompt) > self.max_prompt_len and self.window is not None:
+        if len(req.prompt) > self.max_prompt_len \
+                and self.window is not None and not self.mixed:
+            # (two block spaces chunk: a chunk reads the window layers'
+            # rows of its own space, recycled after every chunk)
             raise NotImplementedError(
                 "chunked prefill + sliding-window recycling not combined")
         if len(req.prompt) > self.max_prompt_len and \
@@ -892,7 +949,7 @@ class LLMEngine:
                     + k * (self.mgr.blocks_needed(
                         req.max_new_tokens + self.block_size) + 2))
         total = p + self._remaining(req)
-        if self.window is None:
+        if self.window is None or self.mixed:   # the full space keeps all
             return self.mgr.blocks_needed(total)
         live = self.mgr.blocks_needed(
             min(total, self.window + 2 * self.block_size))
@@ -1009,17 +1066,32 @@ class LLMEngine:
     def _update_resv(self, rid: int):
         self.kv.update(rid)
 
+    def _window_worst_case(self, req) -> int:
+        """Window-space blocks a request can ever hold at once (two block
+        spaces): its whole length if that is less than what a window
+        layer reads plus one chunk."""
+        return min(self.mgr.blocks_needed(len(self._pr(req))
+                                          + self._remaining(req)),
+                   self._window_row_blocks)
+
     def _recycle_window(self, slots):
         """Free blocks entirely below cur - window for the given slots —
-        live blocks per sequence stay O(window). Host-only: the paged
-        kernel masks every position BELOW lens - window, so stale table
-        entries pointing at recycled (even reused) blocks are never
-        read."""
+        live blocks per sequence stay O(window) in the layers that have a
+        window. Host-only: the paged kernel masks every position BELOW
+        lens - window, so stale table entries pointing at recycled (even
+        reused) blocks are never read."""
         for slot in slots:
-            rid = int(self.slot_req[slot])
-            dead = int(max(0, self.cur[slot] - self.window)
-                       ) // self.block_size
-            if dead > 0 and self.mgr.free_prefix(rid, dead):
+            self._recycle_row(int(self.slot_req[slot]), int(self.cur[slot]))
+
+    def _recycle_row(self, rid: int, cur: int):
+        """``_recycle_window`` for one request with ``cur`` tokens in the
+        cache: in the window space where the model has two, else in the
+        one space (whose reservation gets the headroom back)."""
+        dead = max(0, cur - self.window) // self.block_size
+        freed = dead > 0 and self._wspace.free_prefix(rid, dead)
+        if freed:
+            _WINDOW_RECYCLED.inc(len(freed))
+            if not self.mixed:
                 self._update_resv(rid)
 
     def _req_sampling(self, req):
@@ -1055,6 +1127,8 @@ class LLMEngine:
             offs = np.zeros(R, np.int32)
             slots = np.full(R, self.num_slots, np.int32)  # sentinel = drop
             rows = np.full((R, max_b), nb, np.int32)
+            wrows = ((np.full((R, max_b), self.kv.window.num_blocks,
+                              np.int32),) if self.mixed else ())
             row_aidx = np.full(R, -1, np.int64)
             row_temps = np.zeros(R, np.float32)
             row_tps = np.ones(R, np.float32)
@@ -1066,6 +1140,13 @@ class LLMEngine:
                 offs[i] = off
                 slots[i] = slot
                 rows[i, :len(table)] = table
+                if self.mixed:
+                    # the window space's table: recycled positions keep
+                    # the sentinel (the kernels never read below a window)
+                    for j, blk in enumerate(self.kv.window.tables[
+                            int(self.slot_req[slot])]):
+                        if blk is not None:
+                            wrows[0][i, j] = blk
                 row_aidx[i] = aidx
                 if sampling is not None:
                     row_temps[i], row_tps[i] = sampling
@@ -1073,9 +1154,10 @@ class LLMEngine:
             lora = self._lora_arg(row_aidx, cap)
             if chunked:
                 out = self.exe.prefill_chunk(ids, lens, offs, slots, rows,
-                                             lora=lora)
+                                             lora=lora, wrows=wrows)
             else:
-                out = self.exe.prefill(ids, lens, slots, rows, lora=lora)
+                out = self.exe.prefill(ids, lens, slots, rows, lora=lora,
+                                       wrows=wrows)
             # roofline: one weight pass a call; a chunk attends its own
             # tokens plus everything already consumed (its offset)
             self._acc_phase("prefill", int(lens.sum()), 1,
@@ -1149,6 +1231,9 @@ class LLMEngine:
             # reservation too (the prompt-size floor only mattered DURING
             # prefill)
             self._recycle_window([slot for slot, _ in admits])
+        if self.window is not None and not self.mixed:
+            # (two spaces: the reservation is the full space's, which
+            # keeps every block)
             live_bound = self.mgr.blocks_needed(
                 self.window + 2 * self.block_size)
             for slot, req in admits:
@@ -1386,6 +1471,11 @@ class LLMEngine:
                 req._snapshot_plan = None
                 self.mgr.attach_snapshot(self._pr(req), plan[0], plan[1],
                                          adapter=req.adapter_id)
+            if self.window is not None:
+                # the chunk is queued: the window layers' rows below the
+                # next chunk's first window are dead (two block spaces;
+                # a one-space windowed model sends no chunk)
+                self._recycle_row(rid, consumed + len(chunk))
             if sampling is None:
                 self.prefilling[rid] = (slot, consumed + len(chunk))
                 continue
@@ -1890,13 +1980,18 @@ class LLMEngine:
     # ------------------------------------------------------------- decode
     def _grow_tables(self, mask=None):
         """At most one new block per slot per tick; returns the incremental
-        (rows, cols, vals) update triple (sentinel-padded, fixed shape).
+        (rows, cols, vals) update triple (sentinel-padded, fixed shape) and
+        ``wvals``: ``(the window table's new entries,)`` for a model with
+        two block spaces, () for any other.
         ``mask`` restricts growth to those slots (spec-handled slots skip
         the normal tick, so their updates must not ride a tick that may
         never run — their tables grow in the verify staging instead)."""
         rows = np.full(self.num_slots, self.num_slots, np.int32)
         cols = np.zeros(self.num_slots, np.int32)
         vals = np.zeros(self.num_slots, np.int32)
+        # two block spaces: the window table grows at the same rows and
+        # columns (both are indexed by position), by its own block numbers
+        wvals = (np.zeros(self.num_slots, np.int32),) if self.mixed else ()
         base = (self.active & ~self.is_beam) if mask is None else mask
         crossing = base & (
             self.cur // self.block_size >= self.table_len)
@@ -1923,10 +2018,12 @@ class LLMEngine:
             rows[slot] = slot
             cols[slot] = idx
             vals[slot] = t[idx]
+            if self.mixed:
+                wvals[0][slot] = self.kv.window.tables[rid][idx]
             self.table_len[slot] = idx + 1
         if self.window is not None:
             self._recycle_window(np.nonzero(self.active & ~self.is_beam)[0])
-        return rows, cols, vals
+        return rows, cols, vals, wvals
 
     def _emit(self, slot: int, token: int):
         """Record one sampled token for the request in ``slot``; finish on
@@ -2016,6 +2113,7 @@ class LLMEngine:
         self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
         self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
         self._refuse(_LATENT, self.latent and _LATENT_HANDOFF)
+        self._refuse(_MIXED, self.mixed and _MIXED_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2115,6 +2213,7 @@ class LLMEngine:
         self._refuse(self._looped, self.ut_steps > 1 and _LOOPED_HANDOFF)
         self._refuse(_STATEFUL, self.stateful and _HYBRID_HANDOFF)
         self._refuse(_LATENT, self.latent and _LATENT_HANDOFF)
+        self._refuse(_MIXED, self.mixed and _MIXED_HANDOFF)
         if self.cp > 1:
             raise NotImplementedError(
                 "KV handoff under context parallelism (cp>1) is not "
@@ -2229,6 +2328,19 @@ class LLMEngine:
         bs = self.block_size
         return int((-(-lens // bs) * bs).sum())
 
+    def _space_blocks(self, mask, ctx: int) -> dict:
+        """``kv_blocks_full`` and ``kv_blocks_window`` of a decode tick of
+        a model with two block spaces: the blocks a layer of each space
+        walks for the masked slots (``ctx``: :meth:`_ctx_blocks` of them),
+        a window layer from the block of ``len - window`` on. Nothing, and
+        no work, for any other model."""
+        if not self.mixed:
+            return {}
+        bs = self.block_size
+        below = np.maximum(self.cur[mask] + 1 - self.window, 0) // bs
+        return {"kv_blocks_full": ctx // bs,
+                "kv_blocks_window": ctx // bs - int(below.sum())}
+
     @staticmethod
     def _ctx_causal(lens, offs) -> int:
         """Σ attended (query, position) pairs of a causal chunk batch:
@@ -2288,7 +2400,15 @@ class LLMEngine:
                 return
         self._gauge_t = time.monotonic()
         self._gauge_sweeps += 1
-        with _span("serving.gauges", shadow=shadow):
+        with _span("serving.gauges", shadow=shadow) as sweep:
+            if self.mixed:
+                # each space's blocks: held by a table, and free
+                full, win = self.mgr, self.kv.window
+                held = win.num_blocks - win.free_blocks
+                _WINDOW_KV_IN_USE.set(held)
+                sweep.set(full_held=full.num_blocks - full.free_blocks,
+                          full_free=full.free_blocks, window_held=held,
+                          window_free=win.free_blocks)
             if self.async_depth:
                 _ASYNC_DEPTH.set(self.async_depth)
             _QUEUE_DEPTH.set(len(self.queue))
@@ -2733,7 +2853,8 @@ class LLMEngine:
                 # reconcile stay clean (exception-atomic).
                 fault_point("serving.cp_gather", engine=self,
                             slots=np.nonzero(run_mask)[0])
-            rows, cols, vals = self._grow_tables(run_mask & ~self.is_beam)
+            rows, cols, vals, wvals = self._grow_tables(
+                run_mask & ~self.is_beam)
             # growth may have preempted slots — recompute the mask after it
             run_mask = self.active & ~spec_handled
             # roofline: one weight pass over the batch; every running slot
@@ -2755,12 +2876,13 @@ class LLMEngine:
         with self._tick_timer("sample", "serving.decode", slots=n_run,
                               greedy=greedy,
                               kv_blocks=ctx // self.block_size,
+                              **self._space_blocks(run_mask, ctx),
                               **self.exe.span_args,
                               **self.exe.state_slots(n_run)) as stage:
             nxt, logp = self.exe.decode_tick(
                 self.last_tok, run_mask, rows, cols, vals, self.temps,
                 self.top_ps, bool(self.groups),
-                lora=self._lora_arg(d_aidx, 1), bias=d_bias)
+                lora=self._lora_arg(d_aidx, 1), bias=d_bias, wvals=wvals)
             was_active = run_mask.copy()
             # the program is queued: the copy of its tokens is asked for
             # now, and the host sweeps its gauges while the device works

@@ -31,8 +31,9 @@ from paddle_tpu.models.paged import (LATENT_LAYER, PagedKVCache,
                                      _PREFIX_COW_JIT, _REWIND_LENS_JIT,
                                      _STATE_RESTORE_JIT, _STATE_TAKE_JIT,
                                      _TICK_JIT, _VERIFY_CHUNK_JIT,
-                                     _async_tick_jit, _prefix_cow_update,
-                                     init_states, is_moe_model, layer_kinds,
+                                     _async_tick_jit, _backbone,
+                                     _prefix_cow_update, counts_routed,
+                                     init_states, kv_windows, layer_kinds,
                                      llama_verify_chunk_paged,
                                      prefill_chunk_staged, prefill_staged,
                                      prefill_staging, spec_rewind_lens,
@@ -94,14 +95,21 @@ def _token_rows(ids, lens) -> dict:
 STATEFUL_MODEL = "a model with recurrent (linear-attention) layers"
 # and one whose layers keep latent rows (multi-head latent attention)
 LATENT_MODEL = "a model with latent-attention (MLA) layers"
+# and one with window layers beside full ones (two block spaces)
+MIXED_MODEL = "a model with window (sliding_attention) layers beside full ones"
 
 
-def _chunk_kv_blocks(lens, offs, block_size) -> int:
+def _chunk_kv_blocks(lens, offs, block_size, window=None) -> int:
     """Pool blocks a cache layer the chunk kernel walks in one call: every
-    live row's blocks up to the end of its chunk, from the host's lengths
+    live row's blocks up to the end of its chunk (in a layer of ``window``,
+    from the block of the first query's window on), from the host's lengths
     (``serving.decode`` carries the same count for the decode kernel)."""
     lens, offs = np.asarray(lens), np.asarray(offs)
-    return int(np.sum(-(-(offs + lens)[lens > 0] // block_size)))
+    live = lens > 0
+    blocks = -(-(offs + lens)[live] // block_size)
+    if window is not None:
+        blocks = blocks - np.maximum(offs[live] - window + 1, 0) // block_size
+    return int(np.sum(blocks))
 
 
 class ModelExecutor:
@@ -120,7 +128,7 @@ class ModelExecutor:
     def __init__(self, model, *, num_slots, num_blocks, block_size,
                  max_blocks_per_seq, top_k=None, seed=0, draft_model=None,
                  spec_k=4, max_seq_len=None, kv_dtype=None, cp=1,
-                 num_state_snapshots=0):
+                 num_state_snapshots=0, window_blocks=None):
         cfg = model.cfg
         # the one copy of the model the executor keeps, and what every
         # program is handed: the weights as they were when it was built
@@ -131,7 +139,6 @@ class ModelExecutor:
         # the program just sent
         self.seq = self.model_seq = 0
         self.top_k = top_k
-        self._tick_staging = tick_staging(num_slots)
         self.rng = jax.random.PRNGKey(seed)     # the setter: no pair held
         self.cp = int(cp)
         self.mesh = None
@@ -140,7 +147,14 @@ class ModelExecutor:
         # dequantizes on read (ISSUE 17). None = pools in the model dtype.
         self.cache = PagedKVCache.init_for(
             cfg, num_blocks, block_size, num_slots, max_blocks_per_seq,
-            kv_dtype=kv_dtype)
+            kv_dtype=kv_dtype, window_blocks=window_blocks)
+        # a cache with two block spaces (window layers beside full ones):
+        # its programs are staged the window space's tables too, and the
+        # chunk spans count each space's blocks with ``window``
+        self.two_spaces = bool(self.cache.window_layers)
+        self.window = next((w for w in kv_windows(cfg) if w is not None),
+                           None) if self.two_spaces else None
+        self._tick_staging = tick_staging(num_slots, self.two_spaces)
         # on every program span, so a reader need not know the family:
         # passes over the stack a token, and the K/V layers it keeps
         self.span_args = {"ut_steps": self.cache.passes,
@@ -155,7 +169,7 @@ class ModelExecutor:
         # host fetches anyway; the prefill calls' wait here, (program, seq,
         # device counts), for the next wait (``take_routed``)
         self.latent = LATENT_LAYER in (layer_kinds(cfg) or ())
-        self.routes = self.latent and is_moe_model(model)
+        self.routes = any(map(counts_routed, _backbone(model).layers))
         self._routed = []
         self.snaps = ()
         if self.state_layers:
@@ -304,18 +318,20 @@ class ModelExecutor:
         return lora
 
     # ------------------------------------------------------------ prefill
-    def prefill(self, ids, lens, slots, rows, lora=None):
+    def prefill(self, ids, lens, slots, rows, lora=None, wrows=()):
         """Slot-aware padded prefill: admitted prompts scattered into
         their cache slots while other slots keep decoding state.
         ``lora`` (optional pytree, see ``models.paged._lora_delta``)
         applies the batched multi-LoRA correction per row. The cache is
         donated, as in the chunk program: a tick's several calls in
-        flight hold one pool, not one more for each."""
+        flight hold one pool, not one more for each. ``wrows``, here and in
+        the chunk call: ``(the rows' window-space tables,)`` for a cache
+        with two block spaces."""
         with _span("exe.prefill", **_token_rows(ids, lens),
                    **self._ctx_tokens(lens, 0), **self.span_args):
             layout = prefill_staging(*np.shape(ids), np.shape(rows)[1],
-                                     False)
-            staged = layout.pack(ids, lens, slots, rows)
+                                     False, self.two_spaces)
+            staged = layout.pack(ids, lens, slots, rows, *wrows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache, _ = self._dispatch(
@@ -329,16 +345,18 @@ class ModelExecutor:
                 self._routed.append(("prefill", self.seq, routed))
             return logits
 
-    def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None):
+    def prefill_chunk(self, ids, lens, offs, slots, rows, lora=None,
+                      wrows=()):
         """One chunk per row, written from an arbitrary offset over the
         slot's pool prefix (chunked prefill / prefix-cache resume)."""
         with _span("exe.prefill_chunk", **_token_rows(ids, lens),
                    kv_blocks=_chunk_kv_blocks(lens, offs,
                                               self.cache.block_size),
+                   **self._space_blocks(lens, offs),
                    **self._ctx_tokens(lens, offs), **self.span_args):
             layout = prefill_staging(*np.shape(ids), np.shape(rows)[1],
-                                     True)
-            staged = layout.pack(ids, lens, offs, slots, rows)
+                                     True, self.two_spaces)
+            staged = layout.pack(ids, lens, offs, slots, rows, *wrows)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 logits, self.cache, _ = self._dispatch(
@@ -366,13 +384,24 @@ class ModelExecutor:
                     sp.set(routed_pairs=pairs, experts_hit=hit)
         self._routed.clear()
 
+    def _space_blocks(self, lens, offs) -> dict:
+        """``kv_blocks_full`` and ``kv_blocks_window`` of a chunk call on a
+        cache with two block spaces: the blocks a layer of each space walks
+        for the call's live rows. Nothing for any other cache."""
+        if not self.two_spaces:
+            return {}
+        bs = self.cache.block_size
+        return {"kv_blocks_full": _chunk_kv_blocks(lens, offs, bs),
+                "kv_blocks_window": _chunk_kv_blocks(lens, offs, bs,
+                                                     self.window)}
+
     def _ctx_tokens(self, lens, offs) -> dict:
         """``ctx_tokens`` of a prefill call for a model with recurrent or
-        latent layers: the sum over its live rows of ``offset + len``, from
-        the host's lengths (with ``useful``, each token's context for a
-        count of the call's FLOPs). Nothing, and no work, for any other
-        model."""
-        if not (self.state_layers or self.latent):
+        latent layers or two block spaces: the sum over its live rows of
+        ``offset + len``, from the host's lengths (with ``useful``, each
+        token's context for a count of the call's FLOPs). Nothing, and no
+        work, for any other model."""
+        if not (self.state_layers or self.latent or self.two_spaces):
             return {}
         lens = np.asarray(lens)
         return {"ctx_tokens": int(np.sum((np.asarray(offs) + lens)[lens > 0]))}
@@ -408,6 +437,10 @@ class ModelExecutor:
             raise NotImplementedError(
                 f"{LATENT_MODEL} is not served with verify_chunk: no test "
                 "has rewound a latent pool past a rejected proposal")
+        if self.two_spaces:
+            raise NotImplementedError(
+                f"{MIXED_MODEL} is not served with verify_chunk: the "
+                "verify program is handed one space's tables")
         ids, clens, offs = (jnp.asarray(ids), jnp.asarray(clens),
                             jnp.asarray(offs))
         slot_ids, rows = jnp.asarray(slot_ids), jnp.asarray(rows)
@@ -430,12 +463,14 @@ class ModelExecutor:
 
     # ------------------------------------------------------------- decode
     def decode_tick(self, last_tok, run_mask, rows, cols, vals, temps,
-                    top_ps, need_logp, lora=None, bias=None):
+                    top_ps, need_logp, lora=None, bias=None, wvals=()):
         """The fused one-token tick: incremental table update + paged
         attention + on-device sampling. Returns (sampled [num_slots],
         logp [num_slots, vocab] or None per ``need_logp``). ``lora`` is
         the per-slot multi-LoRA pytree; ``bias`` a [num_slots, V]
-        grammar-mask logit bias applied before sampling."""
+        grammar-mask logit bias applied before sampling; ``wvals`` ``(the
+        window table's new entries,)`` for a cache with two block
+        spaces."""
         n_run = int(np.sum(run_mask))
         with _span("exe.decode_tick", slots=n_run, **self.span_args,
                    **self.state_slots(n_run)):
@@ -444,7 +479,7 @@ class ModelExecutor:
             # argument each is a transfer each, and a ``jnp.asarray`` each
             # a dispatch each, with the device idle meanwhile
             staged = self._tick_staging.pack(last_tok, run_mask, rows, cols,
-                                             vals, temps, top_ps)
+                                             vals, temps, top_ps, *wvals)
             if self.cp > 1:
                 self._no_cp_lora(lora)
                 if need_logp:

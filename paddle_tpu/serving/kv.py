@@ -11,7 +11,8 @@ decides WHO gets blocks; this layer tracks what was promised.
 """
 from __future__ import annotations
 
-from paddle_tpu.models.paged import RadixPrefixBlockManager
+from paddle_tpu.models.paged import (RadixPrefixBlockManager,
+                                     TwoSpaceBlockManager)
 from paddle_tpu.observability.flight import FLIGHT
 from paddle_tpu.serving.telemetry import (_PREFIX_EVICTIONS,
                                           _PREFIX_HIT_RATE, _PREFIX_HITS,
@@ -39,14 +40,26 @@ def cache_block_bytes(cache) -> int:
 class KVManager:
     """Block allocation + worst-case reservation accounting."""
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int,
+                 window_blocks: int = 0):
         # refcounted + prefix-cached: beam groups share prompt blocks
         # copy-on-write; requests with equal prompt prefixes share the
         # prefix blocks outright (prefill only runs on the uncached
         # suffix); with no sharing it behaves exactly like BlockManager.
         # The radix trie matches token spans and reuses partial blocks
         # copy-on-write.
-        self.mgr = RadixPrefixBlockManager(num_blocks, block_size)
+        # ``window_blocks`` > 0: a model with window layers beside full
+        # ones. ``mgr`` is then the FULL space's manager, everything below
+        # (the ledger, the reservations) is that space's as ever, and the
+        # window space's manager is ``window`` (None for any other model),
+        # with a ledger of promises of its own (``promise_window``)
+        self.mgr = (TwoSpaceBlockManager(num_blocks, block_size,
+                                         window_blocks)
+                    if window_blocks
+                    else RadixPrefixBlockManager(num_blocks, block_size))
+        self.window = getattr(self.mgr, "window", None)
+        self.window_promised = 0            # window blocks promised in all
+        self.window_need: dict[int, int] = {}   # req_id -> its promise
         self.stateful = False        # until ``keep_state``
         # the block manager owns the per-pool memory ledger (its own
         # mutation choke points notify it); this layer mirrors the
@@ -132,6 +145,20 @@ class KVManager:
         self.reserved -= self.resv.pop(rid, 0)
         self.need.pop(rid, None)
         self.ledger.set_reserved(self.reserved)
+        if self.window_need:
+            self.window_promised -= self.window_need.pop(rid, 0)
+
+    # ---- the window space's own ledger (a model with two block spaces):
+    # a request is promised the most window blocks it can ever hold at
+    # once (the engine's bound: what a window layer reads plus one chunk),
+    # for as long as it lives; the promises never pass the space, so an
+    # allocation there cannot fail and nothing is preempted for it
+    def window_fits(self, need: int) -> bool:
+        return self.window_promised + need <= self.window.num_blocks
+
+    def promise_window(self, rid: int, need: int):
+        self.window_need[rid] = need
+        self.window_promised += need
 
     def headroom(self, rid: int = None) -> int:
         """Free blocks net of OTHER requests' standing reservations."""
@@ -168,6 +195,13 @@ class KVManager:
             assert not self.resv and not self.need, (
                 f"ledger leak: resv={self.resv} need={self.need}")
             assert not self.mgr.tables, f"table leak: {list(self.mgr.tables)}"
+            if self.window is not None:
+                w = self.window
+                assert w.free_blocks == w.num_blocks and not w.tables, (
+                    f"window-space leak: {w.num_blocks - w.free_blocks} of "
+                    f"{w.num_blocks} blocks, tables {list(w.tables)}")
+                assert not self.window_promised and not self.window_need, (
+                    f"window-space promise leak: {self.window_need}")
             if self.stateful:
                 held = self.mgr.snapshot_audit()["reserved"]
                 assert not held, f"state-snapshot reservation leak: {held}"

@@ -325,8 +325,12 @@ class Scheduler:
                     min(len(p), ct + eng.max_prompt_len)) - n_shared + 1)
             else:
                 need = eng._worst_case_blocks(req)
+            # a model with two block spaces: the window space is promised
+            # a request's most for its whole life, never past the space
+            wneed = eng._window_worst_case(req) if eng.mixed else 0
             if (k > len(free_slots)
-                    or need > kv.free_blocks - kv.reserved):
+                    or need > kv.free_blocks - kv.reserved
+                    or (wneed and not kv.window_fits(wneed))):
                 # stall forensics: which ledger state holds the blocks
                 # (or slots) the queue head is waiting on
                 kv.record_stall(need, slots_short=(k > len(free_slots)))
@@ -383,6 +387,8 @@ class Scheduler:
             if eng.preemption and k == 1:
                 need = 0                   # no standing reservation
             kv.begin(req.req_id, need)
+            if wneed:
+                kv.promise_window(req.req_id, wneed)
             if k == 1:
                 slot = int(free_slots.pop(0))
                 if cached:

@@ -51,6 +51,14 @@ _KV_IN_USE = METRICS.gauge(
     "serving_kv_blocks_in_use", "paged KV blocks currently allocated")
 _KV_UTIL = METRICS.gauge(
     "serving_kv_block_utilization", "allocated fraction of the KV pool")
+_WINDOW_KV_IN_USE = METRICS.gauge(
+    "serving_window_kv_blocks_in_use",
+    "blocks allocated in the window space of a model with two block spaces "
+    "(window layers beside full ones)")
+_WINDOW_RECYCLED = METRICS.counter(
+    "serving_window_blocks_recycled_total",
+    "blocks freed below a row's attention window (the window space's, or "
+    "the one space's of a model whose every layer is windowed)")
 _TTFT = METRICS.histogram(
     "serving_ttft_seconds", "submission → first token (engine clock)")
 _TOK_LAT = METRICS.histogram(

@@ -238,6 +238,10 @@ _MEMLEDGER_ALLOWLIST = {
         "delegates to the block manager, whose free notifies",
     "paddle_tpu/models/paged.py::RefBlockManager.allocate":
         "delegates to BlockManager.allocate, which notifies",
+    "paddle_tpu/models/paged.py::TwoSpaceBlockManager.allocate":
+        "delegates to each space's own allocate, which notifies its ledger",
+    "paddle_tpu/models/paged.py::TwoSpaceBlockManager.free":
+        "delegates to each space's own free, which notifies its ledger",
 }
 
 

@@ -858,3 +858,88 @@ def test_model_parts_carry_their_scopes(model):
         model, jnp.zeros((1, 8), i32)).lower().as_text(debug_info=True)
     for scope in ("attention", "mlp", "norm", "lm_head"):
         assert f"/{scope}/" in train, scope
+
+
+# ------------------------------------- two block spaces (window beside full)
+@pytest.fixture(scope="module")
+def window_model():
+    import paddle_tpu as pt
+    from paddle_tpu.models.trinity import TrinityConfig, TrinityForCausalLM
+    pt.seed(0)
+    return TrinityForCausalLM(TrinityConfig.tiny()).eval()
+
+
+def test_two_space_spans_count_each_spaces_blocks_and_the_routing(
+        window_model):
+    """``serving.decode`` and ``exe.prefill_chunk`` of a model with window
+    layers beside full ones carry ``kv_blocks_full`` (as ``kv_blocks``),
+    ``kv_blocks_window`` (the blocks from the window's first on) and what
+    the expert layers routed; ``serving.gauges`` each space's held and
+    free blocks. Window 32, block 8, chunks of 24."""
+    eng = LLMEngine(window_model, num_slots=2, block_size=8,
+                    max_prompt_len=24, max_seq_len=128,
+                    prefix_caching=False)
+    rs = np.random.RandomState(3)
+    eng.add_request(Request(rs.randint(1, 256, (70,)), max_new_tokens=6))
+    eng.add_request(Request(rs.randint(1, 256, (10,)), max_new_tokens=6))
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    evs = _spans()
+    chunks = [e["args"] for e in evs if e["name"] == "exe.prefill_chunk"]
+    # 70 tokens in chunks of 24 at offsets 0, 24, 48: ceil(end / 8) blocks
+    # in the full layer, less those below offset - 32 + 1 in a window layer
+    assert [(a["kv_blocks"], a["kv_blocks_full"], a["kv_blocks_window"],
+             a["ctx_tokens"]) for a in chunks] == [
+        (3, 3, 3, 24), (6, 6, 6, 48), (9, 9, 9 - 17 // 8, 70)]
+    decodes = [e["args"] for e in evs if e["name"] == "serving.decode"]
+    assert decodes and all(
+        a["kv_blocks_full"] == a["kv_blocks"] >= a["kv_blocks_window"]
+        for a in decodes)
+    # the long row alone: len 75 -> 10 blocks, 5 below 75 - 32 = 43
+    last = decodes[-1]
+    assert (last["slots"], last["kv_blocks_full"],
+            last["kv_blocks_window"]) == (1, 10, 5)
+    # 4 pairs a token in each of the 4 expert layers; at most 16 experts
+    assert all(a["routed_pairs"] == 16 * a["slots"] for a in decodes)
+    assert all(0 < a["experts_hit"] <= 64 for a in decodes)
+    routed = [e["args"] for e in evs if e["name"] == "exe.routed"]
+    assert sorted(a["routed_pairs"] for a in routed) == sorted(
+        16 * n for n in (10, 24, 24, 22))
+    sweeps = [e["args"] for e in evs if e["name"] == "serving.gauges"]
+    assert all({"full_held", "full_free", "window_held",
+                "window_free"} <= set(a) for a in sweeps)
+    assert max(a["full_held"] for a in sweeps) >= 10
+    assert max(a["window_held"] for a in sweeps) <= (32 + 24) // 8 + 2 + 2
+    assert sweeps[-1]["full_held"] == sweeps[-1]["window_held"] == 0
+
+
+def test_a_model_of_one_kind_carries_no_space_counts(model):
+    eng = _engine(model)
+    _traffic(eng)
+    TRACER.enable()
+    _run(eng)
+    TRACER.disable()
+    for e in _spans():
+        assert not {"kv_blocks_window", "kv_blocks_full", "window_held"} \
+            & set(e.get("args", ())), e["name"]
+
+
+def test_the_two_kinds_of_layer_are_two_scopes_in_the_lowering(window_model):
+    """``attention.window`` and ``attention.full`` around the paged
+    kernels' calls of a model with both kinds, in the tick and in the chunk
+    program; a model of one kind has neither."""
+    from paddle_tpu.models import paged
+    cache = paged.PagedKVCache.init_for(window_model.cfg, 16, 8, 2, 6,
+                                        window_blocks=12)
+    i32, z2 = jnp.int32, jnp.zeros(2, jnp.int32)
+    tick = jax.jit(paged.llama_decode_step_paged).trace(
+        window_model, z2, cache, jnp.ones(2, bool)
+    ).lower().as_text(debug_info=True)
+    chunk = jax.jit(paged.llama_prefill_chunk_paged).trace(
+        window_model, jnp.zeros((2, 8), i32), z2 + 8, z2, cache, z2,
+        jnp.zeros((2, 6), i32), window_rows=jnp.zeros((2, 6), i32)
+    ).lower().as_text(debug_info=True)
+    for text in (tick, chunk):
+        assert "/attention/attention.window/" in text
+        assert "/attention/attention.full/" in text

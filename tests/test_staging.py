@@ -284,13 +284,15 @@ def test_the_executors_entries_keep_their_signatures(family):
     """What the benchmark's drivers and the engine call: names and order."""
     def params(fn):
         return list(inspect.signature(fn).parameters)[1:]
+    # (PR 44 appended one keyword to each, the window space's tables of a
+    # model with two block spaces: () for every other)
     assert params(ModelExecutor.prefill) == ["ids", "lens", "slots", "rows",
-                                             "lora"]
+                                             "lora", "wrows"]
     assert params(ModelExecutor.prefill_chunk) == [
-        "ids", "lens", "offs", "slots", "rows", "lora"]
+        "ids", "lens", "offs", "slots", "rows", "lora", "wrows"]
     assert params(ModelExecutor.decode_tick) == [
         "last_tok", "run_mask", "rows", "cols", "vals", "temps", "top_ps",
-        "need_logp", "lora", "bias"]
+        "need_logp", "lora", "bias", "wvals"]
     # positionally, as ``chipbench/drivers/serve.py``'s counter passes them
     eng = _engine(family)
     seen = []

@@ -110,6 +110,12 @@ DECODE = [
     ("mqa_f32", 4, 8, 1, 128, 64, 16, 8, f32, {}),
     ("gqa16_2_d256", 8, 16, 2, 256, 512, 16, 32, bf16, {}),
     ("h40", 8, 40, 40, 128, 512, 16, 32, bf16, {}),
+    # trinitymini.serve.mixedlen: 32 slots, 32 query heads to 4 K/V heads,
+    # a table of 1,056 blocks; a window layer over the window space's pool
+    # and the full layer over the full space's
+    ("trinity_window_pool8256", 32, 32, 4, 128, 8256, 16, 1056, bf16,
+     {"window": 2048}),
+    ("trinity_full_pool33792", 32, 32, 4, 128, 33792, 16, 1056, bf16, {}),
 ]
 
 # (id, H, H_kv, head dim, pool dtype): slabs off the pool's tiling, which
@@ -209,6 +215,12 @@ CHUNK = [
     # variants of the Mistral cell's shape that no cell runs
     ("cell_window", 16, 256, 32, 8, 3072, 256, False, {"window": 1024}),
     ("cell_partials", 16, 256, 32, 8, 3072, 256, False, {"partials": True}),
+    # trinitymini.serve.mixedlen's chunk forward: one row of 2,048 tokens,
+    # 32 query heads to 4 K/V heads, in each of its two block spaces
+    ("trinity_window_a1_c2048_gqa32_4_pool8256", 1, 2048, 32, 4, 8256, 1056,
+     False, {"window": 2048}),
+    ("trinity_full_a1_c2048_gqa32_4_pool33792", 1, 2048, 32, 4, 33792, 1056,
+     False),
 ]
 
 
@@ -596,6 +608,13 @@ GROUPED = [
     ("kimi_down_tick", 256, 12, 2048, 7168, False),
     ("kimi_gate_up_chunk", 2048, 12, 7168, 4096, False),
     ("kimi_down_chunk_all_pairs", 16384, 12, 2048, 7168, False),
+    # trinitymini.serve.mixedlen: all 128 experts of 2048 x 1024 held; a
+    # 32-slot tick's 256 pairs (two rows an expert) and a 2,048-token
+    # chunk's 16,384
+    ("trinity_gate_up_tick_128_groups", 256, 128, 2048, 2048, False),
+    ("trinity_down_tick_128_groups", 256, 128, 1024, 2048, False),
+    ("trinity_gate_up_chunk_128_groups", 16384, 128, 2048, 2048, False),
+    ("trinity_down_chunk_128_groups", 16384, 128, 1024, 2048, False),
 ]
 
 
@@ -710,6 +729,46 @@ def test_kimi_tick_and_chunk_programs_compile_with_their_kernels(
     # its own, and with two conditionals beside the latent chunk kernel's
     # 48 MiB the chip never returned (PERF.md section 6, PR 41)
     assert '"scoped_memory_configs":[{' not in text
+
+
+def test_two_space_tick_and_chunk_programs_compile_with_their_kernels(
+        one_chip, monkeypatch):
+    """The staged tick and chunk programs of a model with window layers
+    beside full ones and every expert held (Trinity's head sizes at a
+    narrow hidden size): the paged kernels over both block spaces, the
+    window table's staging behind the tick's seven arrays, the grouped
+    products, the counts a K/V layer's expert block hands out."""
+    from paddle_tpu.models import paged
+    from paddle_tpu.models.trinity import TrinityConfig, TrinityForCausalLM
+    cfg = TrinityConfig(
+        vocab_size=8192, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention", "sliding_attention",
+                     "sliding_attention"), num_experts=16, dtype=bf16)
+    model = jax.eval_shape(lambda: TrinityForCausalLM(cfg))
+    cache = jax.eval_shape(lambda: paged.PagedKVCache.init_for(
+        cfg, 512, 16, 8, 160, window_blocks=264))
+    S = jax.ShapeDtypeStruct
+    layout = paged.tick_staging(8, True)
+    assert layout.size == 8 * 8
+    args = _placed((model, S((layout.size,), i32), cache,
+                    S((2,), jnp.uint32)), one_chip)
+    text = _compiled_for_the_chip(monkeypatch, paged._TICK_JIT, *args,
+                                  layout, None, False)
+    assert text.count("paged_decode_attention") >= 5
+    assert "grouped_matmul" in text and "s32[10]" in text
+    assert "attention.window" in text and "attention.full" in text
+    layout = paged.prefill_staging(1, 2048, 160, True, True)
+    args = _placed((model, S((layout.size,), i32), cache), one_chip)
+    chunk = _program_for_the_chip(monkeypatch, paged._PREFILL_CHUNK_JIT,
+                                  *args, layout)
+    text = chunk.as_text()
+    assert "paged_chunk_attention" in text and "grouped_matmul" in text
+    assert "attention.window" in text and "attention.full" in text
+    # the head runs over the row's last position alone: no [2048, vocab]
+    assert "2048,8192]" not in text.replace(" ", "")
+    assert chunk.memory_analysis().temp_size_in_bytes < 96 << 20
 
 
 # rows x hidden: chip_smoke.py's own (decode tick, chunk rows, reference
