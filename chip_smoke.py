@@ -7,6 +7,7 @@ cut in depth only, with random weights made from ``--seed``.
     python chip_smoke.py              # one TPU chip; anything else fails
     python chip_smoke.py --chips 4    # ONLY the cross-chip legs (run by hand)
     timeout 240 python chip_smoke.py --latent   # ONLY the latent leg (by hand)
+    python chip_smoke.py --grouped    # ONLY the grouped product's 8 shapes
     python chip_smoke.py --tiny       # CPU rehearsal of the control flow
 
 One process, no children, no network, no git. Every phase fails the run on
@@ -605,6 +606,62 @@ def latent_chunk_kernel_alone(tiny, att):
                 ms_a_call=round((time.perf_counter() - t0) / 5 * 1e3, 3))
 
 
+def grouped_kernel_alone(tiny, seed):
+    """The grouped product by itself at the shapes the two MoE cells send it
+    (``trinitymini.serve.mixedlen``: 128 experts of 2048 x 1024, a 32-slot
+    tick's 256 pairs and a 2,048-token chunk's 16,384;
+    ``kimik2.serve.longshared``: 12 held experts of 7168 x 2048, a tick's 8
+    held pairs of 256 and a chunk pass's ~512 of 2,048): the tile plan each
+    shape takes, the kernel against its XLA twin on the live rows, ms a
+    call of the whole product (tile map, scatter, kernel, gather) and the
+    share of its floor, the hit experts' weights once at the HBM rate."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+
+    shapes = [("tiny", 64, 8, 128, 384, None)] if tiny else [
+        ("trinity_tick_gate_up", 256, 128, 2048, 2048, None),
+        ("trinity_tick_down", 256, 128, 1024, 2048, None),
+        ("trinity_chunk_gate_up", 16384, 128, 2048, 2048, None),
+        ("trinity_chunk_down", 16384, 128, 1024, 2048, None),
+        ("kimi_tick_gate_up", 256, 12, 7168, 4096, 8),
+        ("kimi_tick_down", 256, 12, 2048, 7168, 8),
+        ("kimi_chunk_gate_up", 2048, 12, 7168, 4096, 512),
+        ("kimi_chunk_down", 2048, 12, 2048, 7168, 512)]
+    interpret = jax.default_backend() != "tpu"
+    for i, (name, m, e, k, n, live) in enumerate(shapes):
+        live = m if live is None else live
+        sizes = np.random.RandomState(seed + i).multinomial(
+            live, np.full(e, 1.0 / e)).astype(np.int32)
+        key = jax.random.PRNGKey(seed + i)
+        lhs = jax.random.normal(key, (m, k), jnp.bfloat16)
+        rhs = (jax.random.normal(jax.random.fold_in(key, 1), (e, k, n),
+                                 jnp.float32) * 0.02).astype(jnp.bfloat16)
+        gs = jnp.asarray(sizes)
+        fn = jax.jit(lambda a, b, g: G.grouped_matmul(
+            a, b, g, impl="pallas", interpret=interpret))
+        got = fn(lhs, rhs, gs)[:live].astype(jnp.float32)
+        twin = G.grouped_matmul(lhs, rhs, gs, impl="xla")[:live].astype(
+            jnp.float32)
+        gap = float(jnp.abs(got - twin).max()) if live else 0.0
+        check(gap <= 0.02 * max(1.0, float(jnp.abs(twin).max())),
+              "the grouped kernel and its XLA twin differ by", gap, name)
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(lhs, rhs, gs)
+        out.block_until_ready()
+        ms = (time.perf_counter() - t0) / 10 * 1e3
+        floor_ms = int((sizes > 0).sum()) * k * n * 2 / 819e9 * 1e3
+        say(grouped_kernel=name, plan=list(G.tile_plan(m, e, k, n,
+                                                       jnp.bfloat16)),
+            experts_hit=int((sizes > 0).sum()), widest_gap_to_twin=gap,
+            ms_a_call=round(ms, 3), weights_once_ms=round(floor_ms, 3),
+            **({} if interpret else
+               {"share_of_floor": round(floor_ms / ms, 3)}))
+        del lhs, rhs, got, twin, out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
@@ -616,6 +673,9 @@ def main():
     ap.add_argument("--latent", action="store_true",
                     help="run only the latent (MLA) leg: the program that "
                          "hung a chip in PR 41; run it under timeout")
+    ap.add_argument("--grouped", action="store_true",
+                    help="run only the grouped expert product, alone, at "
+                         "the two MoE cells' shapes")
     args = ap.parse_args()
 
     import jax
@@ -649,6 +709,8 @@ def main():
 
     if args.latent:
         latent(args.tiny, args.seed)
+    elif args.grouped:
+        grouped_kernel_alone(args.tiny, args.seed)
     elif args.chips == 1:
         check_block_until_ready(args.tiny)
         serve(args.tiny, args.seed, dev)

@@ -29,6 +29,7 @@ _INSTRUMENT_MODULES = (
     "paddle_tpu.serving.quant",
     "paddle_tpu.serving.cp",
     "paddle_tpu.ops.pallas.paged_attention",
+    "paddle_tpu.ops.pallas.grouped_matmul",
     "paddle_tpu.train.trainer",
     "paddle_tpu.train.checkpoint",
     "paddle_tpu.train.elastic",

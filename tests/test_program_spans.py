@@ -839,6 +839,39 @@ def test_kernel_names_reach_the_tpu_lowering(case):
     assert set(scopes) == set(names), scopes
 
 
+def test_grouped_plan_leaves_a_breadcrumb_and_a_count_a_traced_site():
+    """The forward grouped product chooses its tiles when it is traced,
+    and says so there: ``grouped_matmul:bm<..>:bn<..>`` among the
+    kernels' breadcrumbs and ``moe_grouped_matmul_plans_total`` under the
+    same two labels, once a traced call site, not once a call."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    plans = METRICS.get("moe_grouped_matmul_plans_total")
+    assert plans.labelnames == ("block_m", "block_n")
+    x = jnp.ones((16, 32), jnp.float32)
+    w = jnp.ones((8, 32, 384), jnp.float32)
+    g = jnp.full((8,), 2, jnp.int32)
+    one = dict(block_m="8", block_n="128")
+    two = dict(block_m="8", block_n="32")
+    before = plans.value(**one), plans.value(**two)
+    pa._trace_events.clear()
+    fn = jax.jit(lambda a, b, c: gmm.grouped_matmul(a, b, c, impl="pallas"))
+    fn(x, w, g), fn(x, w, g)               # the second from the jit's cache
+    assert pa._trace_events == ["grouped_matmul:bm8:bn128"]
+    assert plans.value(**one) == before[0] + 1
+    # an expert MLP is two sites; the XLA twin and the dense path are none
+    pa._trace_events.clear()
+    down = jnp.ones((8, 192, 32), jnp.float32)
+    act = gmm.grouped_matmul(x, w, g, impl="pallas")[:, :192]
+    gmm.grouped_matmul(act, down, g, impl="pallas")
+    gmm.grouped_matmul(x, w, g, impl="xla")
+    gmm.grouped_matmul(x, w, g, impl="dense")
+    assert pa._trace_events == ["grouped_matmul:bm8:bn128",
+                                "grouped_matmul:bm8:bn32"]
+    assert (plans.value(**one), plans.value(**two)) == (
+        before[0] + 2, before[1] + 1)
+
+
 def test_model_parts_carry_their_scopes(model):
     """attention / mlp / norm / lm_head / sampler on the serving tick."""
     from paddle_tpu.models.paged import PagedKVCache, llama_decode_tick

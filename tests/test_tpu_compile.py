@@ -433,6 +433,19 @@ def _assert_sort_under_the_samplers_conditional(hlo):
     assert sorts and sorts <= stochastic - greedy, sorts
 
 
+def _assert_no_loop_outside_the_sampler(hlo):
+    """Every ``while`` of the program lies under the sampler's
+    conditional (its stochastic branch's draw): none in a layer's body."""
+    comps = _computations(hlo)
+    conds = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}"
+                       r".*/sampler/cond", hlo)
+    assert len(conds) == 1, conds
+    under = set().union(*(_reached(comps, b.strip().lstrip("%"))
+                          for b in conds[0].split(",")))
+    loops = {c for c, body in comps.items() if " while(" in body}
+    assert loops <= under, loops - under
+
+
 def _tiny_served(family):
     """(abstract model, abstract cache of 8 slots) of a served family on
     the slab tiling."""
@@ -607,6 +620,7 @@ GROUPED = [
     ("kimi_gate_up_tick", 256, 12, 7168, 4096, False),
     ("kimi_down_tick", 256, 12, 2048, 7168, False),
     ("kimi_gate_up_chunk", 2048, 12, 7168, 4096, False),
+    ("kimi_down_chunk", 2048, 12, 2048, 7168, False),
     ("kimi_down_chunk_all_pairs", 16384, 12, 2048, 7168, False),
     # trinitymini.serve.mixedlen: all 128 experts of 2048 x 1024 held; a
     # 32-slot tick's 256 pairs (two rows an expert) and a 2,048-token
@@ -626,7 +640,19 @@ def test_grouped_matmul_compiles(one_chip, case):
     if bwd:
         fn = jax.grad(lambda x, w, g: gmm(x, w, g).astype(f32).sum(),
                       argnums=(0, 1))
-    _compile(fn, one_chip, ((m, k), bf16), ((e, k, n), bf16), ((e,), i32))
+    text = _compile(fn, one_chip, ((m, k), bf16), ((e, k, n), bf16),
+                    ((e,), i32)).as_text()
+    # the kernel under the name the trace's readers match, inside the
+    # compiler's own scoped VMEM whatever tiles the shape chose, and the
+    # tile map beside it without a loop for the device
+    # (a sum's gradient needs no forward product: the backward's two,
+    # which autodiff's scopes wrap)
+    if bwd:
+        assert "grouped_matmul_dx" in text and "grouped_matmul_dw" in text
+    else:
+        assert re.search(r"%grouped_matmul(\.\d+)? = ", text)
+    assert '"scoped_memory_configs":[{' not in text
+    assert " while(" not in text
 
 
 # ---- latent attention (MLA) at Kimi-K2's sizes: 64 heads over rows of
@@ -759,6 +785,10 @@ def test_two_space_tick_and_chunk_programs_compile_with_their_kernels(
     assert text.count("paged_decode_attention") >= 5
     assert "grouped_matmul" in text and "s32[10]" in text
     assert "attention.window" in text and "attention.full" in text
+    # the grouped products' tile map is compares and sums: the tick holds
+    # no loop but what the sampler's stochastic branch may draw with
+    _assert_no_loop_outside_the_sampler(text)
+    assert '"scoped_memory_configs":[{' not in text
     layout = paged.prefill_staging(1, 2048, 160, True, True)
     args = _placed((model, S((layout.size,), i32), cache), one_chip)
     chunk = _program_for_the_chip(monkeypatch, paged._PREFILL_CHUNK_JIT,
